@@ -783,11 +783,12 @@ class Accelerator:
         _telemetry_from_env()
         # Persistent XLA compilation cache is default-ON (pipeline/
         # compile_cache.py): repeated runs load compiled executables instead
-        # of recompiling.  ACCELERATE_TPU_COMPILE_CACHE= (empty) disables,
-        # =/path redirects; hits surface as the jit.cache_hits counter.
-        from .pipeline.compile_cache import maybe_enable_compile_cache_from_env
+        # of recompiling.  JAX_COMPILATION_CACHE_DIR places it;
+        # ACCELERATE_TPU_COMPILE_CACHE= (empty) disables; hits surface as
+        # the jit.cache_hits counter.
+        from .pipeline.compile_cache import enable_compile_cache
 
-        maybe_enable_compile_cache_from_env()
+        enable_compile_cache()
         # ZeRO sharded weight update (ACCELERATE_TPU_ZERO=1): arm the XLA
         # latency-hiding scheduler flags before the TPU backend boots so the
         # per-leaf grad reduce-scatters overlap remaining backward compute.
@@ -1368,9 +1369,12 @@ class Accelerator:
         "Overload & failure handling" in ``docs/usage_guides/serving.md``.
 
         ``apply_cached``/``init_cache`` are a family's cached-inference pair
-        (``models/{gpt2,llama,mixtral}.py`` — fp or int8 KV); ``params`` stay
-        wherever the caller placed them (replicated params keep the decode
-        step mesh-shardable under GSPMD).  Geometry comes from a
+        (``models/{gpt2,llama,mixtral}.py`` — fp or int8 KV).  On a
+        multi-device mesh ``params`` that do not already live on the mesh's
+        devices are replicated over it (jit refuses arguments committed to
+        other devices than the installed mesh's, and a tree left on device 0
+        would be exactly that); params the caller sharded over the mesh stay
+        as placed.  Geometry comes from a
         :class:`~accelerate_tpu.serving.ServingConfig` (or its fields as
         keyword arguments)::
 
@@ -1389,6 +1393,18 @@ class Accelerator:
             raise ValueError("pass either a ServingConfig or its fields, not both")
         if serving is None:
             serving = ServingConfig(**serving_kwargs)
+        mesh = self.state.mesh
+        if mesh.size > 1:
+            from .parallel.sharding import replicated
+
+            mesh_devices = set(mesh.devices.flat)
+            on_mesh = replicated(mesh)
+            params = jax.tree_util.tree_map(
+                lambda x: x
+                if isinstance(x, jax.Array) and x.sharding.device_set == mesh_devices
+                else jax.device_put(x, on_mesh),
+                params,
+            )
         engine = ServingEngine(apply_cached, init_cache, params, config, serving=serving)
         # Graceful drain: an installed PreemptionGuard (enable_preemption_
         # handling) makes the engine stop admission and requeue-journal the

@@ -392,10 +392,6 @@ def apply_paged(
     k_pos = jnp.arange(total, dtype=jnp.int32)
     mask = positions[:, :, None] >= k_pos[None, None, :]  # [B, T, M*bs]
     use_kernel = kernel and not quant
-    if use_kernel:
-        from ..ops.pallas_attention import pallas_available
-
-        use_kernel = pallas_available()
 
     def body(carry, xs):
         if quant:

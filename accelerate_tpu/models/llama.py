@@ -149,6 +149,25 @@ class LlamaConfig:
         return cls(**defaults)
 
     @classmethod
+    def llama3_2_1b(cls, **kw) -> "LlamaConfig":
+        """Llama-3.2-1B as published (meta-llama/Llama-3.2-1B ``config.json``):
+        1.236 B parameters with the tied embedding counted once."""
+        defaults = dict(
+            vocab_size=128256,
+            hidden_size=2048,
+            intermediate_size=8192,
+            num_layers=16,
+            num_heads=32,
+            num_kv_heads=8,
+            head_dim=64,
+            max_seq_len=131072,
+            tie_embeddings=True,
+            rope_scaling=("llama3", 32.0, 1.0, 4.0, 8192),
+        )
+        defaults.update(kw)
+        return cls(**defaults)
+
+    @classmethod
     def llama3_8b(cls, **kw) -> "LlamaConfig":
         defaults = dict(
             vocab_size=128256,
@@ -294,7 +313,7 @@ from ..parallel.sharding import (  # noqa: E402
 def _sp_active() -> bool:
     """True when the installed global mesh has a >1 sequence-parallel axis."""
     m = _abstract_mesh()
-    return bool(m is not None and not m.empty and "sp" in m.axis_names and m.shape["sp"] > 1)
+    return "sp" in m.axis_names and m.shape["sp"] > 1
 
 
 def _sp_use_pallas(c, s: int, head_dim: int) -> bool:
@@ -305,17 +324,12 @@ def _sp_use_pallas(c, s: int, head_dim: int) -> bool:
     impl = getattr(c, "attention_impl", "auto")
     if impl == "pallas":
         return True
-    if impl != "auto":
+    if impl != "auto" or jax.default_backend() != "tpu":
         return False
-    try:
-        from ..ops.flash_attention import pick_block_pallas
-        from ..ops.pallas_attention import pallas_available
-    except ImportError:  # pragma: no cover
-        return False
-    if not pallas_available() or jax.default_backend() != "tpu":
-        return False
+    from ..ops.flash_attention import pick_block_pallas
+
     m = _abstract_mesh()
-    sp = m.shape["sp"] if m is not None and "sp" in m.axis_names else 1
+    sp = m.shape["sp"] if "sp" in m.axis_names else 1
     return s % sp == 0 and pick_block_pallas(s // sp, head_dim=head_dim) is not None
 
 
@@ -419,11 +433,7 @@ def _use_pallas(c: "LlamaConfig", s: int, b: int, h: int, kh: int) -> bool:
         return True
     if c.attention_impl != "auto" or s < 1024 or _flash_block(s) is None:
         return False
-    try:
-        from ..ops.pallas_attention import pallas_available
-    except ImportError:
-        return False
-    if not pallas_available() or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return False
     if jax.device_count() == 1:
         return True
@@ -536,7 +546,7 @@ def attention_block(x, p, c, mask, positions, kv_valid=None) -> jax.Array:
             )
         # On a sharded (non-sp) mesh the spmd wrapper runs the kernel
         # per-device under shard_map; trivial meshes take the plain call.
-        # Padded batches mask keys inside the kernel (round 5).
+        # Padded batches mask keys inside the kernel.
         attn = pallas_attention_spmd(q, k, v, causal=True, block_size=blk, kv_valid=kv_valid)
     elif mask is None and (
         c.attention_impl == "flash" or (c.attention_impl == "auto" and s >= 1024)
@@ -859,10 +869,6 @@ def apply_paged(
     k_pos = jnp.arange(total, dtype=jnp.int32)
     mask = positions[:, :, None] >= k_pos[None, None, :]  # [B, T, M*bs]
     use_kernel = kernel and not quant
-    if use_kernel:
-        from ..ops.pallas_attention import pallas_available
-
-        use_kernel = pallas_available()
 
     def body(carry, xs):
         if quant:

@@ -41,11 +41,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "pallas_attention",
@@ -53,14 +49,9 @@ __all__ = [
     "ring_attention_pallas",
     "pallas_paged_attention",
     "pallas_paged_window_attention",
-    "pallas_available",
 ]
 
 _NEG_INF = -1e30  # finite: avoids inf-inf NaNs inside the exp bookkeeping
-
-
-def pallas_available() -> bool:
-    return pltpu is not None
 
 
 def _vmem_spec(block_shape, index_map):
@@ -71,10 +62,9 @@ def _compiler_params():
     """batch/head/outer-tile grid dims are parallel (lets Mosaic split them
     across the two TensorCores on megacore chips); only the innermost
     accumulation dim is sequential."""
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams", None)
-    if cp is None:  # pragma: no cover
-        return None
-    return cp(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+    )
 
 
 def _causal_mask(s, iq, ik, blk_q, blk_k, rows_are_k=False):
@@ -86,6 +76,16 @@ def _causal_mask(s, iq, ik, blk_q, blk_k, rows_are_k=False):
         q_pos = iq * blk_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_pos = ik * blk_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _valid_row(kv_valid):
+    """[B, S] key validity as [B, 1, S]: Mosaic tiles the last two block dims
+    (8, 128), so a ``(1, blk_k)`` block over a 2-D ``[B, S]`` operand is
+    refused at lowering (B > 1) or aborts the compiler (B == 1); with the unit
+    dim second-to-last the block spans that whole dim and the lane dim stays
+    128-aligned.  The dK/dV kernel, whose score rows are keys, takes the
+    column form ``[B, S, 1]`` instead — the layouts lse/delta already use."""
+    return kv_valid[:, None, :]
 
 
 # ---------------------------------------------------------------------------
@@ -118,8 +118,7 @@ def _fwd_kernel(*refs,
         if causal:
             s = _causal_mask(s, iq, ik, blk_q, blk_k)
         if valid_ref is not None:
-            vmask = valid_ref[0] != 0  # [blk_k] key validity
-            s = jnp.where(vmask[None, :], s, _NEG_INF)
+            s = jnp.where(valid_ref[0] != 0, s, _NEG_INF)  # [1, blk_k] key validity
 
         m_prev = m_scr[:, :1]  # [blk_q, 1]
         l_prev = l_scr[:, :1]
@@ -157,7 +156,7 @@ def _fwd_kernel(*refs,
 
 
 def _flash_fwd(q, k, v, *, scale, causal, blk_q, blk_k, interpret, kv_valid=None):
-    """q: [B, H, S, d]; k, v: [B, K, S, d]; optional kv_valid [B, S] (int8
+    """q: [B, H, S, d]; k, v: [B, K, S, d]; optional kv_valid [B, S] (int32
     key validity).  Returns (out [B,H,S,d], lse [B,H,S])."""
     b, h, s, d = q.shape
     kh = k.shape[1]
@@ -170,7 +169,7 @@ def _flash_fwd(q, k, v, *, scale, causal, blk_q, blk_k, interpret, kv_valid=None
         _fwd_kernel, scale=scale, blk_q=blk_q, blk_k=blk_k, causal=causal, nk=nk,
         has_valid=has_valid,
     )
-    operands = [q, k, v] + ([kv_valid] if has_valid else [])
+    operands = [q, k, v] + ([_valid_row(kv_valid)] if has_valid else [])
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
@@ -178,7 +177,7 @@ def _flash_fwd(q, k, v, *, scale, causal, blk_q, blk_k, interpret, kv_valid=None
             _vmem_spec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             _vmem_spec((1, 1, blk_k, d), lambda ib, ih, iq, ik: (ib, ih // g, ik, 0)),
             _vmem_spec((1, 1, blk_k, d), lambda ib, ih, iq, ik: (ib, ih // g, ik, 0)),
-        ] + ([_vmem_spec((1, blk_k), lambda ib, ih, iq, ik: (ib, ik))] if has_valid else []),
+        ] + ([_vmem_spec((1, 1, blk_k), lambda ib, ih, iq, ik: (ib, 0, ik))] if has_valid else []),
         out_specs=[
             _vmem_spec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             _vmem_spec((1, 1, blk_q, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -229,7 +228,7 @@ def _bwd_dq_kernel(*refs, scale, blk_q, blk_k, causal, nk, has_valid=False):
         if causal:
             s = _causal_mask(s, iq, ik, blk_q, blk_k)
         if valid_ref is not None:
-            s = jnp.where((valid_ref[0] != 0)[None, :], s, _NEG_INF)
+            s = jnp.where(valid_ref[0] != 0, s, _NEG_INF)  # [1, blk_k]
         p = jnp.exp(s - lse)  # [blk_q, blk_k]
         if valid_ref is not None:
             # Empty (fully-masked) rows carry lse ~ -1e30, so exp(s - lse)
@@ -286,7 +285,7 @@ def _bwd_dkv_kernel(*refs, scale, blk_q, blk_k, causal, nq, has_valid=False):
             st = _causal_mask(st, iq, ik, blk_q, blk_k, rows_are_k=True)
         if valid_ref is not None:
             # rows are K here: mask invalid KEY rows (their dk/dv stay 0).
-            st = jnp.where((valid_ref[0] != 0)[:, None], st, _NEG_INF)
+            st = jnp.where(valid_ref[0] != 0, st, _NEG_INF)  # [blk_k, 1]
         pt = jnp.exp(st - lse)  # [blk_k, blk_q]
         if valid_ref is not None:
             # Same empty-row lse guard as the dq kernel, transposed.
@@ -345,13 +344,13 @@ def _flash_bwd(q, k, v, out, lse, do, *, scale, causal, blk_q, blk_k, interpret,
             _vmem_spec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             _vmem_spec((1, 1, blk_q, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             _vmem_spec((1, 1, blk_q, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        ] + ([_vmem_spec((1, blk_k), lambda ib, ih, iq, ik: (ib, ik))] if has_valid else []),
+        ] + ([_vmem_spec((1, 1, blk_k), lambda ib, ih, iq, ik: (ib, 0, ik))] if has_valid else []),
         out_specs=_vmem_spec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(*([q, k, v, do, lse_col, delta_col] + ([kv_valid] if has_valid else [])))
+    )(*([q, k, v, do, lse_col, delta_col] + ([_valid_row(kv_valid)] if has_valid else [])))
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, blk_q=blk_q, blk_k=blk_k, causal=causal, nq=nq,
@@ -368,7 +367,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, scale, causal, blk_q, blk_k, interpret,
             _vmem_spec((1, 1, blk_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
             _vmem_spec((1, 1, 1, blk_q), lambda ib, ih, ik, iq: (ib, ih, 0, iq)),
             _vmem_spec((1, 1, 1, blk_q), lambda ib, ih, ik, iq: (ib, ih, 0, iq)),
-        ] + ([_vmem_spec((1, blk_k), lambda ib, ih, ik, iq: (ib, ik))] if has_valid else []),
+        ] + ([_vmem_spec((1, blk_k, 1), lambda ib, ih, ik, iq: (ib, ik, 0))] if has_valid else []),
         out_specs=[
             _vmem_spec((1, 1, blk_k, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)),
             _vmem_spec((1, 1, blk_k, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)),
@@ -383,7 +382,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, scale, causal, blk_q, blk_k, interpret,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(*([q, k, v, do, lse_row, delta_row] + ([kv_valid] if has_valid else [])))
+    )(*([q, k, v, do, lse_row, delta_row] + ([kv_valid[:, :, None]] if has_valid else [])))
 
     if g > 1:
         dk = dk_h.reshape(b, kh, g, s, d).sum(axis=2)
@@ -447,8 +446,6 @@ def pallas_attention(
     auto-enables the Pallas interpreter off-TPU so the same tests run on the
     CPU mesh.
     """
-    if pltpu is None:
-        raise RuntimeError("jax.experimental.pallas.tpu unavailable")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s, h, d = q.shape
@@ -463,7 +460,7 @@ def pallas_attention(
     kk = k.transpose(0, 2, 1, 3)  # [B, K, S, d]
     vv = v.transpose(0, 2, 1, 3)
     scale = float(1.0 / np.sqrt(d))
-    valid = None if kv_valid is None else kv_valid.astype(jnp.int8)
+    valid = None if kv_valid is None else kv_valid.astype(jnp.int32)
     out = _mha(qh, kk, vv, valid, scale, causal, blk, blk, interpret)
     return out.transpose(0, 2, 1, 3)
 
@@ -505,7 +502,7 @@ def pallas_attention_spmd(
         from ..parallel.sharding import _abstract_mesh
 
         am = _abstract_mesh()
-        if am is not None and not am.empty and am.axis_names:
+        if not am.empty:
             mesh = am
     if mesh is None or mesh.size == 1:
         return pallas_attention(
@@ -537,7 +534,7 @@ def pallas_attention_spmd(
 
     return shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec, valid_spec), out_specs=spec
-    )(q, k, v, kv_valid.astype(jnp.int8))
+    )(q, k, v, kv_valid.astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -641,8 +638,6 @@ def pallas_paged_attention(
     blocks the tables actually name.  ``interpret=None`` auto-enables the
     Pallas interpreter off-TPU (the CPU test path).
     """
-    if pltpu is None:
-        raise RuntimeError("jax.experimental.pallas.tpu unavailable")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
@@ -784,8 +779,6 @@ def pallas_paged_window_attention(
     with ``groups*W`` effective groups.  ``W == 1`` degenerates to the
     single-token kernel's semantics exactly.
     """
-    if pltpu is None:
-        raise RuntimeError("jax.experimental.pallas.tpu unavailable")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, w, h, d = q.shape
@@ -963,8 +956,6 @@ def ring_attention_pallas(
     from .flash_attention import pick_block_pallas
     from .ring_attention import resolve_sp_mesh, shard_map, tp_head_axis
 
-    if pltpu is None:
-        raise RuntimeError("jax.experimental.pallas.tpu unavailable")
     mesh = resolve_sp_mesh(mesh, axis_name)
     if mesh is None:
         return pallas_attention(q, k, v, causal=causal, block_size=block_size, interpret=interpret)

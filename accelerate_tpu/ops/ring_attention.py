@@ -65,22 +65,12 @@ def tp_head_axis(mesh: Mesh, num_heads: int, num_kv_heads: int, extra_div: int =
         return "tp"
     return None
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # jax < 0.6 ships it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def shard_map(f, mesh, in_specs, out_specs):
-    # Replication/varying-axes checking is off: the bodies contain ops opaque
-    # to the checker (pallas_call outputs carry no vma annotation).  The kwarg
-    # was renamed check_rep -> check_vma across jax versions; try both.
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError as e_vma:
-        if "check_vma" not in str(e_vma):
-            raise  # genuine error from inside shard_map, not a kwarg mismatch
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    # Varying-axes checking is off: the bodies contain ops opaque to the
+    # checker (pallas_call outputs carry no vma annotation).
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def _block_attention(q, k, v, mask, m_prev, l_prev, o_prev, scale):
@@ -127,10 +117,10 @@ def full_sequence_attention(q, k, v, causal: bool = True, kv_valid=None, impl=No
 
     if impl == "pallas":
         from .flash_attention import pick_block_pallas
-        from .pallas_attention import pallas_attention, pallas_available
+        from .pallas_attention import pallas_attention
 
         blk = pick_block_pallas(s, head_dim=d)
-        if pallas_available() and blk is not None:
+        if blk is not None:
             return pallas_attention(
                 q, k, v, causal=causal, block_size=blk, kv_valid=kv_valid
             )
@@ -167,13 +157,7 @@ def _ring_body(
     # Mark accumulators device-varying over the ring axis so the fori_loop carry
     # type stays consistent (shard_map VMA rules).
     axes = tuple(vary_axes) or (axis_name,)
-    # (pvary was deprecated in jax 0.9 in favor of pcast(..., to="varying");
-    # keep the old spelling as a fallback, and on jax < 0.5 — which has no
-    # varying-axes type system at all — the marking is unnecessary, so skip.)
-    if hasattr(jax.lax, "pcast"):
-        m0, l0, o0 = (jax.lax.pcast(x, axes, to="varying") for x in (m0, l0, o0))
-    elif hasattr(jax.lax, "pvary"):
-        m0, l0, o0 = (jax.lax.pvary(x, axes) for x in (m0, l0, o0))
+    m0, l0, o0 = (jax.lax.pcast(x, axes, to="varying") for x in (m0, l0, o0))
 
     local_pos = jnp.arange(sq)
 

@@ -88,9 +88,15 @@ def _update_body(
     if health_ok is not None:
         ok = jnp.logical_and(ok, health_ok)
         health_norm = jnp.where(health_ok, health_norm, jnp.nan)
-    grads = jax.tree_util.tree_map(
-        lambda g: jnp.where(clip_value >= 0, jnp.clip(g, -clip_value, clip_value), g), grads
-    )
+    # The clip scalars are float32 ARRAYS: left as they are they promote a
+    # bf16 gradient tree (and, through optax's moments, the optimizer state)
+    # to float32 — a program whose outputs no longer alias its donated
+    # inputs, and a second compile when the wider state comes back in.
+    def _value_clip(g):
+        bound = clip_value.astype(g.dtype)
+        return jnp.where(clip_value >= 0, jnp.clip(g, -bound, bound), g)
+
+    grads = jax.tree_util.tree_map(_value_clip, grads)
     if norm_ndp:
         gnorm = chunked_global_norm(grads, norm_ndp, fence)
     else:
@@ -98,7 +104,7 @@ def _update_body(
     scale = jnp.where(
         clip_norm >= 0, jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-12)), 1.0
     )
-    grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+    grads = jax.tree_util.tree_map(lambda g: g * scale.astype(g.dtype), grads)
     if norm_ndp:
         grads = jax.tree_util.tree_map(
             lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads

@@ -32,27 +32,6 @@ DATA_AXES = ("dcn_dp", "dp", "fsdp")
 # Axes over which *weights* may be sharded.
 MODEL_AXES = ("fsdp", "pp", "ep", "tp")
 
-# jax < 0.5 has no AxisType (every axis is implicitly Auto there, which is
-# exactly the GSPMD-hint semantics we want); newer jax needs it spelled out.
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-
-def _auto_axis_types(n: int):
-    return (jax.sharding.AxisType.Auto,) * n if _HAS_AXIS_TYPES else None
-
-
-def _make_mesh(shape, axis_names):
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axis_names, axis_types=_auto_axis_types(len(axis_names)))
-    return jax.make_mesh(shape, axis_names)
-
-
-def _mesh_from_devices(dev_array, axis_names):
-    if _HAS_AXIS_TYPES:
-        return Mesh(dev_array, axis_names, axis_types=_auto_axis_types(len(axis_names)))
-    return Mesh(dev_array, axis_names)
-
-
 def mesh_axis_names() -> tuple[str, ...]:
     return tuple(ParallelismConfig.AXIS_ORDER)
 
@@ -74,55 +53,52 @@ def build_mesh(
     """Build the global mesh for ``cfg``.
 
     On real TPU topologies ``jax.make_mesh`` (mesh_utils under the hood) arranges
-    devices so that inner axes are ICI-contiguous; on the CPU simulation mesh the
-    arrangement is arbitrary (topology-free), which is fine for semantics tests.
+    devices so that inner axes are ICI-contiguous, and a shape it cannot lay
+    out is an error — a plain reshape there would silently drop the ICI-aware
+    layout.  The reshape of ``devices`` in enumeration order is kept for an
+    explicit ``devices=`` and for the topology-free CPU simulation mesh.
     """
     axis_names = mesh_axis_names()
     shape = tuple(getattr(cfg, a) for a in axis_names)
     # Auto axis types: shardings are GSPMD *hints* (with_sharding_constraint
     # propagates), not the assert semantics of Explicit mode.
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
     if devices is None:
-        try:
-            return _make_mesh(shape, axis_names)
-        except (ValueError, RuntimeError):
-            devices = jax.devices()
+        if jax.default_backend() != "cpu":
+            return jax.make_mesh(shape, axis_names, axis_types=axis_types)
+        devices = jax.devices()
     n = int(np.prod(shape))
     if len(devices) < n:
         raise ValueError(f"Need {n} devices for mesh {dict(zip(axis_names, shape))}, have {len(devices)}")
     dev_array = np.asarray(devices[:n]).reshape(shape)
-    return _mesh_from_devices(dev_array, axis_names)
+    return Mesh(dev_array, axis_names, axis_types=axis_types)
 
 
 def local_mesh_shape(mesh: Mesh) -> dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
 
-def trivial_mesh() -> Mesh:
-    """A 1-device mesh with every named axis at size 1 — used to reset the global
-    mesh context so sharding constraints become no-ops."""
-    names = mesh_axis_names()
-    dev = np.asarray(jax.devices()[:1]).reshape((1,) * len(names))
-    return _mesh_from_devices(dev, names)
-
-
-# jax < 0.5 has no jax.set_mesh; the Mesh object itself is the (thread-local,
-# stack-based) global-mesh context manager.  Keep the entered mesh here and
-# swap strictly exit-then-enter so the stack never grows past one extra frame.
-_ACTIVE_LEGACY_MESH: Optional[Mesh] = None
+# The handle of the mesh this module installed with ``jax.set_mesh`` — its
+# ``__exit__`` restores whatever was in context before (normally nothing).
+# jax keeps that context per thread: install and reset on the same thread (the
+# state singletons are built and reset on the main one).
+_INSTALLED: Optional[jax.set_mesh] = None
 
 
 def install_global_mesh(mesh: Mesh) -> None:
     """Install ``mesh`` as the global mesh context so bare-``PartitionSpec``
-    sharding constraints inside model code resolve against it."""
-    global _ACTIVE_LEGACY_MESH
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-        return
-    if _ACTIVE_LEGACY_MESH is not None:
-        _ACTIVE_LEGACY_MESH.__exit__(None, None, None)
-    mesh.__enter__()
-    _ACTIVE_LEGACY_MESH = mesh
+    sharding constraints inside model code resolve against it.  Replaces a
+    mesh installed earlier by this function."""
+    global _INSTALLED
+    reset_global_mesh()
+    _INSTALLED = jax.set_mesh(mesh)
 
 
 def reset_global_mesh() -> None:
-    install_global_mesh(trivial_mesh())
+    """Take the installed mesh out of context.  Nothing is left behind: jit
+    refuses arguments committed to devices other than the context mesh's, so
+    a stale one-device mesh would poison every later multi-device program."""
+    global _INSTALLED
+    if _INSTALLED is not None:
+        _INSTALLED.__exit__(None, None, None)
+        _INSTALLED = None

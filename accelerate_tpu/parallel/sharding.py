@@ -76,29 +76,7 @@ def in_manual_region() -> bool:
 
 
 def _abstract_mesh():
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:  # older jax
-        from jax._src import mesh as _mesh_lib
-
-        try:
-            ctx = _mesh_lib.get_abstract_mesh()
-        except Exception:
-            ctx = None
-        if isinstance(ctx, tuple):
-            # jax < 0.5: get_abstract_mesh returns a context STACK tuple
-            # (usually empty — Mesh.__enter__ does not feed it).
-            ctx = ctx[-1] if ctx else None
-        if ctx is not None:
-            return ctx
-        # jax < 0.5 keeps the entered global mesh on the thread-resources env;
-        # a concrete Mesh duck-types the AbstractMesh surface we read
-        # (.empty / .shape / .axis_names).
-        try:
-            physical = _mesh_lib.thread_resources.env.physical_mesh
-        except Exception:
-            return None
-        return None if physical.empty else physical
+    return jax.sharding.get_abstract_mesh()
 
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:
@@ -113,7 +91,7 @@ def constrain(x: jax.Array, spec: P) -> jax.Array:
         # naming a manual axis would be an error.
         return x
     m = _abstract_mesh()
-    if m is None or m.empty or not m.axis_names:
+    if m.empty:
         return x
 
     def prune(dim):
@@ -167,8 +145,6 @@ def embed_lookup(table: jax.Array, input_ids: jax.Array, dtype) -> jax.Array:
     m = _abstract_mesh()
     if (
         not single_token
-        and m is not None
-        and not m.empty
         and any(dict(m.shape).get(a, 1) > 1 for a in ("fsdp", "tp"))
     ):
         one_hot = jax.nn.one_hot(input_ids, table.shape[0], dtype=dtype)

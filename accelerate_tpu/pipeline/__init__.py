@@ -15,20 +15,18 @@ Two coupled pieces (see ``docs/usage_guides/performance.md``):
   bit-exact numerics.
 
 Plus the **persistent XLA compilation cache** (``compile_cache.py``),
-default-on via ``ACCELERATE_TPU_COMPILE_CACHE`` so repeated runs skip the
-multi-minute warmup compile entirely, and the **CPU-tier perf-regression
+default-on and placed by ``JAX_COMPILATION_CACHE_DIR`` so repeated runs skip
+the multi-minute warmup compile entirely, and the **CPU-tier perf-regression
 gate** (``perf_gate.py``, ``make perf-gate``) that asserts the fused-path
 invariants — 1 dispatch/step, the fused-vs-eager speedup, bounded
 host-blocked time — against a committed baseline inside tier-1, so the
-wins above cannot silently rot while the TPU backend is unreachable.
+wins above cannot silently rot between chip runs.
 """
 
 from .compile_cache import (
     DEFAULT_COMPILE_CACHE_DIR,
     ENV_COMPILE_CACHE,
-    compile_cache_dir_from_env,
     enable_compile_cache,
-    maybe_enable_compile_cache_from_env,
 )
 from .prefetch import (
     ENV_PREFETCH,
@@ -53,8 +51,6 @@ __all__ = [
     "TrainStep",
     "make_train_step",
     "enable_compile_cache",
-    "maybe_enable_compile_cache_from_env",
-    "compile_cache_dir_from_env",
     "ENV_COMPILE_CACHE",
     "DEFAULT_COMPILE_CACHE_DIR",
 ]

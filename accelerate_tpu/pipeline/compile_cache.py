@@ -1,26 +1,32 @@
-"""Persistent XLA compilation cache — default-on.
+"""Persistent XLA compilation cache — default-on, placed from outside.
 
 Warmup compiles are the dominant startup cost of a large GSPMD program
 (minutes at scale); XLA can serialize compiled executables and re-load them
-keyed by (HLO, flags, topology).  This module turns that cache on by default
-for every :class:`Accelerator` run:
+keyed by (HLO, flags, topology).  :func:`enable_compile_cache` turns that
+cache on for both entry points (``Accelerator.__init__`` and
+``ServingEngine.__init__``):
 
-- ``ACCELERATE_TPU_COMPILE_CACHE`` unset → cache at
-  ``~/.cache/accelerate_tpu/xla_cache`` (created on demand);
-- ``ACCELERATE_TPU_COMPILE_CACHE=/path`` → cache there;
-- ``ACCELERATE_TPU_COMPILE_CACHE=`` (set but empty) → cache OFF.
+- ``JAX_COMPILATION_CACHE_DIR`` set → jax itself reads that variable; this
+  module never touches ``jax_compilation_cache_dir`` then, so whoever runs the
+  program decides where the cache lives;
+- unset → one fixed directory inside the checkout, ``<repo>/.jax_cache``
+  (git-ignored).  The path is part of the cache key, so it is never derived
+  from ``$HOME``, a temporary name, a pid or a time;
+- ``ACCELERATE_TPU_COMPILE_CACHE=`` (set but empty) → cache OFF (the test
+  suite's hermeticity switch).
 
 Because the cache is default-on (and caches every program, however small),
 the directory is bounded: jax's LRU eviction is configured to
 ``ACCELERATE_TPU_COMPILE_CACHE_MAX_BYTES`` (default 1 GiB; ``0`` or negative
-→ unbounded) so long-lived dev machines and shared ``$HOME`` filesystems
-never grow it without limit.
+→ unbounded).
 
 Cache *hits* are surfaced through the telemetry compile counters: jax emits a
 ``/jax/compilation_cache/cache_hits`` monitoring event per hit, which
-telemetry's listener tallies as ``jit.cache_hits`` next to the existing
-``jit.compiles`` miss counter (every backend compile is, by definition, a
-persistent-cache miss).
+telemetry's listener tallies as ``jit.cache_hits`` next to ``jit.compiles``.
+The latter counts every compile REQUEST that missed the in-memory jit cache —
+jax's event wraps its compile-or-get-cached, so a persistent-cache hit is
+counted there too — and the cache's misses are ``jit.compiles -
+jit.cache_hits``.
 """
 
 from __future__ import annotations
@@ -33,32 +39,17 @@ __all__ = [
     "ENV_COMPILE_CACHE_MAX_BYTES",
     "DEFAULT_COMPILE_CACHE_DIR",
     "DEFAULT_COMPILE_CACHE_MAX_BYTES",
-    "compile_cache_dir_from_env",
     "compile_cache_max_bytes_from_env",
     "enable_compile_cache",
-    "maybe_enable_compile_cache_from_env",
 ]
 
 ENV_COMPILE_CACHE = "ACCELERATE_TPU_COMPILE_CACHE"
 ENV_COMPILE_CACHE_MAX_BYTES = "ACCELERATE_TPU_COMPILE_CACHE_MAX_BYTES"
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "accelerate_tpu", "xla_cache"
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
 )
 DEFAULT_COMPILE_CACHE_MAX_BYTES = 1 << 30  # 1 GiB LRU bound
-
-_applied_dir: Optional[str] = None
-
-
-def compile_cache_dir_from_env() -> Optional[str]:
-    """Resolve the cache directory from the environment: ``None`` means
-    explicitly disabled (env set to empty), otherwise the directory to use."""
-    raw = os.environ.get(ENV_COMPILE_CACHE)
-    if raw is None:
-        return DEFAULT_COMPILE_CACHE_DIR
-    raw = raw.strip()
-    if not raw:
-        return None
-    return os.path.expanduser(raw)
 
 
 def compile_cache_max_bytes_from_env() -> int:
@@ -80,51 +71,37 @@ def compile_cache_max_bytes_from_env() -> int:
     return max_bytes if max_bytes > 0 else -1
 
 
-def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir`` (default:
-    the env-resolved directory).  Returns the active directory, or ``None``
-    when the cache is disabled.  Idempotent; never raises — a read-only
-    filesystem must not take down training, it just forfeits the cache."""
-    global _applied_dir
-    if cache_dir is None:
-        cache_dir = compile_cache_dir_from_env()
-    if cache_dir is None:
+def enable_compile_cache() -> Optional[str]:
+    """Turn jax's persistent compilation cache on.  Returns the active
+    directory, or ``None`` when the cache is disabled.  Idempotent; a
+    filesystem that refuses the directory forfeits the cache with a warning
+    instead of taking down the run."""
+    if os.environ.get(ENV_COMPILE_CACHE, "on").strip() == "":
         return None
-    if _applied_dir == cache_dir:
-        return _applied_dir
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # Cache every program: the default 1s floor skips exactly the small
-        # programs a CPU-smoke run compiles, and at TPU scale everything
-        # worth running clears 1s anyway.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # ...but a default-on cache-everything policy needs a bound, or the
-        # directory grows forever on long-lived machines: LRU-evict past
-        # the configured size (default 1 GiB).
-        jax.config.update(
-            "jax_compilation_cache_max_size", compile_cache_max_bytes_from_env()
-        )
-        # jax latches "cache unused/initialized" on the FIRST compile; a
-        # process that already compiled something (warmup, an earlier
-        # Accelerator with the cache off) must reset that latch or the new
-        # dir is silently ignored.
+    placed_outside = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    if not placed_outside and jax.config.jax_compilation_cache_dir != DEFAULT_COMPILE_CACHE_DIR:
+        try:
+            os.makedirs(DEFAULT_COMPILE_CACHE_DIR, exist_ok=True)
+        except OSError as e:
+            import warnings
+
+            warnings.warn(f"persistent compilation cache unavailable ({e}); continuing without it")
+            return None
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+        # jax latches "cache unused" on the FIRST compile; a process that
+        # already compiled something must reset that latch or the directory
+        # set just now is silently ignored.
         from jax.experimental.compilation_cache import compilation_cache as _cc
 
         _cc.reset_cache()
-    except Exception as e:  # pragma: no cover - fs/backend specific
-        import warnings
-
-        warnings.warn(f"persistent compilation cache unavailable ({e}); continuing without it")
-        return None
-    _applied_dir = cache_dir
-    return _applied_dir
-
-
-def maybe_enable_compile_cache_from_env() -> Optional[str]:
-    """Default-on hook called by ``Accelerator.__init__``: enable the cache
-    unless ``$ACCELERATE_TPU_COMPILE_CACHE`` is set to the empty string."""
-    return enable_compile_cache()
+    # Cache every program: the default 1s floor skips exactly the small
+    # programs a CPU-smoke run compiles, and at TPU scale everything worth
+    # running clears 1s anyway.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # ...but a default-on cache-everything policy needs a bound, or the
+    # directory grows forever on long-lived machines.
+    jax.config.update("jax_compilation_cache_max_size", compile_cache_max_bytes_from_env())
+    return jax.config.jax_compilation_cache_dir

@@ -300,13 +300,15 @@ class TrainStep:
             opt = self.optimizer
             opt.opt_state, _ = zero_mod.shard_opt_state(opt.opt_state, mesh)
             opt_sh = zero_mod.opt_state_shardings(opt.opt_state, mesh)
-            # Donate params ONLY: donating params AND opt state together into
-            # the shard_map program deterministically corrupts the XLA CPU
-            # runtime heap (segfault after a few steps on jaxlib 0.4.x;
-            # either donation alone is clean).  The un-donated opt-state copy
-            # is dp-fold smaller under ZeRO than the replicated state it
-            # replaces, so the transient costs less HBM than the feature
-            # saves.
+            # Donate params ONLY.  Donating params AND opt state together into
+            # the shard_map program segfaulted the XLA CPU runtime after a few
+            # steps on the jaxlib this was written against (0.4.x).  On the
+            # installed 0.9.0 it does not reproduce (PR 21: tests/test_zero.py
+            # and a 40-step soak on eight virtual CPU devices, both donated);
+            # the restriction stays until the wider donation has run on a chip.
+            # The un-donated opt-state copy is dp-fold smaller under ZeRO than
+            # the replicated state it replaces, so the transient costs less
+            # HBM than the feature saves.
             donate = (0,)
             param_sh = jax.tree_util.tree_map(
                 lambda x: x.sharding
@@ -377,7 +379,6 @@ class TrainStep:
         where ``shard_grads`` is the dp-sharded global gradient tree and
         ``losses`` matches the unsharded step's shape (scalar, or [accum]).
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..parallel import zero as zero_mod
@@ -476,12 +477,12 @@ class TrainStep:
                 jax.tree_util.tree_map(batch_spec, b) for b in batches
             )
             with manual_region():
-                shards, lane_losses = shard_map(
+                shards, lane_losses = jax.shard_map(
                     wrapped,
                     mesh=mesh,
                     in_specs=in_specs,
                     out_specs=(pspecs, lane_losses_spec),
-                    check_rep=False,
+                    check_vma=False,
                 )(params, *batches)
             losses = jnp.mean(lane_losses, axis=0)
             if accum == 1:
@@ -513,7 +514,9 @@ class TrainStep:
 
     # -- execution ------------------------------------------------------------
 
-    def __call__(self, *batches):
+    def _window(self, batches) -> tuple:
+        """Validate one call's micro-batches and normalize each to the
+        ``(args, kwargs)`` the prepared model is called with."""
         from ..accelerator import _torch_to_jax_tree
 
         # Only a LIST unpacks as the accumulation window: a tuple is a valid
@@ -529,9 +532,36 @@ class TrainStep:
                 "in one call as a LIST of micro-batches (a tuple is treated as "
                 "one positional-args micro-batch)."
             )
-        batches = tuple(
-            _as_args_kwargs(_torch_to_jax_tree(b)) for b in batches
+        return tuple(_as_args_kwargs(_torch_to_jax_tree(b)) for b in batches)
+
+    def _jit_args(self, batches: tuple, clip_norm, clip_value) -> tuple:
+        jit_args = (
+            self.model.params,
+            self.optimizer.opt_state,
+            batches,
+            jnp.asarray(clip_norm if clip_norm is not None else -1.0, jnp.float32),
+            jnp.asarray(clip_value if clip_value is not None else -1.0, jnp.float32),
         )
+        if self._poison_armed:
+            from ..resilience import faultinject
+
+            poison = faultinject.grad_poison_scale(self.optimizer._step_count + 1)
+            jit_args = jit_args + (
+                jnp.asarray(1.0 if poison is None else poison, jnp.float32),
+            )
+        return jit_args
+
+    def lower(self, *batches):
+        """Lower the fused program for this window without running it (the
+        ``jax.jit(f).lower(...)`` idiom): ``.compile()`` the result to read
+        the executable's ``as_text()`` / ``memory_analysis()`` — what kernels
+        and collectives the step really holds.  Nothing is donated."""
+        batches = self._window(batches)
+        self._build_jit()
+        return self._jit.lower(*self._jit_args(batches, None, None))
+
+    def __call__(self, *batches):
+        batches = self._window(batches)
         self._build_jit()
         opt = self.optimizer
         # Clip resolution mirrors the eager update: one-shot arms win once,
@@ -548,20 +578,7 @@ class TrainStep:
         )
         opt._clip_norm_once = None
         opt._clip_value_once = None
-        jit_args = (
-            self.model.params,
-            opt.opt_state,
-            batches,
-            jnp.asarray(clip_norm if clip_norm is not None else -1.0, jnp.float32),
-            jnp.asarray(clip_value if clip_value is not None else -1.0, jnp.float32),
-        )
-        if self._poison_armed:
-            from ..resilience import faultinject
-
-            poison = faultinject.grad_poison_scale(opt._step_count + 1)
-            jit_args = jit_args + (
-                jnp.asarray(1.0 if poison is None else poison, jnp.float32),
-            )
+        jit_args = self._jit_args(batches, clip_norm, clip_value)
         self._maybe_introspect(jit_args)
         try:
             with _span("pipeline.train_step"):

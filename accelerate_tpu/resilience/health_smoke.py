@@ -154,10 +154,10 @@ def _child(role: str, ckpt_root: str, out_path: str, extra_env: dict) -> dict:
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
     # Hermetic compile cache: shared between this run's children (warm
-    # recompiles) but never the user-global ~/.cache one — a child killed
-    # mid-write must not be able to tear state later runs deserialize.
+    # recompiles) but never the checkout's — a child killed mid-write must
+    # not be able to tear state later runs deserialize.
     env.setdefault(
-        "ACCELERATE_TPU_COMPILE_CACHE", os.path.join(os.path.dirname(out_path), "xla_cache")
+        "JAX_COMPILATION_CACHE_DIR", os.path.join(os.path.dirname(out_path), "xla_cache")
     )
     env.update(extra_env)
     cmd = [

@@ -315,6 +315,12 @@ class ServingEngine:
             raise ValueError(f"spec_tokens must be >= 0, got {sc.spec_tokens}")
         if sc.host_blocks < 0:
             raise ValueError(f"host_blocks must be >= 0, got {sc.host_blocks}")
+        # Every table-width bucket compiles on first use, in a request's
+        # latency path: a bare engine gets the persistent cache the
+        # Accelerator entry point turns on.
+        from ..pipeline.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         self._apply_cached = apply_cached
         self._config = config
         self.params = params

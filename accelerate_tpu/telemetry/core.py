@@ -8,7 +8,7 @@ file handles, no listeners firing, no records.
 JSONL schema (one record per line, ``telemetry_p<process>.jsonl``):
 
 - ``{"kind": "span", "name", "path", "depth", "dur_ms", "t", "proc", ...}``
-- ``{"kind": "compile", "dur_ms", ...}`` — one per XLA backend compile (cache miss)
+- ``{"kind": "compile", "dur_ms", ...}`` — one per compile request that missed the in-memory jit cache
 - ``{"kind": "stall", "elapsed_s", "deadline_s", "threads", ...}``
 - ``{"kind": "event", "name", ...}`` — ad-hoc markers
 - ``{"kind": "metrics", "snapshot": {...}}`` — final registry dump on disable/exit
@@ -359,9 +359,9 @@ def _install_compile_listener():
 
     # Persistent-compilation-cache hits (pipeline/compile_cache.py): jax
     # records one event per executable loaded from the cache instead of
-    # compiled.  Every backend compile (counted above) is by definition a
-    # cache MISS, so jit.cache_hits/jit.compiles together are the cache's
-    # hit/miss ledger.
+    # compiled.  The compile event above fires for those loads too (it wraps
+    # jax's compile-or-get-cached), so jit.compiles counts REQUESTS and the
+    # cache's misses are jit.compiles - jit.cache_hits.
     def _on_event(event, **kwargs):
         tel = _TELEMETRY
         if not tel.enabled or event != CACHE_HIT_EVENT:

@@ -1,7 +1,7 @@
 """Black-box flight recorder: a durable timeline of the training hot path.
 
 Telemetry (``core.py``) answers "how is the run doing" *while the process is
-alive*; when a TPU run dies (preemption, OOM kill, a wedged tunnel, an
+alive*; when a TPU run dies (preemption, OOM kill, a hung device runtime, an
 unhandled exception) the in-process registry evaporates with it and the
 postmortem starts from nothing.  The flight recorder is the black box: a
 bounded ring buffer of structured per-step events — step time, dispatches per

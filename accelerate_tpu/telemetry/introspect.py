@@ -146,7 +146,7 @@ def estimate_comms_compute_ratio(
         return None
     try:
         peak = peak_flops_per_chip(device)
-    except Exception:
+    except ValueError:  # device not in the peak table: no estimate
         return None
     compute_s = flops / peak
     comm_s = float(comm_bytes) / _ici_bandwidth(device)
@@ -160,9 +160,6 @@ def _cost_analysis(compiled) -> dict:
         ca = compiled.cost_analysis()
     except Exception:
         return {}
-    # jax < 0.5 returns a per-computation list; newer returns one dict.
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return dict(ca) if ca else {}
 
 

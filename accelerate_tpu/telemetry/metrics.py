@@ -35,7 +35,9 @@ __all__ = [
     "peak_flops_per_chip",
 ]
 
-# jax.monitoring key emitted once per XLA backend compile (cache hits skip it).
+# jax.monitoring key emitted once per compile REQUEST that missed the in-memory
+# jit cache — a real backend compile, or (with the duration of the load) an
+# executable the persistent cache returned.
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # jax.monitoring event recorded once per persistent-compilation-cache hit
@@ -207,11 +209,12 @@ _PEAK_FLOPS_TABLE = (
     ("v6", 918e12),
     ("trillium", 918e12),
 )
-_DEFAULT_PEAK_FLOPS = 197e12  # conservative default
 
 
 def peak_flops_per_chip(device=None) -> float:
-    """bf16 peak FLOP/s for one chip of ``device``'s kind (default: device 0)."""
+    """bf16 peak FLOP/s for one chip of ``device``'s kind (default: device 0).
+    A device kind the table does not list is a ``ValueError``: a utilization
+    against a guessed peak is not a measurement."""
     if device is None:
         import jax
 
@@ -220,7 +223,10 @@ def peak_flops_per_chip(device=None) -> float:
     for key, flops in _PEAK_FLOPS_TABLE:
         if key in kind:
             return flops
-    return _DEFAULT_PEAK_FLOPS
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device.device_kind!r} "
+        f"(table: {[k for k, _ in _PEAK_FLOPS_TABLE]})"
+    )
 
 
 def collect_hbm(registry: MetricsRegistry, device=None) -> dict:
@@ -230,7 +236,7 @@ def collect_hbm(registry: MetricsRegistry, device=None) -> dict:
     constraint, since the first chip to fill kills the whole SPMD program).
 
     ``hbm.stats_available`` is always published (1/0) so a dashboard can
-    tell "no data" (CPU builds and tunnels return no ``memory_stats()``)
+    tell "no data" (CPU builds return no ``memory_stats()``)
     from "zero bytes"; the byte gauges only exist where stats do.
     """
     try:
@@ -342,15 +348,17 @@ class StepTimer:
                     self.registry.gauge("step.mfu").set(
                         flops / dt / peak_flops_per_chip()
                     )
-            except Exception:
-                pass
+            except ValueError:
+                pass  # device not in the peak table: publish no MFU
         self._last = now
         return dt
 
 
 class CompileWatcher:
-    """Standalone compile counter: registers a ``jax.monitoring`` duration
-    listener and tallies backend compiles between construction and ``stop()``.
+    """Standalone compile counter: registers ``jax.monitoring`` listeners and
+    tallies compile requests (``count``/``total_ms``) and, among them, the
+    ones the persistent cache answered (``cache_hits``) between construction
+    and ``stop()``.
 
     jax has no per-listener unregister, so the listener stays installed but
     goes inert after ``stop()`` — construct sparingly (one per process is the
@@ -359,6 +367,7 @@ class CompileWatcher:
     def __init__(self):
         self.count = 0
         self.total_ms = 0.0
+        self.cache_hits = 0
         self._active = True
         from jax import monitoring
 
@@ -367,7 +376,12 @@ class CompileWatcher:
                 self.count += 1
                 self.total_ms += duration * 1e3
 
+        def _on_event(event, **kwargs):
+            if self._active and event == CACHE_HIT_EVENT:
+                self.cache_hits += 1
+
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
 
     def stop(self):
         self._active = False

@@ -44,7 +44,7 @@ __all__ = [
     "INFEED",
 ]
 
-# Bucket names (the taxonomy the attribution report speaks).  Idle time is
+# Bucket names (the vocabulary the attribution report speaks).  Idle time is
 # derived (window minus device-busy), not a per-op bucket.
 COMPUTE = "compute"
 COLLECTIVE = "collective"

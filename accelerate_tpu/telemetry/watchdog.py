@@ -1,6 +1,6 @@
 """Stall watchdog: warn (with a thread dump) when no step completes in time.
 
-A wedged device tunnel, a deadlocked collective, or a host-side data stall all
+A hung device runtime, a deadlocked collective, or a host-side data stall all
 present the same way — the training loop simply stops making progress, inside
 a C call no Python-level timeout can interrupt.  The watchdog runs on a
 daemon thread, fed heartbeats by the instrumented hot paths

@@ -7,10 +7,6 @@ import os
 if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-from accelerate_tpu.state import honor_cpu_platform_env
-
-honor_cpu_platform_env()
-
 import numpy as np
 
 import jax

@@ -54,6 +54,8 @@ def test_cli_help_and_env_command():
     assert res.returncode == 0, res.stderr
     assert "JAX version" in res.stdout
     assert "accelerate_tpu version" in res.stdout
+    # Reported in-process (a probe child would need the chip its parent holds).
+    assert "- JAX backend: cpu" in res.stdout and "- Device kind: cpu" in res.stdout
 
 
 def test_merge_weights_roundtrip(tmp_path):
@@ -139,6 +141,42 @@ def test_local_state_dict_on_two_process_cluster():
     """LOCAL_STATE_DICT across two OS processes: each rank dumps only its
     own shards and restores them exactly (the topology-bound contract)."""
     _run_cluster_worker("run_local_state_dict_roundtrip", "LOCAL_SD_OK", timeout=300)
+
+
+def test_launch_parent_holds_no_backend_when_it_spawns(tmp_path):
+    """One process for each chip: a parent that has touched JAX holds the
+    chip, and the child that needs it then fails or hangs.  On one host
+    ``accelerate-tpu launch`` starts exactly one child and, at that moment,
+    has initialised no backend itself; the child builds its Accelerator."""
+    (tmp_path / "three_lines.py").write_text(
+        "from accelerate_tpu import Accelerator\n"
+        "acc = Accelerator()\n"
+        "print('LAUNCHED', acc.state.platform, acc.state.num_devices, flush=True)\n"
+    )
+    (tmp_path / "spy.py").write_text(
+        "import subprocess, sys\n"
+        "real_run, spawns = subprocess.run, []\n"
+        "def spying_run(cmd, **kw):\n"
+        "    import jax._src.xla_bridge as xb\n"
+        "    spawns.append(cmd)\n"
+        "    print('PARENT_BACKENDS', sorted(xb._backends), flush=True)\n"
+        "    return real_run(cmd, **kw)\n"
+        "subprocess.run = spying_run\n"
+        "from accelerate_tpu.commands.accelerate_cli import main\n"
+        "sys.argv = ['accelerate-tpu', 'launch', 'three_lines.py']\n"
+        "main()\n"
+        "print('SPAWNS', len(spawns), flush=True)\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu", ACCELERATE_TPU_COMPILE_CACHE="")
+    env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")
+    res = subprocess.run(
+        [sys.executable, "spy.py"], cwd=tmp_path, env=env,
+        capture_output=True, text=True, timeout=180,
+    )
+    assert res.returncode == 0, res.stderr[-1500:]
+    assert "PARENT_BACKENDS []" in res.stdout, res.stdout
+    assert "SPAWNS 1" in res.stdout
+    assert "LAUNCHED cpu" in res.stdout
 
 
 def test_launch_module_flag(tmp_path):

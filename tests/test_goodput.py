@@ -141,8 +141,8 @@ def test_retry_waits_split_by_label():
                error="OSError: disk")
     )
     led.observe_record(
-        _event("resilience.retry", 3.0, label="bench.device_probe", wait_s=0.25,
-               error="TimeoutError: tunnel")
+        _event("resilience.retry", 3.0, label="device.alloc", wait_s=0.25,
+               error="TimeoutError: busy")
     )
     led.observe_record(
         _event("resilience.gave_up", 4.0, label="alloc",

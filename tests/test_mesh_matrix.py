@@ -18,21 +18,11 @@ from accelerate_tpu.models import llama, mixtral
 from accelerate_tpu.parallel.sharding import data_sharding, shard_params
 from accelerate_tpu.state import AcceleratorState
 
-# Pre-existing (seed) numeric bug: sp composed with a second model-sharding
-# axis on a 3-axis mesh NaNs the loss (tp2xsp4 reproduces it too; ring
-# attention probed clean in isolation — the divergence is in the composed
-# llama/mixtral step, not the kernel).  Tracked as xfail so tier-1 output
-# stays readable; strict so a fix surfaces as XPASS.
-_SP_COMPOSED_NAN = pytest.mark.xfail(
-    reason="pre-existing: sp x {tp,ep} 3-axis composition NaNs the loss (seed bug)",
-    strict=True,
-)
-
 LLAMA_MESHES = [
     dict(fsdp=2, sp=4),
     dict(fsdp=4, tp=2),
-    pytest.param(dict(tp=2, sp=2, dp=2), marks=_SP_COMPOSED_NAN),
-    pytest.param(dict(fsdp=2, tp=2, sp=2), marks=_SP_COMPOSED_NAN),
+    dict(tp=2, sp=2, dp=2),
+    dict(fsdp=2, tp=2, sp=2),
     dict(dp=4, tp=2),
     dict(pp=2, fsdp=2, dp=2),
     # ~13s; tier-1 budget rebalance (PR 18) — pp2xfsdp2xdp2 keeps pp-composed
@@ -44,7 +34,7 @@ MIXTRAL_MESHES = [
     # ~12s; tier-1 budget rebalance (PR 18) — ep2xfsdp2xdp2 keeps ep-composed
     # coverage in tier-1.
     pytest.param(dict(ep=4, tp=2), marks=pytest.mark.slow),
-    pytest.param(dict(ep=2, sp=2, dp=2), marks=_SP_COMPOSED_NAN),
+    dict(ep=2, sp=2, dp=2),
 ]
 
 

@@ -10,9 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from accelerate_tpu.ops.pallas_attention import pallas_attention, pallas_available
-
-pytestmark = pytest.mark.skipif(not pallas_available(), reason="pallas tpu backend missing")
+from accelerate_tpu.ops.pallas_attention import pallas_attention
 
 
 def _dense_reference(q, k, v, causal=True):

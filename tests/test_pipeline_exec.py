@@ -40,7 +40,6 @@ from accelerate_tpu.pipeline import (
 from accelerate_tpu.pipeline import compile_cache as compile_cache_mod
 from accelerate_tpu.pipeline.compile_cache import (
     DEFAULT_COMPILE_CACHE_DIR,
-    compile_cache_dir_from_env,
     enable_compile_cache,
 )
 from accelerate_tpu.test_utils import RegressionDataset, RegressionModelWithLoss
@@ -431,6 +430,45 @@ def test_fused_step_one_dispatch_per_window_eager_three_per_micro(tmp_path):
     assert fused_losses == eager_losses
 
 
+def test_fused_step_keeps_bf16_state_and_compiles_once():
+    """bf16 parameters: the clip scalars (float32 arrays) must not promote the
+    gradient tree, and through optax's moments the optimizer state, to
+    float32.  Promoted, the state leaves the first step twice as wide — it no
+    longer aliases the donated input (at Llama-3.2-1B widths 17.7 GiB live
+    instead of 12.4: more than one v5e holds) and the second step compiles
+    the whole program again for the wider state."""
+    import optax
+
+    from accelerate_tpu import JaxModel
+    from accelerate_tpu.telemetry import CompileWatcher
+
+    acc = Accelerator(mixed_precision="bf16")
+    params = {"w": jnp.full((16, 16), 0.1, jnp.bfloat16), "b": jnp.zeros((16,), jnp.bfloat16)}
+
+    def apply_fn(p, x):
+        return {"loss": jnp.mean((x @ p["w"] + p["b"]) ** 2)}
+
+    model, opt = acc.prepare(JaxModel(apply_fn, params), optax.adamw(1e-2))
+    step = acc.make_train_step(model, opt, clip_norm=1.0, clip_value=0.5)
+    batch = {"x": jnp.ones((8, 16), jnp.bfloat16)}
+    mem = step.lower(batch).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.nbytes for x in jax.tree_util.tree_leaves((model.params, opt.opt_state))
+    )  # every donated buffer is reused by an output of its own width
+    watcher = CompileWatcher()
+    float(step(batch))
+    warm = watcher.count
+    for _ in range(2):
+        float(step(batch))
+    watcher.stop()
+    assert watcher.count == warm, "the fused step compiled again after its first call"
+    floating = [
+        x.dtype for x in jax.tree_util.tree_leaves((model.params, opt.opt_state))
+        if jnp.issubdtype(x.dtype, jnp.floating)
+    ]
+    assert floating and all(d == jnp.bfloat16 for d in floating), floating
+
+
 def test_fused_step_window_size_validation():
     acc, model, opt, dl = _build_training(accum=4)
     step_fn = acc.make_train_step(model, opt)
@@ -583,7 +621,6 @@ def _restore_compile_cache():
     yield
     from jax.experimental.compilation_cache import compilation_cache as _cc
 
-    compile_cache_mod._applied_dir = None
     jax.config.update("jax_compilation_cache_dir", None)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
@@ -591,18 +628,58 @@ def _restore_compile_cache():
     _cc.reset_cache()
 
 
-def test_compile_cache_env_resolution(monkeypatch):
-    monkeypatch.delenv("ACCELERATE_TPU_COMPILE_CACHE", raising=False)
-    assert compile_cache_dir_from_env() == DEFAULT_COMPILE_CACHE_DIR
-    monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE", "")
-    assert compile_cache_dir_from_env() is None  # explicit off
-    monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE", "/tmp/somewhere")
-    assert compile_cache_dir_from_env() == "/tmp/somewhere"
+def _placed_from_outside(monkeypatch, path) -> str:
+    """What a process started with JAX_COMPILATION_CACHE_DIR=path looks like:
+    jax read the variable into its own config when it was imported."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(path))
+    jax.config.update("jax_compilation_cache_dir", str(path))
+    monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE", "on")
+    return str(path)
 
 
-def test_compile_cache_disabled_by_empty_env(monkeypatch):
+def test_compile_cache_placed_from_outside_is_left_alone(
+    tmp_path, monkeypatch, _restore_compile_cache
+):
+    """JAX_COMPILATION_CACHE_DIR set: our code makes NO update to
+    jax_compilation_cache_dir — only the cache-everything settings."""
+    placed = _placed_from_outside(monkeypatch, tmp_path / "outside")
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: (updates.append(k), real_update(k, v))[1]
+    )
+    assert enable_compile_cache() == placed
+    assert "jax_compilation_cache_dir" not in updates
+    assert "jax_persistent_cache_min_compile_time_secs" in updates
+    assert jax.config.jax_compilation_cache_dir == placed
+
+
+def test_compile_cache_defaults_to_one_fixed_path_in_the_checkout(
+    monkeypatch, _restore_compile_cache
+):
+    """Unset: the one fixed, ignored directory inside the checkout — derived
+    from the package's __file__, never $HOME, a temp name, a pid or a time."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE", "on")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    made_here = not os.path.isdir(DEFAULT_COMPILE_CACHE_DIR)
+    try:
+        assert enable_compile_cache() == DEFAULT_COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_COMPILE_CACHE_DIR
+        assert enable_compile_cache() == DEFAULT_COMPILE_CACHE_DIR  # idempotent
+    finally:
+        if made_here and not os.listdir(DEFAULT_COMPILE_CACHE_DIR):
+            os.rmdir(DEFAULT_COMPILE_CACHE_DIR)
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_compile_cache_disabled_by_empty_env(monkeypatch, _restore_compile_cache):
     monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE", "")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir is None  # untouched
 
 
 def test_compile_cache_size_bound(tmp_path, monkeypatch, _restore_compile_cache):
@@ -617,14 +694,15 @@ def test_compile_cache_size_bound(tmp_path, monkeypatch, _restore_compile_cache)
         monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE_MAX_BYTES", "lots")
         assert compile_cache_mod.compile_cache_max_bytes_from_env() == -1
     monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE_MAX_BYTES", "54321")
-    assert enable_compile_cache(str(tmp_path / "xla_cache")) is not None
+    _placed_from_outside(monkeypatch, tmp_path / "xla_cache")
+    assert enable_compile_cache() is not None
     assert jax.config.jax_compilation_cache_max_size == 54321
 
 
-def test_compile_cache_round_trip_and_hit_counter(tmp_path, _restore_compile_cache):
+def test_compile_cache_round_trip_and_hit_counter(tmp_path, monkeypatch, _restore_compile_cache):
     cache_dir = tmp_path / "xla_cache"
-    assert enable_compile_cache(str(cache_dir)) == str(cache_dir)
-    assert jax.config.jax_compilation_cache_dir == str(cache_dir)
+    _placed_from_outside(monkeypatch, cache_dir)
+    assert enable_compile_cache() == str(cache_dir)
     tel = telemetry.enable(dir=str(tmp_path / "tel"))
 
     def f(x):

@@ -156,9 +156,12 @@ def test_mixed_acceptance_across_lanes_in_one_dispatch(gpt2_setup):
     full = want_good[len(p_good):]
 
     def good_fn(feed, k):
-        done = len(feed) - len(p_good)   # generated so far (incl. the one
-        nxt = full[max(done - 1, 0):]    # last emitted token fed back)
-        return nxt[:k]
+        # The feed holds the prompt and every emitted token, the last of which
+        # is the window's first row: the drafts are what follows it.  (This
+        # read ``full[done - 1:]`` — one token late — and passed only while
+        # the oracle's continuation kept repeating one token.)
+        done = len(feed) - len(p_good)
+        return full[done:done + k]
 
     def junk_fn(feed, k):
         return [0] * k

@@ -145,9 +145,14 @@ def test_registry_counter_gauge_histogram():
         reg.gauge("c")
 
 
-def test_peak_flops_table_matches_bench_defaults():
-    # On the CPU test mesh the device kind is unknown → conservative default.
-    assert peak_flops_per_chip() == 197e12
+def test_peak_flops_unknown_device_is_an_error():
+    class _Dev:
+        device_kind = "TPU v5 lite"
+
+    assert peak_flops_per_chip(_Dev()) == 197e12
+    # The CPU test mesh is not in the table: no default peak to hide behind.
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        peak_flops_per_chip()
 
 
 def test_step_timer_tokens_and_mfu(tmp_path):
@@ -161,7 +166,8 @@ def test_step_timer_tokens_and_mfu(tmp_path):
     assert snap["step.time_ms.count"] == 1  # first step has no prior boundary
     assert snap["step.time_ms.last"] >= 20
     assert snap["step.tokens_per_sec"] > 0
-    assert 0 < snap["step.mfu"] < 1
+    # ...and no MFU against a guessed peak: the CPU is not in the table.
+    assert "step.mfu" not in snap
 
 
 # ---------------------------------------------------------------------------
