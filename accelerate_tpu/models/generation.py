@@ -139,6 +139,7 @@ def make_paged_pool(init_cache: Callable, config, num_blocks: int, block_size: i
     return pool
 
 
+@jax.named_scope("kv_pool.gather")
 def gather_block_view(pool_leaf: jax.Array, tables: jax.Array) -> jax.Array:
     """Dense per-slot view of a pool leaf: ``[L, N, bs, *r]`` gathered through
     block tables ``[S, M]`` -> ``[S, L, 1, M*bs, *r]`` (the families'
@@ -166,6 +167,7 @@ def extract_token_rows(view_leaf: jax.Array, start: jax.Array, count: int) -> ja
     return rows.reshape(rows.shape[0], rows.shape[1], count, *rows.shape[4:])
 
 
+@jax.named_scope("kv_pool.write")
 def scatter_token_rows(
     pool_leaf: jax.Array,
     rows: jax.Array,
@@ -236,6 +238,7 @@ def _insert_rows(ctx: jax.Array, new_rows: jax.Array, starts: jax.Array) -> jax.
     return jnp.where(in_new, picked, ctx)
 
 
+@jax.named_scope("kv_pool.gather")
 def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts: jax.Array, dtype):
     """Per-layer paged analog of :func:`cache_write`: compute the stored
     representation of ``new_rows`` ``[B, T, K, hd]`` (cast for the fp pool,

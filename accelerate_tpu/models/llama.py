@@ -526,14 +526,33 @@ def attention_block(x, p, c, mask, positions, kv_valid=None) -> jax.Array:
     ``kv_valid`` [B, S] is the padding mask for causal batches — kept factored
     so the flash/ring/ulysses paths never materialize an [S, S] mask.
     """
-    hd = c.head_dim_
-    h = _norm(x, p["ln_attn"], c)
-    b, s, _ = h.shape
-    q, k, v = _qkv_proj(h, p, c, b, s)
-    q, k = _rope(q, k, positions, c.rope_theta, getattr(c, 'rope_scaling', None))
+    with jax.named_scope("attn"):
+        h = _norm(x, p["ln_attn"], c)
+        b, s, _ = h.shape
+        with jax.named_scope("attn.qkv"):
+            q, k, v = _qkv_proj(h, p, c, b, s)
+            q, k = _rope(q, k, positions, c.rope_theta, getattr(c, 'rope_scaling', None))
+        return x + _out_proj(_attention_core(q, k, v, c, mask, kv_valid), p, c)
+
+
+@jax.named_scope("attn.out")
+def _out_proj(attn, p, c) -> jax.Array:
+    b, s = attn.shape[:2]
+    out = _mm(attn.reshape(b, s, c.num_heads * c.head_dim_), p["wo"], c)
+    if "bo" in p:
+        out = out + p["bo"].astype(out.dtype)
+    return out
+
+
+@jax.named_scope("attn.core")
+def _attention_core(q, k, v, c, mask, kv_valid) -> jax.Array:
+    """The training-shape attention itself, by the path the config and the
+    mesh select: ring/ulysses (sp), the Pallas flash kernel, the XLA flash
+    loop, or the masked einsum."""
+    b, s = q.shape[:2]
     if _sp_active():
-        attn = sp_attention(q, k, v, c, causal=True, kv_valid=kv_valid)
-    elif mask is None and _use_pallas(c, s, b, c.num_heads, c.num_kv_heads):
+        return sp_attention(q, k, v, c, causal=True, kv_valid=kv_valid)
+    if mask is None and _use_pallas(c, s, b, c.num_heads, c.num_kv_heads):
         from ..ops.pallas_attention import pallas_attention_spmd
 
         from ..ops.flash_attention import pick_block_pallas
@@ -547,36 +566,36 @@ def attention_block(x, p, c, mask, positions, kv_valid=None) -> jax.Array:
         # On a sharded (non-sp) mesh the spmd wrapper runs the kernel
         # per-device under shard_map; trivial meshes take the plain call.
         # Padded batches mask keys inside the kernel.
-        attn = pallas_attention_spmd(q, k, v, causal=True, block_size=blk, kv_valid=kv_valid)
-    elif mask is None and (
+        return pallas_attention_spmd(q, k, v, causal=True, block_size=blk, kv_valid=kv_valid)
+    if mask is None and (
         c.attention_impl == "flash" or (c.attention_impl == "auto" and s >= 1024)
     ) and _flash_block(s) is not None:
         from ..ops.flash_attention import flash_attention
 
-        attn = flash_attention(
+        return flash_attention(
             q, k, v, causal=True, block_size=_flash_block(s), kv_valid=kv_valid
         )
-    else:
-        if mask is None:
-            mask = jnp.broadcast_to(jnp.tril(jnp.ones((s, s), bool)), (b, s, s))
-            if kv_valid is not None:
-                mask = mask & kv_valid.astype(bool)[:, None, :]
-        attn = _attention(q, k, v, mask, c.num_heads // c.num_kv_heads)
-    out = _mm(attn.reshape(b, s, c.num_heads * hd), p["wo"], c)
-    if "bo" in p:
-        out = out + p["bo"].astype(out.dtype)
-    return x + out
+    if mask is None:
+        mask = jnp.broadcast_to(jnp.tril(jnp.ones((s, s), bool)), (b, s, s))
+        if kv_valid is not None:
+            mask = mask & kv_valid.astype(bool)[:, None, :]
+    return _attention(q, k, v, mask, c.num_heads // c.num_kv_heads)
+
+
+@jax.named_scope("mlp")
+def _mlp_block(x, p, c) -> jax.Array:
+    """Pre-norm gated MLP sub-block with residual."""
+    h = _norm(x, p["ln_mlp"], c)
+    gate = _act(_mm(h, p["w_gate"], c), c)
+    up = _mm(h, p["w_up"], c)
+    return x + _mm(gate * up, p["w_down"], c)
 
 
 def _layer(carry, layer_params, *, config: LlamaConfig, mask, positions, act_spec, kv_valid=None):
     c = config
     p = layer_params
     x = attention_block(carry, p, c, mask, positions, kv_valid=kv_valid)
-
-    h = _norm(x, p["ln_mlp"], c)
-    gate = _act(_mm(h, p["w_gate"], c), c)
-    up = _mm(h, p["w_up"], c)
-    x = x + _mm(gate * up, p["w_down"], c)
+    x = _mlp_block(x, p, c)
     if act_spec is not None:
         x = _maybe_constrain(x, act_spec)
     return x, None
@@ -614,8 +633,9 @@ def apply(
     attention_mask: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Forward pass: token ids [B, S] -> logits [B, S, V] (fp32)."""
-    hidden = apply_hidden(params, input_ids, config, positions, attention_mask)
-    return (hidden @ lm_head(params, config)).astype(jnp.float32)
+    x = _trunk(params, input_ids, config, positions, attention_mask)
+    with jax.named_scope("head"):
+        return unembed(params, x, config)
 
 
 def apply_hidden(
@@ -628,6 +648,14 @@ def apply_hidden(
     """Trunk forward: token ids [B, S] -> final-normed hidden [B, S, d]
     (compute dtype) — the chunked loss consumes this directly so the full
     logits tensor never exists."""
+    x = _trunk(params, input_ids, config, positions, attention_mask)
+    with jax.named_scope("head"):
+        return final_norm(params, x, config)
+
+
+def _trunk(params, input_ids, config, positions=None, attention_mask=None) -> jax.Array:
+    """Embedding and the layer loop: token ids [B, S] -> hidden [B, S, d]
+    before the final norm."""
     c = config
     b, s = input_ids.shape
     # Padding stays factored as a [B, S] key-validity vector all the way down —
@@ -654,8 +682,9 @@ def apply_hidden(
 
     if c.remat:
         body = jax.checkpoint(body, policy=_remat_policy(c.remat_policy))
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    return final_norm(params, x, c)
+    with jax.named_scope("layers"):
+        x, _ = jax.lax.scan(body, x, params["layers"])
+    return x
 
 
 def _remat_policy(name: str):
@@ -666,6 +695,7 @@ def _remat_policy(name: str):
     raise ValueError(f"Unknown remat_policy {name!r} (use 'nothing' or 'dots')")
 
 
+@jax.named_scope("embed")
 def embed_tokens(params: dict, input_ids: jax.Array, config: LlamaConfig) -> jax.Array:
     """Token embedding lookup in compute dtype — shared by the dense and
     pipeline-parallel paths.  ``embed_scale`` multiplies by sqrt(d) in the
@@ -732,17 +762,16 @@ def loss_fn(
     ``ops/chunked_ce.py`` without ever materializing the [B, S, V] logits —
     the HBM that usually caps the batch size."""
     labels, weights = labels_and_weights(batch)
-    if config.loss_impl == "chunked":
-        from ..ops.chunked_ce import chunked_cross_entropy
+    x = _trunk(params, batch["input_ids"], config, attention_mask=batch.get("attention_mask"))
+    with jax.named_scope("head_loss"):
+        if config.loss_impl == "chunked":
+            from ..ops.chunked_ce import chunked_cross_entropy
 
-        x = apply_hidden(
-            params, batch["input_ids"], config, attention_mask=batch.get("attention_mask")
-        )
-        return chunked_cross_entropy(
-            x, lm_head(params, config), labels, weights, config.loss_chunk_size
-        )
-    logits = apply(params, batch["input_ids"], config, attention_mask=batch.get("attention_mask"))
-    return cross_entropy(logits, labels, weights)
+            return chunked_cross_entropy(
+                final_norm(params, x, config), lm_head(params, config), labels, weights,
+                config.loss_chunk_size,
+            )
+        return cross_entropy(unembed(params, x, config), labels, weights)
 
 
 # ---------------------------------------------------------------------------
@@ -772,29 +801,28 @@ def init_cache(config: LlamaConfig, batch_size: int, max_len: int) -> dict:
 def _attention_block_cached(x, p, c, ck, cv, index, positions):
     """Attention sub-block against the cache.  x: [B, S, D] (S = new tokens);
     ck/cv: [B, max_len, K, hd].  Returns (out, new_ck, new_cv)."""
-    hd = c.head_dim_
-    h = _norm(x, p["ln_attn"], c)
-    b, s, _ = h.shape
-    max_len = (ck[0] if isinstance(ck, tuple) else ck).shape[1]
-    q, k, v = _qkv_proj(h, p, c, b, s)
-    q, k = _rope(q, k, positions, c.rope_theta, getattr(c, 'rope_scaling', None))
-
     from .generation import cache_write
 
-    # Plain and int8 (codes, scale) cache layouts share one write/read
-    # helper; the dequant multiply fuses into the attention matmuls.
-    ck, k_full = cache_write(ck, k, index, c.dtype)
-    cv, v_full = cache_write(cv, v, index, c.dtype)
+    with jax.named_scope("attn"):
+        h = _norm(x, p["ln_attn"], c)
+        b, s, _ = h.shape
+        max_len = (ck[0] if isinstance(ck, tuple) else ck).shape[1]
+        with jax.named_scope("attn.qkv"):
+            q, k, v = _qkv_proj(h, p, c, b, s)
+            q, k = _rope(q, k, positions, c.rope_theta, getattr(c, 'rope_scaling', None))
 
-    # q position i (global index + i) attends cache slots <= its position.
-    q_pos = index + jnp.arange(s)
-    k_pos = jnp.arange(max_len)
-    mask = jnp.broadcast_to(q_pos[:, None] >= k_pos[None, :], (b, s, max_len))
-    attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
-    out = _mm(attn.reshape(b, s, c.num_heads * hd), p["wo"], c)
-    if "bo" in p:
-        out = out + p["bo"].astype(out.dtype)
-    return x + out, ck, cv
+        # Plain and int8 (codes, scale) cache layouts share one write/read
+        # helper; the dequant multiply fuses into the attention matmuls.
+        ck, k_full = cache_write(ck, k, index, c.dtype)
+        cv, v_full = cache_write(cv, v, index, c.dtype)
+
+        with jax.named_scope("attn.core"):
+            # q position i (global index + i) attends cache slots <= its position.
+            q_pos = index + jnp.arange(s)
+            k_pos = jnp.arange(max_len)
+            mask = jnp.broadcast_to(q_pos[:, None] >= k_pos[None, :], (b, s, max_len))
+            attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
+        return x + _out_proj(attn, p, c), ck, cv
 
 
 def apply_cached(
@@ -822,14 +850,13 @@ def apply_cached(
         lp, ck, cv = xs
         lp = _dequant_layer(lp)
         y, ck, cv = _attention_block_cached(carry, lp, c, ck, cv, index, positions)
-        h = _norm(y, lp["ln_mlp"], c)
-        gate = _act(_mm(h, lp["w_gate"], c), c)
-        up = _mm(h, lp["w_up"], c)
-        return y + _mm(gate * up, lp["w_down"], c), (ck, cv)
+        return _mlp_block(y, lp, c), (ck, cv)
 
     ck_in, cv_in, quant = pack_cache_for_scan(cache)
-    x, (new_k, new_v) = jax.lax.scan(body, x, (params["layers"], ck_in, cv_in))
-    logits = unembed(params, x, c)
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = jax.lax.scan(body, x, (params["layers"], ck_in, cv_in))
+    with jax.named_scope("head"):
+        logits = unembed(params, x, c)
     return logits, unpack_cache_from_scan(new_k, new_v, index + s, quant)
 
 
@@ -860,7 +887,6 @@ def apply_paged(
 
     c = config
     b, t = input_ids.shape
-    hd = c.head_dim_
     _, _, quant = pack_paged_pool_for_scan(pool)
     bs = pool["k"].shape[2]
     total = tables.shape[1] * bs
@@ -878,44 +904,45 @@ def apply_paged(
             lp, pk, pv = xs
         lp = _dequant_layer(lp)
         x = carry
-        h = _norm(x, lp["ln_attn"], c)
-        q, k, v = _qkv_proj(h, lp, c, b, t)
-        q, k = _rope(q, k, positions, c.rope_theta, getattr(c, "rope_scaling", None))
-        if use_kernel:
-            from ..ops.pallas_attention import (
-                pallas_paged_attention,
-                pallas_paged_window_attention,
-            )
-
-            k_store = k.astype(pk.dtype)
-            v_store = v.astype(pv.dtype)
-            if t == 1:
-                attn = pallas_paged_attention(
-                    q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, tables, starts
-                )[:, None]
-            else:
-                attn = pallas_paged_window_attention(
-                    q, k_store, v_store, pk, pv, tables, starts
+        with jax.named_scope("attn"):
+            h = _norm(x, lp["ln_attn"], c)
+            with jax.named_scope("attn.qkv"):
+                q, k, v = _qkv_proj(h, lp, c, b, t)
+                q, k = _rope(q, k, positions, c.rope_theta, getattr(c, "rope_scaling", None))
+            if use_kernel:
+                from ..ops.pallas_attention import (
+                    pallas_paged_attention,
+                    pallas_paged_window_attention,
                 )
-        else:
-            k_store, k_full = paged_cache_write(pk, k, tables, starts, c.dtype)
-            v_store, v_full = paged_cache_write(pv, v, tables, starts, c.dtype)
-            attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
-        out = _mm(attn.reshape(b, t, c.num_heads * hd), lp["wo"], c)
-        if "bo" in lp:
-            out = out + lp["bo"].astype(out.dtype)
-        y = x + out
-        h = _norm(y, lp["ln_mlp"], c)
-        gate = _act(_mm(h, lp["w_gate"], c), c)
-        up = _mm(h, lp["w_up"], c)
-        return y + _mm(gate * up, lp["w_down"], c), (k_store, v_store)
+
+                k_store = k.astype(pk.dtype)
+                v_store = v.astype(pv.dtype)
+                with jax.named_scope("attn.core"):
+                    if t == 1:
+                        attn = pallas_paged_attention(
+                            q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, tables, starts
+                        )[:, None]
+                    else:
+                        attn = pallas_paged_window_attention(
+                            q, k_store, v_store, pk, pv, tables, starts
+                        )
+            else:
+                with jax.named_scope("kv_pool"):
+                    k_store, k_full = paged_cache_write(pk, k, tables, starts, c.dtype)
+                    v_store, v_full = paged_cache_write(pv, v, tables, starts, c.dtype)
+                with jax.named_scope("attn.core"):
+                    attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
+            y = x + _out_proj(attn, lp, c)
+        return _mlp_block(y, lp, c), (k_store, v_store)
 
     xs = (params["layers"],) + (
         (pool["k"], pool["k_scale"], pool["v"], pool["v_scale"]) if quant
         else (pool["k"], pool["v"])
     )
-    x, (k_rows, v_rows) = jax.lax.scan(body, x, xs)
-    logits = unembed(params, x, c)
+    with jax.named_scope("layers"):
+        x, (k_rows, v_rows) = jax.lax.scan(body, x, xs)
+    with jax.named_scope("head"):
+        logits = unembed(params, x, c)
     return logits, unpack_paged_rows_from_scan(k_rows, v_rows, quant)
 
 

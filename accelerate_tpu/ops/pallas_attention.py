@@ -172,6 +172,7 @@ def _flash_fwd(q, k, v, *, scale, causal, blk_q, blk_k, interpret, kv_valid=None
     operands = [q, k, v] + ([_valid_row(kv_valid)] if has_valid else [])
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b, h, nq, nk),
         in_specs=[
             _vmem_spec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -336,6 +337,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, scale, causal, blk_q, blk_k, interpret,
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(b, h, nq, nk),
         in_specs=[
             _vmem_spec((1, 1, blk_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -359,6 +361,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, scale, causal, blk_q, blk_k, interpret,
     # dK/dV computed per Q-head ([B, H, S, d]) then group-summed to K heads.
     dk_h, dv_h = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(b, h, nk, nq),
         in_specs=[
             _vmem_spec((1, 1, blk_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
@@ -671,6 +674,7 @@ def pallas_paged_attention(
     )
     return pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
@@ -818,6 +822,7 @@ def pallas_paged_window_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_window_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hw, d), q.dtype),
         interpret=interpret,

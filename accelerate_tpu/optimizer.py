@@ -65,62 +65,64 @@ def _update_body(
     ``norm_ndp=None`` (no dp axes — the overwhelmingly common single-device
     test path) this body is exactly the legacy one.
     """
-    if norm_ndp:
-        from .parallel.zero import chunked_global_norm
+    with jax.named_scope("clip"):
+        if norm_ndp:
+            from .parallel.zero import chunked_global_norm
 
-        # Runtime-true, compile-time-opaque fence pred.  x == x is the
-        # NaN-check: True for every real clip argument INCLUDING inf
-        # (clip_grad_norm_(inf) is the standard measure-without-clipping
-        # idiom and must not trip the fence), never constant-foldable for
-        # floats.  ANDing health_ok keeps the poisoned-step semantics:
-        # zeroed grads make the norms finite, but ``ok`` still fails via
-        # health_ok and health_norm is forced NaN below.
-        fence = jnp.logical_and(clip_norm == clip_norm, clip_value == clip_value)
+            # Runtime-true, compile-time-opaque fence pred.  x == x is the
+            # NaN-check: True for every real clip argument INCLUDING inf
+            # (clip_grad_norm_(inf) is the standard measure-without-clipping
+            # idiom and must not trip the fence), never constant-foldable for
+            # floats.  ANDing health_ok keeps the poisoned-step semantics:
+            # zeroed grads make the norms finite, but ``ok`` still fails via
+            # health_ok and health_norm is forced NaN below.
+            fence = jnp.logical_and(clip_norm == clip_norm, clip_value == clip_value)
+            if health_ok is not None:
+                fence = jnp.logical_and(fence, health_ok)
+            grads = jax.tree_util.tree_map(
+                lambda g: jnp.where(fence, g, jnp.zeros_like(g)), grads
+            )
+            health_norm = chunked_global_norm(grads, norm_ndp, fence)
+        else:
+            health_norm = optax.global_norm(grads)
+        ok = jnp.isfinite(health_norm)
         if health_ok is not None:
-            fence = jnp.logical_and(fence, health_ok)
-        grads = jax.tree_util.tree_map(
-            lambda g: jnp.where(fence, g, jnp.zeros_like(g)), grads
-        )
-        health_norm = chunked_global_norm(grads, norm_ndp, fence)
-    else:
-        health_norm = optax.global_norm(grads)
-    ok = jnp.isfinite(health_norm)
-    if health_ok is not None:
-        ok = jnp.logical_and(ok, health_ok)
-        health_norm = jnp.where(health_ok, health_norm, jnp.nan)
-    # The clip scalars are float32 ARRAYS: left as they are they promote a
-    # bf16 gradient tree (and, through optax's moments, the optimizer state)
-    # to float32 — a program whose outputs no longer alias its donated
-    # inputs, and a second compile when the wider state comes back in.
-    def _value_clip(g):
-        bound = clip_value.astype(g.dtype)
-        return jnp.where(clip_value >= 0, jnp.clip(g, -bound, bound), g)
+            ok = jnp.logical_and(ok, health_ok)
+            health_norm = jnp.where(health_ok, health_norm, jnp.nan)
+        # The clip scalars are float32 ARRAYS: left as they are they promote a
+        # bf16 gradient tree (and, through optax's moments, the optimizer state)
+        # to float32 — a program whose outputs no longer alias its donated
+        # inputs, and a second compile when the wider state comes back in.
+        def _value_clip(g):
+            bound = clip_value.astype(g.dtype)
+            return jnp.where(clip_value >= 0, jnp.clip(g, -bound, bound), g)
 
-    grads = jax.tree_util.tree_map(_value_clip, grads)
-    if norm_ndp:
-        gnorm = chunked_global_norm(grads, norm_ndp, fence)
-    else:
-        gnorm = optax.global_norm(grads)
-    scale = jnp.where(
-        clip_norm >= 0, jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-12)), 1.0
-    )
-    grads = jax.tree_util.tree_map(lambda g: g * scale.astype(g.dtype), grads)
-    if norm_ndp:
-        grads = jax.tree_util.tree_map(
-            lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads
+        grads = jax.tree_util.tree_map(_value_clip, grads)
+        if norm_ndp:
+            gnorm = chunked_global_norm(grads, norm_ndp, fence)
+        else:
+            gnorm = optax.global_norm(grads)
+        scale = jnp.where(
+            clip_norm >= 0, jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-12)), 1.0
         )
-    updates, new_opt_state = tx_update(grads, opt_state, params)
-    if norm_ndp:
-        updates = jax.tree_util.tree_map(
-            lambda u: jnp.where(ok, u, jnp.zeros_like(u)), updates
+        grads = jax.tree_util.tree_map(lambda g: g * scale.astype(g.dtype), grads)
+        if norm_ndp:
+            grads = jax.tree_util.tree_map(
+                lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads
+            )
+    with jax.named_scope("optimizer"):
+        updates, new_opt_state = tx_update(grads, opt_state, params)
+        if norm_ndp:
+            updates = jax.tree_util.tree_map(
+                lambda u: jnp.where(ok, u, jnp.zeros_like(u)), updates
+            )
+        new_params = optax.apply_updates(params, updates)
+        new_params = jax.tree_util.tree_map(
+            lambda n, o: jnp.where(ok, n, o), new_params, params
         )
-    new_params = optax.apply_updates(params, updates)
-    new_params = jax.tree_util.tree_map(
-        lambda n, o: jnp.where(ok, n, o), new_params, params
-    )
-    new_opt_state = jax.tree_util.tree_map(
-        lambda n, o: jnp.where(ok, n, o), new_opt_state, opt_state
-    )
+        new_opt_state = jax.tree_util.tree_map(
+            lambda n, o: jnp.where(ok, n, o), new_opt_state, opt_state
+        )
     return new_params, new_opt_state, gnorm, health_norm
 
 

@@ -2,8 +2,10 @@
 
 Warmup compiles are the dominant startup cost of a large GSPMD program
 (minutes at scale); XLA can serialize compiled executables and re-load them
-keyed by (HLO, flags, topology).  :func:`enable_compile_cache` turns that
-cache on for both entry points (``Accelerator.__init__`` and
+keyed by (HLO, flags, topology) and, here, this library's source
+(:func:`library_digest`: the executable carries the source's names).
+:func:`enable_compile_cache` turns that cache on for both entry points
+(``Accelerator.__init__`` and
 ``ServingEngine.__init__``):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set → jax itself reads that variable; this
@@ -31,6 +33,8 @@ jit.cache_hits``.
 
 from __future__ import annotations
 
+import functools
+import hashlib
 import os
 from typing import Optional
 
@@ -41,6 +45,7 @@ __all__ = [
     "DEFAULT_COMPILE_CACHE_MAX_BYTES",
     "compile_cache_max_bytes_from_env",
     "enable_compile_cache",
+    "library_digest",
 ]
 
 ENV_COMPILE_CACHE = "ACCELERATE_TPU_COMPILE_CACHE"
@@ -71,6 +76,48 @@ def compile_cache_max_bytes_from_env() -> int:
     return max_bytes if max_bytes > 0 else -1
 
 
+@functools.cache
+def library_digest() -> str:
+    """sha256 over this package's Python sources (relative path and bytes, in
+    sorted order): what an executable in the cache was compiled from."""
+    package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digest = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(package):
+        dirnames.sort()
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                digest.update(os.path.relpath(path, package).encode())
+                with open(path, "rb") as f:
+                    digest.update(f.read())
+    return digest.hexdigest()
+
+
+def _key_by_library_source() -> None:
+    """An executable from the cache keeps the metadata of the source that
+    compiled it, and jax leaves metadata out of the cache key: a program
+    whose named scopes or kernel names changed comes back with the old
+    ``op_name``s, in every profile and in every metric read from one (seen on
+    the v5e: the serving programs of the commit before the scopes, out of the
+    machine's cache).  jax's own switch for that
+    (``jax_compilation_cache_include_metadata_in_key``) also keys on the source
+    line of every frame of the caller's stack, so that one function called
+    from two places misses; the names are this library's, so the key takes
+    this library's source instead, through the hook jax keeps for additions
+    to the key.  A library edit costs one cold start; the user's edits none."""
+    from jax._src import cache_key
+
+    if hasattr(cache_key.custom_hook, "library_digest"):
+        return
+    outer = cache_key.custom_hook
+
+    def custom_hook() -> str:
+        return outer() + " accelerate_tpu=" + custom_hook.library_digest
+
+    custom_hook.library_digest = library_digest()
+    cache_key.custom_hook = custom_hook
+
+
 def enable_compile_cache() -> Optional[str]:
     """Turn jax's persistent compilation cache on.  Returns the active
     directory, or ``None`` when the cache is disabled.  Idempotent; a
@@ -96,6 +143,7 @@ def enable_compile_cache() -> Optional[str]:
         from jax.experimental.compilation_cache import compilation_cache as _cc
 
         _cc.reset_cache()
+    _key_by_library_source()
     # Cache every program: the default 1s floor skips exactly the small
     # programs a CPU-smoke run compiles, and at TPU scale everything worth
     # running clears 1s anyway.

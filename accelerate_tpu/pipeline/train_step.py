@@ -218,7 +218,8 @@ class TrainStep:
                 loss = out["loss"] if isinstance(out, dict) else out[0]
                 return jnp.asarray(loss, jnp.float32).mean()
 
-            return jax.value_and_grad(lossf)(params)
+            with jax.named_scope("loss_grad"):
+                return jax.value_and_grad(lossf)(params)
 
         if self.zero_active:
             grads_and_losses = self._build_zero_grads_fn(_loss_and_grads, _scaled)

@@ -74,6 +74,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..telemetry import annotate
+
 __all__ = [
     "BlockAllocator",
     "BlockOutOfMemory",
@@ -624,10 +626,11 @@ class PagedKVCache:
             raise BlockOutOfMemory(
                 f"host tier cannot fit {len(blocks)} blocks ({free} free of {cap})"
             )
-        host_ids = self.host.alloc(len(blocks))
-        rows = demote_pool_blocks(self.pool, blocks)
-        for name, leaf in self.host.leaves.items():
-            leaf[:, host_ids] = rows[name]
+        with annotate("serving.tier.demote", blocks=len(blocks)):
+            host_ids = self.host.alloc(len(blocks))
+            rows = demote_pool_blocks(self.pool, blocks)
+            for name, leaf in self.host.leaves.items():
+                leaf[:, host_ids] = rows[name]
         return host_ids
 
     def try_demote(self, blocks: List[int]) -> Optional[List[int]]:
@@ -652,9 +655,10 @@ class PagedKVCache:
             return
         if self.host is None:
             raise ValueError("promote without a host tier")
-        rows = {name: leaf[:, host_ids] for name, leaf in self.host.leaves.items()}
-        self.pool = promote_pool_blocks(self.pool, rows, dst_blocks)
-        self.host.free(host_ids)
+        with annotate("serving.tier.promote", blocks=len(host_ids)):
+            rows = {name: leaf[:, host_ids] for name, leaf in self.host.leaves.items()}
+            self.pool = promote_pool_blocks(self.pool, rows, dst_blocks)
+            self.host.free(host_ids)
 
     @property
     def leaf_names(self) -> list:

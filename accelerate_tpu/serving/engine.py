@@ -113,7 +113,7 @@ from ..models.generation import (
     gather_block_view,
     scatter_token_rows,
 )
-from ..telemetry import get_telemetry
+from ..telemetry import annotate, get_telemetry
 from .blocks import (
     NULL_BLOCK,
     BlockOutOfMemory,
@@ -277,6 +277,30 @@ class CompletedRequest:
     prefill_dispatches: int = 0
 
 
+class _TickPhase:
+    """One phase of a tick, twice over: a ``serving.tick.<name>`` span on the
+    profiler's timeline (``telemetry.annotate``; ``with`` gives the span, for
+    ``set_metadata``), and its milliseconds in the tick's record for
+    ``ServingTracer``'s slow ticks.  Phases are counted back to back, each
+    from the end of the one before, so they sum to the tick."""
+
+    __slots__ = ("engine", "name", "span")
+
+    def __init__(self, engine: "ServingEngine", name: str, **meta):
+        self.engine, self.name = engine, name
+        self.span = annotate("serving.tick." + name, tick=engine.ticks, **meta)
+
+    def __enter__(self):
+        return self.span.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self.span.__exit__(*exc)
+        engine, now = self.engine, time.monotonic()
+        engine._tick["phase_ms"][self.name] = (now - engine._phase_t0) * 1e3
+        engine._phase_t0 = now
+        return False
+
+
 class ServingEngine:
     """Continuous-batching serving over a model family's
     ``apply_cached``/``init_cache`` pair (any family following the
@@ -427,6 +451,8 @@ class ServingEngine:
         self._seen_widths: Dict[str, set] = {
             "decode": set(), "decode_spec": set(), "prefill": set(),
         }
+        self._tick: dict = {}  # what the running tick is doing: step() starts one
+        self._phase_t0 = 0.0
         # Live /debug endpoints: the metrics HTTP server asks registered
         # engines for request/block snapshots (weakly — a collected engine
         # just drops off the page).
@@ -808,37 +834,58 @@ class ServingEngine:
             self.drain()
             return []
         self.ticks += 1
-        if self.tracer is not None:
-            self.tracer.begin_tick(now)
-        self._drain_scrubs()
-        # Deadline expiry FIRST: an expired queued request is shed before a
-        # slot, a prefill chunk, or any blocks are spent on it.
-        self._expire_deadlines(now)
-        # Demote-before-shed: with the raw free list under the watermark,
-        # batch-demote cold prefix chains to host DRAM BEFORE admission, so
-        # the allocations this tick makes hit the free list instead of
-        # dropping cached content on demand.
-        self._pressure_relief()
-        admitted = self.sched.admit(now)
-        if self.tracer is not None:
-            admit_t = time.monotonic()
-            for idx in admitted:
-                self.tracer.on_admit(self.sched.slots[idx].request, admit_t, idx)
-        for idx in admitted:
-            # Host-tier round-trip first: a re-admitted migration victim
-            # promotes its demoted KV back and resumes exactly where it
-            # stopped (zero re-prefill dispatches); _attach_prefix then
-            # skips it (its cache_len is already set).
-            self._promote_admitted(idx)
-        for idx in admitted:
-            self._attach_prefix(idx)
-        self._observe_requeue_waits(admitted)
-        self._prefill_tick(now)
-        self._decode_tick(now)
-        self._drain_scrubs()
-        if self.tracer is not None:
-            self.tracer.end_tick(time.monotonic(), self.sched.slots)
-        self._publish_gauges()
+        states = [slot.request.state for slot in self.sched.slots.values()]
+        # What the tick was doing, for ServingTracer's slow-tick record:
+        # _TickPhase fills phase_ms, _decode_tick the dispatch's shape.
+        self._tick = tick = {
+            "tick": self.ticks,
+            "prefilling": states.count(RequestState.PREFILLING),
+            "live": 0, "width": None, "fresh": False, "phase_ms": {},
+        }
+        self._phase_t0 = now
+        with annotate(
+            "serving.tick", tick=self.ticks, queued=self.sched.pending,
+            prefilling=tick["prefilling"], decoding=states.count(RequestState.DECODING),
+        ):
+            with _TickPhase(self, "admit") as span:
+                if self.tracer is not None:
+                    self.tracer.begin_tick(now)
+                self._drain_scrubs()
+                # Deadline expiry FIRST: an expired queued request is shed before a
+                # slot, a prefill chunk, or any blocks are spent on it.
+                self._expire_deadlines(now)
+                # Demote-before-shed: with the raw free list under the watermark,
+                # batch-demote cold prefix chains to host DRAM BEFORE admission, so
+                # the allocations this tick makes hit the free list instead of
+                # dropping cached content on demand.
+                self._pressure_relief()
+                admitted = self.sched.admit(now)
+                if self.tracer is not None:
+                    admit_t = time.monotonic()
+                    for idx in admitted:
+                        self.tracer.on_admit(self.sched.slots[idx].request, admit_t, idx)
+                for idx in admitted:
+                    # Host-tier round-trip first: a re-admitted migration victim
+                    # promotes its demoted KV back and resumes exactly where it
+                    # stopped (zero re-prefill dispatches); _attach_prefix then
+                    # skips it (its cache_len is already set).
+                    self._promote_admitted(idx)
+                for idx in admitted:
+                    self._attach_prefix(idx)
+                self._observe_requeue_waits(admitted)
+                span.set_metadata(admitted=len(admitted))
+            self._prefill_tick(now)
+            self._decode_tick(now)
+            with annotate("serving.tick.publish", tick=self.ticks):
+                self._drain_scrubs()
+                self._publish_gauges()
+                if self.tracer is not None:
+                    # Last, so that the tick's record holds all of the tick
+                    # but the tracer's own bookkeeping.
+                    end = time.monotonic()
+                    tick["phase_ms"]["publish"] = (end - self._phase_t0) * 1e3
+                    tick["total_ms"] = (end - now) * 1e3
+                    self.tracer.end_tick(end, self.sched.slots, tick)
         return self._finished[done_before:]
 
     def run(self, max_ticks: Optional[int] = None) -> Dict[int, List[int]]:
@@ -1371,6 +1418,7 @@ class ServingEngine:
             # "dispatch" not "kind": event() reserves "kind" for the record
             # envelope, and a field named kind would shadow it in the JSONL.
             tel.event("serving.bucket_compile", dispatch=kind, width=key)
+        self._tick["fresh"] = True
         return True
 
     def _table_row(self, blocks: List[int], width: Optional[int] = None) -> np.ndarray:
@@ -1381,219 +1429,233 @@ class ServingEngine:
 
     def _prefill_tick(self, now: float) -> None:
         sched = self.sched
-        candidates = [
-            (slot.admit_seq, idx)
-            for idx, slot in sched.slots.items()
-            if slot.request.state == RequestState.PREFILLING
-        ]
-        if not candidates:
-            return
-        _, idx = min(candidates)
-        slot = sched.slots[idx]
-        req = slot.request
-        feed = req.to_feed
-        start = slot.cache_len
-        chunk_len = self.serving.prefill_chunk
-        n_real = min(chunk_len, len(feed) - start)
-        if not sched.grow_to(idx, start + n_real):
-            return  # the slot itself was preempted to find blocks
-        chunk = np.zeros((1, chunk_len), np.int32)
-        chunk[0, :n_real] = feed[start : start + n_real]
-        width = None
-        if self.decode_path == "paged":
-            # Bucket the table to the chunk's padded write extent — the
-            # gather reads the blocks this prefill can actually touch.
-            width = self._bucket_width(
-                blocks_for_tokens(start + chunk_len, self.serving.block_size)
+        with _TickPhase(self, "prefill.build") as span:
+            candidates = [
+                (slot.admit_seq, idx)
+                for idx, slot in sched.slots.items()
+                if slot.request.state == RequestState.PREFILLING
+            ]
+            if not candidates:
+                return
+            _, idx = min(candidates)
+            slot = sched.slots[idx]
+            req = slot.request
+            feed = req.to_feed
+            start = slot.cache_len
+            chunk_len = self.serving.prefill_chunk
+            n_real = min(chunk_len, len(feed) - start)
+            if not sched.grow_to(idx, start + n_real):
+                return  # the slot itself was preempted to find blocks
+            chunk = np.zeros((1, chunk_len), np.int32)
+            chunk[0, :n_real] = feed[start : start + n_real]
+            width = None
+            if self.decode_path == "paged":
+                # Bucket the table to the chunk's padded write extent — the
+                # gather reads the blocks this prefill can actually touch.
+                width = self._bucket_width(
+                    blocks_for_tokens(start + chunk_len, self.serving.block_size)
+                )
+            fresh = self._note_bucket("prefill", width)
+            table_row = self._table_row(slot.blocks, width)
+            span.set_metadata(request=req.id, start=start, rows=n_real, width=width or 0)
+        with _TickPhase(self, "prefill.wait", request=req.id):
+            next_tok, ok, self.cache.pool = self._prefill_fn(
+                self.params,
+                self.cache.pool,
+                table_row,
+                np.int32(start),
+                chunk,
+                np.int32(n_real),
             )
-        fresh = self._note_bucket("prefill", width)
-        next_tok, ok, self.cache.pool = self._prefill_fn(
-            self.params,
-            self.cache.pool,
-            self._table_row(slot.blocks, width),
-            np.int32(start),
-            chunk,
-            np.int32(n_real),
-        )
-        self.prefill_dispatches += 1
-        req.prefill_dispatches += 1  # per-request: the zero-re-prefill oracle
-        tel = get_telemetry()
-        if tel.enabled:
-            tel.registry.counter("serving.prefill_dispatches").inc()
-        slot.cache_len = start + n_real
-        poisoned = not bool(ok)  # host sync point: the dispatch is done here
-        if self.tracer is not None:
-            self.tracer.on_prefill(
-                req, idx, time.monotonic(),
-                padded_rows=chunk_len - n_real, width=width, fresh=fresh,
-            )
-        if poisoned:
-            self._quarantine(idx, time.monotonic())
-            return
-        self._register_prefix_blocks(idx)
-        if slot.cache_len == len(feed):
-            # Final chunk: its last real logits row IS the next token — the
-            # first generated token of a fresh request (TTFT lands here) or
-            # the resume token of a re-prefilled one.
-            self._emit(idx, int(next_tok), time.monotonic())
-            if idx in sched.slots:
-                sched.slots[idx].request.state = RequestState.DECODING
+            self.prefill_dispatches += 1
+            req.prefill_dispatches += 1  # per-request: the zero-re-prefill oracle
+            tel = get_telemetry()
+            if tel.enabled:
+                tel.registry.counter("serving.prefill_dispatches").inc()
+            slot.cache_len = start + n_real
+            poisoned = not bool(ok)  # host sync point: the dispatch is done here
+        with _TickPhase(self, "prefill.emit", request=req.id) as span:
+            if self.tracer is not None:
+                self.tracer.on_prefill(
+                    req, idx, time.monotonic(),
+                    padded_rows=chunk_len - n_real, width=width, fresh=fresh,
+                )
+            if poisoned:
+                self._quarantine(idx, time.monotonic())
+                return
+            self._register_prefix_blocks(idx)
+            final = slot.cache_len == len(feed)
+            span.set_metadata(first_token=int(final and not req.emitted))
+            if final:
+                # Final chunk: its last real logits row IS the next token — the
+                # first generated token of a fresh request (TTFT lands here) or
+                # the resume token of a re-prefilled one.
+                self._emit(idx, int(next_tok), time.monotonic())
+                if idx in sched.slots:
+                    sched.slots[idx].request.state = RequestState.DECODING
 
     def _decode_tick(self, now: float) -> None:
         sched = self.sched
-        decoding = sorted(
-            (idx for idx, slot in sched.slots.items()
-             if slot.request.state == RequestState.DECODING),
-            key=lambda i: sched.slots[i].admit_seq,
-        )
-        # Speculative drafts come BEFORE block growth: a spec engine's every
-        # decode tick is a k+1-window verify dispatch whose write extent is
-        # the full window for EVERY live slot (the program scatters all
-        # rows), so growth must budget window rows whether or not a given
-        # slot has drafts of its own.  Draft-less slots (and draft-less
-        # ticks) ride the same program with ``draft_len = 0`` — the window
-        # is FIXED at k+1 whenever speculation is on, so each bucket has
-        # exactly one decode program shape and a rare draft-less tick can
-        # never trigger a fresh single-token compile mid-serve.  A draft
-        # never exceeds remaining-1 — the window position after the last
-        # accepted draft must still be emittable.
-        k = self.spec_tokens
-        drafts: Dict[int, List[int]] = {}
-        if k > 0:
+        with _TickPhase(self, "decode.build") as span:
+            decoding = sorted(
+                (idx for idx, slot in sched.slots.items()
+                 if slot.request.state == RequestState.DECODING),
+                key=lambda i: sched.slots[i].admit_seq,
+            )
+            # Speculative drafts come BEFORE block growth: a spec engine's every
+            # decode tick is a k+1-window verify dispatch whose write extent is
+            # the full window for EVERY live slot (the program scatters all
+            # rows), so growth must budget window rows whether or not a given
+            # slot has drafts of its own.  Draft-less slots (and draft-less
+            # ticks) ride the same program with ``draft_len = 0`` — the window
+            # is FIXED at k+1 whenever speculation is on, so each bucket has
+            # exactly one decode program shape and a rare draft-less tick can
+            # never trigger a fresh single-token compile mid-serve.  A draft
+            # never exceeds remaining-1 — the window position after the last
+            # accepted draft must still be emittable.
+            k = self.spec_tokens
+            drafts: Dict[int, List[int]] = {}
+            if k > 0:
+                for idx in decoding:
+                    slot = sched.slots.get(idx)
+                    if slot is None or slot.request.state != RequestState.DECODING:
+                        continue
+                    req = slot.request
+                    want = min(k, req.remaining - 1)
+                    if want <= 0:
+                        continue
+                    d = self._drafter.propose(req.to_feed, want)
+                    if d:
+                        drafts[idx] = [int(t) for t in d[:want]]
+            window = k + 1 if k > 0 else 1
+            # Grow oldest-first so older requests steal blocks from younger ones
+            # (matching the LIFO victim policy), then re-collect the survivors.
             for idx in decoding:
-                slot = sched.slots.get(idx)
-                if slot is None or slot.request.state != RequestState.DECODING:
-                    continue
-                req = slot.request
-                want = min(k, req.remaining - 1)
-                if want <= 0:
-                    continue
-                d = self._drafter.propose(req.to_feed, want)
-                if d:
-                    drafts[idx] = [int(t) for t in d[:want]]
-        window = k + 1 if k > 0 else 1
-        # Grow oldest-first so older requests steal blocks from younger ones
-        # (matching the LIFO victim policy), then re-collect the survivors.
-        for idx in decoding:
-            if idx in sched.slots and sched.slots[idx].request.state == RequestState.DECODING:
-                sched.grow_to(idx, sched.slots[idx].cache_len + window)
-        live = [
-            idx for idx in decoding
-            if idx in sched.slots and sched.slots[idx].request.state == RequestState.DECODING
-        ]
-        if not live:
-            return
-        s = self.serving.max_slots
-        if self.decode_path == "paged":
-            # Bucket the tables to the widest live slot: gather traffic (and
-            # attention width) scale with the blocks requests actually own.
-            m = self._bucket_width(max(len(sched.slots[idx].blocks) for idx in live))
-            gathered = sum(len(sched.slots[idx].blocks) for idx in live)
-        else:
-            m = self.serving.resolved_max_blocks()
-            # The dense program gathers every slot's full worst-case view,
-            # live or not — exactly the tax the paged path removes.
-            gathered = s * m
-        tables = np.zeros((s, m), np.int32)
-        lengths = np.zeros((s,), np.int32)
-        tokens = np.zeros((s, window), np.int32)
-        draft_len = np.zeros((s,), np.int32)
-        for idx in live:
-            slot = sched.slots[idx]
-            tables[idx] = self._table_row(slot.blocks, m)
-            lengths[idx] = slot.cache_len
-            tokens[idx, 0] = slot.request.emitted[-1]
-            d = drafts.get(idx)
-            if d:
-                tokens[idx, 1 : 1 + len(d)] = d
-                draft_len[idx] = len(d)
-        self.decode_gather_bytes += gathered * self._block_bytes
-        fresh = self._note_bucket("decode_spec" if window > 1 else "decode", m)
-        dispatch_t0 = time.monotonic()
-        if window > 1:
-            args = [self.params, self.cache.pool, tables, lengths, tokens, draft_len]
-        else:
-            args = [self.params, self.cache.pool, tables, lengths, tokens[:, 0]]
-        if self._poison_ordinal is not None:
-            # Armed: the program was traced with the poison lane.  NaN rides
-            # into exactly one slot's logits on that request's first decode
-            # dispatch; every other lane multiplies by 1.0 (vmap lanes are
-            # independent, so their tokens are bit-identical to unarmed).
-            poison = np.ones((s,), np.float32)
-            for idx in live:
-                req = sched.slots[idx].request
-                if getattr(req, "_poison_pending", False):
-                    poison[idx] = np.nan
-                    req._poison_pending = False  # fires once
-            args.append(poison)
-        if window > 1:
-            # The verify program REPLACES the single-token one this tick —
-            # still exactly one fused decode dispatch per bucket.
-            t_rows, m_counts, ok_flags, self.cache.pool = self._decode_spec_fn(*args)
-            out = np.asarray(t_rows)
-            accepts = np.asarray(m_counts)
-        else:
-            next_tokens, ok_flags, self.cache.pool = self._decode_fn(*args)
-            out = np.asarray(next_tokens)[:, None]
-            accepts = np.zeros((s,), np.int32)
-        self.decode_dispatches += 1
-        tel = get_telemetry()
-        if tel.enabled:
-            tel.registry.counter("serving.decode_dispatches").inc()
-            tel.registry.counter("serving.decode_gather_bytes").inc(
-                gathered * self._block_bytes
-            )
-            tel.registry.gauge("serving.decode_bucket_width").set(m)
-        oks = np.asarray(ok_flags)
-        emit_t = time.monotonic()
-        if self.tracer is not None:
-            # emit_t is PAST the np.asarray sync point, so the interval
-            # covers the real device work despite async dispatch.
-            self.tracer.on_decode(
-                [(sched.slots[idx].request, idx) for idx in live],
-                emit_t, co_batch=len(live), width=m, fresh=fresh,
-                dispatch_ms=(emit_t - dispatch_t0) * 1e3,
-                phase="verify" if window > 1 else "decode",
-            )
-        # rounds counts verify DISPATCHES (with >= 1 healthy lane);
-        # proposed/accepted are per-slot sums over the healthy lanes.
-        spec_rounds = spec_proposed = spec_accepted = 0
-        for idx in live:
-            slot = sched.slots[idx]
-            req = slot.request
-            if window > 1:
-                # Accept bookkeeping: the emitted chunk is t[:count] where
-                # count = accepted drafts + the correction/bonus row, capped
-                # at remaining (count == remaining finishes the request on
-                # its exact last token).  cache_len advances by count — the
-                # rewind; rows past it are stale and re-written before read.
-                count = min(int(accepts[idx]) + 1, req.remaining)
+                if idx in sched.slots and sched.slots[idx].request.state == RequestState.DECODING:
+                    sched.grow_to(idx, sched.slots[idx].cache_len + window)
+            live = [
+                idx for idx in decoding
+                if idx in sched.slots and sched.slots[idx].request.state == RequestState.DECODING
+            ]
+            if not live:
+                return
+            s = self.serving.max_slots
+            if self.decode_path == "paged":
+                # Bucket the tables to the widest live slot: gather traffic (and
+                # attention width) scale with the blocks requests actually own.
+                m = self._bucket_width(max(len(sched.slots[idx].blocks) for idx in live))
+                gathered = sum(len(sched.slots[idx].blocks) for idx in live)
             else:
-                count = 1
-            slot.cache_len += count
-            if not bool(oks[idx]):
-                # Quarantine instead of emitting the garbage argmax; the
-                # other slots' emissions proceed untouched.
-                self._quarantine(idx, emit_t)
-                continue
+                m = self.serving.resolved_max_blocks()
+                # The dense program gathers every slot's full worst-case view,
+                # live or not — exactly the tax the paged path removes.
+                gathered = s * m
+            tables = np.zeros((s, m), np.int32)
+            lengths = np.zeros((s,), np.int32)
+            tokens = np.zeros((s, window), np.int32)
+            draft_len = np.zeros((s,), np.int32)
+            for idx in live:
+                slot = sched.slots[idx]
+                tables[idx] = self._table_row(slot.blocks, m)
+                lengths[idx] = slot.cache_len
+                tokens[idx, 0] = slot.request.emitted[-1]
+                d = drafts.get(idx)
+                if d:
+                    tokens[idx, 1 : 1 + len(d)] = d
+                    draft_len[idx] = len(d)
+            self.decode_gather_bytes += gathered * self._block_bytes
+            fresh = self._note_bucket("decode_spec" if window > 1 else "decode", m)
+            span.set_metadata(live=len(live), width=m, drafted=len(drafts))
+            self._tick["live"], self._tick["width"] = len(live), m
+            dispatch_t0 = time.monotonic()
             if window > 1:
-                spec_rounds = 1
-                spec_proposed += int(draft_len[idx])
-                spec_accepted += int(accepts[idx])
-            self.decode_emitted_tokens += count
-            self.decode_slot_ticks += 1
-            for j in range(count):
-                self._emit(idx, int(out[idx, j]), emit_t)
-        if spec_rounds:
-            self.spec_rounds += spec_rounds
-            self.spec_proposed += spec_proposed
-            self.spec_accepted += spec_accepted
+                args = [self.params, self.cache.pool, tables, lengths, tokens, draft_len]
+            else:
+                args = [self.params, self.cache.pool, tables, lengths, tokens[:, 0]]
+            if self._poison_ordinal is not None:
+                # Armed: the program was traced with the poison lane.  NaN rides
+                # into exactly one slot's logits on that request's first decode
+                # dispatch; every other lane multiplies by 1.0 (vmap lanes are
+                # independent, so their tokens are bit-identical to unarmed).
+                poison = np.ones((s,), np.float32)
+                for idx in live:
+                    req = sched.slots[idx].request
+                    if getattr(req, "_poison_pending", False):
+                        poison[idx] = np.nan
+                        req._poison_pending = False  # fires once
+                args.append(poison)
+        with _TickPhase(self, "decode.wait", live=len(live)):
+            if window > 1:
+                # The verify program REPLACES the single-token one this tick —
+                # still exactly one fused decode dispatch per bucket.
+                t_rows, m_counts, ok_flags, self.cache.pool = self._decode_spec_fn(*args)
+                out = np.asarray(t_rows)
+                accepts = np.asarray(m_counts)
+            else:
+                next_tokens, ok_flags, self.cache.pool = self._decode_fn(*args)
+                out = np.asarray(next_tokens)[:, None]
+                accepts = np.zeros((s,), np.int32)
+            self.decode_dispatches += 1
+            tel = get_telemetry()
             if tel.enabled:
-                tel.registry.counter("serving.spec.rounds").inc(spec_rounds)
-                if spec_proposed:
-                    tel.registry.counter("serving.spec.proposed").inc(spec_proposed)
-                if spec_accepted:
-                    tel.registry.counter("serving.spec.accepted").inc(spec_accepted)
+                tel.registry.counter("serving.decode_dispatches").inc()
+                tel.registry.counter("serving.decode_gather_bytes").inc(
+                    gathered * self._block_bytes
+                )
+                tel.registry.gauge("serving.decode_bucket_width").set(m)
+            oks = np.asarray(ok_flags)
+        with _TickPhase(self, "decode.emit") as span:
+            emit_t = time.monotonic()
+            if self.tracer is not None:
+                # emit_t is PAST the np.asarray sync point, so the interval
+                # covers the real device work despite async dispatch.
+                self.tracer.on_decode(
+                    [(sched.slots[idx].request, idx) for idx in live],
+                    emit_t, co_batch=len(live), width=m, fresh=fresh,
+                    dispatch_ms=(emit_t - dispatch_t0) * 1e3,
+                    phase="verify" if window > 1 else "decode",
+                )
+            # rounds counts verify DISPATCHES (with >= 1 healthy lane);
+            # proposed/accepted are per-slot sums over the healthy lanes.
+            spec_rounds = spec_proposed = spec_accepted = emitted = 0
+            for idx in live:
+                slot = sched.slots[idx]
+                req = slot.request
+                if window > 1:
+                    # Accept bookkeeping: the emitted chunk is t[:count] where
+                    # count = accepted drafts + the correction/bonus row, capped
+                    # at remaining (count == remaining finishes the request on
+                    # its exact last token).  cache_len advances by count — the
+                    # rewind; rows past it are stale and re-written before read.
+                    count = min(int(accepts[idx]) + 1, req.remaining)
+                else:
+                    count = 1
+                slot.cache_len += count
+                if not bool(oks[idx]):
+                    # Quarantine instead of emitting the garbage argmax; the
+                    # other slots' emissions proceed untouched.
+                    self._quarantine(idx, emit_t)
+                    continue
+                if window > 1:
+                    spec_rounds = 1
+                    spec_proposed += int(draft_len[idx])
+                    spec_accepted += int(accepts[idx])
+                self.decode_emitted_tokens += count
+                self.decode_slot_ticks += 1
+                emitted += count
+                for j in range(count):
+                    self._emit(idx, int(out[idx, j]), emit_t)
+            if spec_rounds:
+                self.spec_rounds += spec_rounds
+                self.spec_proposed += spec_proposed
+                self.spec_accepted += spec_accepted
+                if tel.enabled:
+                    tel.registry.counter("serving.spec.rounds").inc(spec_rounds)
+                    if spec_proposed:
+                        tel.registry.counter("serving.spec.proposed").inc(spec_proposed)
+                    if spec_accepted:
+                        tel.registry.counter("serving.spec.accepted").inc(spec_accepted)
+            span.set_metadata(tokens=emitted)
 
     # -- completion / metrics ------------------------------------------------
 
@@ -1931,4 +1993,5 @@ class ServingEngine:
             "trace_blame": (
                 dict(self.tracer.blame_counts) if self.tracer is not None else None
             ),
+            "slow_ticks": self.tracer.slow_ticks() if self.tracer is not None else None,
         }

@@ -54,6 +54,8 @@ import os
 import time
 from typing import Dict, List, Optional
 
+from ..telemetry import annotate
+
 __all__ = ["ServingJournal", "JournalError", "JOURNAL_VERSION"]
 
 JOURNAL_VERSION = 1
@@ -164,16 +166,17 @@ class ServingJournal:
         }
         tmp = f"{self.path}.tmp"
         parent = os.path.dirname(os.path.abspath(self.path))
-        os.makedirs(parent, exist_ok=True)
-        with open(tmp, "w") as f:
-            json.dump(state, f)
-            f.flush()
-            if _fsync_enabled():
-                try:
-                    os.fsync(f.fileno())
-                except OSError:
-                    pass
-        os.replace(tmp, self.path)
+        with annotate("serving.journal.append", requests=len(self._requests)):
+            os.makedirs(parent, exist_ok=True)
+            with open(tmp, "w") as f:
+                json.dump(state, f)
+                f.flush()
+                if _fsync_enabled():
+                    try:
+                        os.fsync(f.fileno())
+                    except OSError:
+                        pass
+            os.replace(tmp, self.path)
         self._flushed = True
 
     # -- recovery ------------------------------------------------------------

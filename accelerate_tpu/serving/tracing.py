@@ -53,8 +53,10 @@ exists when a directory is configured (``ServingConfig.trace_dir``,
 from __future__ import annotations
 
 import collections
+import gc
 import glob
 import gzip
+import heapq
 import json
 import os
 import time
@@ -88,6 +90,7 @@ ENV_FLUSH_EVERY = "ACCELERATE_TPU_SERVING_TRACE_FLUSH_EVERY"
 
 DEFAULT_CAPACITY = 1024
 DEFAULT_FLUSH_EVERY = 32
+SLOW_TICKS = 8  # how many of the engine's slowest ticks the tracer keeps
 
 PHASES = (
     "queue_wait",
@@ -315,6 +318,7 @@ class ServingTracer:
         self._events = 0
         self._tick_t0: Optional[float] = None
         self._ticked: set = set()
+        self._slow: list = []  # min-heap of (total_ms, tick number, record)
 
     # -- engine hooks --------------------------------------------------------
 
@@ -422,14 +426,22 @@ class ServingTracer:
             self._ticked.add(req.id)
         self._note_event()
 
-    def end_tick(self, now: float, slots: dict) -> None:
+    def end_tick(self, now: float, slots: dict, tick: Optional[dict] = None) -> None:
         """Close the tick for every resident request: dispatched requests'
         last interval stretches to the tick boundary (the emit/bookkeeping
         tail stays attributed); a prefilling slot that never got its chunk
         turn records a ``waiting`` prefill interval — the co-batched-behind-
-        another-prefill time the blame question asks about."""
+        another-prefill time the blame question asks about.  ``tick`` is the
+        engine's record of the tick (``total_ms``, ``phase_ms`` by the names
+        of its ``serving.tick.*`` spans, the decode dispatch's ``live`` and
+        ``width``): kept if it is among the slowest, see :meth:`slow_ticks`."""
         if self._tick_t0 is None:
             return
+        # A tick that met a table width for the first time compiles, and is
+        # the compile_in_path phase's to tell: left out, or a cold engine's
+        # warm-up would hold all eight places for good.
+        if tick is not None and not tick["fresh"]:
+            self._note_slow(tick)
         for idx, slot in slots.items():
             t = self.live.get(slot.request.id)
             if t is None:
@@ -454,6 +466,31 @@ class ServingTracer:
                 t.add("prefill", now, waiting=True, ticks=1, slot=idx)
         self._tick_t0 = None
         self._note_event()
+
+    def _note_slow(self, tick: dict) -> None:
+        if len(self._slow) == SLOW_TICKS and tick["total_ms"] <= self._slow[0][0]:
+            return
+        record = {
+            "tick": tick["tick"],
+            "total_ms": round(tick["total_ms"], 3),
+            "phase_ms": {k: round(v, 3) for k, v in tick["phase_ms"].items()},
+            "live": tick["live"],
+            "prefilling": tick["prefilling"],
+            "width": tick["width"],
+            "gc_count": list(gc.get_count()),
+        }
+        entry = (tick["total_ms"], tick["tick"], record)
+        if len(self._slow) < SLOW_TICKS:
+            heapq.heappush(self._slow, entry)
+        else:
+            heapq.heapreplace(self._slow, entry)
+
+    def slow_ticks(self) -> List[dict]:
+        """The engine's slowest ticks since it started, slowest first, at
+        most ``SLOW_TICKS``: where a stall's time went, phase by phase, for
+        the operator who asks why p99 jumped (``engine.stats()["slow_ticks"]``;
+        one ``slow_ticks`` line in the JSONL at each flush, the last one wins)."""
+        return [record for _, _, record in sorted(self._slow, reverse=True)]
 
     def on_terminal(self, req, status: str) -> None:
         t = self.live.pop(req.id, None)
@@ -499,6 +536,8 @@ class ServingTracer:
         for t in self.live.values():
             if t.intervals or now > t.arrival:
                 self._write(t.to_record(status="inflight", now=now))
+        if self._slow:
+            self._write({"kind": "slow_ticks", "ticks": self.slow_ticks()})
         if self._file is not None:
             self._file.flush()
 

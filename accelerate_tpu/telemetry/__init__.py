@@ -3,8 +3,9 @@
 Three pillars (see ``docs/usage_guides/telemetry.md``):
 
 - **trace spans** — ``span("name")`` context-manager/decorator: wall-time,
-  process index and nesting to a per-process JSONL file, mirrored into
-  ``jax.profiler.TraceAnnotation`` for Perfetto/XPlane dumps;
+  process index and nesting to a per-process JSONL file, mirrored into the
+  profiler's trace (``annotate``, the profiler-only half) for Perfetto/XPlane
+  dumps;
 - **metrics registry** — counters/gauges/histograms with built-in collectors
   for step time, jit compile count/time (cache-miss detection via
   ``jax.monitoring``), tokens/sec, achieved-MFU, and device HBM bytes;
@@ -77,7 +78,7 @@ from .introspect import (
     inspect_compiled,
     lint_reshardings,
 )
-from .spans import span
+from .spans import annotate, span
 from .watchdog import StallWatchdog, thread_dump
 
 __all__ = [
@@ -87,6 +88,7 @@ __all__ = [
     "enable",
     "disable",
     "maybe_enable_from_env",
+    "annotate",
     "span",
     "Counter",
     "Gauge",

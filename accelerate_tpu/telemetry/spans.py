@@ -1,12 +1,16 @@
 """Trace spans: ``with span("name"):`` / ``@span("name")``.
 
 Each span records wall-time, process index, and nesting (a thread-local name
-stack) to the telemetry JSONL sink, and mirrors into
-``jax.profiler.TraceAnnotation`` so the same names show up in Perfetto/XPlane
-dumps captured with ``Accelerator.profile()``.
+stack) to the telemetry JSONL sink, and mirrors into the profiler through
+:func:`annotate` so the same names show up in Perfetto/XPlane dumps captured
+with ``Accelerator.profile()`` or a bare ``jax.profiler`` session.
 
-When telemetry is disabled, ``__enter__`` is a single attribute check — safe
-to leave on every hot path.
+:func:`annotate` is the profiler-only half, and the one place where the
+program touches ``jax.profiler``: a ``TraceAnnotation`` entered whatever
+telemetry's state is.  With no profiler session open it checks one flag (its
+keywords are encoded only while tracing), so it stays on every hot path; it
+writes no JSONL record and no histogram.  ``span`` adds those two, and only
+when telemetry is enabled.
 """
 
 from __future__ import annotations
@@ -15,11 +19,21 @@ import functools
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from .core import get_telemetry
 
-__all__ = ["span"]
+__all__ = ["annotate", "span"]
 
 _tls = threading.local()
+
+
+def annotate(name: str, **meta) -> TraceAnnotation:
+    """``with annotate("serving.tick", tick=7):`` — a host span on the
+    profiler's own timeline (``.xplane.pb``, beside the device operations),
+    ``meta`` as the event's stats.  What is known only at the end goes in
+    through the returned object's ``set_metadata(**meta)``."""
+    return TraceAnnotation(name, **meta)
 
 
 class span:
@@ -42,6 +56,8 @@ class span:
         self._path = None
 
     def __enter__(self):
+        self._ann = annotate(self.name)
+        self._ann.__enter__()
         tel = get_telemetry()
         if not tel.enabled:
             return self
@@ -51,27 +67,16 @@ class span:
             stack = _tls.stack = []
         self._path = "/".join(stack + [self.name])
         stack.append(self.name)
-        try:
-            import jax
-
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
+        self._ann = None
         if self._t0 is None:  # telemetry was off at __enter__
             return False
         dur_ms = (time.perf_counter() - self._t0) * 1e3
         self._t0 = None
-        if self._ann is not None:
-            try:
-                self._ann.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
-            self._ann = None
         stack = _tls.stack
         if stack and stack[-1] == self.name:
             stack.pop()

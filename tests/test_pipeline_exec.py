@@ -714,3 +714,14 @@ def test_compile_cache_round_trip_and_hit_counter(tmp_path, monkeypatch, _restor
     before = tel.registry.counter("jit.cache_hits").value
     jax.jit(f)(jnp.arange(8.0)).block_until_ready()
     assert tel.registry.counter("jit.cache_hits").value > before
+    # The key holds the library's source: an executable compiled by another
+    # source (whose named scopes it would carry into every profile) is no hit.
+    from jax._src import cache_key
+
+    assert cache_key.custom_hook().endswith("accelerate_tpu=" + compile_cache_mod.library_digest())
+    monkeypatch.setattr(cache_key.custom_hook, "library_digest", "another source")
+    jax.clear_caches()
+    before, entries = tel.registry.counter("jit.cache_hits").value, len(os.listdir(cache_dir))
+    jax.jit(f)(jnp.arange(8.0)).block_until_ready()
+    assert tel.registry.counter("jit.cache_hits").value == before
+    assert len(os.listdir(cache_dir)) > entries

@@ -310,6 +310,106 @@ def test_bucket_compile_event_and_width_gauge_without_tracing(
     assert all(isinstance(e["width"], int) for e in events)
 
 
+# ---------------------------------------------------------------------------
+# Spans inside the tick (telemetry.annotate) and the slow-tick record
+# ---------------------------------------------------------------------------
+
+TICK_PHASES = ["admit", "prefill.build", "prefill.wait", "prefill.emit",
+               "decode.build", "decode.wait", "decode.emit", "publish"]
+
+
+def _busy_engine(cfg, params, **overrides):
+    """An engine in mid-flight with its programs warm: one request decoding,
+    one with prompt chunks still to prefill, so a tick runs every phase."""
+    eng = _engine(cfg, params, prefix_cache=False, **overrides)
+    for _ in range(2):  # the first pair runs to its end and leaves every table width compiled
+        eng.run()
+        eng.submit(list(range(1, 6)), 24)
+        eng.submit(list(range(1, 25)), 4)
+    eng.step()
+    return eng
+
+
+def test_tick_spans_in_a_profiler_session(gpt2_setup, tmp_path):
+    """A profiler session round three ticks holds every serving.tick.* span:
+    nested in its serving.tick, in the tick's own order, children sharing the
+    parent's ``tick``, the counts the issue's table gives as their stats."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg, params = gpt2_setup
+    eng = _busy_engine(cfg, params)
+    first = eng.ticks + 1
+    jax.profiler.start_trace(str(tmp_path))
+    for _ in range(3):
+        eng.step()
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = sorted(
+        (e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes for line in plane.lines for e in line.events
+        if e.name.startswith("serving.tick")
+    )
+    ticks = [e for e in events if e[2] == "serving.tick"]
+    assert [e[3]["tick"] for e in ticks] == [first, first + 1, first + 2]
+    assert set(ticks[0][3]) == {"tick", "queued", "prefilling", "decoding"}
+    assert ticks[0][3]["prefilling"] == 1 and ticks[0][3]["decoding"] == 1
+    for start, end, _, stats in ticks:
+        children = [e for e in events if e[2] != "serving.tick" and e[3]["tick"] == stats["tick"]]
+        assert [e[2] for e in children] == ["serving.tick." + p for p in TICK_PHASES]
+        assert all(start <= e[0] and e[1] <= end for e in children)
+        assert all(a[1] <= b[0] for a, b in zip(children, children[1:]))  # one after the other
+        by_name = {e[2].removeprefix("serving.tick."): e[3] for e in children}
+        assert by_name["admit"]["admitted"] == 0
+        assert set(by_name["prefill.build"]) == {"tick", "request", "start", "rows", "width"}
+        assert by_name["prefill.wait"]["request"] == by_name["prefill.emit"]["request"] == by_name["prefill.build"]["request"]
+        assert by_name["prefill.emit"]["first_token"] in (0, 1)
+        assert by_name["decode.build"]["live"] == by_name["decode.wait"]["live"] >= 1
+        assert by_name["decode.emit"]["tokens"] == by_name["decode.build"]["live"]
+    assert sum(e[3]["first_token"] for e in events if e[2] == "serving.tick.prefill.emit") == 1
+
+
+def test_slow_ticks_keep_the_slowest_and_say_which_phase(gpt2_setup, tmp_path, monkeypatch):
+    """The tracer keeps the engine's eight slowest ticks, slowest first, each
+    with its phases (summing to the tick) and the decode dispatch's shape; a
+    tick held up in admission shows its time under ``admit``; ticks that met
+    a fresh table width are the compile_in_path phase's and are left out."""
+    from accelerate_tpu.serving.tracing import SLOW_TICKS
+
+    cfg, params = gpt2_setup
+    assert _engine(cfg, params, trace=False).stats()["slow_ticks"] is None
+    eng = _busy_engine(cfg, params, trace_dir=str(tmp_path))
+    admit = eng.sched.admit
+    held = eng.ticks + 2
+
+    def slow_admit(now):
+        if eng.ticks == held:
+            time.sleep(0.05)
+        return admit(now)
+
+    monkeypatch.setattr(eng.sched, "admit", slow_admit)
+    for _ in range(4):
+        eng.step()
+    slow = eng.stats()["slow_ticks"]
+    assert len(slow) == SLOW_TICKS == 8
+    assert [t["total_ms"] for t in slow] == sorted((t["total_ms"] for t in slow), reverse=True)
+    for t in slow:
+        assert set(t) == {"tick", "total_ms", "phase_ms", "live", "prefilling", "width", "gc_count"}
+        assert abs(sum(t["phase_ms"].values()) - t["total_ms"]) < 1.0
+        assert set(t["phase_ms"]) <= set(TICK_PHASES) and len(t["gc_count"]) == 3
+    assert slow[0]["tick"] == held and slow[0]["phase_ms"]["admit"] >= 50.0
+    assert slow[0]["total_ms"] - slow[0]["phase_ms"]["admit"] < slow[0]["phase_ms"]["admit"]
+    assert slow[0]["live"] >= 1 and slow[0]["width"] >= 1
+    assert 1 not in [t["tick"] for t in slow]  # the first tick compiled both programs: by far the slowest, and left out
+    assert eng.stats()["ticks"] - 1 > SLOW_TICKS
+    eng.tracer.flush()
+    with open(eng.tracer.path) as f:
+        lines = [json.loads(line) for line in f]
+    assert [r for r in lines if r["kind"] == "slow_ticks"][-1]["ticks"] == slow
+    assert all(r["kind"] == "serving_trace" for r in load_serving_traces(str(tmp_path)))
+
+
 def test_sigkill_trace_stitches_across_engine_lives(gpt2_setup, tmp_path):
     """Satellite (extends the PR 14 chaos proof): a SIGKILLed engine's
     periodic in-flight snapshots plus the successor's terminal records

@@ -21,6 +21,7 @@ from accelerate_tpu.telemetry import (
     CompileWatcher,
     MetricsRegistry,
     StallWatchdog,
+    annotate,
     get_telemetry,
     peak_flops_per_chip,
     span,
@@ -40,6 +41,10 @@ def _telemetry_off():
     get_telemetry().step_timer.reset()
     yield
     telemetry.disable()
+    # configure()'s constants outlive reset(): left set, they decide the next
+    # test file's effective_flops_per_step on the same xdist worker
+    timer = get_telemetry().step_timer
+    timer.tokens_per_step = timer.flops_per_step = None
 
 
 def _read_jsonl(tel):
@@ -109,6 +114,44 @@ def test_span_decorator_and_exception_flag(tmp_path):
     assert failing["error"] == "ValueError"
     # Registry mirrors every span into a histogram.
     assert tel.registry.snapshot()["span.decorated_ms.count"] == 2
+
+
+def _profiled_events(trace_dir, name):
+    """Host events called ``name`` in the one profile under ``trace_dir``: (stats, duration_ns)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    return [
+        (dict(e.stats), e.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for e in line.events
+        if e.name == name
+    ]
+
+
+@pytest.mark.parametrize("make,enabled,records", [
+    (annotate, False, 0), (annotate, True, 0), (span, False, 0), (span, True, 1),
+])
+def test_annotate_is_the_profiler_half_of_span(tmp_path, make, enabled, records):
+    """Both show in a profiler session whatever telemetry's state is (annotate
+    with its keywords as the event's stats); a JSONL record and a histogram
+    come from span alone, and only with telemetry on."""
+    tel = telemetry.enable(dir=str(tmp_path / "tel")) if enabled else get_telemetry()
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    with make("probe.scope", detail=3):
+        pass
+    jax.profiler.stop_trace()
+    ((stats, duration_ns),) = _profiled_events(str(tmp_path / "prof"), "probe.scope")
+    assert duration_ns > 0 and stats == ({"detail": 3} if make is annotate else {})
+    if not enabled:
+        assert tel._file is None and tel.registry.snapshot() == {}
+        return
+    spans = [r for r in _read_jsonl(tel) if r["kind"] == "span"]
+    assert [r["name"] for r in spans] == ["probe.scope"] * records
+    assert ("span.probe.scope_ms.count" in tel.registry.snapshot()) == bool(records)
 
 
 def test_span_enabled_mid_flight_records_nothing_for_open_context(tmp_path):
@@ -420,3 +463,87 @@ def test_profile_honors_trace_dir_env(tmp_path, monkeypatch):
     trace_dir = os.path.join(out_dir, "profile_0")
     assert os.path.isdir(trace_dir)
     assert any(files for _, _, files in os.walk(trace_dir)), "no trace artifacts written"
+
+
+# ---------------------------------------------------------------------------
+# Stable names on the device side: named scopes in the jitted steps, kernel names
+# ---------------------------------------------------------------------------
+
+
+def _op_names(lowered):
+    """Every ``op_name`` of the compiled program, as one text (what a device trace shows per operation)."""
+    import re
+
+    return "\n".join(sorted(set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))))
+
+
+def _lowered_train_step():
+    import optax
+
+    from accelerate_tpu import Accelerator, JaxModel
+    from accelerate_tpu.models import llama
+
+    cfg = llama.LlamaConfig.tiny(remat=True, remat_policy="nothing")
+    params = llama.init_params(cfg, jax.random.key(0))
+
+    def apply_fn(params, input_ids):
+        return {"loss": llama.loss_fn(params, {"input_ids": input_ids}, cfg)}
+
+    acc = Accelerator()
+    model, opt = acc.prepare(JaxModel(apply_fn, params, partition_rules=llama.PARTITION_RULES), optax.adamw(1e-3))
+    return acc.make_train_step(model, opt, clip_norm=1.0).lower({"input_ids": jnp.zeros((2, 16), jnp.int32)})
+
+
+def _lowered_paged_decode():
+    import numpy as np
+
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init_params(cfg, jax.random.key(0))
+    eng = ServingEngine(
+        llama.apply_cached, llama.init_cache, params, cfg,
+        serving=ServingConfig(block_size=4, num_blocks=16, max_slots=2, max_blocks_per_seq=4, prefill_chunk=8),
+    )
+    assert eng.decode_path == "paged"
+    return eng._decode_fn.lower(
+        params, eng.cache.pool, np.zeros((2, 2), np.int32), np.zeros((2,), np.int32), np.zeros((2,), np.int32)
+    )
+
+
+@pytest.mark.parametrize("lower,scopes", [
+    (_lowered_train_step, [
+        "loss_grad/jvp(embed)", "loss_grad/jvp(layers)/", "loss_grad/transpose(jvp(layers))/", "/attn/attn.qkv/",
+        "/attn/attn.core/", "/attn/attn.out/", "/mlp/", "loss_grad/jvp(head_loss)/", "jit(step)/clip/",
+        "jit(step)/optimizer/", "/checkpoint/rematted_computation/attn/", "/checkpoint/rematted_computation/mlp/",
+    ]),
+    (_lowered_paged_decode, [
+        "jit(decode)/embed/", "jit(decode)/layers/", "/attn/attn.qkv/", "/attn/kv_pool/kv_pool.gather/", "/attn/attn.core/",
+        "/attn/attn.out/", "/mlp/", "jit(decode)/head/", "jit(decode)/kv_pool.write/",
+    ]),
+])
+def test_jitted_steps_carry_their_scopes(lower, scopes):
+    """What chipbench's scope metrics read by name (PERF.md section 3): each
+    scope stands in the ``op_name``s of the compiled program, autodiff's and
+    remat's wrappers round it as the readers expect them."""
+    names = _op_names(lower())
+    missing = [s for s in scopes if s not in names]
+    assert not missing, missing
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_attention", "paged_window_attention"])
+def test_pallas_kernels_carry_their_names(kernel):
+    from accelerate_tpu.ops import pallas_attention as pa
+
+    q = jnp.ones((1, 128, 2, 64))
+    pool, tables, lengths = jnp.ones((4, 8, 2, 64)), jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32)
+    if kernel.startswith("flash"):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda x: pa.pallas_attention(x, x, x, block_size=128, interpret=True).sum()))(q)
+    elif kernel == "paged_attention":
+        jaxpr = jax.make_jaxpr(lambda: pa.pallas_paged_attention(
+            q[:, 0], q[:, 0], q[:, 0], pool, pool, tables, lengths, interpret=True))()
+    else:
+        jaxpr = jax.make_jaxpr(lambda: pa.pallas_paged_window_attention(
+            q[:, :2], q[:, :2], q[:, :2], pool, pool, tables, lengths, interpret=True))()
+    assert f"name={kernel}\n" in str(jaxpr) or f"name={kernel} " in str(jaxpr)
