@@ -19,7 +19,7 @@ __all__ = [
     "unpack_cache_from_scan", "cache_write", "speculative_generate_loop",
     "speculative_verify_greedy",
     "make_paged_pool", "gather_block_view", "extract_token_rows",
-    "scatter_token_rows", "paged_cache_write", "pack_paged_pool_for_scan",
+    "scatter_token_rows", "paged_cache_write", "address_paged_pool_by_layer",
     "unpack_paged_rows_from_scan", "demote_pool_blocks", "promote_pool_blocks",
 ]
 
@@ -238,6 +238,25 @@ def _insert_rows(ctx: jax.Array, new_rows: jax.Array, starts: jax.Array) -> jax.
     return jnp.where(in_new, picked, ctx)
 
 
+def _blocks_lie_row_by_row(leaf) -> bool:
+    """Whether a TPU holds a K/V pool leaf ``[..., bs, K, hd]`` block by block
+    and, within a block, as ``bs*K`` whole rows of ``hd`` one after the other.
+    It tiles the two minor axes by 8 sublanes x 128 lanes (K x 128 where K is
+    1, 2 or 4 and hd 128), so it does exactly when K fills whole tiles and hd
+    whole lanes; other geometries it pads or permutes (at hd 64 the block
+    axis lies in the lanes; an int8 pool's ``bs`` and ``K`` change places).
+    Where this holds, ``[L, N, bs, K, hd] -> [L*N, bs*K, hd]`` is a free view
+    and XLA:TPU gathers blocks from the pool where it lies; where it does not,
+    any gather from the whole pool is answered with a re-laid-out copy of the
+    whole pool (PERF.md section 7.0a).  Deliberately narrow: a wrong ``False``
+    costs what the program cost until PR 27, a wrong ``True`` a second pool,
+    and ``tests/test_tpu_compile.py`` holds both sides of every edge to the
+    compiler's own answer."""
+    kv_heads, head_dim = leaf.shape[-2:]
+    return leaf.dtype.itemsize > 1 and head_dim % 128 == 0 and (
+        kv_heads % 8 == 0 or (head_dim == 128 and kv_heads in (1, 2, 4)))
+
+
 @jax.named_scope("kv_pool.gather")
 def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts: jax.Array, dtype):
     """Per-layer paged analog of :func:`cache_write`: compute the stored
@@ -246,11 +265,16 @@ def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts
     ``[B, M*bs, K, hd]`` gathered straight through the block tables ``[B, M]``
     with the new rows overlaid at ``starts[b] + arange(T)``.
 
+    ``pool_layer`` is any leaf whose leading axis the tables index, as
+    :func:`address_paged_pool_by_layer` hands it over: the whole pool
+    flattened to ``[L*N, bs, K, hd]`` with the tables offset to the layer's
+    rows, or one layer ``[N, bs, K, hd]``.
+
     Unlike the dense path, nothing here flows back out as an updated cache:
-    the pool leaf is consumed read-only (a scan ``xs``), the stored rows ride
-    out as tiny per-layer ``ys``, and the engine scatters them into the
-    donated pool after the forward — HBM write traffic per token is the new
-    rows, not the per-slot worst-case view."""
+    the pool leaf is consumed read-only, the stored rows ride out as tiny
+    per-layer ``ys``, and the engine scatters them into the donated pool
+    after the forward — HBM write traffic per token is the new rows, not the
+    per-slot worst-case view."""
     b = tables.shape[0]
     m = tables.shape[1]
     if isinstance(pool_layer, tuple):  # int8: (codes [N, bs, K, hd], scale [N, bs, K])
@@ -268,23 +292,48 @@ def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts
         # token-identical to the offline int8 cache.
         new_full = dequantize_kv(n_codes, n_scale, dtype)
     else:
-        bs = pool_layer.shape[1]
+        n, bs, kh, hd = pool_layer.shape
         stored = new_rows.astype(pool_layer.dtype)
-        ctx = jnp.take(pool_layer, tables, axis=0).reshape(
-            b, m * bs, *pool_layer.shape[2:]
-        )
+        # Whole blocks are gathered as [bs*K, hd] rows where that is a free
+        # view: the TPU then reads a block as full (8, 128) tiles, 2.7 times
+        # as fast as through the (K, 128) tiles of [bs, K, hd] at K = 2.
+        rows = pool_layer.reshape(n, bs * kh, hd) if _blocks_lie_row_by_row(pool_layer) else pool_layer
+        ctx = jnp.take(rows, tables, axis=0).reshape(b, m * bs, kh, hd)
         new_full = stored
     return stored, _insert_rows(ctx, new_full, starts)
 
 
-def pack_paged_pool_for_scan(pool: dict):
-    """Pool leaves in the tuple form the per-layer scan body consumes:
-    ``(k, v)`` arrays, or ``((k, k_scale), (v, v_scale))`` for int8 — each
-    leading with the layer axis so ``lax.scan`` slices one layer per step."""
-    quant = "k_scale" in pool
-    pk = (pool["k"], pool["k_scale"]) if quant else pool["k"]
-    pv = (pool["v"], pool["v_scale"]) if quant else pool["v"]
-    return pk, pv, quant
+@jax.named_scope("kv_pool.gather")
+def address_paged_pool_by_layer(pool: dict, tables: jax.Array, layer: jax.Array):
+    """One layer of the pool for a family's per-layer scan body: ``(pk, pv,
+    tables)`` as ``paged_cache_write`` and the Pallas paged kernels take them
+    (a leaf ``[rows, bs, K, hd]``, or ``(codes, scale)`` for int8, and block
+    ids that index its rows).  The scan closes over the pool and scans the
+    layer number; nothing of the pool is a scanned input.
+
+    Where the TPU holds the pool block by block (:func:`_blocks_lie_row_by_row`)
+    the leaves are the whole pool with its two major axes merged, ``[L, N, bs,
+    ...] -> [L*N, bs, ...]`` — a free view — and the tables are offset to
+    the layer's rows (``tables + layer * N``; the null block of layer ``l`` is
+    row ``l*N``): the layer gathers the blocks its tables name from the pool
+    where it lies, and nothing else of the pool is read, sliced or re-tiled.
+    Handed to ``lax.scan`` as ``xs`` instead, every layer's whole ``[N, bs,
+    ...]`` slice was cut out and re-tiled, a cost in ``num_blocks`` and not
+    in the blocks named (PERF.md section 6, PR 27).
+
+    Any other pool (int8, ``hd`` under 128, odd ``K``) no gather reads where
+    it lies: from the merged view XLA:TPU would re-lay out the *whole* pool
+    once a dispatch, a second pool in memory.  There the layer's slice is cut
+    here, as the scan did, at the same cost as before."""
+    if _blocks_lie_row_by_row(pool["k"]):
+        num_blocks = pool["k"].shape[1]
+        leaves = {name: leaf.reshape((-1,) + leaf.shape[2:]) for name, leaf in pool.items()}
+        tables = tables + layer * num_blocks
+    else:
+        leaves = {name: jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False) for name, leaf in pool.items()}
+    if "k_scale" in pool:
+        return (leaves["k"], leaves["k_scale"]), (leaves["v"], leaves["v_scale"]), tables
+    return leaves["k"], leaves["v"], tables
 
 
 def unpack_paged_rows_from_scan(k_rows, v_rows, quant: bool) -> dict:

@@ -372,14 +372,14 @@ def apply_paged(
     int8 pools take the always-correct XLA path.  Prefill never passes
     ``kernel=True``."""
     from .generation import (
-        pack_paged_pool_for_scan,
+        address_paged_pool_by_layer,
         paged_cache_write,
         unpack_paged_rows_from_scan,
     )
 
     c = config
     b, t = input_ids.shape
-    pk_in, pv_in, quant = pack_paged_pool_for_scan(pool)
+    quant = "k_scale" in pool
     bs = pool["k"].shape[2]
     total = tables.shape[1] * bs
     if total > c.max_seq_len:
@@ -394,14 +394,11 @@ def apply_paged(
     use_kernel = kernel and not quant
 
     def body(carry, xs):
-        if quant:
-            lp, ck, cks, cv, cvs = xs
-            pk, pv = (ck, cks), (cv, cvs)
-        else:
-            lp, pk, pv = xs
+        lp, layer = xs
         lp = _dequant_layer(lp)
         x = carry
         q, k, v = _qkv(x, lp, c)
+        pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
         if use_kernel:
             from ..ops.pallas_attention import (
                 pallas_paged_attention,
@@ -412,25 +409,23 @@ def apply_paged(
             v_store = v.astype(pv.dtype)
             if t == 1:
                 attn = pallas_paged_attention(
-                    q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, tables, starts
+                    q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, ltab, starts
                 )[:, None].reshape(b, t, c.hidden_size)
             else:
                 attn = pallas_paged_window_attention(
-                    q, k_store, v_store, pk, pv, tables, starts
+                    q, k_store, v_store, pk, pv, ltab, starts
                 ).reshape(b, t, c.hidden_size)
         else:
-            k_store, k_full = paged_cache_write(pk, k, tables, starts, c.dtype)
-            v_store, v_full = paged_cache_write(pv, v, tables, starts, c.dtype)
+            k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
+            v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
             attn = _attend(q, k_full, v_full, mask[:, None], c)
         x = x + attn @ lp["w_proj"].astype(c.dtype) + lp["b_proj"].astype(c.dtype)
         x = _mlp_block(x, lp, c)
         return x, (k_store, v_store)
 
-    xs = (params["layers"],) + (
-        (pool["k"], pool["k_scale"], pool["v"], pool["v_scale"]) if quant
-        else (pool["k"], pool["v"])
-    )
-    x, (k_rows, v_rows) = jax.lax.scan(body, x, xs)
+    # the pool is a constant of the loop, addressed by layer in its body: never a scanned input
+    layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
+    x, (k_rows, v_rows) = jax.lax.scan(body, x, (params["layers"], layers))
     x = _layer_norm(x, params["final_ln_scale"], params["final_ln_bias"], c.layer_norm_eps)
     logits = (x @ params["wte"].astype(c.dtype).T).astype(jnp.float32)
     return logits, unpack_paged_rows_from_scan(k_rows, v_rows, quant)

@@ -880,14 +880,14 @@ def apply_paged(
     window variant at ``T > 1`` (the speculative verify dispatch; GQA folds
     into the kernel's grouped layout); int8 pools stay on the XLA path."""
     from .generation import (
-        pack_paged_pool_for_scan,
+        address_paged_pool_by_layer,
         paged_cache_write,
         unpack_paged_rows_from_scan,
     )
 
     c = config
     b, t = input_ids.shape
-    _, _, quant = pack_paged_pool_for_scan(pool)
+    quant = "k_scale" in pool
     bs = pool["k"].shape[2]
     total = tables.shape[1] * bs
     positions = starts[:, None].astype(jnp.int32) + jnp.arange(t, dtype=jnp.int32)[None]
@@ -897,11 +897,7 @@ def apply_paged(
     use_kernel = kernel and not quant
 
     def body(carry, xs):
-        if quant:
-            lp, ck, cks, cv, cvs = xs
-            pk, pv = (ck, cks), (cv, cvs)
-        else:
-            lp, pk, pv = xs
+        lp, layer = xs
         lp = _dequant_layer(lp)
         x = carry
         with jax.named_scope("attn"):
@@ -909,6 +905,7 @@ def apply_paged(
             with jax.named_scope("attn.qkv"):
                 q, k, v = _qkv_proj(h, lp, c, b, t)
                 q, k = _rope(q, k, positions, c.rope_theta, getattr(c, "rope_scaling", None))
+            pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
             if use_kernel:
                 from ..ops.pallas_attention import (
                     pallas_paged_attention,
@@ -920,27 +917,25 @@ def apply_paged(
                 with jax.named_scope("attn.core"):
                     if t == 1:
                         attn = pallas_paged_attention(
-                            q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, tables, starts
+                            q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, ltab, starts
                         )[:, None]
                     else:
                         attn = pallas_paged_window_attention(
-                            q, k_store, v_store, pk, pv, tables, starts
+                            q, k_store, v_store, pk, pv, ltab, starts
                         )
             else:
                 with jax.named_scope("kv_pool"):
-                    k_store, k_full = paged_cache_write(pk, k, tables, starts, c.dtype)
-                    v_store, v_full = paged_cache_write(pv, v, tables, starts, c.dtype)
+                    k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
+                    v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
                 with jax.named_scope("attn.core"):
                     attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
             y = x + _out_proj(attn, lp, c)
         return _mlp_block(y, lp, c), (k_store, v_store)
 
-    xs = (params["layers"],) + (
-        (pool["k"], pool["k_scale"], pool["v"], pool["v_scale"]) if quant
-        else (pool["k"], pool["v"])
-    )
+    # the pool is a constant of the loop, addressed by layer in its body: never a scanned input
+    layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
     with jax.named_scope("layers"):
-        x, (k_rows, v_rows) = jax.lax.scan(body, x, xs)
+        x, (k_rows, v_rows) = jax.lax.scan(body, x, (params["layers"], layers))
     with jax.named_scope("head"):
         logits = unembed(params, x, c)
     return logits, unpack_paged_rows_from_scan(k_rows, v_rows, quant)

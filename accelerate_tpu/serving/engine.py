@@ -9,16 +9,25 @@ One engine **tick** (:meth:`ServingEngine.step`) is:
    huge dispatch that stalls every in-flight request;
 3. **decode** — ONE fused jitted dispatch advances every decoding slot by
    one token.  On the default **paged fast path** the family's
-   ``apply_paged`` consumes pool K/V *in place* through the block tables
-   (``models/generation.py paged_cache_write``): no dense per-slot cache
-   view is ever materialized, no updated view ever flows back out of the
-   program — only the freshly written K/V rows, which scatter into the
-   donated pool.  Block tables are **bucketed** to the next power of two of
-   the widest live slot, so per-token gather traffic scales with the blocks
-   requests actually own, not the worst-case table width (the
-   ``serving.decode_gather_bytes`` counter is the accounting).  Families
-   without ``apply_paged`` (capacity-routed MoE) or
-   ``ServingConfig(decode_path="dense")`` fall back to the PR 9 program:
+   ``apply_paged`` reads pool K/V through the block tables
+   (``models/generation.py paged_cache_write``): the pool is a constant of
+   the layer loop, never a scanned input of it.  Where the TPU holds the
+   pool block by block (bf16, ``hd`` a multiple of 128, ``K`` 1, 2, 4 or a
+   multiple of 8) it is addressed by (layer, block) in one flat view
+   (``address_paged_pool_by_layer``): a layer gathers the blocks its tables
+   name and nothing else of the pool is sliced, copied or re-tiled (as a
+   scanned input every layer's whole slice was: 47% of the device's time at
+   8192 blocks, PERF.md section 6, PR 27).  Any other pool (int8, ``hd``
+   64, odd ``K``) still has its layer's slice cut in the loop, a cost in
+   ``num_blocks``, until the resident layout changes (ROADMAP A11).  No dense
+   per-slot cache view is ever materialized, no updated view ever flows
+   back out of the program — only the freshly written K/V rows, which
+   scatter into the donated pool.  Block tables are **bucketed** to the next
+   power of two of the widest live slot, so per-token gather traffic scales
+   with the blocks requests actually own, not the worst-case table width or
+   the pool's size (``serving.decode_gather_bytes`` counts the blocks the
+   tables name, on the host).  Families without ``apply_paged``
+   (capacity-routed MoE) or ``ServingConfig(decode_path="dense")`` fall back to the PR 9 program:
    gather the dense view, ``vmap`` the family's ``apply_cached``, extract
    and scatter the written rows.  Either way the
    1-dispatch-per-decode-step invariant from ``make_train_step`` carries

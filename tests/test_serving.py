@@ -453,10 +453,91 @@ def test_decode_path_matrix_token_identical(decode_path, quant):
         assert out == want[ids[rid]], f"{decode_path}/int8={quant}: request {rid} diverged"
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (scan and while bodies, pjit and closed calls, branches, kernels)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("pool_kind", ["fp", "int8", "fp_hd16"])
+@pytest.mark.parametrize("family_name", ["gpt2", "llama"])
+def test_paged_pool_is_a_constant_of_the_layer_scan(family_name, pool_kind, kernel):
+    """What the device moves, as far as a jaxpr can say it: ``apply_paged``
+    hands the layer scan no pool leaf to slice (its ``xs`` are the layers'
+    weights and the layer number); the pool reaches the scan's body as loop
+    constants.  As scanned inputs (until PR 27) XLA cut every layer's ``[N,
+    bs, ...]`` slice out of the pool and re-tiled it whole, in every layer of
+    every dispatch: 47% of the chip's busy time in the chat cell (PERF.md
+    section 6, PR 27).
+
+    ``fp`` is a pool the TPU holds block by block (K 2, hd 128, the chat
+    cell's): no ``dynamic_slice`` anywhere cuts a ``num_blocks``-sized piece,
+    and every gather from the pool reads the flat ``[L*N, ...]`` view through
+    tables offset by ``layer * N``.  ``int8`` and ``fp_hd16`` are pools no
+    gather reads where they lie (PERF.md section 7.0a): from the flat view
+    XLA:TPU would copy the whole pool, so the body cuts its layer out, once
+    a leaf, and gathers from that; no operation but that slice takes the
+    whole pool.  On the chip the witness is ``serve.layer_loop_share``;
+    ``tests/test_tpu_compile.py`` asks the TPU compiler itself."""
+    from accelerate_tpu.models import llama
+
+    family, config_cls = {"gpt2": (gpt2, gpt2.GPT2Config), "llama": (llama, llama.LlamaConfig)}[family_name]
+    wide = dict(hidden_size=256, num_heads=2) if pool_kind == "fp" else {}
+    cfg = config_cls.tiny(dtype=jnp.float32, kv_cache_quant=pool_kind == "int8", **wide)
+    num_blocks, block_size, width, slots, chunk = 37, 4, 5, 3, 8
+    params = jax.eval_shape(lambda: family.init_params(cfg, jax.random.key(0)))
+    pool = jax.eval_shape(lambda: make_paged_pool(family.init_cache, cfg, num_blocks, block_size))
+    whole = cfg.num_layers * num_blocks
+    pool_sized = {num_blocks, whole}
+    assert len(pool) == (4 if pool_kind == "int8" else 2)
+    assert pool["k"].shape[-1] == (128 if pool_kind == "fp" else 16)
+    assert not any(pool_sized & set(leaf.shape) for leaf in jax.tree.leaves(params))  # the sizes name the pool alone
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    shapes = {"decode": (i32(slots, 1), i32(slots, width), i32(slots)), "prefill": (i32(1, chunk), i32(1, width), i32(1))}
+    for what, (ids, tables, starts) in shapes.items():
+        jaxpr = jax.make_jaxpr(
+            lambda p, pl, i, t, s: family.apply_paged(p, i, cfg, pl, t, s, kernel=kernel)
+        )(params, pool, ids, tables, starts).jaxpr
+        scans = [e for e in jaxpr.eqns if e.primitive.name == "scan" and e.params["length"] == cfg.num_layers]
+        assert len(scans) == 1, f"{what}: one layer scan expected"
+        scan = scans[0]
+        first_x = scan.params["num_consts"] + scan.params["num_carry"]
+        for var in scan.invars[first_x:]:
+            assert not pool_sized & set(var.aval.shape), f"{what}: the scan slices a pool-sized input {var.aval}"
+        consts = [v for v in scan.invars[:scan.params["num_consts"]] if v.aval.shape[:2] == (cfg.num_layers, num_blocks)]
+        assert len(consts) == len(pool), f"{what}: {len(consts)} of {len(pool)} pool leaves are constants of the loop"
+        cut = [v.aval.shape for e in _eqns(jaxpr) if e.primitive.name == "dynamic_slice" for v in e.outvars
+               if pool_sized & set(v.aval.shape)]
+        takes_whole_pool = [e.primitive.name for e in _eqns(jaxpr)
+                            if any(hasattr(v, "aval") and v.aval.shape[:2] == (cfg.num_layers, num_blocks) for v in e.invars)]
+        if pool_kind == "fp":
+            assert not cut, f"{what}: dynamic_slice cuts {cut} out of the pool"
+            read = [v.aval.shape[0] for e in _eqns(jaxpr) if e.primitive.name in ("gather", "pallas_call")
+                    for v in e.invars if hasattr(v, "aval") and pool_sized & set(v.aval.shape[:1])]
+            assert read and set(read) == {whole}, f"{what}: reads of the pool lead with {read}, not with L*N"
+        else:
+            assert sorted(cut) == sorted((1,) + leaf.shape[1:] for leaf in pool.values()), f"{what}: {cut}"
+            assert set(takes_whole_pool) <= {"scan", "dynamic_slice"}, f"{what}: {takes_whole_pool} take the whole pool"
+
+
 def test_paged_decode_gather_bytes_scale_with_live_blocks(gpt2_setup):
-    """The headline invariant: paged decode's per-tick gather traffic is
-    proportional to the blocks live requests own; the dense program always
-    pays the worst-case table."""
+    """A host count, not a device measurement: ``decode_gather_bytes`` books
+    the blocks that the (bucketed) tables of a paged decode *name*, which
+    scale with what live requests own, while the dense program books the
+    worst-case table.  What the device moves for them is another matter
+    (until PR 27 it copied every layer's whole slice of the pool besides):
+    ``test_paged_pool_is_a_constant_of_the_layer_scan`` holds the program's
+    structure to it, ``serve.layer_loop_share`` reads it on the chip."""
     cfg, params = gpt2_setup
 
     def gather_per_tick(path):

@@ -1,4 +1,5 @@
-"""Every Pallas entry point compiles for a TPU v5e — checked without a chip.
+"""Every Pallas entry point, and the paged model step, compiles for a TPU v5e —
+checked without a chip.
 
 ``jax.experimental.topologies.get_topology_desc(platform="tpu", ...)`` gives a
 compile-only client: ``jit(...).lower(abstract args on its devices).compile()``
@@ -33,6 +34,13 @@ try:
 except Exception as e:  # no compile-only TPU client in this install: the test skips
     print("NO_TOPOLOGY", type(e).__name__, str(e)[:300].replace("\n", " "), flush=True)
     sys.exit(0)
+import functools
+from accelerate_tpu.ops import pallas_attention as kernels
+
+# apply_paged leaves ``interpret`` to the kernels, which interpret wherever the default backend is not a TPU, as
+# here: hand them the argument the cases below pass themselves
+for name in ("pallas_paged_attention", "pallas_paged_window_attention"):
+    setattr(kernels, name, functools.partial(getattr(kernels, name), interpret=False))
 from accelerate_tpu.ops.pallas_attention import (
     pallas_attention, pallas_paged_attention, pallas_paged_window_attention,
 )
@@ -54,6 +62,8 @@ def program(case, hd, b):
         f = loss(lambda q, k, v, m: pallas_attention(
             q, k, v, block_size=512, interpret=False, kv_valid=m))
         return jax.grad(f, argnums=(0, 1, 2)), (q, kv, kv, sds((b, s), jnp.bool_))
+    if case.startswith("paged_step"):
+        return paged_step(case, hd, b)
     slots, width, block, blocks, window = b, 8, 16, 64, 4
     pool, tables, lengths = sds((blocks, block, kh, hd)), sds((slots, width), jnp.int32), sds((slots,), jnp.int32)
     if case == "paged":
@@ -64,18 +74,73 @@ def program(case, hd, b):
         pool, pool, tables, lengths)
 
 
+STEP_LAYERS, STEP_BLOCKS, STEP_SLOTS = 4, 6144, 4  # 6144 and 4 * 6144 are sizes of nothing but the pool, and no leaf of it fits the chip's fast memory
+
+# Bytes of temporaries of the same step at the parent of PR 27, where the pool was a scanned input of the layer
+# loop (this file's child, run in that checkout): what the geometries that still slice their layer may cost
+SCANNED_POOL_TEMP_BYTES = {
+    "paged_step:64:8": 211_563_008, "paged_step:64:12": 467_623_936, "paged_step:128:3": 0, "paged_step:256:2": 101_179_392,
+    "paged_step_int8:128:2": 0, "paged_step_int8:128:8": 4_710_400,
+}
+
+
+def paged_step(case, hd, kv_heads):
+    # llama.apply_paged at a decode shape (one token a slot) or a prefill
+    # shape (one row of 32) over a pool of [4, 6144, 16, K, hd] a leaf, bf16
+    # or int8 codes with bf16 scales
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.models.generation import make_paged_pool
+
+    c = llama.LlamaConfig(
+        vocab_size=1024, hidden_size=2 * kv_heads * hd, intermediate_size=1024, num_layers=STEP_LAYERS,
+        num_heads=2 * kv_heads, num_kv_heads=kv_heads, head_dim=hd, max_seq_len=4096, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, remat=False, kv_cache_quant=case == "paged_step_int8")
+    place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: llama.init_params(c, jax.random.key(0))))
+    pool = place(jax.eval_shape(lambda: make_paged_pool(llama.init_cache, c, STEP_BLOCKS, 16)))
+    rows, tokens = (1, 32) if case == "paged_step_prefill" else (STEP_SLOTS, 1)
+    f = lambda p, pl, i, t, s: llama.apply_paged(p, i, c, pl, t, s, kernel=case == "paged_step_kernel")
+    return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, 64), jnp.int32), sds((rows,), jnp.int32))
+
+
+def pool_sized_results(text, whole_pool_only):
+    # Instructions of a compiled paged step that produce an array as large as
+    # the pool (whole_pool_only) or as a layer's slice of it: a copy, a slice
+    # or a re-tiling.  Views (bitcast), parameters and tuple reads move nothing
+    import re
+    sizes = (STEP_LAYERS * STEP_BLOCKS, "%d,%d" % (STEP_LAYERS, STEP_BLOCKS)) + (() if whole_pool_only else (STEP_BLOCKS,))
+    sized = re.compile(r"= \w+\[(%s)," % "|".join(map(str, sizes)))
+    return [line.strip()[:160] for line in text.splitlines()
+            if sized.search(line) and not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
+
+
+def check_paged_step(spec, compiled):
+    # A pool the TPU holds block by block is read where it lies: no result of
+    # the step is as large as a layer's slice.  Any other pool costs at most
+    # what it cost as a scanned input, and never a copy of the whole pool.
+    text, temp = compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+    sliced = spec in SCANNED_POOL_TEMP_BYTES
+    moved = pool_sized_results(text, whole_pool_only=sliced)
+    if moved:
+        raise AssertionError("the step moves pool-sized arrays: " + " ;; ".join(moved[:4]))
+    if sliced and temp > 1.01 * SCANNED_POOL_TEMP_BYTES[spec] + 2**16:
+        raise AssertionError(f"temporaries {temp} bytes, {SCANNED_POOL_TEMP_BYTES[spec]} with the pool as a scanned input")
+    return f"temp_bytes={temp}"
+
+
 for spec in sys.argv[2:]:
     case, hd, b = spec.split(":")
     print("BEGIN", spec, flush=True)   # an abort after this line belongs to this case
     try:
         f, args = program(case, int(hd), int(b))
-        text = jax.jit(f).lower(*args).compile().as_text()
-        if "tpu_custom_call" not in text:
+        compiled = jax.jit(f).lower(*args).compile()
+        if "tpu_custom_call" not in compiled.as_text() and case not in ("paged_step", "paged_step_prefill", "paged_step_int8"):
             raise AssertionError("compiled, but the executable holds no Mosaic kernel")
+        note = check_paged_step(spec, compiled) if case.startswith("paged_step") else ""
     except Exception:
         print("REFUSED", spec, traceback.format_exc()[-1500:].replace("\n", " | "), flush=True)
     else:
-        print("COMPILED", spec, flush=True)
+        print("COMPILED", spec, note, flush=True)
 """
 
 CASES = [
@@ -89,7 +154,28 @@ CASES = [
         ("paged", 4),
         ("paged_window", 4),
     )
+] + [
+    # the whole paged step of models/llama.py; the third field is the number of kv heads.  Beyond "it compiles":
+    # pools the TPU holds block by block (generation._blocks_lie_row_by_row) are gathered from where they lie, on the
+    # XLA path at a decode and a prefill shape and on the kernel path (PERF.md section 6, PR 27) ...
+    ("paged_step", 128, 2),
+    ("paged_step_prefill", 128, 2),
+    ("paged_step_kernel", 128, 2),
+    ("paged_step", 128, 1),
+    ("paged_step", 128, 4),
+    ("paged_step", 128, 8),
+    ("paged_step", 256, 8),
+    # ... and just past each edge of that rule, where a gather from the whole pool would cost a second pool (PERF.md
+    # section 7.0a), the layer's slice costs what it did as a scanned input: Llama-3.2-1B's heads, GPT-2 small's,
+    # an odd K, a wide head with few K, an int8 pool at the chat cell's heads and at Llama-3-8B's
+    ("paged_step", 64, 8),
+    ("paged_step", 64, 12),
+    ("paged_step", 128, 3),
+    ("paged_step", 256, 2),
+    ("paged_step_int8", 128, 2),
+    ("paged_step_int8", 128, 8),
 ]
+IDS = [f"{c}-hd{h}-{'k' if c.startswith('paged_step') else 'b'}{b}" for c, h, b in CASES]
 
 
 # ``python -c`` puts its working directory first on sys.path: the child
@@ -133,7 +219,7 @@ def compiled():
     return results
 
 
-@pytest.mark.parametrize("case,hd,b", CASES, ids=[f"{c}-hd{h}-b{b}" for c, h, b in CASES])
+@pytest.mark.parametrize("case,hd,b", CASES, ids=IDS)
 def test_pallas_entry_point_compiles_for_v5e(compiled, case, hd, b):
     verdict, detail = compiled[f"{case}:{hd}:{b}"]
     assert verdict == "COMPILED", f"{case} head={hd} batch={b}: {verdict}\n{detail}"
