@@ -19,7 +19,8 @@ __all__ = [
     "unpack_cache_from_scan", "cache_write", "speculative_generate_loop",
     "speculative_verify_greedy",
     "make_paged_pool", "gather_block_view", "extract_token_rows",
-    "scatter_token_rows", "paged_cache_write", "address_paged_pool_by_layer",
+    "scatter_token_rows", "paged_cache_write", "gather_paged_context", "overlay_new_rows",
+    "address_paged_pool_by_layer", "address_paged_leaf_by_layer",
     "unpack_paged_rows_from_scan", "demote_pool_blocks", "promote_pool_blocks",
 ]
 
@@ -187,7 +188,17 @@ def scatter_token_rows(
     blk = jnp.take_along_axis(tables, jnp.clip(blk_idx, 0, m - 1), axis=1)
     blk = jnp.where(blk_idx < m, blk, 0)
     off = pos % bs
-    return pool_leaf.at[:, blk, off].set(jnp.moveaxis(rows, 0, 1))
+    rows = jnp.moveaxis(rows, 0, 1)  # [L, S, count, *r]
+    if pool_leaf.ndim == 4:
+        # A leaf with no head axis (latent rows, int8 scales): a token's row
+        # is one packed sublane of a tile, and a scatter whose window spans
+        # the layers makes XLA:TPU re-lay out the whole leaf to bring them
+        # together, and back (two pool-sized copies a dispatch, compile-only,
+        # PR 28).  Indexed by layer too, the window is the row and the leaf is
+        # written where it lies.
+        layer = jnp.arange(pool_leaf.shape[0], dtype=jnp.int32)[:, None, None]
+        return pool_leaf.at[layer, blk[None], off[None]].set(rows)
+    return pool_leaf.at[:, blk, off].set(rows)
 
 
 def demote_pool_blocks(pool: dict, blocks) -> dict:
@@ -238,6 +249,13 @@ def _insert_rows(ctx: jax.Array, new_rows: jax.Array, starts: jax.Array) -> jax.
     return jnp.where(in_new, picked, ctx)
 
 
+@jax.named_scope("kv_pool.gather")
+def overlay_new_rows(ctx: jax.Array, new_rows: jax.Array, starts: jax.Array) -> jax.Array:
+    """:func:`_insert_rows` for a family that gathers a context itself
+    (:func:`gather_paged_context`) and cuts its part out before the overlay."""
+    return _insert_rows(ctx, new_rows, starts)
+
+
 def _blocks_lie_row_by_row(leaf) -> bool:
     """Whether a TPU holds a K/V pool leaf ``[..., bs, K, hd]`` block by block
     and, within a block, as ``bs*K`` whole rows of ``hd`` one after the other.
@@ -263,7 +281,8 @@ def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts
     representation of ``new_rows`` ``[B, T, K, hd]`` (cast for the fp pool,
     ``(codes, scale)`` for the int8 one) and the **dense attention context**
     ``[B, M*bs, K, hd]`` gathered straight through the block tables ``[B, M]``
-    with the new rows overlaid at ``starts[b] + arange(T)``.
+    with the new rows overlaid at ``starts[b] + arange(T)``.  A latent leaf
+    (``new_rows`` ``[B, T, w]``, no head axis) goes the same way.
 
     ``pool_layer`` is any leaf whose leading axis the tables index, as
     :func:`address_paged_pool_by_layer` hands it over: the whole pool
@@ -292,15 +311,50 @@ def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts
         # token-identical to the offline int8 cache.
         new_full = dequantize_kv(n_codes, n_scale, dtype)
     else:
-        n, bs, kh, hd = pool_layer.shape
         stored = new_rows.astype(pool_layer.dtype)
+        ctx = gather_paged_context(pool_layer, tables)
+        new_full = stored
+    return stored, _insert_rows(ctx, new_full, starts)
+
+
+def gather_paged_context(pool_layer: jax.Array, tables: jax.Array) -> jax.Array:
+    """The blocks the tables ``[B, M]`` name, as one context a row: ``[rows, bs,
+    *r] -> [B, M*bs, *r]``, for a K/V leaf (``*r`` = ``K, hd``) and for a latent
+    leaf (``*r`` = its width) alike.  Callers put it under the
+    ``kv_pool.gather`` scope (``paged_cache_write`` does)."""
+    n, bs, *rest = pool_layer.shape
+    b, m = tables.shape
+    rows = pool_layer
+    if len(rest) == 2 and _blocks_lie_row_by_row(pool_layer):
         # Whole blocks are gathered as [bs*K, hd] rows where that is a free
         # view: the TPU then reads a block as full (8, 128) tiles, 2.7 times
         # as fast as through the (K, 128) tiles of [bs, K, hd] at K = 2.
-        rows = pool_layer.reshape(n, bs * kh, hd) if _blocks_lie_row_by_row(pool_layer) else pool_layer
-        ctx = jnp.take(rows, tables, axis=0).reshape(b, m * bs, kh, hd)
-        new_full = stored
-    return stored, _insert_rows(ctx, new_full, starts)
+        rows = pool_layer.reshape(n, bs * rest[0], rest[1])
+    return jnp.take(rows, tables, axis=0).reshape(b, m * bs, *rest)
+
+
+def _latent_rows_lie_block_by_block(leaf) -> bool:
+    """Whether a TPU holds a latent pool leaf ``[..., bs, w]`` (no head axis)
+    block by block: it does where a row is whole 128-lane tiles (``w`` 512, or
+    two layers' rotated keys of 64 side by side); a row of 576 or of 64 it lays
+    out with the block axis in the lanes, and any gather from the whole leaf is
+    then answered with a copy of the whole leaf (compile-only, PR 28:
+    ``tests/test_tpu_compile.py`` holds the cache geometries of
+    ``models/deepseek_v3.py`` to the compiler's answer)."""
+    return leaf.dtype.itemsize > 1 and leaf.shape[-1] % 128 == 0
+
+
+@jax.named_scope("kv_pool.gather")
+def address_paged_leaf_by_layer(leaf: jax.Array, tables: jax.Array, layer: jax.Array):
+    """One layer of a latent pool leaf ``[L, N, bs, w]`` for a family's
+    per-layer scan body: ``(rows, tables)`` as :func:`paged_cache_write` and
+    :func:`gather_paged_context` take them.  Where the TPU holds the leaf block
+    by block, the whole leaf with its two major axes merged and the tables
+    offset to the layer's rows; else the layer's slice, cut here (see
+    :func:`address_paged_pool_by_layer`, which does the same for K/V pairs)."""
+    if _latent_rows_lie_block_by_block(leaf):
+        return leaf.reshape((-1,) + leaf.shape[2:]), tables + layer * leaf.shape[1]
+    return jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False), tables
 
 
 @jax.named_scope("kv_pool.gather")

@@ -32,6 +32,13 @@ Supported families and their HF architectures:
                 with the 1/sqrt(d) output rescale)
 - ``mixtral`` — MixtralForCausalLM (experts w1/w3/w2 -> gate/up/down
                 stacked [L, E, ...]; the router gate maps transposed)
+- ``deepseek_v3`` — DeepseekV3ForCausalLM without a query rank or RoPE scaling
+                (kanana-2-30b-a3b): ``kv_b_proj`` split by head into the
+                ``w_uk`` / ``w_uv`` column groups, the experts stacked
+                ``[L, E, ...]``, the shared experts as published (one MLP
+                of ``n_shared_experts`` widths), ``e_score_correction_bias``
+                as ``router_bias``; the leading dense layers and the
+                expert layers land in the ``dense`` and ``moe`` stacks
 - ``vit``     — ViTForImageClassification / ViTModel (patch-conv kernel
                 [d, C, p, p] -> the patchify matmul's [p*p*C, d])
 - ``resnet``  — ResNetForImageClassification / ResNetModel (HF's v1.5
@@ -86,7 +93,7 @@ def _stack_cat(sd: dict, fmts: list, n: int, transpose: bool = False) -> np.ndar
 
 def _detect_family(hf_config) -> str:
     mt = getattr(hf_config, "model_type", "")
-    known = {"llama", "gpt2", "bert", "t5", "mixtral", "vit", "resnet"}
+    known = {"llama", "gpt2", "bert", "t5", "mixtral", "deepseek_v3", "vit", "resnet"}
     if mt in ("qwen2", "mistral", "gemma", "phi3"):
         # llama-architecture variants: qwen2 adds Q/K/V biases, mistral is
         # llama-shaped GQA, gemma swaps in GeGLU + (1+w) RMSNorm + sqrt(d)
@@ -281,6 +288,37 @@ def config_from_hf(hf_config, **overrides):
         )
         kw.update(overrides)
         return MixtralConfig(**kw)
+    if family == "deepseek_v3":
+        from .deepseek_v3 import DeepseekV3Config
+
+        if getattr(c, "q_lora_rank", None) is not None or getattr(c, "rope_scaling", None) is not None:
+            raise ValueError("deepseek_v3 import: q_lora_rank and rope_scaling are not implemented (models/deepseek_v3.py)")
+        if getattr(c, "n_group", 1) != 1 or getattr(c, "topk_group", 1) != 1:
+            raise ValueError("deepseek_v3 import: routing groups (n_group > 1) are not implemented")
+        kw = dict(
+            vocab_size=c.vocab_size,
+            hidden_size=c.hidden_size,
+            intermediate_size=c.intermediate_size,
+            moe_intermediate_size=c.moe_intermediate_size,
+            num_layers=c.num_hidden_layers,
+            first_k_dense_replace=c.first_k_dense_replace,
+            num_heads=c.num_attention_heads,
+            kv_lora_rank=c.kv_lora_rank,
+            qk_nope_head_dim=c.qk_nope_head_dim,
+            qk_rope_head_dim=c.qk_rope_head_dim,
+            v_head_dim=c.v_head_dim,
+            n_routed_experts=c.n_routed_experts,
+            num_experts_per_tok=c.num_experts_per_tok,
+            n_shared_experts=c.n_shared_experts,
+            routed_scaling_factor=float(c.routed_scaling_factor),
+            norm_topk_prob=bool(c.norm_topk_prob),
+            scoring_func=getattr(c, "scoring_func", "sigmoid"),
+            max_seq_len=c.max_position_embeddings,
+            rope_theta=float(c.rope_theta),
+            rms_eps=float(c.rms_norm_eps),
+        )
+        kw.update(overrides)
+        return DeepseekV3Config(**kw)
     if family == "resnet":
         from .resnet import ResNetConfig
 
@@ -579,6 +617,60 @@ def _import_mixtral(sd: dict, cfg) -> dict:
     return params
 
 
+def _import_deepseek_v3(sd: dict, cfg) -> dict:
+    c = cfg
+    h, r, nope = c.num_heads, c.kv_lora_rank, c.qk_nope_head_dim
+
+    def stack(layers, fmt, transpose=False):
+        mats = [_np(sd[f"layers.{i}." + fmt]) for i in layers]
+        return np.stack([m.T for m in mats] if transpose else mats)
+
+    def attention(layers) -> dict:
+        # kv_b_proj [H * (nope + v), r]: every head's k_nope rows, then its v rows
+        kvb = stack(layers, "self_attn.kv_b_proj.weight", transpose=True).reshape(len(layers), r, h, nope + c.v_head_dim)
+        return {
+            "wq": stack(layers, "self_attn.q_proj.weight", transpose=True),
+            "w_kva": stack(layers, "self_attn.kv_a_proj_with_mqa.weight", transpose=True),
+            "ln_kv": stack(layers, "self_attn.kv_a_layernorm.weight"),
+            "w_uk": kvb[..., :nope].reshape(len(layers), r, h * nope),
+            "w_uv": kvb[..., nope:].reshape(len(layers), r, h * c.v_head_dim),
+            "wo": stack(layers, "self_attn.o_proj.weight", transpose=True),
+            "ln_attn": stack(layers, "input_layernorm.weight"),
+            "ln_mlp": stack(layers, "post_attention_layernorm.weight"),
+        }
+
+    dense, moe = range(c.first_k_dense_replace), range(c.first_k_dense_replace, c.num_layers)
+
+    def experts(which: str) -> np.ndarray:
+        return np.stack([
+            np.stack([_np(sd[f"layers.{i}.mlp.experts.{j}.{which}.weight"]).T for j in range(c.n_routed_experts)])
+            for i in moe
+        ])  # [L, E, in, out]
+
+    params = {
+        "embed": _np(sd["embed_tokens.weight"]),
+        "moe": {
+            **attention(moe),
+            "router": stack(moe, "mlp.gate.weight", transpose=True),
+            "router_bias": stack(moe, "mlp.gate.e_score_correction_bias"),
+            "w_gate": experts("gate_proj"), "w_up": experts("up_proj"), "w_down": experts("down_proj"),
+            "ws_gate": stack(moe, "mlp.shared_experts.gate_proj.weight", transpose=True),
+            "ws_up": stack(moe, "mlp.shared_experts.up_proj.weight", transpose=True),
+            "ws_down": stack(moe, "mlp.shared_experts.down_proj.weight", transpose=True),
+        },
+        "final_norm": _np(sd["norm.weight"]),
+        "lm_head": _np(sd["lm_head.weight"]).T,
+    }
+    if len(dense):
+        params["dense"] = {
+            **attention(dense),
+            "w_gate": stack(dense, "mlp.gate_proj.weight", transpose=True),
+            "w_up": stack(dense, "mlp.up_proj.weight", transpose=True),
+            "w_down": stack(dense, "mlp.down_proj.weight", transpose=True),
+        }
+    return params
+
+
 def _import_vit(sd: dict, cfg) -> dict:
     L = cfg.num_layers
     p = cfg.patch_size
@@ -703,6 +795,7 @@ _IMPORTERS = {
     "bert": _import_bert,
     "t5": _import_t5,
     "mixtral": _import_mixtral,
+    "deepseek_v3": _import_deepseek_v3,
     "vit": _import_vit,
     "resnet": _import_resnet,
 }
@@ -715,6 +808,7 @@ _PREFIXES = {
     "bert": ("bert.",),
     "t5": (),
     "mixtral": ("model.",),
+    "deepseek_v3": ("model.",),
     "vit": ("vit.",),
     "resnet": ("resnet.",),
 }

@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.sharding import constrain
 
-__all__ = ["router", "dispatch_combine", "moe_ffn", "moe_ffn_ragged", "expert_capacity"]
+__all__ = ["router", "dispatch_combine", "moe_ffn", "moe_ffn_ragged", "routed_experts", "swiglu", "expert_capacity"]
 
 
 def expert_capacity(seq_len: int, num_experts: int, top_k: int, capacity_factor: float) -> int:
@@ -149,6 +149,96 @@ def moe_ffn(
     return y.astype(x.dtype), aux
 
 
+def routed_experts(
+    x: jax.Array,
+    w_router: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    top_k: int,
+    scoring: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
+    normalize: bool = True,
+    scale: float = 1.0,
+    first_expert: Any = 0,
+    compute_dtype: Any = jnp.bfloat16,
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Dropless routed SwiGLU experts: every row goes to its ``top_k`` experts,
+    whatever else is in the batch.
+
+    x: [..., d]; w_router: [d, E]; w_gate/w_up: [G, d, f]; w_down: [G, f, d],
+    with ``G >= E``: the router's experts are rows ``first_expert ..
+    first_expert + E`` of the weights (``G == E`` and 0 for one layer's own
+    experts; a layer loop hands over all its layers' experts merged, ``[L*E, d,
+    f]``, and ``layer * E``, so that the grouped product reads the layer's
+    experts where the stack lies: cut out per layer they would be copied whole,
+    1.2 GB a layer at 128 experts of 2048 x 768, before a row is multiplied).
+    Scores are ``softmax`` or ``sigmoid`` of the fp32 router logits; the experts
+    are the ``top_k`` of ``scores + select_bias`` (the bias only chooses), the
+    weights are the chosen experts' scores, divided by their sum when
+    ``normalize`` and multiplied by ``scale``.  Rows are sorted by expert and
+    each expert's rows run as one group of ``lax.ragged_dot`` (on a TPU a
+    grouped-matmul kernel that streams only the experts that have rows):
+    compute is exactly ``rows * top_k`` pairs, no capacity, no drops, and a
+    row's result does not depend on the other rows.  Group sizes depend on
+    the data, so this runs per device (replicated experts); the ``ep``-sharded
+    path is ``moe_ffn``.
+
+    Returns (y [..., d] in x.dtype, routing dict: ``scores`` and ``logits``
+    [..., E] fp32, ``experts`` [..., top_k], ``weights`` [..., top_k] fp32,
+    ``group_sizes`` [E] int32 = rows each expert computed).
+    """
+    lead, d = x.shape[:-1], x.shape[-1]
+    e = w_router.shape[-1]
+    tokens = x.reshape(-1, d)
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("nd,de->ne", tokens.astype(jnp.float32), w_router.astype(jnp.float32))
+        if scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
+        choice = scores if select_bias is None else scores + select_bias.astype(jnp.float32)
+        _, idx = jax.lax.top_k(choice, top_k)  # [N, k]
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
+        if normalize:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights * scale
+
+        n = tokens.shape[0] * top_k
+        expert_of = idx.reshape(n)
+        token_of = jnp.repeat(jnp.arange(tokens.shape[0]), top_k)
+        order = jnp.argsort(expert_of, stable=True)
+        group_sizes = jnp.bincount(expert_of, length=e).astype(jnp.int32)
+
+    with jax.named_scope("moe.experts"):
+        rows = tokens.astype(compute_dtype)[token_of[order]]  # [N*k, d] grouped by expert
+        groups = group_sizes
+        if w_gate.shape[0] != e:  # the other layers' experts are groups of no rows
+            groups = jax.lax.dynamic_update_slice(jnp.zeros((w_gate.shape[0],), jnp.int32), group_sizes, (first_expert,))
+        gate = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate.astype(compute_dtype), groups))
+        up = jax.lax.ragged_dot(rows, w_up.astype(compute_dtype), groups)
+        y_rows = jax.lax.ragged_dot(gate * up, w_down.astype(compute_dtype), groups)
+        weighted = y_rows.astype(jnp.float32) * weights.reshape(n)[order][:, None]
+        y = jnp.zeros(tokens.shape, jnp.float32).at[token_of[order]].add(weighted)
+
+    routing = {
+        "scores": scores.reshape(*lead, e), "logits": logits.reshape(*lead, e),
+        "experts": idx.reshape(*lead, top_k), "weights": weights.reshape(*lead, top_k),
+        "group_sizes": group_sizes,
+    }
+    return y.reshape(x.shape).astype(x.dtype), routing
+
+
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, compute_dtype: Any) -> jax.Array:
+    """One dense SwiGLU expert, [..., d] -> [..., d]: the shared expert every
+    row passes through beside its routed ones."""
+    h = x.astype(compute_dtype)
+    return (jax.nn.silu(h @ w_gate.astype(compute_dtype)) * (h @ w_up.astype(compute_dtype))) @ w_down.astype(compute_dtype)
+
+
 def moe_ffn_ragged(
     x: jax.Array,
     w_router: jax.Array,
@@ -159,48 +249,19 @@ def moe_ffn_ragged(
     top_k: int = 2,
     compute_dtype: Any = jnp.bfloat16,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """Megablocks-style exact MoE FFN via ``lax.ragged_dot`` — the grouped
-    matmul the dense dispatch approximates.
-
-    Tokens are sorted by their routed expert and each expert's rows run as
-    one group of a ragged matmul: compute is exactly ``S*top_k`` rows (no
-    capacity padding — the dense path does ``E*C >= S*top_k*cf`` rows) and
-    no token is ever dropped.  Group sizes are data-dependent, so this path
-    is per-device (use it for single-chip decode / fsdp-replicated experts);
-    the dense dispatch remains the GSPMD `ep`-sharded path where static
-    shapes let XLA place the all-to-all.
-
-    Same signature/return contract as ``moe_ffn`` minus the capacity knobs;
-    ``fraction_dropped`` is identically zero.
-    """
-    b, s, d = x.shape
+    """Mixtral's routing (softmax scores, top-k renormalised to one) over
+    :func:`routed_experts`, with the Switch aux losses ``moe_ffn`` returns:
+    the exact computation the dense dispatch approximates, no capacity
+    padding and no token dropped (``fraction_dropped`` is identically zero).
+    Same signature/return contract as ``moe_ffn`` minus the capacity knobs."""
     e = w_gate.shape[0]
-    probs, logits = router(x, w_router)
-    gates, idx = jax.lax.top_k(probs, top_k)  # [B, S, k] fp32
-    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
-
-    n = b * s * top_k
-    expert_of = idx.reshape(n)
-    token_of = jnp.repeat(jnp.arange(b * s), top_k)
-    order = jnp.argsort(expert_of, stable=True)
-
-    tokens = x.reshape(b * s, d).astype(compute_dtype)
-    rows = tokens[token_of[order]]  # [N, d] grouped by expert
-    group_sizes = jnp.bincount(expert_of, length=e).astype(jnp.int32)
-
-    gate = jax.nn.silu(
-        jax.lax.ragged_dot(rows, w_gate.astype(compute_dtype), group_sizes)
+    y, routing = routed_experts(
+        x, w_router, w_gate, w_up, w_down, top_k=top_k, scoring="softmax", compute_dtype=compute_dtype,
     )
-    up = jax.lax.ragged_dot(rows, w_up.astype(compute_dtype), group_sizes)
-    y_rows = jax.lax.ragged_dot(gate * up, w_down.astype(compute_dtype), group_sizes)
-
-    weighted = y_rows.astype(jnp.float32) * gates.reshape(n)[order][:, None]
-    y = jnp.zeros((b * s, d), jnp.float32).at[token_of[order]].add(weighted)
-
-    # Aux losses use the same Switch formula as the dense path; every routed
-    # token is kept, so the dispatch mass is the one-hot top-k assignment
-    # itself (per batch row, like load_balancing_loss).
-    onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32).sum(axis=2)  # [B, S, E]
+    # Every routed token is kept, so the dispatch mass is the one-hot top-k
+    # assignment itself (per batch row, like load_balancing_loss).
+    probs = routing["scores"]
+    onehot = jax.nn.one_hot(routing["experts"], e, dtype=jnp.float32).sum(axis=2)  # [B, S, E]
     tokens_per_expert = jnp.sum(onehot, axis=1)  # [B, E]
     f = tokens_per_expert / jnp.maximum(
         jnp.sum(tokens_per_expert, axis=-1, keepdims=True), 1.0
@@ -208,7 +269,7 @@ def moe_ffn_ragged(
     p = jnp.mean(probs, axis=1)
     aux = {
         "load_balancing_loss": e * jnp.mean(jnp.sum(f * p, axis=-1)),
-        "router_z_loss": router_z_loss(logits),
+        "router_z_loss": router_z_loss(routing["logits"]),
         "fraction_dropped": jnp.zeros((), jnp.float32),
     }
-    return y.reshape(b, s, d).astype(x.dtype), aux
+    return y, aux

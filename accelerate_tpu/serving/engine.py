@@ -26,8 +26,14 @@ One engine **tick** (:meth:`ServingEngine.step`) is:
    power of two of the widest live slot, so per-token gather traffic scales
    with the blocks requests actually own, not the worst-case table width or
    the pool's size (``serving.decode_gather_bytes`` counts the blocks the
-   tables name, on the host).  Families without ``apply_paged``
-   (capacity-routed MoE) or ``ServingConfig(decode_path="dense")`` fall back to the PR 9 program:
+   tables name, on the host).  A pool need not be K and V per head:
+   ``models/deepseek_v3.py`` pages latent rows (``ckv``, ``kr``) the same way,
+   and any family with an ``apply_paged``, experts or not, serves on this path
+   (an expert family's routing must be row by row, as ``ops/moe.py:routed_experts``
+   is; its per-dispatch expert counters ride out behind the ``ok`` flags into
+   ``stats()["moe_rows"]``, ``"moe_experts_hit"``, ``"moe_max_rows"``).  Families
+   without ``apply_paged`` (``models/mixtral.py``: capacity routing depends on who
+   shares the batch) or ``ServingConfig(decode_path="dense")`` fall back to the PR 9 program:
    gather the dense view, ``vmap`` the family's ``apply_cached``, extract
    and scatter the written rows.  Either way the
    1-dispatch-per-decode-step invariant from ``make_train_step`` carries
@@ -286,6 +292,23 @@ class CompletedRequest:
     prefill_dispatches: int = 0
 
 
+# What an expert family's ``apply_paged`` counts in a dispatch (``models/deepseek_v3.py:expert_counters``), each
+# summed over its expert layers: token-expert pairs computed, experts with at least one row, the hottest expert's rows.
+MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows")
+
+
+def _ok_with_counters(ok, counters):
+    """A dispatch's finiteness flags and, for a family whose ``apply_paged``
+    returns expert counters as a third value, those counters behind them in
+    one int32 vector: the read-back of ``ok`` that a tick makes anyway carries
+    them to the host.  A family without experts returns two values, keeps its
+    flags as they are and compiles to the program it always had."""
+    if not counters:
+        return ok
+    behind = jnp.stack([counters[0][name] for name in MOE_COUNTERS]).astype(jnp.int32)
+    return jnp.concatenate([jnp.atleast_1d(ok).astype(jnp.int32), behind])
+
+
 class _TickPhase:
     """One phase of a tick, twice over: a ``serving.tick.<name>`` span on the
     profiler's timeline (``telemetry.annotate``; ``with`` gives the span, for
@@ -312,12 +335,18 @@ class _TickPhase:
 
 class ServingEngine:
     """Continuous-batching serving over a model family's
-    ``apply_cached``/``init_cache`` pair (any family following the
-    ``make_kv_cache`` layout — gpt2/llama/mixtral, fp or int8 KV).  The
-    token-identity-vs-``generate_loop`` guarantee needs a
-    chunking-independent forward (dense FFN); capacity-limited MoE routing
-    (mixtral) varies with prefill chunking here exactly as it does under
-    offline ``prefill_chunk``.
+    ``apply_cached``/``init_cache`` pair: any family whose cache leaves are
+    token rows ``[L, B, max_len, ...]`` — K and V per head (gpt2, llama,
+    mixtral; fp or int8) or latent rows without a head axis (deepseek_v3:
+    ``ckv`` and ``kr``, 576 values a token a layer).  A family with an
+    ``apply_paged`` serves on the paged path, experts or not (llama, gpt2,
+    deepseek_v3: its dropless routing is row by row, so a token gets the
+    same experts whatever the chunk and the batch); one without (mixtral)
+    falls back to the dense gather program, ``stats()["decode_path"]`` says
+    which.  The token-identity-vs-``generate_loop`` guarantee needs a
+    chunking-independent forward; capacity-limited MoE routing (mixtral)
+    varies with prefill chunking here exactly as it does under offline
+    ``prefill_chunk``.
 
     ::
 
@@ -398,6 +427,8 @@ class ServingEngine:
         self.prefix_blocks_reused = 0
         self.cow_copies = 0
         self.decode_gather_bytes = 0
+        # What the expert layers of every dispatch did (MOE_COUNTERS); stays 0 for a family without experts.
+        self.moe_counters = dict.fromkeys(MOE_COUNTERS, 0)
         # KV-tiering accounting (engine-side migrations; the prefix cache's
         # own demote/promote churn is folded in at publish time).
         self.tier_demotions = 0
@@ -419,9 +450,11 @@ class ServingEngine:
             ServingJournal(sc.journal_path) if sc.journal_path else None
         )
         # Decode-path resolution: "paged" consumes the pool in place through
-        # the family's apply_paged (same module as apply_cached); a family
-        # without one (capacity-routed MoE — per-batch routing is not
-        # row-independent) falls back to the dense gather-view program.
+        # the family's apply_paged (same module as apply_cached): llama, gpt2,
+        # and deepseek_v3 with its latent pool and dropless experts.  A family
+        # without one (mixtral: capacity routing depends on who shares the
+        # batch, so rows are not independent) falls back to the dense
+        # gather-view program.
         if sc.decode_path not in ("paged", "dense"):
             raise ValueError(
                 f"decode_path must be 'paged' or 'dense', got {sc.decode_path!r}"
@@ -585,7 +618,7 @@ class ServingEngine:
         kernel = self.serving.paged_kernel
 
         def decode(params, pool, tables, lengths, tokens, *poison):
-            logits, rows = apply_paged(
+            logits, rows, *counters = apply_paged(
                 params, tokens[:, None], config, pool, tables, lengths,
                 kernel=kernel,
             )
@@ -597,7 +630,7 @@ class ServingEngine:
             new_pool = dict(pool)
             for n, r in rows.items():
                 new_pool[n] = scatter_token_rows(pool[n], r, tables, lengths, 1)
-            return next_tok, ok, new_pool
+            return next_tok, _ok_with_counters(ok, counters), new_pool
 
         return decode
 
@@ -610,7 +643,7 @@ class ServingEngine:
         chunk_len = self.serving.prefill_chunk
 
         def prefill(params, pool, table_row, length, chunk, n_real):
-            logits, rows = apply_paged(
+            logits, rows, *counters = apply_paged(
                 params, chunk, config, pool, table_row[None], length[None]
             )
             next_tok = jnp.argmax(logits[0, n_real - 1], axis=-1).astype(jnp.int32)
@@ -620,7 +653,7 @@ class ServingEngine:
                 new_pool[n] = scatter_token_rows(
                     pool[n], r, table_row[None], length[None], chunk_len
                 )
-            return next_tok, ok, new_pool
+            return next_tok, _ok_with_counters(ok, counters), new_pool
 
         return prefill
 
@@ -640,7 +673,7 @@ class ServingEngine:
 
         def decode(params, pool, tables, lengths, tokens, draft_len, *poison):
             window = tokens.shape[1]
-            logits, rows = apply_paged(
+            logits, rows, *counters = apply_paged(
                 params, tokens, config, pool, tables, lengths, kernel=kernel,
             )  # [S, W, V]
             if poison:  # trace-time gate: unarmed programs carry no plumbing
@@ -650,7 +683,7 @@ class ServingEngine:
             new_pool = dict(pool)
             for n, r in rows.items():
                 new_pool[n] = scatter_token_rows(pool[n], r, tables, lengths, window)
-            return t, m, ok, new_pool
+            return t, m, _ok_with_counters(ok, counters), new_pool
 
         return decode
 
@@ -1436,6 +1469,17 @@ class ServingEngine:
         row[: len(blocks)] = blocks
         return row
 
+    def _read_ok(self, ok, lanes: int) -> np.ndarray:
+        """The host sync point of a dispatch: its ``lanes`` finiteness flags.
+        What an expert family's program put behind them (``_ok_with_counters``)
+        is added to ``moe_counters`` from the same read-back."""
+        flags = np.asarray(ok)
+        if flags.size > lanes:
+            for name, value in zip(MOE_COUNTERS, flags[lanes:]):
+                self.moe_counters[name] += int(value)
+            flags = flags[:lanes]
+        return flags
+
     def _prefill_tick(self, now: float) -> None:
         sched = self.sched
         with _TickPhase(self, "prefill.build") as span:
@@ -1482,7 +1526,7 @@ class ServingEngine:
             if tel.enabled:
                 tel.registry.counter("serving.prefill_dispatches").inc()
             slot.cache_len = start + n_real
-            poisoned = not bool(ok)  # host sync point: the dispatch is done here
+            poisoned = not self._read_ok(ok, 1).all()  # host sync point: the dispatch is done here
         with _TickPhase(self, "prefill.emit", request=req.id) as span:
             if self.tracer is not None:
                 self.tracer.on_prefill(
@@ -1612,7 +1656,7 @@ class ServingEngine:
                     gathered * self._block_bytes
                 )
                 tel.registry.gauge("serving.decode_bucket_width").set(m)
-            oks = np.asarray(ok_flags)
+            oks = self._read_ok(ok_flags, s)
         with _TickPhase(self, "decode.emit") as span:
             emit_t = time.monotonic()
             if self.tracer is not None:
@@ -1955,6 +1999,7 @@ class ServingEngine:
             "free_pool_bytes": alloc.free_blocks * self._block_bytes,
             "decode_path": self.decode_path,
             "decode_gather_bytes": self.decode_gather_bytes,
+            **self.moe_counters,
             "prefix_hits": self.prefix_hits,
             "prefix_blocks_reused": self.prefix_blocks_reused,
             "prefix_cow_copies": self.cow_copies,

@@ -64,6 +64,8 @@ def program(case, hd, b):
         return jax.grad(f, argnums=(0, 1, 2)), (q, kv, kv, sds((b, s), jnp.bool_))
     if case.startswith("paged_step"):
         return paged_step(case, hd, b)
+    if case.startswith("latent_step"):
+        return latent_step(case)
     slots, width, block, blocks, window = b, 8, 16, 64, 4
     pool, tables, lengths = sds((blocks, block, kh, hd)), sds((slots, width), jnp.int32), sds((slots,), jnp.int32)
     if case == "paged":
@@ -103,6 +105,58 @@ def paged_step(case, hd, kv_heads):
     return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, 64), jnp.int32), sds((rows,), jnp.int32))
 
 
+LATENT_EXPERTS = (8, 256, 128)  # one layer's routed experts [E, d, f] in latent_step
+
+
+def latent_step(case):
+    # models/deepseek_v3.apply_paged and the engine's scatter of the new rows, at a decode shape (one token a slot)
+    # or a prefill shape (one row of 32), over a latent pool at the published widths of the cache: ckv [4, 6144, 16, 512]
+    # and kr [2, 6144, 16, 128] (two layers' rotated keys of 64 side by side); 1 dense + 3 expert layers
+    from accelerate_tpu.models import deepseek_v3 as ds
+    from accelerate_tpu.models.generation import make_paged_pool, scatter_token_rows
+
+    c = ds.DeepseekV3Config(
+        vocab_size=1024, hidden_size=LATENT_EXPERTS[1], intermediate_size=512, moe_intermediate_size=LATENT_EXPERTS[2],
+        num_layers=STEP_LAYERS, first_k_dense_replace=1, num_heads=2, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=LATENT_EXPERTS[0], num_experts_per_tok=2, n_shared_experts=2,
+        max_seq_len=4096, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False)
+    place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: ds.init_params(c, jax.random.key(0))))
+    pool = place(jax.eval_shape(lambda: make_paged_pool(ds.init_cache, c, STEP_BLOCKS, 16)))
+    assert {k: v.shape for k, v in pool.items()} == {"ckv": (4, STEP_BLOCKS, 16, 512), "kr": (2, STEP_BLOCKS, 16, 128)}
+    rows, tokens = (1, 32) if case == "latent_step_prefill" else (STEP_SLOTS, 1)
+
+    def f(p, pl, i, t, s):
+        logits, new_rows, counters = ds.apply_paged(p, i, c, pl, t, s)
+        return logits, counters, {n: scatter_token_rows(pl[n], r, t, s, tokens) for n, r in new_rows.items()}
+
+    f.donate = (1,)  # the engine donates the pool: the scatter writes it where it lies
+    return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, 64), jnp.int32), sds((rows,), jnp.int32))
+
+
+def check_latent_step(compiled):
+    # The latent leaves are read where they lie: nothing but the scatter of the new rows has a result as large as a
+    # leaf or a layer's slice of one.  The routed experts are read where the stack lies too: the grouped product is the
+    # Mosaic kernel XLA:TPU makes of lax.ragged_dot, and no layer's experts are cut out of the stack for it.
+    import re
+    text = compiled.as_text()
+    sizes = "|".join(str(x) for x in (4 * STEP_BLOCKS, 2 * STEP_BLOCKS, "4,%d" % STEP_BLOCKS, "2,%d" % STEP_BLOCKS, STEP_BLOCKS))
+    sized = re.compile(r"= \w+\[(%s)," % sizes)
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if sized.search(line) and not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)
+             and "scatter(" not in line and "kv_pool.write/scatter" not in line]
+    if moved:
+        raise AssertionError("the step moves pool-sized arrays besides the scatter: " + " ;; ".join(moved[:4]))
+    if "ragged-dot" not in text or "tpu_custom_call" not in text:
+        raise AssertionError("the expert product is not the grouped-matmul kernel")
+    cut = re.compile(r"= \w+\[%d,(%d,%d|%d,%d)\]" % (LATENT_EXPERTS[0], *LATENT_EXPERTS[1:], *LATENT_EXPERTS[:0:-1]))
+    experts = [line.strip()[:160] for line in text.splitlines()
+               if cut.search(line) and not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
+    if experts:
+        raise AssertionError("a layer's experts are cut out of the stack: " + " ;; ".join(experts[:3]))
+    return f"temp_bytes={compiled.memory_analysis().temp_size_in_bytes}"
+
+
 def pool_sized_results(text, whole_pool_only):
     # Instructions of a compiled paged step that produce an array as large as
     # the pool (whole_pool_only) or as a layer's slice of it: a copy, a slice
@@ -133,10 +187,11 @@ for spec in sys.argv[2:]:
     print("BEGIN", spec, flush=True)   # an abort after this line belongs to this case
     try:
         f, args = program(case, int(hd), int(b))
-        compiled = jax.jit(f).lower(*args).compile()
+        compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
         if "tpu_custom_call" not in compiled.as_text() and case not in ("paged_step", "paged_step_prefill", "paged_step_int8"):
             raise AssertionError("compiled, but the executable holds no Mosaic kernel")
         note = check_paged_step(spec, compiled) if case.startswith("paged_step") else ""
+        note = check_latent_step(compiled) if case.startswith("latent_step") else note
     except Exception:
         print("REFUSED", spec, traceback.format_exc()[-1500:].replace("\n", " | "), flush=True)
     else:
@@ -174,8 +229,12 @@ CASES = [
     ("paged_step", 256, 2),
     ("paged_step_int8", 128, 2),
     ("paged_step_int8", 128, 8),
+    # the latent pool of models/deepseek_v3.py (PR 28; the two numbers are not read): no pool-sized result but the scatter
+    # of the new rows, the grouped expert product a Mosaic kernel fed from the stacked experts where they lie
+    ("latent_step", 512, 64),
+    ("latent_step_prefill", 512, 64),
 ]
-IDS = [f"{c}-hd{h}-{'k' if c.startswith('paged_step') else 'b'}{b}" for c, h, b in CASES]
+IDS = [f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}" for c, h, b in CASES]
 
 
 # ``python -c`` puts its working directory first on sys.path: the child
