@@ -231,28 +231,30 @@ def promote_pool_blocks(pool: dict, host_rows: dict, dst_blocks) -> dict:
 
 def _insert_rows(ctx: jax.Array, new_rows: jax.Array, starts: jax.Array) -> jax.Array:
     """Overlay ``new_rows`` ``[B, T, *r]`` onto the gathered context ``[B, P,
-    *r]`` at positions ``starts[b] .. starts[b]+T-1`` — the paged analog of
-    the dense view after ``cache_write``: attention sees exactly the values a
-    dense-view write would have produced, without an updated view ever being
-    materialized as a program output."""
-    b, p = ctx.shape[:2]
-    t = new_rows.shape[1]
-    rel = (
-        jnp.arange(p, dtype=jnp.int32)[None, :]
-        - starts[:, None].astype(jnp.int32)
-    )  # [B, P]: position minus the slot's write start
-    tail = (1,) * (ctx.ndim - 2)
-    picked = jnp.take_along_axis(
-        new_rows, jnp.clip(rel, 0, t - 1).reshape(b, p, *tail), axis=1
-    )
-    in_new = ((rel >= 0) & (rel < t)).reshape(b, p, *tail)
-    return jnp.where(in_new, picked, ctx)
+    *r]`` at positions ``starts[b] .. starts[b]+T-1`` (``starts`` never
+    negative) — the paged analog of the dense view after ``cache_write``:
+    attention sees exactly the values a dense-view write would have produced,
+    without an updated view ever being materialized as a program output.
+
+    A **row-sized write**: the ``B*T`` rows are scattered into the gathered
+    blocks, which XLA:TPU updates in place; nothing context-sized is gathered
+    or selected (a ``take_along_axis`` + ``where`` over ``[B, P]`` was 5.95 of
+    a chat decode's 17.0 ms at table width 64, the scatters are 0.08: PERF.md
+    section 6, PR 29).  **Positions past the
+    extent are dropped** (the padding of a last prefill chunk past the
+    tables), never clamped: a clamped write lands on the context's last real
+    row (the trap :func:`scatter_token_rows` documents for the pool)."""
+    slot = jnp.arange(ctx.shape[0], dtype=jnp.int32)[:, None]
+    pos = _token_positions(starts, new_rows.shape[1])  # [B, T]
+    return ctx.at[slot, pos].set(new_rows, mode="drop", indices_are_sorted=True, unique_indices=True)
 
 
 @jax.named_scope("kv_pool.gather")
 def overlay_new_rows(ctx: jax.Array, new_rows: jax.Array, starts: jax.Array) -> jax.Array:
     """:func:`_insert_rows` for a family that gathers a context itself
-    (:func:`gather_paged_context`) and cuts its part out before the overlay."""
+    (:func:`gather_paged_context`) and cuts its part out before the overlay:
+    a row-sized write, positions past the extent dropped (not clamped onto
+    the context's last row)."""
     return _insert_rows(ctx, new_rows, starts)
 
 
@@ -282,7 +284,11 @@ def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts
     ``(codes, scale)`` for the int8 one) and the **dense attention context**
     ``[B, M*bs, K, hd]`` gathered straight through the block tables ``[B, M]``
     with the new rows overlaid at ``starts[b] + arange(T)``.  A latent leaf
-    (``new_rows`` ``[B, T, w]``, no head axis) goes the same way.
+    (``new_rows`` ``[B, T, w]``, no head axis) goes the same way.  The overlay
+    is a row-sized write into the gathered blocks (:func:`_insert_rows`):
+    after the gather nothing context-sized runs before attention.  Positions
+    past the extent ``M*bs`` are dropped; a clamp would put a padded row of
+    a last prefill chunk onto the context's last real row.
 
     ``pool_layer`` is any leaf whose leading axis the tables index, as
     :func:`address_paged_pool_by_layer` hands it over: the whole pool
@@ -302,8 +308,8 @@ def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts
         n_codes, n_scale = quantize_kv(new_rows)
         stored = (n_codes, n_scale)
         ctx = dequantize_kv(
-            jnp.take(codes, tables, axis=0).reshape(b, m * bs, *codes.shape[2:]),
-            jnp.take(scale, tables, axis=0).reshape(b, m * bs, *scale.shape[2:]),
+            jnp.take(codes, tables, axis=0, mode="clip").reshape(b, m * bs, *codes.shape[2:]),
+            jnp.take(scale, tables, axis=0, mode="clip").reshape(b, m * bs, *scale.shape[2:]),
             dtype,
         )
         # Attention must see the QUANTIZED new rows (the dense path writes
@@ -321,7 +327,12 @@ def gather_paged_context(pool_layer: jax.Array, tables: jax.Array) -> jax.Array:
     """The blocks the tables ``[B, M]`` name, as one context a row: ``[rows, bs,
     *r] -> [B, M*bs, *r]``, for a K/V leaf (``*r`` = ``K, hd``) and for a latent
     leaf (``*r`` = its width) alike.  Callers put it under the
-    ``kv_pool.gather`` scope (``paged_cache_write`` does)."""
+    ``kv_pool.gather`` scope (``paged_cache_write`` does).  The block ids are
+    the engine's own (null block 0, offset to the layer's rows) and lie inside
+    the leaf: gathered with ``mode="clip"``, because ``jnp.take``'s default
+    fills what is out of range, which XLA:TPU answers with a select over the
+    whole context after the gather (3.4 of a chat decode's 20.8 ms at table
+    width 256, PR 29)."""
     n, bs, *rest = pool_layer.shape
     b, m = tables.shape
     rows = pool_layer
@@ -330,7 +341,7 @@ def gather_paged_context(pool_layer: jax.Array, tables: jax.Array) -> jax.Array:
         # view: the TPU then reads a block as full (8, 128) tiles, 2.7 times
         # as fast as through the (K, 128) tiles of [bs, K, hd] at K = 2.
         rows = pool_layer.reshape(n, bs * rest[0], rest[1])
-    return jnp.take(rows, tables, axis=0).reshape(b, m * bs, *rest)
+    return jnp.take(rows, tables, axis=0, mode="clip").reshape(b, m * bs, *rest)
 
 
 def _latent_rows_lie_block_by_block(leaf) -> bool:

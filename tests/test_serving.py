@@ -15,9 +15,12 @@ import jax.numpy as jnp
 from accelerate_tpu import telemetry
 from accelerate_tpu.models import gpt2
 from accelerate_tpu.models.generation import (
+    dequantize_kv,
     extract_token_rows,
     gather_block_view,
+    gather_paged_context,
     make_paged_pool,
+    overlay_new_rows,
     paged_cache_write,
     quantize_kv,
     scatter_token_rows,
@@ -211,11 +214,76 @@ def test_paged_cache_write_int8_attends_quantized_rows():
     starts = jnp.asarray([3], jnp.int32)
     new = jnp.asarray(rng.standard_normal((1, 1, K, hd)), jnp.float32)
     (n_codes, n_scale), full = paged_cache_write(pk, new, tables, starts, jnp.float32)
-    from accelerate_tpu.models.generation import dequantize_kv
-
     want_row = dequantize_kv(n_codes, n_scale, jnp.float32)[0, 0]
     np.testing.assert_array_equal(np.asarray(full[0, 3]), np.asarray(want_row))
     assert n_codes.dtype == jnp.int8 and n_scale.shape == (1, 1, K)
+
+
+def _overlay_by_select(ctx, new_rows, starts):
+    """The overlay as ``generation._insert_rows`` computed it until PR 29: a
+    context-sized index gathered out of the new rows and a select over the
+    whole context.  Kept here as the oracle of the row-sized write."""
+    b, p = ctx.shape[:2]
+    t = new_rows.shape[1]
+    rel = jnp.arange(p, dtype=jnp.int32)[None, :] - starts[:, None].astype(jnp.int32)
+    tail = (1,) * (ctx.ndim - 2)
+    picked = jnp.take_along_axis(new_rows, jnp.clip(rel, 0, t - 1).reshape(b, p, *tail), axis=1)
+    return jnp.where(((rel >= 0) & (rel < t)).reshape(b, p, *tail), picked, ctx)
+
+
+_OVERLAY_STARTS = {  # the first slot's write start, from the extent P and the number of new rows T
+    "zero": lambda p, t: 0,
+    "mid_block": lambda p, t: 5,
+    "last_row": lambda p, t: p - 1,
+    "across_extent": lambda p, t: p - t + 3,  # the last three rows fall past the extent (T = 1: all of it)
+    "past_extent": lambda p, t: p + 2,
+}
+
+
+@pytest.mark.parametrize("start", list(_OVERLAY_STARTS))
+@pytest.mark.parametrize("t", [1, 5, 32])
+@pytest.mark.parametrize(
+    "leaf,dtype",
+    [("kv", "float32"), ("kv", "bfloat16"), ("kv", "int8"), ("latent", "float32"), ("latent", "bfloat16")],
+)
+def test_new_rows_are_written_where_the_select_put_them(leaf, dtype, t, start):
+    """The row-sized write of ``_insert_rows`` gives, bit for bit, the context
+    the select over the whole context gave: for a K/V leaf and a latent leaf,
+    through ``paged_cache_write`` (int8 too: the dequantised new rows) and
+    through ``overlay_new_rows``; rows past the extent are dropped, never
+    clamped onto the context's last row."""
+    rng = np.random.default_rng(29)
+    n, bs, m = 9, 16, 3
+    rest = (2, 8) if leaf == "kv" else (24,)
+    p = m * bs  # a context of 48 rows
+    fp = jnp.float32 if dtype == "int8" else jnp.dtype(dtype)
+    pool = jnp.asarray(rng.standard_normal((n, bs) + rest), fp)
+    new = jnp.asarray(rng.standard_normal((2, t) + rest), fp)
+    tables = jnp.asarray([[4, 1, 7], [2, 8, 0]], jnp.int32)
+    starts = jnp.asarray([_OVERLAY_STARTS[start](p, t), 7], jnp.int32)
+    if dtype == "int8":
+        codes, scale = quantize_kv(pool)
+        pool = (codes, scale)
+        ctx = dequantize_kv(codes[tables].reshape(2, p, *rest), scale[tables].reshape(2, p, *rest[:-1]), fp)
+        new_full = dequantize_kv(*quantize_kv(new), fp)
+    else:
+        ctx = pool[tables].reshape(2, p, *rest)
+        new_full = new
+        np.testing.assert_array_equal(np.asarray(gather_paged_context(pool, tables)), np.asarray(ctx))
+    want = np.asarray(_overlay_by_select(ctx, new_full, starts))
+    _, full = jax.jit(paged_cache_write, static_argnums=4)(pool, new, tables, starts, fp)
+    assert full.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(full), want)
+    if leaf == "latent":  # the rotated keys: a lane slice of the gathered context, then the overlay
+        lanes = slice(8, 16)
+        got = jax.jit(overlay_new_rows)(ctx[..., lanes], new[..., lanes], starts)
+        np.testing.assert_array_equal(np.asarray(got), want[..., lanes])
+    # where the first slot's rows went, said without the oracle
+    s0, ctx0, new0, got0 = int(starts[0]), np.asarray(ctx[0]), np.asarray(new_full[0]), np.asarray(full[0])
+    kept = min(max(p - s0, 0), t)  # 0: nothing written, the last real row is the gathered one and no clamped new row
+    np.testing.assert_array_equal(got0[:s0], ctx0[:s0])
+    np.testing.assert_array_equal(got0[s0:s0 + kept], new0[:kept])
+    np.testing.assert_array_equal(got0[s0 + kept:], ctx0[s0 + kept:])
 
 
 # ---------------------------------------------------------------------------

@@ -76,6 +76,7 @@ def program(case, hd, b):
         pool, pool, tables, lengths)
 
 
+STEP_WIDTH = 64  # table width of every step: a context of 64 * 16 = 1024 rows a slot
 STEP_LAYERS, STEP_BLOCKS, STEP_SLOTS = 4, 6144, 4  # 6144 and 4 * 6144 are sizes of nothing but the pool, and no leaf of it fits the chip's fast memory
 
 # Bytes of temporaries of the same step at the parent of PR 27, where the pool was a scanned input of the layer
@@ -102,7 +103,7 @@ def paged_step(case, hd, kv_heads):
     pool = place(jax.eval_shape(lambda: make_paged_pool(llama.init_cache, c, STEP_BLOCKS, 16)))
     rows, tokens = (1, 32) if case == "paged_step_prefill" else (STEP_SLOTS, 1)
     f = lambda p, pl, i, t, s: llama.apply_paged(p, i, c, pl, t, s, kernel=case == "paged_step_kernel")
-    return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, 64), jnp.int32), sds((rows,), jnp.int32))
+    return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, STEP_WIDTH), jnp.int32), sds((rows,), jnp.int32))
 
 
 LATENT_EXPERTS = (8, 256, 128)  # one layer's routed experts [E, d, f] in latent_step
@@ -131,7 +132,7 @@ def latent_step(case):
         return logits, counters, {n: scatter_token_rows(pl[n], r, t, s, tokens) for n, r in new_rows.items()}
 
     f.donate = (1,)  # the engine donates the pool: the scatter writes it where it lies
-    return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, 64), jnp.int32), sds((rows,), jnp.int32))
+    return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, STEP_WIDTH), jnp.int32), sds((rows,), jnp.int32))
 
 
 def check_latent_step(compiled):
@@ -155,6 +156,24 @@ def check_latent_step(compiled):
     if experts:
         raise AssertionError("a layer's experts are cut out of the stack: " + " ;; ".join(experts[:3]))
     return f"temp_bytes={compiled.memory_analysis().temp_size_in_bytes}"
+
+
+def check_context_assembly(text):
+    # A decode step assembles its context in one pass (PR 29): under kv_pool.gather the blocks are gathered and the new
+    # rows scattered into them, a row-sized write.  Nothing else there is as large as the context: no select over it (the
+    # fill pass of a gather in jnp.take's default mode; the overlay as a where), no mask or index as long as it (pred, s32),
+    # no gather out of the new rows (take_along_axis)
+    import re
+    # as large as the context: [slots, rows, ...] or, gathered block by block, [slots, blocks, a block's rows, ...]
+    sized = re.compile(r"= (\w+)\[%d,(%d[,\]]|%d,)\S* ([\w-]+)\(" % (STEP_SLOTS, STEP_WIDTH * 16, STEP_WIDTH))
+    passes = []
+    for line in text.splitlines():
+        found = sized.search(line)
+        if found and "kv_pool.gather" in line and (
+                found.group(3) == "select" or found.group(1) in ("pred", "s32") or "take_along_axis" in line):
+            passes.append(line.strip()[:160])
+    if passes:
+        raise AssertionError("the context is passed over again after the block gather: " + " ;; ".join(passes[:4]))
 
 
 def pool_sized_results(text, whole_pool_only):
@@ -192,6 +211,8 @@ for spec in sys.argv[2:]:
             raise AssertionError("compiled, but the executable holds no Mosaic kernel")
         note = check_paged_step(spec, compiled) if case.startswith("paged_step") else ""
         note = check_latent_step(compiled) if case.startswith("latent_step") else note
+        if case in ("paged_step", "latent_step") and spec not in SCANNED_POOL_TEMP_BYTES:  # a decode over a pool read in place
+            check_context_assembly(compiled.as_text())
     except Exception:
         print("REFUSED", spec, traceback.format_exc()[-1500:].replace("\n", " | "), flush=True)
     else:
