@@ -496,7 +496,6 @@ def apply_paged(
     pool: dict,
     tables: jax.Array,
     starts: jax.Array,
-    kernel: bool = False,
 ):
     """Forward over new tokens straight against the paged latent pool (the
     contract of ``llama.apply_paged``): row ``b``'s tokens sit at positions
@@ -504,12 +503,9 @@ def apply_paged(
     keys through the block tables from the pool where it lies, overlays the new
     rows, and attends, expanded for a chunk and absorbed for one token.  Returns
     (logits, the written rows ``{leaf: [B, layers or groups, T, ...]}`` for the
-    caller's scatter, :func:`expert_counters` of the dispatch).  There is no
-    Pallas kernel over latents: ``kernel=True`` (``paged_kernel``) is refused."""
+    caller's scatter, :func:`expert_counters` of the dispatch)."""
     from .generation import address_paged_leaf_by_layer, gather_paged_context, overlay_new_rows, paged_cache_write
 
-    if kernel:
-        raise NotImplementedError("models/deepseek_v3.py has no paged-attention kernel over a latent pool: serve it with paged_kernel=False")
     c = config
     t = input_ids.shape[1]
     pack = _rope_pack(c)

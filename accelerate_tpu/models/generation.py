@@ -371,7 +371,7 @@ def address_paged_leaf_by_layer(leaf: jax.Array, tables: jax.Array, layer: jax.A
 @jax.named_scope("kv_pool.gather")
 def address_paged_pool_by_layer(pool: dict, tables: jax.Array, layer: jax.Array):
     """One layer of the pool for a family's per-layer scan body: ``(pk, pv,
-    tables)`` as ``paged_cache_write`` and the Pallas paged kernels take them
+    tables)`` as ``paged_cache_write`` takes them
     (a leaf ``[rows, bs, K, hd]``, or ``(codes, scale)`` for int8, and block
     ids that index its rows).  The scan closes over the pool and scans the
     layer number; nothing of the pool is a scanned input.

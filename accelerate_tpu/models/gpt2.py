@@ -354,7 +354,6 @@ def apply_paged(
     pool: dict,
     tables: jax.Array,
     starts: jax.Array,
-    kernel: bool = False,
 ) -> tuple[jax.Array, dict]:
     """Forward over new tokens straight against the paged block pool — the
     serving engine's decode/prefill fast path (no per-slot dense cache view
@@ -364,13 +363,7 @@ def apply_paged(
     starts[b]+T-1``; attention consumes pool K/V through the block tables
     ``tables [B, M]`` (``paged_cache_write``) and the freshly written rows
     come back as ``{leaf: [B, L, T, ...]}`` for the caller to scatter into
-    the pool.  ``kernel=True`` routes fp decode through the Pallas
-    paged-attention kernels (``ops/pallas_attention.py``): the single-token
-    kernel at ``T == 1`` and the multi-token window kernel at ``T > 1`` (the
-    speculative verify dispatch, where the T queries form a causal window at
-    the cache tail — exactly this function's position/mask contract);
-    int8 pools take the always-correct XLA path.  Prefill never passes
-    ``kernel=True``."""
+    the pool."""
     from .generation import (
         address_paged_pool_by_layer,
         paged_cache_write,
@@ -378,7 +371,7 @@ def apply_paged(
     )
 
     c = config
-    b, t = input_ids.shape
+    t = input_ids.shape[1]
     quant = "k_scale" in pool
     bs = pool["k"].shape[2]
     total = tables.shape[1] * bs
@@ -391,7 +384,6 @@ def apply_paged(
     x = _embed_lookup(params["wte"], input_ids, c.dtype) + params["wpe"].astype(c.dtype)[positions]
     k_pos = jnp.arange(total, dtype=jnp.int32)
     mask = positions[:, :, None] >= k_pos[None, None, :]  # [B, T, M*bs]
-    use_kernel = kernel and not quant
 
     def body(carry, xs):
         lp, layer = xs
@@ -399,26 +391,9 @@ def apply_paged(
         x = carry
         q, k, v = _qkv(x, lp, c)
         pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
-        if use_kernel:
-            from ..ops.pallas_attention import (
-                pallas_paged_attention,
-                pallas_paged_window_attention,
-            )
-
-            k_store = k.astype(pk.dtype)
-            v_store = v.astype(pv.dtype)
-            if t == 1:
-                attn = pallas_paged_attention(
-                    q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, ltab, starts
-                )[:, None].reshape(b, t, c.hidden_size)
-            else:
-                attn = pallas_paged_window_attention(
-                    q, k_store, v_store, pk, pv, ltab, starts
-                ).reshape(b, t, c.hidden_size)
-        else:
-            k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
-            v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
-            attn = _attend(q, k_full, v_full, mask[:, None], c)
+        k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
+        v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
+        attn = _attend(q, k_full, v_full, mask[:, None], c)
         x = x + attn @ lp["w_proj"].astype(c.dtype) + lp["b_proj"].astype(c.dtype)
         x = _mlp_block(x, lp, c)
         return x, (k_store, v_store)

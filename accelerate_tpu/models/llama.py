@@ -867,7 +867,6 @@ def apply_paged(
     pool: dict,
     tables: jax.Array,
     starts: jax.Array,
-    kernel: bool = False,
 ) -> tuple[jax.Array, dict]:
     """Forward over new tokens straight against the paged block pool — the
     serving engine's decode/prefill fast path (see ``gpt2.apply_paged``; the
@@ -875,10 +874,7 @@ def apply_paged(
     starts[b]+T-1`` (RoPE is position-exact per slot); attention consumes
     pool K/V through the block tables via ``paged_cache_write`` and the
     written rows return as ``{leaf: [B, L, T, ...]}`` for the caller's
-    scatter.  ``kernel=True`` routes fp decode through the Pallas
-    paged-attention kernels: single-token at ``T == 1``, the multi-token
-    window variant at ``T > 1`` (the speculative verify dispatch; GQA folds
-    into the kernel's grouped layout); int8 pools stay on the XLA path."""
+    scatter."""
     from .generation import (
         address_paged_pool_by_layer,
         paged_cache_write,
@@ -894,7 +890,6 @@ def apply_paged(
     x = embed_tokens(params, input_ids, c)
     k_pos = jnp.arange(total, dtype=jnp.int32)
     mask = positions[:, :, None] >= k_pos[None, None, :]  # [B, T, M*bs]
-    use_kernel = kernel and not quant
 
     def body(carry, xs):
         lp, layer = xs
@@ -906,29 +901,11 @@ def apply_paged(
                 q, k, v = _qkv_proj(h, lp, c, b, t)
                 q, k = _rope(q, k, positions, c.rope_theta, getattr(c, "rope_scaling", None))
             pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
-            if use_kernel:
-                from ..ops.pallas_attention import (
-                    pallas_paged_attention,
-                    pallas_paged_window_attention,
-                )
-
-                k_store = k.astype(pk.dtype)
-                v_store = v.astype(pv.dtype)
-                with jax.named_scope("attn.core"):
-                    if t == 1:
-                        attn = pallas_paged_attention(
-                            q[:, 0], k_store[:, 0], v_store[:, 0], pk, pv, ltab, starts
-                        )[:, None]
-                    else:
-                        attn = pallas_paged_window_attention(
-                            q, k_store, v_store, pk, pv, ltab, starts
-                        )
-            else:
-                with jax.named_scope("kv_pool"):
-                    k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
-                    v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
-                with jax.named_scope("attn.core"):
-                    attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
+            with jax.named_scope("kv_pool"):
+                k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
+                v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
+            with jax.named_scope("attn.core"):
+                attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
             y = x + _out_proj(attn, lp, c)
         return _mlp_block(y, lp, c), (k_store, v_store)
 

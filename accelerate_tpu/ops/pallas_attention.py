@@ -47,8 +47,6 @@ __all__ = [
     "pallas_attention",
     "pallas_attention_spmd",
     "ring_attention_pallas",
-    "pallas_paged_attention",
-    "pallas_paged_window_attention",
 ]
 
 _NEG_INF = -1e30  # finite: avoids inf-inf NaNs inside the exp bookkeeping
@@ -538,297 +536,6 @@ def pallas_attention_spmd(
     return shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec, valid_spec), out_specs=spec
     )(q, k, v, kv_valid.astype(jnp.int32))
-
-
-# ---------------------------------------------------------------------------
-# Paged decode attention: block-table K/V straight out of the serving pool
-# ---------------------------------------------------------------------------
-#
-# Single-token decode against the serving engine's paged KV pool
-# (serving/blocks.py): each slot owns a block table mapping token positions
-# to physical pool blocks.  The kernel walks the table with scalar-prefetched
-# indices — the BlockSpec index map reads tables[b, j], so the DMA engine
-# fetches ONLY the physical blocks a slot's table names (unowned entries
-# point at the null block, a single hot line) — and runs the standard online
-# -softmax recurrence per block, folding the slot's freshly-computed K/V row
-# (its position is `length`, always attended) in at the last grid step.  The
-# [P] score vector never materializes and no dense per-slot cache view ever
-# exists; compute on fully-invalid blocks is skipped with pl.when.
-#
-# This is the `ServingConfig.paged_kernel` fast path; the XLA paged path in
-# models/*.apply_paged is the always-correct fallback (int8 pools and
-# multi-token prefill chunks stay on it).  Online-softmax reassociates the
-# reduction, so outputs may differ from the XLA path in final ulps.
-
-
-def _paged_kernel(tables_ref, lengths_ref, q_ref, kn_ref, vn_ref, pk_ref, pv_ref,
-                  o_ref, acc, m_scr, l_scr, *, scale, bs, groups, nblocks):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-
-    length = lengths_ref[b]
-    kh = kn_ref.shape[1]
-
-    def online_update(s, v):
-        """One online-softmax step: s [K, g, n] scores, v [n, K, hd] values."""
-        m_prev = m_scr[:, :1].reshape(kh, groups, 1)
-        l_prev = l_scr[:, :1].reshape(kh, groups, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s > _NEG_INF * 0.5, p, 0.0)  # fully-masked entries stay 0
-        alpha = jnp.exp(m_prev - m_new)  # [K, g, 1]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )  # [K, g, hd]
-        h = kh * groups
-        acc[:] = (acc[:].reshape(kh, groups, -1) * alpha + pv).reshape(h, -1)
-        m_scr[:] = jnp.broadcast_to(m_new.reshape(h, 1), m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new.reshape(h, 1), l_scr.shape)
-
-    @pl.when(j * bs < length)
-    def _block():
-        q = q_ref[0].astype(jnp.float32).reshape(kh, groups, -1)  # [K, g, hd]
-        k = pk_ref[0].astype(jnp.float32)  # [bs, K, hd]
-        v = pv_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        ) * scale  # [K, g, bs]
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < length, s, _NEG_INF)
-        online_update(s, v)
-
-    @pl.when(j == nblocks - 1)
-    def _finish():
-        # The slot's own new K/V row sits at position `length` — the one row
-        # the causal mask always admits for the query at that position.
-        q = q_ref[0].astype(jnp.float32).reshape(kh, groups, -1)
-        kn = kn_ref[0].astype(jnp.float32)  # [K, hd]
-        s = jnp.sum(q * kn[:, None, :], axis=-1, keepdims=True) * scale  # [K, g, 1]
-        online_update(s, vn_ref[0][None])  # [1, K, hd]
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
-
-
-def pallas_paged_attention(
-    q: jax.Array,
-    k_new: jax.Array,
-    v_new: jax.Array,
-    pool_k: jax.Array,
-    pool_v: jax.Array,
-    tables: jax.Array,
-    lengths: jax.Array,
-    *,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Single-token paged decode attention through block tables.
-
-    q ``[B, H, hd]`` (one query token per slot), k_new/v_new ``[B, K, hd]``
-    (the slot's freshly computed K/V row, already in pool dtype), pool_k/v
-    ``[N, bs, K, hd]`` (ONE layer of the serving pool), tables ``[B, M]``,
-    lengths ``[B]`` (valid cache rows per slot; the new row logically sits at
-    position ``lengths[b]``).  Returns ``[B, H, hd]``.  GQA is handled by
-    grouping H into K kv-heads; the kernel grid is ``(B, M)`` with the pool
-    block index scalar-prefetched from the table, so HBM traffic is the
-    blocks the tables actually name.  ``interpret=None`` auto-enables the
-    Pallas interpreter off-TPU (the CPU test path).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, h, d = q.shape
-    kh = k_new.shape[1]
-    if h % kh:
-        raise ValueError(f"num q heads {h} not divisible by kv heads {kh}")
-    groups = h // kh
-    n, bs = pool_k.shape[:2]
-    m = tables.shape[1]
-    scale = float(1.0 / np.sqrt(d))
-
-    kernel = functools.partial(
-        _paged_kernel, scale=scale, bs=bs, groups=groups, nblocks=m,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, m),
-        in_specs=[
-            _vmem_spec((1, h, d), lambda ib, j, tbl, ln: (ib, 0, 0)),
-            _vmem_spec((1, kh, d), lambda ib, j, tbl, ln: (ib, 0, 0)),
-            _vmem_spec((1, kh, d), lambda ib, j, tbl, ln: (ib, 0, 0)),
-            _vmem_spec((1, bs, kh, d), lambda ib, j, tbl, ln: (tbl[ib, j], 0, 0, 0)),
-            _vmem_spec((1, bs, kh, d), lambda ib, j, tbl, ln: (tbl[ib, j], 0, 0, 0)),
-        ],
-        out_specs=_vmem_spec((1, h, d), lambda ib, j, tbl, ln: (ib, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        name="paged_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_new, v_new,
-      pool_k, pool_v)
-
-
-def _paged_window_kernel(tables_ref, lengths_ref, q_ref, kn_ref, vn_ref,
-                         pk_ref, pv_ref, o_ref, acc, m_scr, l_scr, *,
-                         scale, bs, groups, window, nblocks):
-    # Multi-token variant of _paged_kernel: the W window queries ride the
-    # GQA groups dimension (row g*W + w per kv-head), so every dot_general
-    # and the online-softmax scratch layout are the single-token shapes with
-    # groups -> groups*W.  Pool blocks mask `pos < length` for ALL window
-    # queries — genuine history strictly precedes the window, and the pool
-    # rows at positions >= length are stale (this very dispatch's scatter
-    # overwrites them); the in-window K/V land in the final grid step under
-    # an intra-window causal mask.
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    geff = groups * window
-
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-
-    length = lengths_ref[b]
-    kh = kn_ref.shape[2]
-
-    def online_update(s, v):
-        """One online-softmax step: s [K, g*W, n] scores, v [n, K, hd]."""
-        m_prev = m_scr[:, :1].reshape(kh, geff, 1)
-        l_prev = l_scr[:, :1].reshape(kh, geff, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s > _NEG_INF * 0.5, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )  # [K, g*W, hd]
-        h = kh * geff
-        acc[:] = (acc[:].reshape(kh, geff, -1) * alpha + pv).reshape(h, -1)
-        m_scr[:] = jnp.broadcast_to(m_new.reshape(h, 1), m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new.reshape(h, 1), l_scr.shape)
-
-    @pl.when(j * bs < length)
-    def _block():
-        q = q_ref[0].astype(jnp.float32).reshape(kh, geff, -1)  # [K, g*W, hd]
-        k = pk_ref[0].astype(jnp.float32)  # [bs, K, hd]
-        v = pv_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        ) * scale  # [K, g*W, bs]
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < length, s, _NEG_INF)
-        online_update(s, v)
-
-    @pl.when(j == nblocks - 1)
-    def _finish():
-        # The W in-window K/V rows sit at positions length..length+W-1;
-        # window query w (the `gw % W` component of the folded row index)
-        # admits in-window keys kw <= w.  Every query admits at least kw=0,
-        # so l is never the epsilon fallback.
-        q = q_ref[0].astype(jnp.float32).reshape(kh, geff, -1)
-        kn = kn_ref[0].astype(jnp.float32)  # [W, K, hd]
-        s = jax.lax.dot_general(
-            q, kn, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        ) * scale  # [K, g*W, W]
-        qw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) % window
-        kw = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(kw <= qw, s, _NEG_INF)
-        online_update(s, vn_ref[0])  # [W, K, hd]
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
-
-
-def pallas_paged_window_attention(
-    q: jax.Array,
-    k_new: jax.Array,
-    v_new: jax.Array,
-    pool_k: jax.Array,
-    pool_v: jax.Array,
-    tables: jax.Array,
-    lengths: jax.Array,
-    *,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Multi-token-window paged decode attention — the speculative
-    draft-then-verify fast path.
-
-    q ``[B, W, H, hd]`` (a W-token verify window per slot, window position 0
-    at cache position ``lengths[b]``), k_new/v_new ``[B, W, K, hd]`` (the
-    window's freshly computed K/V rows, pool dtype), pool_k/v
-    ``[N, bs, K, hd]``, tables ``[B, M]``, lengths ``[B]``.  Returns
-    ``[B, W, H, hd]``.  Window queries attend all genuine history
-    (pool positions ``< lengths[b]`` — stale pool rows at or beyond the
-    length are masked, exactly the rows this dispatch's scatter overwrites)
-    plus the in-window prefix ``kw <= qw`` of the new rows.  Implementation
-    folds W into the GQA groups dimension so the grid, block specs, and
-    online-softmax structure are identical to :func:`pallas_paged_attention`
-    with ``groups*W`` effective groups.  ``W == 1`` degenerates to the
-    single-token kernel's semantics exactly.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, w, h, d = q.shape
-    kh = k_new.shape[2]
-    if h % kh:
-        raise ValueError(f"num q heads {h} not divisible by kv heads {kh}")
-    groups = h // kh
-    n, bs = pool_k.shape[:2]
-    m = tables.shape[1]
-    scale = float(1.0 / np.sqrt(d))
-    hw = h * w
-
-    # [B, W, H, d] -> [B, K, g, W, d] -> [B, K*g*W, d]: folded row g*W + w
-    # per kv-head, so `row % W` recovers the window position in-kernel.
-    qr = q.transpose(0, 2, 1, 3).reshape(b, kh, groups, w, d).reshape(b, hw, d)
-
-    kernel = functools.partial(
-        _paged_window_kernel, scale=scale, bs=bs, groups=groups, window=w,
-        nblocks=m,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, m),
-        in_specs=[
-            _vmem_spec((1, hw, d), lambda ib, j, tbl, ln: (ib, 0, 0)),
-            _vmem_spec((1, w, kh, d), lambda ib, j, tbl, ln: (ib, 0, 0, 0)),
-            _vmem_spec((1, w, kh, d), lambda ib, j, tbl, ln: (ib, 0, 0, 0)),
-            _vmem_spec((1, bs, kh, d), lambda ib, j, tbl, ln: (tbl[ib, j], 0, 0, 0)),
-            _vmem_spec((1, bs, kh, d), lambda ib, j, tbl, ln: (tbl[ib, j], 0, 0, 0)),
-        ],
-        out_specs=_vmem_spec((1, hw, d), lambda ib, j, tbl, ln: (ib, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hw, d), jnp.float32),
-            pltpu.VMEM((hw, 128), jnp.float32),
-            pltpu.VMEM((hw, 128), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="paged_window_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hw, d), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qr, k_new, v_new,
-      pool_k, pool_v)
-    return out.reshape(b, kh, groups, w, d).transpose(0, 3, 1, 2, 4).reshape(b, w, h, d)
 
 
 # ---------------------------------------------------------------------------

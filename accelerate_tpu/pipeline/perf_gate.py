@@ -63,13 +63,6 @@ silently-degraded pipeline schedule.
 knob that proves the **goodput row** (wall-clock productive fraction from
 ``telemetry/goodput.py``'s attribution ledger, compiles warmed outside the
 window) actually judges where the wall clock went.
-``=dense-decode`` runs the **serving row**'s paged arm on the dense
-gather-view decode program — the knob that proves the
-``serving_paged_active`` tripwire and the paged-vs-dense throughput floor
-actually judge the serving decode fast path (PR 15: the paged program reads
-pool K/V in place through bucketed block tables; a regression back to
-"gather the worst-case dense view every token" lands the ratio at ~1.0 and
-fails loudly).
 ``=mem-bloat`` registers four extra live parameter copies in the HBM ledger
 under a ``perf_gate.bloat`` owner — the knob that proves the **memory row**
 (per-chip train-state and serving-pool byte ceilings from
@@ -237,84 +230,50 @@ def run_pp_probe(
     }
 
 
-def run_serving_probe(decode_ticks: int = 25, degrade: Optional[str] = None) -> dict:
-    """The serving row's measurement: paged vs dense decode throughput on a
-    bounded CPU engine pair (gpt2-tiny, identical geometry and request mix).
-
-    The dense arm is the PR 9 program — gather every slot's worst-case
-    ``[S, L, 1, M*bs, *r]`` view, vmap ``apply_cached``, flow the updated
-    view back out; the paged arm reads pool K/V in place through bucketed
-    block tables and returns only the written rows.  The request geometry is
-    chosen so the paged arm's table bucket is CONSTANT across the timed
-    window (prompt 33 rows + 30 budget stays under the 64-row bucket):
-    a bucket crossing recompiles once, which is steady-state-invisible but
-    would poison a 25-tick window.  Judged invariants: decode dispatches per
-    tick == 1 on the paged path, paged-vs-dense steps/s over the committed
-    floor, and ``serving_paged_active`` (the dense-fallback tripwire).
-    ``degrade="dense-decode"`` builds the paged arm on the dense program —
-    the self-test that this row actually judges the fast path."""
+def run_serving_probe(decode_ticks: int = 25) -> dict:
+    """The serving row's measurement: a bounded CPU engine (gpt2-tiny) with
+    four requests in the decode batch, ticked ``decode_ticks`` times.  What
+    repeats exactly, and is judged: decode dispatches per tick == 1,
+    ``serving_paged_active`` (a family with an ``apply_paged`` was served on
+    the paged back end, not the dense gather-view one), and the pool's
+    bytes (the memory row's input)."""
     import numpy as np
 
+    import jax
     import jax.numpy as jnp
 
     from ..models import gpt2
     from ..serving import ServingConfig, ServingEngine
     from ..serving.scheduler import RequestState
 
-    if degrade is None:
-        degrade = os.environ.get(ENV_DEGRADE, "").strip().lower() or None
     cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32)
-    import jax
-
     params = gpt2.init_params(cfg, jax.random.key(0))
-
-    def arm(path):
-        rng = np.random.default_rng(0)
-        eng = ServingEngine(
-            gpt2.apply_cached, gpt2.init_cache, params, cfg,
-            serving=ServingConfig(
-                block_size=8, num_blocks=80, max_slots=4, prefill_chunk=8,
-                max_blocks_per_seq=16, decode_path=path, prefix_cache=False,
-            ),
-        )
-        for _ in range(4):
-            eng.submit(list(rng.integers(0, cfg.vocab_size, size=33)), 30)
-        # Prefill everyone into the decode batch, then warm the decode
-        # program for the active bucket outside the timed window.
-        while (
-            any(s.request.state != RequestState.DECODING for s in eng.sched.slots.values())
-            or eng.sched.pending
-        ):
-            eng.step()
-        for _ in range(2):
-            eng.step()
-        d0 = eng.decode_dispatches
-        t0 = time.perf_counter()
-        for _ in range(decode_ticks):
-            eng.step()
-        dt = time.perf_counter() - t0
-        stats = eng.stats()
-        return (
-            decode_ticks / dt,
-            (eng.decode_dispatches - d0) / decode_ticks,
-            stats["decode_path"],
-            stats.get("pool_bytes"),
-        )
-
-    dense_sps, dense_disp, _, _ = arm("dense")
-    paged_sps, paged_disp, paged_path, pool_bytes = arm(
-        "dense" if degrade == "dense-decode" else "paged"
+    rng = np.random.default_rng(0)
+    eng = ServingEngine(
+        gpt2.apply_cached, gpt2.init_cache, params, cfg,
+        serving=ServingConfig(
+            block_size=8, num_blocks=80, max_slots=4, prefill_chunk=8,
+            max_blocks_per_seq=16, prefix_cache=False,
+        ),
     )
+    for _ in range(4):
+        eng.submit(list(rng.integers(0, cfg.vocab_size, size=33)), 30)
+    # Prefill everyone into the decode batch before the counted ticks.
+    while (
+        any(s.request.state != RequestState.DECODING for s in eng.sched.slots.values())
+        or eng.sched.pending
+    ):
+        eng.step()
+    d0 = eng.decode_dispatches
+    for _ in range(decode_ticks):
+        eng.step()
+    stats = eng.stats()
     return {
-        "serving_dense_decode_steps_per_s": round(dense_sps, 2),
-        "serving_paged_decode_steps_per_s": round(paged_sps, 2),
-        "serving_paged_vs_dense_ratio": round(paged_sps / max(dense_sps, 1e-9), 3),
-        "serving_decode_dispatches_per_tick": paged_disp,
-        "serving_dense_decode_dispatches_per_tick": dense_disp,
-        "serving_paged_active": paged_path == "paged",
+        "serving_decode_dispatches_per_tick": (eng.decode_dispatches - d0) / decode_ticks,
+        "serving_paged_active": stats["decode_path"] == "paged",
         # Memory row input: the engine is single-device by design, so the
         # pool's allocation IS its per-chip footprint.
-        "serving_pool_bytes_per_chip": pool_bytes,
+        "serving_pool_bytes_per_chip": stats.get("pool_bytes"),
     }
 
 
@@ -748,12 +707,12 @@ def run_probe(
         if pp and jax.device_count() >= 4 and jax.device_count() % 4 == 0:
             pp_row = run_pp_probe(degrade=degrade)
 
-        # serving row: paged vs dense decode on the continuous-batching
-        # engine — single-device by design (the engine is mesh-agnostic), so
+        # serving row: the continuous-batching engine's decode tick —
+        # single-device by design (the engine is mesh-agnostic), so
         # unlike the ZeRO/pp rows it runs on every probe.
         serving_row = None
         if serving:
-            serving_row = run_serving_probe(degrade=degrade)
+            serving_row = run_serving_probe()
             # spec row: speculative vs greedy decode on the same engine
             # geometry (one more paired probe; rides the serving flag).
             serving_row.update(run_spec_probe(degrade=degrade))
@@ -1010,7 +969,7 @@ def evaluate(measurements: dict, baseline: dict) -> list:
                 "the committed per-chip ceiling"
             )
     max_pool_bytes = baseline.get("max_serving_pool_bytes_per_chip")
-    if max_pool_bytes is not None and "serving_paged_vs_dense_ratio" in measurements:
+    if max_pool_bytes is not None and "serving_paged_active" in measurements:
         pool_bytes = measurements.get("serving_pool_bytes_per_chip")
         if pool_bytes is None:
             failures.append(
@@ -1064,11 +1023,10 @@ def evaluate(measurements: dict, baseline: dict) -> list:
                 f"{min_pp_ratio} — the interleaved schedule lost its bubble-shrink "
                 "win over gpipe"
             )
-    # serving row: judged only when the arm ran.  A paged decode that
-    # silently fell back to the dense gather-view program, a tick that grew a
-    # second dispatch, or a paged path slower than the dense one it replaces
-    # are exactly the regressions this row exists to catch.
-    if "serving_paged_vs_dense_ratio" in measurements:
+    # serving row: judged only when the arm ran.  A paged family that was
+    # served by the dense gather-view program, or a tick that grew a second
+    # dispatch, are exactly the regressions this row exists to catch.
+    if "serving_paged_active" in measurements:
         if baseline.get("require_serving_paged") and not measurements.get(
             "serving_paged_active"
         ):
@@ -1085,17 +1043,6 @@ def evaluate(measurements: dict, baseline: dict) -> list:
                     f"{max_serving_disp} — the paged decode is no longer one "
                     "fused dispatch per engine tick"
                 )
-        min_serving_ratio = baseline.get("min_paged_vs_dense_ratio")
-        if (
-            min_serving_ratio is not None
-            and measurements["serving_paged_vs_dense_ratio"] < min_serving_ratio
-        ):
-            failures.append(
-                f"paged-vs-dense decode steps/s ratio "
-                f"{measurements['serving_paged_vs_dense_ratio']:.3f} < baseline min "
-                f"{min_serving_ratio} — the serving decode fast path lost its "
-                "win over the dense gather-view program"
-            )
     # spec row: judged only when the arm ran.  A speculative config that
     # silently decodes greedily (drafter never fires, verify program lost),
     # an accept/rewind bug that diverges from greedy, or a verify dispatch
@@ -1195,10 +1142,9 @@ def run_gate(baseline_path: Optional[str] = None, probe_kwargs: Optional[dict] =
         zero_note += (
             f", goodput {measurements['goodput_productive_frac']:.2f} productive"
         )
-    if measurements.get("serving_paged_vs_dense_ratio") is not None:
+    if measurements.get("serving_decode_dispatches_per_tick") is not None:
         zero_note += (
-            f", serving paged/dense {measurements['serving_paged_vs_dense_ratio']}x "
-            f"at {measurements['serving_decode_dispatches_per_tick']:.0f} "
+            f", serving decode {measurements['serving_decode_dispatches_per_tick']:.0f} "
             "dispatch/tick"
         )
     if measurements.get("serving_migrated_vs_reprefill_ratio") is not None:

@@ -11,7 +11,9 @@ Three pieces (see ``docs/usage_guides/serving.md``):
 - **engine** — the serving engine itself: one fused jitted decode step
   over the in-flight batch per tick plus bounded chunked prefill, with
   per-request SLO metrics (TTFT, inter-token latency, queue wait)
-  published through the telemetry registry (``engine.py``).
+  published through the telemetry registry (``engine.py``); the jitted
+  programs and how a dispatch reads the pool, paged or through a dense
+  view as the family decides (``programs.py``).
 
 Entry point: :meth:`accelerate_tpu.Accelerator.prepare_serving`, or
 construct :class:`ServingEngine` directly from a model family's

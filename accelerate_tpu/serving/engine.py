@@ -8,10 +8,12 @@ One engine **tick** (:meth:`ServingEngine.step`) is:
    prompt costs many small dispatches interleaved with decode instead of one
    huge dispatch that stalls every in-flight request;
 3. **decode** — ONE fused jitted dispatch advances every decoding slot by
-   one token.  On the default **paged fast path** the family's
-   ``apply_paged`` reads pool K/V through the block tables
-   (``models/generation.py paged_cache_write``): the pool is a constant of
-   the layer loop, never a scanned input of it.  Where the TPU holds the
+   one token.  ``serving/programs.py`` builds the programs and owns how a
+   dispatch reads the pool; the family decides which of its two back ends
+   serves.  A family with an ``apply_paged`` (gpt2, llama, deepseek_v3) is
+   served **paged**: ``apply_paged`` reads pool K/V through the block tables
+   (``models/generation.py paged_cache_write``), and the pool is a constant
+   of the layer loop, never a scanned input of it.  Where the TPU holds the
    pool block by block (bf16, ``hd`` a multiple of 128, ``K`` 1, 2, 4 or a
    multiple of 8) it is addressed by (layer, block) in one flat view
    (``address_paged_pool_by_layer``): a layer gathers the blocks its tables
@@ -28,20 +30,19 @@ One engine **tick** (:meth:`ServingEngine.step`) is:
    the pool's size (``serving.decode_gather_bytes`` counts the blocks the
    tables name, on the host).  A pool need not be K and V per head:
    ``models/deepseek_v3.py`` pages latent rows (``ckv``, ``kr``) the same way,
-   and any family with an ``apply_paged``, experts or not, serves on this path
-   (an expert family's routing must be row by row, as ``ops/moe.py:routed_experts``
-   is; its per-dispatch expert counters ride out behind the ``ok`` flags into
-   ``stats()["moe_rows"]``, ``"moe_experts_hit"``, ``"moe_max_rows"``).  Families
-   without ``apply_paged`` (``models/mixtral.py``: capacity routing depends on who
-   shares the batch) or ``ServingConfig(decode_path="dense")`` fall back to the PR 9 program:
-   gather the dense view, ``vmap`` the family's ``apply_cached``, extract
-   and scatter the written rows.  Either way the
-   1-dispatch-per-decode-step invariant from ``make_train_step`` carries
-   over — the ``serving.decode_dispatches`` counter is the proof hook, and
-   the perf_gate serving row holds paged-vs-dense decode throughput above a
-   committed floor.
+   and an expert family serves paged too when its routing is row by row, as
+   ``ops/moe.py:routed_experts`` is (its per-dispatch expert counters ride
+   out behind the ``ok`` flags into ``stats()["moe_rows"]``,
+   ``"moe_experts_hit"``, ``"moe_max_rows"``).  A family without an
+   ``apply_paged`` (``models/mixtral.py``: capacity routing depends on who
+   shares the batch) is served **dense**: gather each slot's whole view at
+   the one static table width, ``vmap`` the family's ``apply_cached``,
+   extract and scatter the written rows.  ``stats()["decode_path"]`` reports
+   which.  Either way the 1-dispatch-per-decode-step invariant from
+   ``make_train_step`` carries over — the ``serving.decode_dispatches``
+   counter is the proof hook.
 
-Prefill takes the same paged path: a chunk's program consumes the pool
+Prefill takes the same back end: a paged chunk's program consumes the pool
 through the (bucketed) block table and returns only the rows it writes —
 the full per-slot view is materialized on neither side of the dispatch.
 
@@ -112,7 +113,6 @@ Production-robustness layer (overload / deadlines / quarantine / journal):
 from __future__ import annotations
 
 import contextlib
-import inspect
 import os
 import time
 from dataclasses import dataclass, field
@@ -120,14 +120,8 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
-from ..models.generation import (
-    extract_token_rows,
-    gather_block_view,
-    scatter_token_rows,
-)
 from ..telemetry import annotate, get_telemetry
 from .blocks import (
     NULL_BLOCK,
@@ -137,6 +131,7 @@ from .blocks import (
     blocks_for_tokens,
 )
 from .journal import JournalError, ServingJournal
+from .programs import MOE_COUNTERS, build_programs
 from .scheduler import Request, RequestState, Scheduler
 from .tracing import ServingTracer, resolve_trace_dir, tracing_enabled
 
@@ -158,7 +153,9 @@ class AdmissionRejected(RuntimeError):
 @dataclass
 class ServingConfig:
     """Engine geometry (everything here is a static shape of the compiled
-    programs — two programs total, however many requests flow through).
+    programs — three programs, prefill, decode and with ``spec_tokens`` the
+    verify window, each compiled once per block-table width it meets,
+    however many requests flow through).
 
     - ``block_size``: tokens per KV block.  Small blocks waste less tail
       space per request; large blocks shrink the tables.  16-64 is typical.
@@ -195,18 +192,8 @@ class ServingConfig:
       watermark (demote-before-shed; 0 disables the proactive sweep —
       on-demand demotion inside eviction still applies).
 
-    Decode fast-path knobs:
+    Cache knobs:
 
-    - ``decode_path``: ``"paged"`` (default) computes attention straight
-      through the block tables via the family's ``apply_paged`` — falling
-      back to ``"dense"`` automatically when the family has none (MoE);
-      ``"dense"`` forces the PR 9 gather-view program (the always-correct
-      reference path, and the perf_gate contrast arm).
-    - ``paged_kernel``: route single-token fp decode attention through the
-      Pallas paged-attention kernel (``ops/pallas_attention.py``).  The XLA
-      paged path is the always-correct fallback (int8 pools and prefill
-      chunks stay on it); the kernel's online softmax may differ from it in
-      final ulps.
     - ``prefix_cache``: share full prompt blocks across requests by content
       hash (copy-on-write tail, refcounted blocks, LRU reclaim).  Host-side
       policy only — the compiled programs are identical either way.
@@ -246,8 +233,6 @@ class ServingConfig:
     journal_path: Optional[str] = None
     host_blocks: int = 0
     tier_demote_batch: int = 8
-    decode_path: str = "paged"
-    paged_kernel: bool = False
     prefix_cache: bool = True
     spec_tokens: int = 0
     spec_ngram_max: int = 3
@@ -292,23 +277,6 @@ class CompletedRequest:
     prefill_dispatches: int = 0
 
 
-# What an expert family's ``apply_paged`` counts in a dispatch (``models/deepseek_v3.py:expert_counters``), each
-# summed over its expert layers: token-expert pairs computed, experts with at least one row, the hottest expert's rows.
-MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows")
-
-
-def _ok_with_counters(ok, counters):
-    """A dispatch's finiteness flags and, for a family whose ``apply_paged``
-    returns expert counters as a third value, those counters behind them in
-    one int32 vector: the read-back of ``ok`` that a tick makes anyway carries
-    them to the host.  A family without experts returns two values, keeps its
-    flags as they are and compiles to the program it always had."""
-    if not counters:
-        return ok
-    behind = jnp.stack([counters[0][name] for name in MOE_COUNTERS]).astype(jnp.int32)
-    return jnp.concatenate([jnp.atleast_1d(ok).astype(jnp.int32), behind])
-
-
 class _TickPhase:
     """One phase of a tick, twice over: a ``serving.tick.<name>`` span on the
     profiler's timeline (``telemetry.annotate``; ``with`` gives the span, for
@@ -342,8 +310,8 @@ class ServingEngine:
     ``apply_paged`` serves on the paged path, experts or not (llama, gpt2,
     deepseek_v3: its dropless routing is row by row, so a token gets the
     same experts whatever the chunk and the batch); one without (mixtral)
-    falls back to the dense gather program, ``stats()["decode_path"]`` says
-    which.  The token-identity-vs-``generate_loop`` guarantee needs a
+    is served by the dense gather program (``serving/programs.py``),
+    ``stats()["decode_path"]`` says which.  The token-identity-vs-``generate_loop`` guarantee needs a
     chunking-independent forward; capacity-limited MoE routing (mixtral)
     varies with prefill chunking here exactly as it does under offline
     ``prefill_chunk``.
@@ -383,8 +351,6 @@ class ServingEngine:
         from ..pipeline.compile_cache import enable_compile_cache
 
         enable_compile_cache()
-        self._apply_cached = apply_cached
-        self._config = config
         self.params = params
         self.spec_tokens = int(sc.spec_tokens)
         self.cache = PagedKVCache(
@@ -406,7 +372,6 @@ class ServingEngine:
                 f"max_blocks_per_seq * block_size = {max_len} exceeds the "
                 f"model's max_seq_len {model_max}; shrink the table or blocks"
             )
-        self._kv_names = self.cache.leaf_names
         self._finished: List[CompletedRequest] = []
         self._preempted_published = 0
         self._preemption_guard = None
@@ -449,21 +414,6 @@ class ServingEngine:
         self.journal: Optional[ServingJournal] = (
             ServingJournal(sc.journal_path) if sc.journal_path else None
         )
-        # Decode-path resolution: "paged" consumes the pool in place through
-        # the family's apply_paged (same module as apply_cached): llama, gpt2,
-        # and deepseek_v3 with its latent pool and dropless experts.  A family
-        # without one (mixtral: capacity routing depends on who shares the
-        # batch, so rows are not independent) falls back to the dense
-        # gather-view program.
-        if sc.decode_path not in ("paged", "dense"):
-            raise ValueError(
-                f"decode_path must be 'paged' or 'dense', got {sc.decode_path!r}"
-            )
-        self._paged_apply = None
-        if sc.decode_path == "paged":
-            family = inspect.getmodule(apply_cached)
-            self._paged_apply = getattr(family, "apply_paged", None)
-        self.decode_path = "paged" if self._paged_apply is not None else "dense"
         self._block_bytes = self.cache.block_bytes()
         self._prefix: Optional[PrefixCache] = (
             PrefixCache(self.cache.allocator, sc.block_size)
@@ -557,21 +507,18 @@ class ServingEngine:
         # oscillating right at the line emits one event per genuine pressure
         # episode instead of one per tick-scale wobble.
         self._headroom_rearm_frac = min(self._headroom_watermark_frac * 1.5, 1.0)
-        if self.decode_path == "paged":
-            # One jitted wrapper each; bucketed table widths retrace under it
-            # (jit caches per shape), so a tick is still exactly one decode
-            # dispatch — just of the program matching the live bucket.
-            self._decode_fn = jax.jit(self._build_decode_paged(), donate_argnums=(1,))
-            self._prefill_fn = jax.jit(self._build_prefill_paged(), donate_argnums=(1,))
-        else:
-            self._decode_fn = jax.jit(self._build_decode(), donate_argnums=(1,))
-            self._prefill_fn = jax.jit(self._build_prefill(), donate_argnums=(1,))
-        # Speculative draft-then-verify: one more jitted program (the W-token
-        # verify), plus a host-side drafter.  A tick with live drafts runs
-        # the verify program INSTEAD of the single-token one — still exactly
-        # one fused decode dispatch per tick.
+        # The compiled programs and what the tick asks of their back end
+        # (serving/programs.py): the family decides "paged" or "dense".  One
+        # jitted wrapper each; bucketed table widths retrace under it (jit
+        # caches per shape), so a tick is still exactly one decode dispatch,
+        # of the program matching the live bucket.  With speculation on, a
+        # decode tick runs the k+1-window verify program INSTEAD of the
+        # single-token one, fed by a host-side drafter.
+        self.programs = build_programs(
+            apply_cached, config, self.cache.leaf_names, sc, self.spec_tokens
+        )
+        self.decode_path = self.programs.backend
         self._drafter = None
-        self._decode_spec_fn = None
         if self.spec_tokens > 0:
             if drafter is None:
                 from .drafter import NgramDrafter
@@ -580,11 +527,6 @@ class ServingEngine:
                     max_ngram=sc.spec_ngram_max, min_ngram=sc.spec_ngram_min
                 )
             self._drafter = drafter
-            builder = (
-                self._build_decode_spec_paged
-                if self.decode_path == "paged" else self._build_decode_spec
-            )
-            self._decode_spec_fn = jax.jit(builder(), donate_argnums=(1,))
         # Pre-create the robustness + fast-path counters so the Prometheus
         # endpoint exposes them at 0 from the first scrape — a dashboard can
         # alert on rate() without waiting for the first incident (or the
@@ -606,163 +548,6 @@ class ServingEngine:
             tel.registry.gauge("serving.tokens_per_dispatch").set(0.0)
             tel.registry.gauge("serving.tier.host_bytes").set(0)
             tel.registry.gauge("serving.tier.host_occupancy").set(0.0)
-
-    # -- compiled programs ---------------------------------------------------
-
-    def _build_decode_paged(self):
-        """The in-dispatch paged decode: the family's ``apply_paged`` reads
-        pool K/V straight through the (bucketed) block tables — no dense
-        per-slot view in, no updated view out, only the written rows, which
-        scatter into the donated pool inside the same dispatch."""
-        apply_paged, config = self._paged_apply, self._config
-        kernel = self.serving.paged_kernel
-
-        def decode(params, pool, tables, lengths, tokens, *poison):
-            logits, rows, *counters = apply_paged(
-                params, tokens[:, None], config, pool, tables, lengths,
-                kernel=kernel,
-            )
-            logits = logits[:, -1]
-            if poison:  # trace-time gate: unarmed programs carry no plumbing
-                logits = logits * poison[0][:, None]
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            ok = jnp.all(jnp.isfinite(logits), axis=-1)
-            new_pool = dict(pool)
-            for n, r in rows.items():
-                new_pool[n] = scatter_token_rows(pool[n], r, tables, lengths, 1)
-            return next_tok, _ok_with_counters(ok, counters), new_pool
-
-        return decode
-
-    def _build_prefill_paged(self):
-        """Paged prefill: the chunk's program consumes the pool through the
-        bucketed table row and returns ONLY the rows it writes — the dense
-        per-slot view is materialized on neither side of the dispatch (the
-        PR 9 program gathered it in AND flowed the updated copy out)."""
-        apply_paged, config = self._paged_apply, self._config
-        chunk_len = self.serving.prefill_chunk
-
-        def prefill(params, pool, table_row, length, chunk, n_real):
-            logits, rows, *counters = apply_paged(
-                params, chunk, config, pool, table_row[None], length[None]
-            )
-            next_tok = jnp.argmax(logits[0, n_real - 1], axis=-1).astype(jnp.int32)
-            ok = jnp.all(jnp.isfinite(logits))
-            new_pool = dict(pool)
-            for n, r in rows.items():
-                new_pool[n] = scatter_token_rows(
-                    pool[n], r, table_row[None], length[None], chunk_len
-                )
-            return next_tok, _ok_with_counters(ok, counters), new_pool
-
-        return prefill
-
-    def _build_decode_spec_paged(self):
-        """The speculative verify dispatch, paged flavor: every slot's
-        ``[last, d_1..d_k]`` window goes through ``apply_paged`` as a
-        ``[S, k+1]`` query block (causally masked against the paged K/V plus
-        the in-window prefix), the shared greedy accept kernel scores all
-        rows at once, and all ``k+1`` freshly written K/V rows scatter into
-        the donated pool.  Rows past a slot's accepted length are stale by
-        construction — the next dispatch at the rewound length re-writes
-        them before its masks ever admit those positions (the offline
-        loop's rewind argument, per-slot)."""
-        apply_paged, config = self._paged_apply, self._config
-        kernel = self.serving.paged_kernel
-        from ..models.generation import speculative_verify_greedy
-
-        def decode(params, pool, tables, lengths, tokens, draft_len, *poison):
-            window = tokens.shape[1]
-            logits, rows, *counters = apply_paged(
-                params, tokens, config, pool, tables, lengths, kernel=kernel,
-            )  # [S, W, V]
-            if poison:  # trace-time gate: unarmed programs carry no plumbing
-                logits = logits * poison[0][:, None, None]
-            t, m = speculative_verify_greedy(logits, tokens[:, 1:], draft_len)
-            ok = jnp.all(jnp.isfinite(logits), axis=(1, 2))
-            new_pool = dict(pool)
-            for n, r in rows.items():
-                new_pool[n] = scatter_token_rows(pool[n], r, tables, lengths, window)
-            return t, m, _ok_with_counters(ok, counters), new_pool
-
-        return decode
-
-    def _build_decode_spec(self):
-        """Speculative verify, dense flavor: per-slot gather views (the PR 9
-        reference path) with a W-token cached forward per lane under vmap —
-        the contrast arm proving accept/rewind correctness is independent of
-        the paged fast path."""
-        apply_cached, config, names = self._apply_cached, self._config, self._kv_names
-        from ..models.generation import speculative_verify_greedy
-
-        def decode(params, pool, tables, lengths, tokens, draft_len, *poison):
-            window = tokens.shape[1]
-            views = {n: gather_block_view(pool[n], tables) for n in names}
-            caches = dict(views, index=lengths)
-
-            def one(cache, toks):
-                logits, new_cache = apply_cached(params, toks[None, :], config, cache)
-                return logits[0], new_cache
-
-            logits, new_caches = jax.vmap(one)(caches, tokens)  # [S, W, V]
-            if poison:  # trace-time gate: unarmed programs carry no plumbing
-                logits = logits * poison[0][:, None, None]
-            t, m = speculative_verify_greedy(logits, tokens[:, 1:], draft_len)
-            ok = jnp.all(jnp.isfinite(logits), axis=(1, 2))
-            new_pool = {}
-            for n in names:
-                rows = extract_token_rows(new_caches[n], lengths, window)
-                new_pool[n] = scatter_token_rows(pool[n], rows, tables, lengths, window)
-            return t, m, ok, new_pool
-
-        return decode
-
-    def _build_decode(self):
-        apply_cached, config, names = self._apply_cached, self._config, self._kv_names
-
-        def decode(params, pool, tables, lengths, tokens, *poison):
-            views = {n: gather_block_view(pool[n], tables) for n in names}
-            caches = dict(views, index=lengths)
-
-            def one(cache, tok):
-                logits, new_cache = apply_cached(params, tok[None, None], config, cache)
-                return logits[0, -1], new_cache
-
-            logits, new_caches = jax.vmap(one)(caches, tokens)
-            if poison:  # trace-time gate: unarmed programs carry no plumbing
-                logits = logits * poison[0][:, None]
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # Per-slot finiteness, folded into the SAME dispatch (a [S, V]
-            # reduction — zero extra dispatch): a poisoned slot is detected
-            # the tick it happens, before its garbage token is emitted.
-            ok = jnp.all(jnp.isfinite(logits), axis=-1)
-            new_pool = {}
-            for n in names:
-                rows = extract_token_rows(new_caches[n], lengths, 1)
-                new_pool[n] = scatter_token_rows(pool[n], rows, tables, lengths, 1)
-            return next_tok, ok, new_pool
-
-        return decode
-
-    def _build_prefill(self):
-        apply_cached, config, names = self._apply_cached, self._config, self._kv_names
-        chunk_len = self.serving.prefill_chunk
-
-        def prefill(params, pool, table_row, length, chunk, n_real):
-            tables = table_row[None]  # [1, M]
-            start = length[None]
-            cache = {n: gather_block_view(pool[n], tables)[0] for n in names}
-            cache["index"] = length
-            logits, new_cache = apply_cached(params, chunk, config, cache)
-            next_tok = jnp.argmax(logits[0, n_real - 1], axis=-1).astype(jnp.int32)
-            ok = jnp.all(jnp.isfinite(logits))
-            new_pool = {}
-            for n in names:
-                rows = extract_token_rows(new_cache[n][None], start, chunk_len)
-                new_pool[n] = scatter_token_rows(pool[n], rows, tables, start, chunk_len)
-            return next_tok, ok, new_pool
-
-        return prefill
 
     # -- request API ---------------------------------------------------------
 
@@ -1432,46 +1217,31 @@ class ServingEngine:
 
     # -- tick phases ---------------------------------------------------------
 
-    def _bucket_width(self, blocks_needed: int) -> int:
-        """Block-table width for the paged programs: the next power of two
-        covering ``blocks_needed``, capped at the configured maximum.  Each
-        width compiles once (jit caches per shape); gather traffic then
-        scales with what live requests actually own instead of the
-        worst-case table."""
-        m = self.serving.resolved_max_blocks()
-        width = 1
-        while width < blocks_needed:
-            width *= 2
-        return min(width, m)
-
-    def _note_bucket(self, kind: str, width: Optional[int]) -> bool:
+    def _note_bucket(self, kind: str, width: int) -> bool:
         """Record a dispatch at this table width; returns True when the
         width is FRESH for ``kind`` — the per-width jit cache misses and the
         dispatch pays a trace+compile in the request's latency path.  The
         ``serving.bucket_compile`` event makes that TTFT spike attributable
-        even with tracing disabled (the dense path keys on its one static
-        width: its first dispatch is the one compile)."""
-        key = width if width is not None else self.serving.resolved_max_blocks()
-        if key in self._seen_widths[kind]:
+        even with tracing disabled."""
+        if width in self._seen_widths[kind]:
             return False
-        self._seen_widths[kind].add(key)
+        self._seen_widths[kind].add(width)
         tel = get_telemetry()
         if tel.enabled:
             # "dispatch" not "kind": event() reserves "kind" for the record
             # envelope, and a field named kind would shadow it in the JSONL.
-            tel.event("serving.bucket_compile", dispatch=kind, width=key)
+            tel.event("serving.bucket_compile", dispatch=kind, width=width)
         self._tick["fresh"] = True
         return True
 
-    def _table_row(self, blocks: List[int], width: Optional[int] = None) -> np.ndarray:
-        m = width if width is not None else self.serving.resolved_max_blocks()
-        row = np.zeros((m,), np.int32)
+    def _table_row(self, blocks: List[int], width: int) -> np.ndarray:
+        row = np.zeros((width,), np.int32)
         row[: len(blocks)] = blocks
         return row
 
     def _read_ok(self, ok, lanes: int) -> np.ndarray:
         """The host sync point of a dispatch: its ``lanes`` finiteness flags.
-        What an expert family's program put behind them (``_ok_with_counters``)
+        What an expert family's program put behind them (``programs._ok_with_counters``)
         is added to ``moe_counters`` from the same read-back."""
         flags = np.asarray(ok)
         if flags.size > lanes:
@@ -1501,18 +1271,16 @@ class ServingEngine:
                 return  # the slot itself was preempted to find blocks
             chunk = np.zeros((1, chunk_len), np.int32)
             chunk[0, :n_real] = feed[start : start + n_real]
-            width = None
-            if self.decode_path == "paged":
-                # Bucket the table to the chunk's padded write extent — the
-                # gather reads the blocks this prefill can actually touch.
-                width = self._bucket_width(
-                    blocks_for_tokens(start + chunk_len, self.serving.block_size)
-                )
+            # The table covers the chunk's padded write extent: the gather
+            # reads the blocks this prefill can actually touch.
+            width = self.programs.table_width(
+                blocks_for_tokens(start + chunk_len, self.serving.block_size)
+            )
             fresh = self._note_bucket("prefill", width)
             table_row = self._table_row(slot.blocks, width)
-            span.set_metadata(request=req.id, start=start, rows=n_real, width=width or 0)
+            span.set_metadata(request=req.id, start=start, rows=n_real, width=width)
         with _TickPhase(self, "prefill.wait", request=req.id):
-            next_tok, ok, self.cache.pool = self._prefill_fn(
+            next_tok, ok, self.cache.pool = self.programs.prefill(
                 self.params,
                 self.cache.pool,
                 table_row,
@@ -1593,16 +1361,11 @@ class ServingEngine:
             if not live:
                 return
             s = self.serving.max_slots
-            if self.decode_path == "paged":
-                # Bucket the tables to the widest live slot: gather traffic (and
-                # attention width) scale with the blocks requests actually own.
-                m = self._bucket_width(max(len(sched.slots[idx].blocks) for idx in live))
-                gathered = sum(len(sched.slots[idx].blocks) for idx in live)
-            else:
-                m = self.serving.resolved_max_blocks()
-                # The dense program gathers every slot's full worst-case view,
-                # live or not — exactly the tax the paged path removes.
-                gathered = s * m
+            # The tables are as wide as the widest live slot needs: gather
+            # traffic (and attention width) scale with the blocks requests own.
+            owned = [len(sched.slots[idx].blocks) for idx in live]
+            m = self.programs.table_width(max(owned))
+            gathered = self.programs.gathered_blocks(owned)
             tables = np.zeros((s, m), np.int32)
             lengths = np.zeros((s,), np.int32)
             tokens = np.zeros((s, window), np.int32)
@@ -1641,11 +1404,11 @@ class ServingEngine:
             if window > 1:
                 # The verify program REPLACES the single-token one this tick —
                 # still exactly one fused decode dispatch per bucket.
-                t_rows, m_counts, ok_flags, self.cache.pool = self._decode_spec_fn(*args)
+                t_rows, m_counts, ok_flags, self.cache.pool = self.programs.decode_spec(*args)
                 out = np.asarray(t_rows)
                 accepts = np.asarray(m_counts)
             else:
-                next_tokens, ok_flags, self.cache.pool = self._decode_fn(*args)
+                next_tokens, ok_flags, self.cache.pool = self.programs.decode(*args)
                 out = np.asarray(next_tokens)[:, None]
                 accepts = np.zeros((s,), np.int32)
             self.decode_dispatches += 1

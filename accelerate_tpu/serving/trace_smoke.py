@@ -21,9 +21,7 @@ subsystem (``serving/tracing.py``) end to end:
   block from the JSONL alone;
 - **overhead bounded** — steady-state decode throughput with tracing on
   stays close to tracing off (generous 15% smoke bound against CI timing
-  noise; the 3% acceptance bound is enforced continuously by the perf-gate
-  serving row, which runs with tracing default-ON and must hold its
-  committed paged-vs-dense floor).
+  noise; a CPU ratio, not a device number).
 
 Exit code 0 only when every assertion holds.
 """

@@ -1318,9 +1318,6 @@ def _serving_probe() -> dict:
             },
             "trace": trace_stats,
             "paged_decode": {
-                "paged_steps_per_s": paged_row["serving_paged_decode_steps_per_s"],
-                "dense_steps_per_s": paged_row["serving_dense_decode_steps_per_s"],
-                "paged_vs_dense_ratio": paged_row["serving_paged_vs_dense_ratio"],
                 "dispatches_per_tick": paged_row["serving_decode_dispatches_per_tick"],
                 "gather_bytes_per_tick": round(
                     cached_eng.decode_gather_bytes / max(cached_eng.decode_dispatches, 1)

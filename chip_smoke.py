@@ -4,8 +4,7 @@ Drives the two main paths once, through the entry points a user calls, at the
 published widths of Llama-3.2-1B with seeded random weights:
 
 - kernels, on one device: the compiled flash kernel under a padding mask
-  against the einsum path, and the two paged kernels no default reaches
-  against the XLA paged path;
+  against the einsum path;
 - trainer: ``Accelerator.prepare`` + ``make_train_step`` at s=2048, a fixed
   batch repeated (loss must fall), then one step on a right-padded batch;
 - server: ``Accelerator.prepare_serving`` -> ``ServingEngine`` under a dozen
@@ -286,22 +285,12 @@ def judge_divergence(logits, *, offline: int, engine: int) -> dict:
 
 def kernels_phase(cfg, phase, *, interpret) -> None:
     """The compiled flash kernel with a padding mask against the einsum path,
-    and the two paged kernels no default reaches against the XLA paged path,
-    at this model's head geometry.  A compiler refusal of a paged kernel is
-    reported in its words and does not fail the run (``paged_kernel=True``
-    raises the same words); a wrong result does."""
-    import numpy as np
-
+    at this model's head geometry."""
     import jax
     import jax.numpy as jnp
 
     from accelerate_tpu.models import llama
-    from accelerate_tpu.models.generation import paged_cache_write
-    from accelerate_tpu.ops.pallas_attention import (
-        pallas_attention,
-        pallas_paged_attention,
-        pallas_paged_window_attention,
-    )
+    from accelerate_tpu.ops.pallas_attention import pallas_attention
 
     h, kh, hd, groups = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_, cfg.num_heads // cfg.num_kv_heads
     keys = iter(jax.random.split(jax.random.key(SEED + 2), 16))
@@ -338,43 +327,6 @@ def kernels_phase(cfg, phase, *, interpret) -> None:
     want = jax.jit(jax.grad(scalar(einsum), argnums=(0, 1, 2)))(q, k, v)
     for name, g, w in zip("qkv", got, want):
         close(g, w, f"flash_kv_valid_d{name}")
-
-    # Paged decode kernels against paged_cache_write + einsum attention.
-    slots, width, block, blocks, window = 4, 8, 16, 64, 4
-    pool_k, pool_v = normal((blocks, block, kh, hd)), normal((blocks, block, kh, hd))
-    tables = jnp.asarray(
-        np.random.default_rng(SEED + 2).permutation(blocks - 1)[: slots * width] + 1, jnp.int32
-    ).reshape(slots, width)
-    lengths = jnp.asarray([5, 37, 80, 124 - window], jnp.int32)
-
-    def xla_paged(q, k_new, v_new):
-        t = q.shape[1]
-        _, k_full = paged_cache_write(pool_k, k_new, tables, lengths, cfg.dtype)
-        _, v_full = paged_cache_write(pool_v, v_new, tables, lengths, cfg.dtype)
-        pos = lengths[:, None] + jnp.arange(t)[None]
-        mask = pos[:, :, None] >= jnp.arange(width * block)[None, None, :]
-        return llama._attention(q, k_full, v_full, mask, groups)
-
-    for name, t in (("paged", 1), ("paged_window", window)):
-        q, k_new, v_new = normal((slots, t, h, hd)), normal((slots, t, kh, hd)), normal((slots, t, kh, hd))
-        if t == 1:
-            def kernel(q, k_new, v_new):
-                return pallas_paged_attention(
-                    q[:, 0], k_new[:, 0], v_new[:, 0], pool_k, pool_v, tables, lengths,
-                    interpret=interpret,
-                )[:, None]
-        else:
-            def kernel(q, k_new, v_new):
-                return pallas_paged_window_attention(
-                    q, k_new, v_new, pool_k, pool_v, tables, lengths, interpret=interpret
-                )
-        try:
-            compiled = jax.jit(kernel).lower(q, k_new, v_new).compile()
-        except Exception as e:  # the compiler's words, reported and not gating
-            phase.facts[name] = f"refused: {type(e).__name__}: {str(e)[:400]}"
-            continue
-        close(compiled(q, k_new, v_new), jax.jit(xla_paged)(q, k_new, v_new), name)
-        phase.facts[name] = "compiled and matched"
 
 
 def main() -> int:

@@ -38,6 +38,18 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 
+def without_apply_paged(family):
+    """``family.apply_cached`` as a function of this module, which has no
+    ``apply_paged``: the serving engine chooses its back end from the family
+    alone, so this is how a test reaches the dense back end with a paged
+    family's weights and oracle."""
+
+    def apply_cached(params, input_ids, config, cache):
+        return family.apply_cached(params, input_ids, config, cache)
+
+    return apply_cached
+
+
 @pytest.fixture(autouse=True)
 def _reset_singletons():
     """Reference parity: ``AccelerateTestCase.tearDown`` (``test_utils/testing.py:

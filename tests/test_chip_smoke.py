@@ -56,7 +56,6 @@ def test_phases_run_tiny_on_the_cpu_mesh(capsys):
     assert facts["trainer"]["train_collectives"]["all-gather"][0] > 0
     assert facts["trainer"]["losses"][-1] < facts["trainer"]["losses"][0]
     assert facts["server"]["prefix_hits"] > 0
-    assert facts["kernels"]["paged"] == facts["kernels"]["paged_window"] == "compiled and matched"
 
 
 def test_a_failed_check_fails_the_phase():

@@ -354,7 +354,7 @@ def test_a_family_without_experts_keeps_its_program():
         llama.apply_cached, llama.init_cache, llama.init_params(c, jax.random.key(0)), c, block_size=4, num_blocks=32,
         max_slots=2, max_blocks_per_seq=8, prefill_chunk=4)
     tables, lengths = np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)
-    out = jax.eval_shape(engine._decode_fn, engine.params, engine.cache.pool, tables, lengths, np.zeros((2,), np.int32))
+    out = jax.eval_shape(engine.programs.decode, engine.params, engine.cache.pool, tables, lengths, np.zeros((2,), np.int32))
     assert out[1].dtype == jnp.bool_ and out[1].shape == (2,)
     engine.submit(np.arange(6), 3)
     engine.run()

@@ -11,6 +11,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from conftest import without_apply_paged
 
 from accelerate_tpu import telemetry
 from accelerate_tpu.models import gpt2
@@ -38,6 +39,9 @@ from accelerate_tpu.serving import (
 )
 from accelerate_tpu.serving.blocks import NULL_BLOCK, blocks_for_tokens
 from accelerate_tpu.serving.scheduler import RequestState, Scheduler
+
+# the family decides the engine's back end: gpt2 has an ``apply_paged``, the wrapper's module has none
+GPT2_APPLY_CACHED = {"paged": gpt2.apply_cached, "dense": without_apply_paged(gpt2)}
 
 
 @pytest.fixture(autouse=True)
@@ -496,8 +500,8 @@ def test_chunked_prefill_interleaves_with_decode(gpt2_setup):
 def test_decode_path_matrix_token_identical(decode_path, quant):
     """The acceptance matrix: paged decode x int8 KV x forced preemption x
     chunked-prefill interleaving stays token-identical to the offline
-    generate_loop — and the dense fallback (the always-correct reference
-    program, still used by families without apply_paged) agrees."""
+    generate_loop — and the dense back end (what a family without an
+    apply_paged is served on) agrees."""
     cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32, kv_cache_quant=quant)
     params = gpt2.init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(13)
@@ -507,10 +511,9 @@ def test_decode_path_matrix_token_identical(decode_path, quant):
     max_new = [8, 6, 7]
     want = {i: _oracle(cfg, params, p, m) for i, (p, m) in enumerate(zip(prompts, max_new))}
     eng = ServingEngine(
-        gpt2.apply_cached, gpt2.init_cache, params, cfg,
+        GPT2_APPLY_CACHED[decode_path], gpt2.init_cache, params, cfg,
         serving=ServingConfig(block_size=4, num_blocks=9, max_slots=3,
-                              prefill_chunk=4, max_blocks_per_seq=6,
-                              decode_path=decode_path),
+                              prefill_chunk=4, max_blocks_per_seq=6),
     )
     assert eng.stats()["decode_path"] == decode_path
     ids = {eng.submit(p, m): i for i, (p, m) in enumerate(zip(prompts, max_new))}
@@ -533,10 +536,9 @@ def _eqns(jaxpr):
                     yield from _eqns(sub)
 
 
-@pytest.mark.parametrize("kernel", [False, True])
 @pytest.mark.parametrize("pool_kind", ["fp", "int8", "fp_hd16"])
 @pytest.mark.parametrize("family_name", ["gpt2", "llama"])
-def test_paged_pool_is_a_constant_of_the_layer_scan(family_name, pool_kind, kernel):
+def test_paged_pool_is_a_constant_of_the_layer_scan(family_name, pool_kind):
     """What the device moves, as far as a jaxpr can say it: ``apply_paged``
     hands the layer scan no pool leaf to slice (its ``xs`` are the layers'
     weights and the layer number); the pool reaches the scan's body as loop
@@ -574,7 +576,7 @@ def test_paged_pool_is_a_constant_of_the_layer_scan(family_name, pool_kind, kern
     shapes = {"decode": (i32(slots, 1), i32(slots, width), i32(slots)), "prefill": (i32(1, chunk), i32(1, width), i32(1))}
     for what, (ids, tables, starts) in shapes.items():
         jaxpr = jax.make_jaxpr(
-            lambda p, pl, i, t, s: family.apply_paged(p, i, cfg, pl, t, s, kernel=kernel)
+            lambda p, pl, i, t, s: family.apply_paged(p, i, cfg, pl, t, s)
         )(params, pool, ids, tables, starts).jaxpr
         scans = [e for e in jaxpr.eqns if e.primitive.name == "scan" and e.params["length"] == cfg.num_layers]
         assert len(scans) == 1, f"{what}: one layer scan expected"
@@ -590,7 +592,7 @@ def test_paged_pool_is_a_constant_of_the_layer_scan(family_name, pool_kind, kern
                             if any(hasattr(v, "aval") and v.aval.shape[:2] == (cfg.num_layers, num_blocks) for v in e.invars)]
         if pool_kind == "fp":
             assert not cut, f"{what}: dynamic_slice cuts {cut} out of the pool"
-            read = [v.aval.shape[0] for e in _eqns(jaxpr) if e.primitive.name in ("gather", "pallas_call")
+            read = [v.aval.shape[0] for e in _eqns(jaxpr) if e.primitive.name == "gather"
                     for v in e.invars if hasattr(v, "aval") and pool_sized & set(v.aval.shape[:1])]
             assert read and set(read) == {whole}, f"{what}: reads of the pool lead with {read}, not with L*N"
         else:
@@ -610,10 +612,10 @@ def test_paged_decode_gather_bytes_scale_with_live_blocks(gpt2_setup):
 
     def gather_per_tick(path):
         eng = ServingEngine(
-            gpt2.apply_cached, gpt2.init_cache, params, cfg,
+            GPT2_APPLY_CACHED[path], gpt2.init_cache, params, cfg,
             serving=ServingConfig(block_size=4, num_blocks=40, max_slots=4,
                                   prefill_chunk=8, max_blocks_per_seq=8,
-                                  decode_path=path, prefix_cache=False),
+                                  prefix_cache=False),
         )
         eng.submit([1, 2, 3], 6)  # one short request: 1-2 live blocks
         eng.run(max_ticks=200)
@@ -630,90 +632,6 @@ def test_paged_decode_gather_bytes_scale_with_live_blocks(gpt2_setup):
     snap_stats = eng.stats()
     assert snap_stats["decode_path"] == "paged"
     assert snap_stats["decode_gather_bytes"] == eng.decode_gather_bytes
-
-
-def test_paged_kernel_token_identical(gpt2_setup):
-    """ServingConfig.paged_kernel routes single-token decode attention
-    through the Pallas paged kernel (interpreted off-TPU); outputs stay
-    token-identical to the offline oracle."""
-    cfg, params = gpt2_setup
-    rng = np.random.default_rng(17)
-    prompts = [list(rng.integers(0, cfg.vocab_size, size=n)) for n in (5, 7)]
-    want = {i: _oracle(cfg, params, p, 4) for i, p in enumerate(prompts)}
-    eng = ServingEngine(
-        gpt2.apply_cached, gpt2.init_cache, params, cfg,
-        serving=ServingConfig(block_size=4, num_blocks=20, max_slots=2,
-                              prefill_chunk=8, max_blocks_per_seq=4,
-                              paged_kernel=True),
-    )
-    ids = {eng.submit(p, 4): i for i, p in enumerate(prompts)}
-    outputs = eng.run(max_ticks=200)
-    for rid, out in outputs.items():
-        assert out == want[ids[rid]], f"request {rid} diverged under the Pallas kernel"
-
-
-def test_paged_kernel_gqa_unit_matches_reference():
-    """The kernel's grouped-query layout (groups > 1 — the [K, g, hd]
-    reshapes gpt2's MHA never exercises) against a direct reference:
-    gather the table's blocks, append the new row at ``length``, masked
-    softmax per kv-head group.  Unit-level so tier-1 pays no llama
-    compile; the e2e GQA identity runs in the slow tier below."""
-    from accelerate_tpu.ops.pallas_attention import pallas_paged_attention
-
-    rng = np.random.default_rng(29)
-    b, kh, groups, d, n, bs, m = 2, 2, 2, 8, 7, 4, 3
-    h = kh * groups
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    k_new = jnp.asarray(rng.standard_normal((b, kh, d)), jnp.float32)
-    v_new = jnp.asarray(rng.standard_normal((b, kh, d)), jnp.float32)
-    pool_k = jnp.asarray(rng.standard_normal((n, bs, kh, d)), jnp.float32)
-    pool_v = jnp.asarray(rng.standard_normal((n, bs, kh, d)), jnp.float32)
-    tables = jnp.asarray([[1, 2, 0], [3, 4, 5]], jnp.int32)
-    lengths = jnp.asarray([6, 9], jnp.int32)
-
-    got = np.asarray(pallas_paged_attention(
-        q, k_new, v_new, pool_k, pool_v, tables, lengths, interpret=True
-    ))
-    for i in range(b):
-        ctx_k = np.asarray(pool_k)[np.asarray(tables)[i]].reshape(m * bs, kh, d)
-        ctx_v = np.asarray(pool_v)[np.asarray(tables)[i]].reshape(m * bs, kh, d)
-        ln = int(lengths[i])
-        ks = np.concatenate([ctx_k[:ln], np.asarray(k_new)[i][None]], axis=0)
-        vs = np.concatenate([ctx_v[:ln], np.asarray(v_new)[i][None]], axis=0)
-        for head in range(h):
-            s = ks[:, head // groups] @ np.asarray(q)[i, head] / np.sqrt(d)
-            p = np.exp(s - s.max()); p /= p.sum()
-            want = p @ vs[:, head // groups]
-            np.testing.assert_allclose(got[i, head], want, rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.slow
-def test_paged_kernel_gqa_token_identical():
-    """E2e GQA kernel identity: llama tiny has 4 q heads over 2 kv heads,
-    so a head-grouping mismatch in the kernel would diverge here even
-    though every gpt2 kernel test passes.  Slow tier (llama compiles are
-    heavy); the layout itself is pinned in tier-1 by the unit test above."""
-    from accelerate_tpu.models import llama
-
-    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
-    assert cfg.num_heads != cfg.num_kv_heads  # the point of this test
-    params = llama.init_params(cfg, jax.random.key(0))
-    rng = np.random.default_rng(23)
-    prompts = [list(rng.integers(0, cfg.vocab_size, size=n)) for n in (5, 9)]
-    want = {}
-    for i, p in enumerate(prompts):
-        out = llama.generate(params, jnp.asarray([p], jnp.int32), cfg, max_new_tokens=4)
-        want[i] = [int(t) for t in np.asarray(out[0])]
-    eng = ServingEngine(
-        llama.apply_cached, llama.init_cache, params, cfg,
-        serving=ServingConfig(block_size=4, num_blocks=20, max_slots=2,
-                              prefill_chunk=8, max_blocks_per_seq=4,
-                              paged_kernel=True),
-    )
-    ids = {eng.submit(p, 4): i for i, p in enumerate(prompts)}
-    outputs = eng.run(max_ticks=200)
-    for rid, out in outputs.items():
-        assert out == want[ids[rid]], f"GQA request {rid} diverged under the kernel"
 
 
 # -- prefix caching -----------------------------------------------------------

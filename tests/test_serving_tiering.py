@@ -16,6 +16,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from conftest import without_apply_paged
 
 from accelerate_tpu import telemetry
 from accelerate_tpu.models import gpt2
@@ -268,7 +269,7 @@ def _oracle(cfg, params, prompt, max_new):
     return [int(t) for t in np.asarray(out[0])]
 
 
-def _run_tiered_mix(cfg, params, *, seed=7, host_blocks=16, **overrides):
+def _run_tiered_mix(cfg, params, *, seed=7, host_blocks=16, apply_cached=gpt2.apply_cached, **overrides):
     """A pool tight enough to force preemption, with the host tier on:
     returns (engine, completions, want-by-request-id)."""
     rng = np.random.default_rng(seed)
@@ -280,7 +281,7 @@ def _run_tiered_mix(cfg, params, *, seed=7, host_blocks=16, **overrides):
               max_blocks_per_seq=6, host_blocks=host_blocks)
     kw.update(overrides)
     eng = ServingEngine(
-        gpt2.apply_cached, gpt2.init_cache, params, cfg,
+        apply_cached, gpt2.init_cache, params, cfg,
         serving=ServingConfig(**kw),
     )
     ids = {eng.submit(p, m): i for i, (p, m) in enumerate(zip(prompts, max_new))}
@@ -303,9 +304,10 @@ def test_tiered_preemption_token_identical_matrix(decode_path, quant):
     dispatches on resume."""
     cfg = gpt2.GPT2Config.tiny(dtype=jnp.float32, kv_cache_quant=quant)
     params = gpt2.init_params(cfg, jax.random.key(0))
-    eng, done, ids, prompts = _run_tiered_mix(
-        cfg, params, decode_path=decode_path
-    )
+    # the family decides the back end: the wrapper's module has no apply_paged
+    apply_cached = {"paged": gpt2.apply_cached, "dense": without_apply_paged(gpt2)}[decode_path]
+    eng, done, ids, prompts = _run_tiered_mix(cfg, params, apply_cached=apply_cached)
+    assert eng.stats()["decode_path"] == decode_path
     st = eng.stats()["tiering"]
     assert st["demotions"] > 0 and st["promotions"] > 0, (
         f"no migration happened: {st}"

@@ -1,8 +1,7 @@
 """Speculative serving decode: the per-slot draft-then-verify tick
 (``ServingConfig.spec_tokens``).  Covers the shared greedy verify/accept
 kernel, the drafters, per-slot variable acceptance across vmap lanes in ONE
-fused dispatch, the multi-token Pallas window kernel against a
-gather+masked-softmax reference (including GQA), and the acceptance oracle:
+fused dispatch, and the acceptance oracle:
 speculative serving stays token-identical to the offline ``generate_loop``
 across {paged, dense} x {fp, int8} under randomized mixes, forced
 preemption, and journal recovery."""
@@ -12,11 +11,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from conftest import without_apply_paged
 
 from accelerate_tpu import telemetry
 from accelerate_tpu.models import gpt2, llama
 from accelerate_tpu.models.generation import speculative_verify_greedy
-from accelerate_tpu.ops.pallas_attention import pallas_paged_window_attention
 from accelerate_tpu.serving import (
     DraftModelDrafter,
     NgramDrafter,
@@ -233,76 +232,6 @@ def test_acceptance_caps_at_remaining_exact_finish(gpt2_setup):
 
 
 # ---------------------------------------------------------------------------
-# The multi-token Pallas window kernel
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("kv_heads,groups", [(4, 1), (2, 2)])
-def test_window_kernel_matches_masked_softmax_reference(kv_heads, groups):
-    """pallas_paged_window_attention vs a direct reference: gather the
-    table's blocks, append the window's new rows, masked softmax per
-    window position with intra-window causality — MHA and GQA layouts."""
-    rng = np.random.default_rng(31)
-    b, d, nblk, bs, m, w = 2, 8, 7, 4, 3, 3
-    h = kv_heads * groups
-    q = jnp.asarray(rng.standard_normal((b, w, h, d)), jnp.float32)
-    k_new = jnp.asarray(rng.standard_normal((b, w, kv_heads, d)), jnp.float32)
-    v_new = jnp.asarray(rng.standard_normal((b, w, kv_heads, d)), jnp.float32)
-    pool_k = jnp.asarray(rng.standard_normal((nblk, bs, kv_heads, d)), jnp.float32)
-    pool_v = jnp.asarray(rng.standard_normal((nblk, bs, kv_heads, d)), jnp.float32)
-    tables = jnp.asarray([[1, 2, 0], [3, 4, 5]], jnp.int32)
-    lengths = jnp.asarray([6, 9], jnp.int32)
-
-    got = np.asarray(pallas_paged_window_attention(
-        q, k_new, v_new, pool_k, pool_v, tables, lengths, interpret=True
-    ))
-    assert got.shape == (b, w, h, d)
-    for i in range(b):
-        ctx_k = np.asarray(pool_k)[np.asarray(tables)[i]].reshape(m * bs, kv_heads, d)
-        ctx_v = np.asarray(pool_v)[np.asarray(tables)[i]].reshape(m * bs, kv_heads, d)
-        ln = int(lengths[i])
-        for qw in range(w):
-            # window position qw sees: pool rows < length, then new rows 0..qw
-            ks = np.concatenate([ctx_k[:ln], np.asarray(k_new)[i, :qw + 1]], 0)
-            vs = np.concatenate([ctx_v[:ln], np.asarray(v_new)[i, :qw + 1]], 0)
-            for head in range(h):
-                kh = head // groups
-                s = ks[:, kh] @ np.asarray(q)[i, qw, head] / np.sqrt(d)
-                p = np.exp(s - s.max()); p /= p.sum()
-                want = p @ vs[:, kh]
-                np.testing.assert_allclose(
-                    got[i, qw, head], want, rtol=2e-5, atol=2e-5,
-                    err_msg=f"b={i} w={qw} head={head}",
-                )
-
-
-def test_window_kernel_single_row_degenerates_to_decode_shape():
-    """W=1 window must agree with the reference too (the spec program's
-    draft-less tick)."""
-    rng = np.random.default_rng(37)
-    b, kv_heads, groups, d, nblk, bs = 1, 2, 2, 8, 5, 4
-    h = kv_heads * groups
-    q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    k_new = jnp.asarray(rng.standard_normal((b, 1, kv_heads, d)), jnp.float32)
-    v_new = jnp.asarray(rng.standard_normal((b, 1, kv_heads, d)), jnp.float32)
-    pool_k = jnp.asarray(rng.standard_normal((nblk, bs, kv_heads, d)), jnp.float32)
-    pool_v = jnp.asarray(rng.standard_normal((nblk, bs, kv_heads, d)), jnp.float32)
-    tables = jnp.asarray([[1, 3]], jnp.int32)
-    lengths = jnp.asarray([5], jnp.int32)
-    got = np.asarray(pallas_paged_window_attention(
-        q, k_new, v_new, pool_k, pool_v, tables, lengths, interpret=True))
-    ctx_k = np.asarray(pool_k)[np.asarray(tables)[0]].reshape(2 * bs, kv_heads, d)
-    ctx_v = np.asarray(pool_v)[np.asarray(tables)[0]].reshape(2 * bs, kv_heads, d)
-    ks = np.concatenate([ctx_k[:5], np.asarray(k_new)[0]], 0)
-    vs = np.concatenate([ctx_v[:5], np.asarray(v_new)[0]], 0)
-    for head in range(h):
-        s = ks[:, head // groups] @ np.asarray(q)[0, 0, head] / np.sqrt(d)
-        p = np.exp(s - s.max()); p /= p.sum()
-        np.testing.assert_allclose(got[0, 0, head], p @ vs[:, head // groups],
-                                   rtol=2e-5, atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
 # Token-identity matrix (the acceptance oracle)
 # ---------------------------------------------------------------------------
 
@@ -325,12 +254,13 @@ def test_spec_matrix_token_identical(decode_path, quant):
     max_new = [8, 6, 7]
     want = {i: _oracle(cfg, params, p, m)
             for i, (p, m) in enumerate(zip(prompts, max_new))}
+    # the family decides the back end: the wrapper's module has no apply_paged
+    apply_cached = {"paged": gpt2.apply_cached, "dense": without_apply_paged(gpt2)}[decode_path]
     eng = ServingEngine(
-        gpt2.apply_cached, gpt2.init_cache, params, cfg,
+        apply_cached, gpt2.init_cache, params, cfg,
         serving=ServingConfig(block_size=4, num_blocks=9, max_slots=3,
                               prefill_chunk=4, max_blocks_per_seq=6,
-                              prefix_cache=False, decode_path=decode_path,
-                              spec_tokens=2),
+                              prefix_cache=False, spec_tokens=2),
     )
     assert eng.stats()["decode_path"] == decode_path
     ids = {eng.submit(p, m): i for i, (p, m) in enumerate(zip(prompts, max_new))}
@@ -345,32 +275,10 @@ def test_spec_matrix_token_identical(decode_path, quant):
     assert eng.cache.allocator.used_blocks == 0
 
 
-def test_spec_paged_kernel_token_identical(gpt2_setup):
-    """paged_kernel=True routes the verify window through the Pallas window
-    kernel (interpreted off-TPU); outputs stay token-identical."""
-    cfg, params = gpt2_setup
-    rng = np.random.default_rng(17)
-    pattern = [int(t) for t in rng.integers(0, cfg.vocab_size, size=4)]
-    prompts = [pattern * 2, pattern * 2 + pattern[:2]]
-    want = {i: _oracle(cfg, params, p, 5) for i, p in enumerate(prompts)}
-    eng = ServingEngine(
-        gpt2.apply_cached, gpt2.init_cache, params, cfg,
-        serving=ServingConfig(block_size=4, num_blocks=20, max_slots=2,
-                              prefill_chunk=8, max_blocks_per_seq=5,
-                              prefix_cache=False, paged_kernel=True,
-                              spec_tokens=2),
-    )
-    ids = {eng.submit(p, 5): i for i, p in enumerate(prompts)}
-    outputs = eng.run(max_ticks=200)
-    for rid, out in outputs.items():
-        assert out == want[ids[rid]], f"request {rid} diverged under the window kernel"
-    assert eng.stats()["spec"]["rounds"] > 0
-
-
-def test_llama_gqa_spec_window_kernel_token_identical():
+def test_llama_gqa_spec_window_token_identical():
     """GQA end to end: llama-tiny (4 q heads / 2 kv heads) through the
-    speculative paged path WITH the Pallas window kernel stays
-    token-identical to the offline llama oracle."""
+    speculative paged path (a k+1 window of queries against grouped K/V)
+    stays token-identical to the offline llama oracle."""
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg, jax.random.key(1))
     rng = np.random.default_rng(19)
@@ -385,8 +293,7 @@ def test_llama_gqa_spec_window_kernel_token_identical():
         llama.apply_cached, llama.init_cache, params, cfg,
         serving=ServingConfig(block_size=4, num_blocks=20, max_slots=2,
                               prefill_chunk=8, max_blocks_per_seq=5,
-                              prefix_cache=False, paged_kernel=True,
-                              spec_tokens=2),
+                              prefix_cache=False, spec_tokens=2),
     )
     ids = {eng.submit(p, 5): i for i, p in enumerate(prompts)}
     outputs = eng.run(max_ticks=200)

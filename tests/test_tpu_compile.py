@@ -34,16 +34,7 @@ try:
 except Exception as e:  # no compile-only TPU client in this install: the test skips
     print("NO_TOPOLOGY", type(e).__name__, str(e)[:300].replace("\n", " "), flush=True)
     sys.exit(0)
-import functools
-from accelerate_tpu.ops import pallas_attention as kernels
-
-# apply_paged leaves ``interpret`` to the kernels, which interpret wherever the default backend is not a TPU, as
-# here: hand them the argument the cases below pass themselves
-for name in ("pallas_paged_attention", "pallas_paged_window_attention"):
-    setattr(kernels, name, functools.partial(getattr(kernels, name), interpret=False))
-from accelerate_tpu.ops.pallas_attention import (
-    pallas_attention, pallas_paged_attention, pallas_paged_window_attention,
-)
+from accelerate_tpu.ops.pallas_attention import pallas_attention
 
 sh = SingleDeviceSharding(topo.devices[0])
 sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
@@ -66,14 +57,7 @@ def program(case, hd, b):
         return paged_step(case, hd, b)
     if case.startswith("latent_step"):
         return latent_step(case)
-    slots, width, block, blocks, window = b, 8, 16, 64, 4
-    pool, tables, lengths = sds((blocks, block, kh, hd)), sds((slots, width), jnp.int32), sds((slots,), jnp.int32)
-    if case == "paged":
-        return (lambda *a: pallas_paged_attention(*a, interpret=False)), (
-            sds((slots, h, hd)), sds((slots, kh, hd)), sds((slots, kh, hd)), pool, pool, tables, lengths)
-    return (lambda *a: pallas_paged_window_attention(*a, interpret=False)), (
-        sds((slots, window, h, hd)), sds((slots, window, kh, hd)), sds((slots, window, kh, hd)),
-        pool, pool, tables, lengths)
+    raise ValueError(case)
 
 
 STEP_WIDTH = 64  # table width of every step: a context of 64 * 16 = 1024 rows a slot
@@ -102,7 +86,7 @@ def paged_step(case, hd, kv_heads):
     params = place(jax.eval_shape(lambda: llama.init_params(c, jax.random.key(0))))
     pool = place(jax.eval_shape(lambda: make_paged_pool(llama.init_cache, c, STEP_BLOCKS, 16)))
     rows, tokens = (1, 32) if case == "paged_step_prefill" else (STEP_SLOTS, 1)
-    f = lambda p, pl, i, t, s: llama.apply_paged(p, i, c, pl, t, s, kernel=case == "paged_step_kernel")
+    f = lambda p, pl, i, t, s: llama.apply_paged(p, i, c, pl, t, s)
     return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, STEP_WIDTH), jnp.int32), sds((rows,), jnp.int32))
 
 
@@ -207,7 +191,7 @@ for spec in sys.argv[2:]:
     try:
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
-        if "tpu_custom_call" not in compiled.as_text() and case not in ("paged_step", "paged_step_prefill", "paged_step_int8"):
+        if "tpu_custom_call" not in compiled.as_text() and not case.startswith("paged_step"):
             raise AssertionError("compiled, but the executable holds no Mosaic kernel")
         note = check_paged_step(spec, compiled) if case.startswith("paged_step") else ""
         note = check_latent_step(compiled) if case.startswith("latent_step") else note
@@ -227,16 +211,13 @@ CASES = [
         ("flash_grad", 2),
         ("flash_kv_valid", 1),
         ("flash_kv_valid", 8),
-        ("paged", 4),
-        ("paged_window", 4),
     )
 ] + [
     # the whole paged step of models/llama.py; the third field is the number of kv heads.  Beyond "it compiles":
-    # pools the TPU holds block by block (generation._blocks_lie_row_by_row) are gathered from where they lie, on the
-    # XLA path at a decode and a prefill shape and on the kernel path (PERF.md section 6, PR 27) ...
+    # pools the TPU holds block by block (generation._blocks_lie_row_by_row) are gathered from where they lie, at a
+    # decode and a prefill shape (PERF.md section 6, PR 27) ...
     ("paged_step", 128, 2),
     ("paged_step_prefill", 128, 2),
-    ("paged_step_kernel", 128, 2),
     ("paged_step", 128, 1),
     ("paged_step", 128, 4),
     ("paged_step", 128, 8),
