@@ -489,58 +489,68 @@ def apply_cached(params: dict, input_ids: jax.Array, config: DeepseekV3Config, c
     return logits, {"ckv": new_ckv, "kr": _pack_rope(new_kr, c), "index": index + s}
 
 
-def apply_paged(
-    params: dict,
-    input_ids: jax.Array,
-    config: DeepseekV3Config,
-    pool: dict,
-    tables: jax.Array,
-    starts: jax.Array,
-):
+def apply_paged(params: dict, groups, config: DeepseekV3Config, pool: dict):
     """Forward over new tokens straight against the paged latent pool (the
-    contract of ``llama.apply_paged``): row ``b``'s tokens sit at positions
-    ``starts[b] .. starts[b]+T-1``; every layer gathers its latents and rotated
-    keys through the block tables from the pool where it lies, overlays the new
-    rows, and attends, expanded for a chunk and absorbed for one token.  Returns
-    (logits, the written rows ``{leaf: [B, layers or groups, T, ...]}`` for the
+    contract of ``llama.apply_paged``): ``groups`` is a short tuple of ``(tokens
+    [B, T], tables [B, M], starts [B])``, row ``b`` of a group at positions
+    ``starts[b] .. starts[b]+T-1``.  The projections, the routed and shared
+    experts and the head run once over the rows of all groups, so a tick's
+    chunk and its decode lanes stream the experts they hit once; every layer
+    gathers each group's latents and rotated keys through its block tables from
+    the pool where it lies, overlays the new rows, and attends, expanded for a
+    chunk and absorbed for one token a row.  Returns (logits a group, the written
+    rows a group ``{leaf: [B, layers or groups of layers, T, ...]}`` for the
     caller's scatter, :func:`expert_counters` of the dispatch)."""
-    from .generation import address_paged_leaf_by_layer, gather_paged_context, overlay_new_rows, paged_cache_write
+    from .generation import (
+        address_paged_leaf_by_layer,
+        gather_paged_context,
+        group_positions,
+        join_groups,
+        overlay_new_rows,
+        paged_cache_write,
+        split_groups,
+    )
 
     c = config
-    t = input_ids.shape[1]
     pack = _rope_pack(c)
     rope = c.qk_rope_head_dim
-    total = tables.shape[1] * pool["ckv"].shape[2]
-    positions = starts[:, None].astype(jnp.int32) + jnp.arange(t, dtype=jnp.int32)[None]
-    mask = positions[:, :, None] >= jnp.arange(total, dtype=jnp.int32)[None, None, :]  # [B, T, M*bs]
-    x = _embed(params, input_ids, c)
+    shapes = [tokens.shape for tokens, _, _ in groups]
+    positions, masks = group_positions(groups, pool["ckv"].shape[2])
+    positions = join_groups(positions)
+    x = _embed(params, join_groups([tokens for tokens, _, _ in groups]), c)
 
     def body(x, lp, layer, held):
         with jax.named_scope("attn"):
             h = _llama._rms_norm(x, lp["ln_attn"], c.rms_eps)
-            q_nope, q_rope, ckv, kr = _latent_proj(h, lp, c, positions)
-            with jax.named_scope("kv_pool"):
-                ckv_leaf, ckv_tables = address_paged_leaf_by_layer(pool["ckv"], tables, layer)
-                ckv_rows, ckv_ctx = paged_cache_write(ckv_leaf, ckv, ckv_tables, starts, c.dtype)
-                # a row of "kr" holds the keys of `pack` layers: this layer's lie at lanes [slot * rope, (slot + 1) * rope)
-                kr_leaf, kr_tables = address_paged_leaf_by_layer(pool["kr"], tables, layer // pack)
-                with jax.named_scope("kv_pool.gather"):
-                    kr_ctx = gather_paged_context(kr_leaf, kr_tables)
-                    kr_ctx = jax.lax.dynamic_slice_in_dim(kr_ctx, (layer % pack) * rope, rope, axis=-1)
-                kr_rows = kr.astype(kr_leaf.dtype)
-                kr_ctx = overlay_new_rows(kr_ctx, kr_rows, starts)
-            x = x + _out_proj(_attend(q_nope, q_rope, ckv_ctx, kr_ctx, mask, lp, c), lp, c)
+            attn, stored = [], []
+            for q_nope, q_rope, ckv, kr, (_, tables, starts), mask in zip(
+                    *(split_groups(a, shapes) for a in _latent_proj(h, lp, c, positions)), groups, masks):
+                with jax.named_scope("kv_pool"):
+                    ckv_leaf, ckv_tables = address_paged_leaf_by_layer(pool["ckv"], tables, layer)
+                    ckv_rows, ckv_ctx = paged_cache_write(ckv_leaf, ckv, ckv_tables, starts, c.dtype)
+                    # a row of "kr" holds the keys of `pack` layers: this layer's lie at lanes [slot * rope, (slot + 1) * rope)
+                    kr_leaf, kr_tables = address_paged_leaf_by_layer(pool["kr"], tables, layer // pack)
+                    with jax.named_scope("kv_pool.gather"):
+                        kr_ctx = gather_paged_context(kr_leaf, kr_tables)
+                        kr_ctx = jax.lax.dynamic_slice_in_dim(kr_ctx, (layer % pack) * rope, rope, axis=-1)
+                    kr_rows = kr.astype(kr_leaf.dtype)
+                    kr_ctx = overlay_new_rows(kr_ctx, kr_rows, starts)
+                attn.append(_attend(q_nope, q_rope, ckv_ctx, kr_ctx, mask, lp, c))
+                stored.append((ckv_rows, kr_rows))
+            x = x + _out_proj(join_groups(attn), lp, c)
         x, group_sizes = _ffn(x, lp, c, held)
-        return x, (ckv_rows, kr_rows, group_sizes)
+        return x, (tuple(stored), group_sizes)
 
     # the pool is a constant of the loops, addressed by layer in their bodies: never a scanned input
     with jax.named_scope("layers"):
-        x, (ckv_rows, kr_rows, group_sizes) = _scan_stacks(
+        x, (stored, group_sizes) = _scan_stacks(
             params, body, x, jnp.arange(c.num_layers, dtype=jnp.int32), hold_experts=True)
-    rows = {"ckv": jnp.moveaxis(ckv_rows, 0, 1), "kr": jnp.moveaxis(_pack_rope(kr_rows, c), 0, 1)}
+    rows = tuple(
+        {"ckv": jnp.moveaxis(ckv_rows, 0, 1), "kr": jnp.moveaxis(_pack_rope(kr_rows, c), 0, 1)}
+        for ckv_rows, kr_rows in stored)
     with jax.named_scope("head"):
         logits = _head(params, x, c)
-    return logits, rows, expert_counters(group_sizes)
+    return split_groups(logits, shapes), rows, expert_counters(group_sizes)
 
 
 def generate(

@@ -401,6 +401,40 @@ def address_paged_pool_by_layer(pool: dict, tables: jax.Array, layer: jax.Array)
     return leaves["k"], leaves["v"], tables
 
 
+def group_positions(groups, block_size: int):
+    """Of every group ``(tokens [B, T], tables [B, M], starts [B])`` of an
+    ``apply_paged`` call: the positions of its new tokens ``[B, T]`` (row
+    ``b``'s sit at ``starts[b] .. starts[b] + T - 1``) and its attention mask
+    over the context its own tables name, ``[B, T, M * block_size]``."""
+    positions = tuple(_token_positions(starts, tokens.shape[1]) for tokens, _, starts in groups)
+    masks = tuple(
+        pos[:, :, None] >= jnp.arange(tables.shape[1] * block_size, dtype=jnp.int32)[None, None, :]
+        for pos, (_, tables, _) in zip(positions, groups))
+    return positions, masks
+
+
+def join_groups(parts) -> jax.Array:
+    """The rows of every group ``[B_g, T_g, *r]`` side by side, ``[1, sum(B_g *
+    T_g), *r]``: what an operator that does not look at the cache runs over
+    once, whichever lanes the rows belong to.  One group stays as it is."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate([p.reshape((1, -1) + p.shape[2:]) for p in parts], axis=1)
+
+
+def split_groups(joined: jax.Array, shapes) -> tuple:
+    """Inverse of :func:`join_groups` for groups of ``shapes`` ``(B_g, T_g)``:
+    each group's rows back as ``[B_g, T_g, *r]``, for the attention of its
+    own lanes."""
+    if len(shapes) == 1:
+        return (joined,)
+    parts, at = [], 0
+    for b, t in shapes:
+        parts.append(joined[:, at : at + b * t].reshape((b, t) + joined.shape[2:]))
+        at += b * t
+    return tuple(parts)
+
+
 def unpack_paged_rows_from_scan(k_rows, v_rows, quant: bool) -> dict:
     """Stacked per-layer stored rows ``[L, B, T, ...]`` (scan ``ys``) ->
     ``{leaf: [B, L, T, ...]}``, the layout ``scatter_token_rows`` writes."""

@@ -347,63 +347,65 @@ def apply_cached(
     return logits, unpack_cache_from_scan(new_k, new_v, index + s, quant)
 
 
-def apply_paged(
-    params: dict,
-    input_ids: jax.Array,
-    config: GPT2Config,
-    pool: dict,
-    tables: jax.Array,
-    starts: jax.Array,
-) -> tuple[jax.Array, dict]:
+def apply_paged(params: dict, groups, config: GPT2Config, pool: dict) -> tuple[tuple, tuple]:
     """Forward over new tokens straight against the paged block pool — the
-    serving engine's decode/prefill fast path (no per-slot dense cache view
-    is ever built or returned).
+    serving engine's model step (no per-slot dense cache view is ever built
+    or returned).
 
-    Row ``b``'s tokens ``input_ids[b]`` sit at positions ``starts[b] ..
-    starts[b]+T-1``; attention consumes pool K/V through the block tables
-    ``tables [B, M]`` (``paged_cache_write``) and the freshly written rows
-    come back as ``{leaf: [B, L, T, ...]}`` for the caller to scatter into
-    the pool."""
+    ``groups`` is a short tuple of ``(tokens [B, T], tables [B, M], starts
+    [B])``, what one forward took before a tick's decode lanes and its
+    prefill chunk shared a dispatch: row ``b`` of a group sits at positions
+    ``starts[b] .. starts[b]+T-1`` of the sequence its table row names.
+    Everything that does not look at the cache runs once over the rows of all
+    groups; attention runs group by group, each consuming pool K/V through
+    its own block tables (``paged_cache_write``).  Returns, a group each, the
+    logits ``[B, T, V]`` and the freshly written rows ``{leaf: [B, L, T,
+    ...]}`` for the caller to scatter into the pool."""
     from .generation import (
         address_paged_pool_by_layer,
+        group_positions,
+        join_groups,
         paged_cache_write,
+        split_groups,
         unpack_paged_rows_from_scan,
     )
 
     c = config
-    t = input_ids.shape[1]
     quant = "k_scale" in pool
     bs = pool["k"].shape[2]
-    total = tables.shape[1] * bs
+    total = max(tables.shape[1] for _, tables, _ in groups) * bs
     if total > c.max_seq_len:
         raise ValueError(
             f"block table extent {total} exceeds max_seq_len {c.max_seq_len} "
             "(GPT-2's learned position table)"
         )
-    positions = starts[:, None].astype(jnp.int32) + jnp.arange(t, dtype=jnp.int32)[None]
-    x = _embed_lookup(params["wte"], input_ids, c.dtype) + params["wpe"].astype(c.dtype)[positions]
-    k_pos = jnp.arange(total, dtype=jnp.int32)
-    mask = positions[:, :, None] >= k_pos[None, None, :]  # [B, T, M*bs]
+    shapes = [tokens.shape for tokens, _, _ in groups]
+    positions, masks = group_positions(groups, bs)
+    x = _embed_lookup(params["wte"], join_groups([tokens for tokens, _, _ in groups]), c.dtype)
+    x = x + params["wpe"].astype(c.dtype)[join_groups(positions)]
 
     def body(carry, xs):
         lp, layer = xs
         lp = _dequant_layer(lp)
         x = carry
-        q, k, v = _qkv(x, lp, c)
-        pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
-        k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
-        v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
-        attn = _attend(q, k_full, v_full, mask[:, None], c)
-        x = x + attn @ lp["w_proj"].astype(c.dtype) + lp["b_proj"].astype(c.dtype)
+        attn, stored = [], []
+        for q, k, v, (_, tables, starts), mask in zip(
+                *(split_groups(a, shapes) for a in _qkv(x, lp, c)), groups, masks):
+            pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
+            k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
+            v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
+            attn.append(_attend(q, k_full, v_full, mask[:, None], c))
+            stored.append((k_store, v_store))
+        x = x + join_groups(attn) @ lp["w_proj"].astype(c.dtype) + lp["b_proj"].astype(c.dtype)
         x = _mlp_block(x, lp, c)
-        return x, (k_store, v_store)
+        return x, tuple(stored)
 
     # the pool is a constant of the loop, addressed by layer in its body: never a scanned input
     layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
-    x, (k_rows, v_rows) = jax.lax.scan(body, x, (params["layers"], layers))
+    x, stored = jax.lax.scan(body, x, (params["layers"], layers))
     x = _layer_norm(x, params["final_ln_scale"], params["final_ln_bias"], c.layer_norm_eps)
     logits = (x @ params["wte"].astype(c.dtype).T).astype(jnp.float32)
-    return logits, unpack_paged_rows_from_scan(k_rows, v_rows, quant)
+    return split_groups(logits, shapes), tuple(unpack_paged_rows_from_scan(k, v, quant) for k, v in stored)
 
 
 def generate(

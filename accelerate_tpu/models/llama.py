@@ -860,36 +860,33 @@ def apply_cached(
     return logits, unpack_cache_from_scan(new_k, new_v, index + s, quant)
 
 
-def apply_paged(
-    params: dict,
-    input_ids: jax.Array,
-    config: LlamaConfig,
-    pool: dict,
-    tables: jax.Array,
-    starts: jax.Array,
-) -> tuple[jax.Array, dict]:
+def apply_paged(params: dict, groups, config: LlamaConfig, pool: dict) -> tuple[tuple, tuple]:
     """Forward over new tokens straight against the paged block pool — the
-    serving engine's decode/prefill fast path (see ``gpt2.apply_paged``; the
-    contract is shared).  Row ``b``'s tokens sit at positions ``starts[b] ..
-    starts[b]+T-1`` (RoPE is position-exact per slot); attention consumes
-    pool K/V through the block tables via ``paged_cache_write`` and the
-    written rows return as ``{leaf: [B, L, T, ...]}`` for the caller's
-    scatter."""
+    serving engine's model step (see ``gpt2.apply_paged``; the contract is
+    shared).  ``groups`` is a short tuple of ``(tokens [B, T], tables [B, M],
+    starts [B])``: row ``b`` of a group sits at positions ``starts[b] ..
+    starts[b]+T-1`` (RoPE is position-exact per row) of the sequence its
+    table row names.  Everything that does not look at the cache (embedding,
+    norms, projections, the MLP, the head) runs once over the rows of all
+    groups; attention runs group by group, each consuming pool K/V through
+    its own block tables via ``paged_cache_write``.  Returns, a group each,
+    the logits ``[B, T, V]`` and the written rows ``{leaf: [B, L, T, ...]}``
+    for the caller's scatter."""
     from .generation import (
         address_paged_pool_by_layer,
+        group_positions,
+        join_groups,
         paged_cache_write,
+        split_groups,
         unpack_paged_rows_from_scan,
     )
 
     c = config
-    b, t = input_ids.shape
     quant = "k_scale" in pool
-    bs = pool["k"].shape[2]
-    total = tables.shape[1] * bs
-    positions = starts[:, None].astype(jnp.int32) + jnp.arange(t, dtype=jnp.int32)[None]
-    x = embed_tokens(params, input_ids, c)
-    k_pos = jnp.arange(total, dtype=jnp.int32)
-    mask = positions[:, :, None] >= k_pos[None, None, :]  # [B, T, M*bs]
+    shapes = [tokens.shape for tokens, _, _ in groups]
+    positions, masks = group_positions(groups, pool["k"].shape[2])
+    positions = join_groups(positions)
+    x = embed_tokens(params, join_groups([tokens for tokens, _, _ in groups]), c)
 
     def body(carry, xs):
         lp, layer = xs
@@ -898,24 +895,28 @@ def apply_paged(
         with jax.named_scope("attn"):
             h = _norm(x, lp["ln_attn"], c)
             with jax.named_scope("attn.qkv"):
-                q, k, v = _qkv_proj(h, lp, c, b, t)
+                q, k, v = _qkv_proj(h, lp, c, *h.shape[:2])
                 q, k = _rope(q, k, positions, c.rope_theta, getattr(c, "rope_scaling", None))
-            pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
-            with jax.named_scope("kv_pool"):
-                k_store, k_full = paged_cache_write(pk, k, ltab, starts, c.dtype)
-                v_store, v_full = paged_cache_write(pv, v, ltab, starts, c.dtype)
-            with jax.named_scope("attn.core"):
-                attn = _attention(q, k_full, v_full, mask, c.num_heads // c.num_kv_heads)
-            y = x + _out_proj(attn, lp, c)
-        return _mlp_block(y, lp, c), (k_store, v_store)
+            attn, stored = [], []
+            for q_g, k_g, v_g, (_, tables, starts), mask in zip(
+                    *(split_groups(a, shapes) for a in (q, k, v)), groups, masks):
+                pk, pv, ltab = address_paged_pool_by_layer(pool, tables, layer)
+                with jax.named_scope("kv_pool"):
+                    k_store, k_full = paged_cache_write(pk, k_g, ltab, starts, c.dtype)
+                    v_store, v_full = paged_cache_write(pv, v_g, ltab, starts, c.dtype)
+                with jax.named_scope("attn.core"):
+                    attn.append(_attention(q_g, k_full, v_full, mask, c.num_heads // c.num_kv_heads))
+                stored.append((k_store, v_store))
+            y = x + _out_proj(join_groups(attn), lp, c)
+        return _mlp_block(y, lp, c), tuple(stored)
 
     # the pool is a constant of the loop, addressed by layer in its body: never a scanned input
     layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
     with jax.named_scope("layers"):
-        x, (k_rows, v_rows) = jax.lax.scan(body, x, (params["layers"], layers))
+        x, stored = jax.lax.scan(body, x, (params["layers"], layers))
     with jax.named_scope("head"):
         logits = unembed(params, x, c)
-    return logits, unpack_paged_rows_from_scan(k_rows, v_rows, quant)
+    return split_groups(logits, shapes), tuple(unpack_paged_rows_from_scan(k, v, quant) for k, v in stored)
 
 
 def generate(

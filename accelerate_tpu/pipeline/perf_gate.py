@@ -325,7 +325,7 @@ def run_spec_probe(degrade: Optional[str] = None, max_new: int = 60) -> dict:
                 spec_tokens=spec_tokens,
             ),
         )
-        # Warm every bucket's program (prefill, decode, verify) outside the
+        # Warm every bucket's programs (decode, decode_chunk) outside the
         # timed window — same prompt shape as the timed batch.
         eng.submit(list(prompts[0]), max_new)
         eng.run()

@@ -3,48 +3,67 @@
 One engine **tick** (:meth:`ServingEngine.step`) is:
 
 1. **admit** — queue-head requests take free decode slots (FIFO);
-2. **prefill** — at most ONE bounded chunk (``prefill_chunk`` tokens, padded
-   to a static shape) of the oldest prefilling request runs, so a 10k-token
-   prompt costs many small dispatches interleaved with decode instead of one
-   huge dispatch that stalls every in-flight request;
-3. **decode** — ONE fused jitted dispatch advances every decoding slot by
-   one token.  ``serving/programs.py`` builds the programs and owns how a
-   dispatch reads the pool; the family decides which of its two back ends
-   serves.  A family with an ``apply_paged`` (gpt2, llama, deepseek_v3) is
-   served **paged**: ``apply_paged`` reads pool K/V through the block tables
-   (``models/generation.py paged_cache_write``), and the pool is a constant
-   of the layer loop, never a scanned input of it.  Where the TPU holds the
-   pool block by block (bf16, ``hd`` a multiple of 128, ``K`` 1, 2, 4 or a
-   multiple of 8) it is addressed by (layer, block) in one flat view
-   (``address_paged_pool_by_layer``): a layer gathers the blocks its tables
-   name and nothing else of the pool is sliced, copied or re-tiled (as a
-   scanned input every layer's whole slice was: 47% of the device's time at
-   8192 blocks, PERF.md section 6, PR 27).  Any other pool (int8, ``hd``
-   64, odd ``K``) still has its layer's slice cut in the loop, a cost in
-   ``num_blocks``, until the resident layout changes (ROADMAP A11).  No dense
-   per-slot cache view is ever materialized, no updated view ever flows
-   back out of the program — only the freshly written K/V rows, which
-   scatter into the donated pool.  Block tables are **bucketed** to the next
-   power of two of the widest live slot, so per-token gather traffic scales
-   with the blocks requests actually own, not the worst-case table width or
-   the pool's size (``serving.decode_gather_bytes`` counts the blocks the
-   tables name, on the host).  A pool need not be K and V per head:
-   ``models/deepseek_v3.py`` pages latent rows (``ckv``, ``kr``) the same way,
-   and an expert family serves paged too when its routing is row by row, as
-   ``ops/moe.py:routed_experts`` is (its per-dispatch expert counters ride
-   out behind the ``ok`` flags into ``stats()["moe_rows"]``,
-   ``"moe_experts_hit"``, ``"moe_max_rows"``).  A family without an
-   ``apply_paged`` (``models/mixtral.py``: capacity routing depends on who
-   shares the batch) is served **dense**: gather each slot's whole view at
-   the one static table width, ``vmap`` the family's ``apply_cached``,
-   extract and scatter the written rows.  ``stats()["decode_path"]`` reports
-   which.  Either way the 1-dispatch-per-decode-step invariant from
-   ``make_train_step`` carries over — the ``serving.decode_dispatches``
-   counter is the proof hook.
+2. **build** — at most ONE bounded chunk (``prefill_chunk`` tokens, padded
+   to a static shape) of the oldest prefilling request, so a 10k-token
+   prompt costs many small chunks interleaved with decode instead of one
+   huge dispatch that stalls every in-flight request; then the decode batch:
+   every decoding slot grown by one token (or one ``k + 1`` window),
+   oldest first.  Growing the decoders may preempt the prefilling slot; its
+   chunk is then dropped with it;
+3. **dispatch** — ONE fused jitted program runs what the builds left.  A
+   tick with a chunk and live decoders issues ``decode_chunk``: the chunk
+   rides in the decode's forward, so everything that does not look at the
+   cache (embedding, norms, projections, the MLP or the experts, the head)
+   runs once over all rows of the tick and the weights stream **once a
+   tick**; attention runs a group of lanes at a time (the decoders are one
+   group, the chunk another).  Without a chunk the tick issues ``decode``;
+   a chunk with no live decoder rides ``decode_chunk`` with the lanes idle
+   (a cold start).  One launch, one sync, one read-back
+   (``stats()["mixed_dispatches"]`` counts the ticks whose chunk rode
+   along; ``prefill_dispatches + decode_dispatches - mixed_dispatches`` is
+   the number of dispatches, one a tick).  A prompt whose last chunk rode
+   in this dispatch starts decoding in the next tick;
+4. **emit** — the chunk's bookkeeping (prefix registration, the first token
+   after a last chunk), then every lane's tokens.
 
-Prefill takes the same back end: a paged chunk's program consumes the pool
-through the (bucketed) block table and returns only the rows it writes —
-the full per-slot view is materialized on neither side of the dispatch.
+``serving/programs.py`` builds the two programs and owns how a dispatch reads
+the pool; the family decides which of its two back ends serves.  A family
+with an ``apply_paged`` (gpt2, llama, deepseek_v3) is served **paged**:
+``apply_paged`` reads pool K/V through the block tables
+(``models/generation.py paged_cache_write``), and the pool is a constant of
+the layer loop, never a scanned input of it.  Where the TPU holds the pool
+block by block (bf16, ``hd`` a multiple of 128, ``K`` 1, 2, 4 or a multiple
+of 8) it is addressed by (layer, block) in one flat view
+(``address_paged_pool_by_layer``): a layer gathers the blocks its tables
+name and nothing else of the pool is sliced, copied or re-tiled (as a
+scanned input every layer's whole slice was: 47% of the device's time at
+8192 blocks, PERF.md section 6, PR 27).  Any other pool (int8, ``hd`` 64,
+odd ``K``) still has its layer's slice cut in the loop, a cost in
+``num_blocks``, until the resident layout changes (ROADMAP A11).  No dense
+per-slot cache view is ever materialized, no updated view ever flows back
+out of the program — only the freshly written K/V rows, which scatter into
+the donated pool.  Block tables are **bucketed** to the next power of two of
+the widest lane of the dispatch (the chunk's lane and the decoders share one
+width; no table is narrower than ``programs.MIN_TABLE_ROWS``, 256 rows: a
+width costs two compiles, and a gather that short costs nothing), so
+per-token gather traffic scales with the blocks requests actually own, not the worst-case table width or the pool's size
+(``serving.decode_gather_bytes`` counts the blocks the decoders' tables name,
+on the host).  The first dispatch at a width compiles BOTH programs at it
+(``_note_bucket``): a warm-up of single requests run alone leaves nothing to
+compile under load.  A pool need not be K and V per head:
+``models/deepseek_v3.py`` pages latent rows (``ckv``, ``kr``) the same way,
+and an expert family serves paged too when its routing is row by row, as
+``ops/moe.py:routed_experts`` is (its per-dispatch expert counters ride out
+behind the ``ok`` flags into ``stats()["moe_rows"]``, ``"moe_experts_hit"``,
+``"moe_max_rows"``; a mixed dispatch streams the experts its chunk and its
+decoders hit once).  A family without an ``apply_paged``
+(``models/mixtral.py``: capacity routing depends on who shares the batch) is
+served **dense**: gather each slot's whole view at the one static table
+width, ``vmap`` the family's ``apply_cached``, extract and scatter the
+written rows — a group at a time inside the same one program, so the tick
+has one path.  ``stats()["decode_path"]`` reports which.  Either way the
+1-dispatch-per-tick invariant from ``make_train_step`` carries over — the
+three dispatch counters are the proof hook.
 
 **Prefix caching** (``ServingConfig.prefix_cache``, default on): full
 prompt blocks are content-hashed (a chain hash — K/V rows depend on the
@@ -92,11 +111,13 @@ Production-robustness layer (overload / deadlines / quarantine / journal):
   elapsed wait into ``serving.ttft_ms`` so the PR 13 SLO burn-rate gauges
   see the violation instead of a survivorship-biased histogram.
 - **Poison quarantine** — both compiled programs carry an in-program
-  per-slot logit-finiteness check (a reduction folded into the existing
-  dispatch — zero extra dispatch, the health-guard trick).  A non-finite
-  slot's request completes with ``status="quarantined"``
-  (``serving.quarantined`` counter + event) while every other slot keeps
-  decoding bit-identically (vmap lanes are independent).  The quarantined
+  logit-finiteness check per decoding lane and one for the chunk (a
+  reduction folded into the existing dispatch — zero extra dispatch, the
+  health-guard trick).  A non-finite lane's request completes with
+  ``status="quarantined"`` (``serving.quarantined`` counter + event) while
+  every other lane, and the chunk that shared the forward, go on
+  bit-identically (rows are independent); a poisoned chunk takes no
+  decoding lane with it either.  The quarantined
   request's pool blocks are **scrubbed to zero before being freed**: the
   attention mask zeroes a hidden row's *probability*, but ``0 * NaN = NaN``
   in ``probs @ v``, so a NaN row left in a recycled block would poison its
@@ -116,7 +137,7 @@ import contextlib
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -153,9 +174,9 @@ class AdmissionRejected(RuntimeError):
 @dataclass
 class ServingConfig:
     """Engine geometry (everything here is a static shape of the compiled
-    programs — three programs, prefill, decode and with ``spec_tokens`` the
-    verify window, each compiled once per block-table width it meets,
-    however many requests flow through).
+    programs — two programs, ``decode`` and ``decode_chunk``, over one token
+    a lane or with ``spec_tokens`` the verify window, both compiled once per
+    block-table width the engine meets, however many requests flow through).
 
     - ``block_size``: tokens per KV block.  Small blocks waste less tail
       space per request; large blocks shrink the tables.  16-64 is typical.
@@ -167,7 +188,7 @@ class ServingConfig:
       requests advanced per decode dispatch.
     - ``max_blocks_per_seq``: block-table width (static); caps any single
       request at ``max_blocks_per_seq * block_size`` cache rows.
-    - ``prefill_chunk``: prompt tokens per prefill dispatch (static).
+    - ``prefill_chunk``: prompt tokens a tick's one chunk holds (static).
 
     Robustness knobs (all host-side policy, no effect on the compiled
     programs):
@@ -275,6 +296,28 @@ class CompletedRequest:
     migrations: int = 0
     fallback_reprefills: int = 0
     prefill_dispatches: int = 0
+
+
+class _Chunk(NamedTuple):
+    """The prefill chunk a tick built: slot ``idx``'s tokens ``start .. start +
+    n_real`` padded to ``prefill_chunk``, and the blocks its padded write
+    extent needs."""
+
+    idx: int
+    slot: object
+    start: int
+    n_real: int
+    tokens: np.ndarray
+    blocks: int
+
+
+class _Lanes(NamedTuple):
+    """The decode batch a tick built: the live slots, oldest first, and the
+    window of tokens (last emitted, then drafts) of every lane."""
+
+    live: List[int]
+    tokens: np.ndarray
+    draft_len: np.ndarray
 
 
 class _TickPhase:
@@ -385,6 +428,9 @@ class ServingEngine:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.prefill_dispatches = 0
+        # Ticks whose chunk rode in the decode's forward.  A tick issues
+        # (prefill + decode - mixed) dispatches: one, whatever it holds.
+        self.mixed_dispatches = 0
         self.shed_count = 0
         self.deadline_expired_count = 0
         self.quarantined_count = 0
@@ -439,10 +485,9 @@ class ServingEngine:
             )
         # Per-width jit-cache bookkeeping for bucket-compile attribution:
         # a width this engine has not dispatched yet means the next dispatch
-        # pays a trace+compile in the request's latency path.
-        self._seen_widths: Dict[str, set] = {
-            "decode": set(), "decode_spec": set(), "prefill": set(),
-        }
+        # pays a trace+compile in the request's latency path (_note_bucket).
+        self._warm_widths: set = set()
+        self._decode_widths: set = set()  # widths the decoding lanes ran at: stats()["decode_bucket_widths"]
         self._tick: dict = {}  # what the running tick is doing: step() starts one
         self._phase_t0 = 0.0
         # Live /debug endpoints: the metrics HTTP server asks registered
@@ -510,10 +555,9 @@ class ServingEngine:
         # The compiled programs and what the tick asks of their back end
         # (serving/programs.py): the family decides "paged" or "dense".  One
         # jitted wrapper each; bucketed table widths retrace under it (jit
-        # caches per shape), so a tick is still exactly one decode dispatch,
-        # of the program matching the live bucket.  With speculation on, a
-        # decode tick runs the k+1-window verify program INSTEAD of the
-        # single-token one, fed by a host-side drafter.
+        # caches per shape), so a tick is exactly one dispatch, of the program
+        # matching the live bucket.  With speculation on, the lanes carry the
+        # k+1-window INSTEAD of one token, fed by a host-side drafter.
         self.programs = build_programs(
             apply_cached, config, self.cache.leaf_names, sc, self.spec_tokens
         )
@@ -538,6 +582,7 @@ class ServingEngine:
                 "serving.quarantined", "serving.journal_recoveries",
                 "serving.prefix_hits", "serving.prefix_blocks_reused",
                 "serving.prefix_cow_copies", "serving.decode_gather_bytes",
+                "serving.mixed_dispatches",
                 "serving.spec.proposed", "serving.spec.accepted",
                 "serving.spec.rounds",
                 "serving.tier.demotions", "serving.tier.promotions",
@@ -651,8 +696,9 @@ class ServingEngine:
         return req.id
 
     def step(self) -> List[CompletedRequest]:
-        """One engine tick: admit, one prefill chunk, one fused decode
-        dispatch.  Returns the requests that completed this tick.  With an
+        """One engine tick: admit, build one prefill chunk and the decode
+        batch, ONE fused dispatch of both.  Returns the requests that
+        completed this tick.  With an
         installed :class:`PreemptionGuard` whose signal has arrived, the
         tick drains instead (no admission, no dispatch)."""
         now = time.monotonic()
@@ -663,11 +709,11 @@ class ServingEngine:
         self.ticks += 1
         states = [slot.request.state for slot in self.sched.slots.values()]
         # What the tick was doing, for ServingTracer's slow-tick record:
-        # _TickPhase fills phase_ms, _decode_tick the dispatch's shape.
+        # _TickPhase fills phase_ms, _dispatch_tick the dispatch's shape.
         self._tick = tick = {
             "tick": self.ticks,
             "prefilling": states.count(RequestState.PREFILLING),
-            "live": 0, "width": None, "fresh": False, "phase_ms": {},
+            "live": 0, "width": None, "fresh": False, "mixed": False, "phase_ms": {},
         }
         self._phase_t0 = now
         with annotate(
@@ -701,8 +747,15 @@ class ServingEngine:
                     self._attach_prefix(idx)
                 self._observe_requeue_waits(admitted)
                 span.set_metadata(admitted=len(admitted))
-            self._prefill_tick(now)
-            self._decode_tick(now)
+            # Both builds come before the one dispatch: the chunk of the
+            # oldest prefilling slot, then the decoding lanes (whose growth
+            # may preempt that slot: its chunk is dropped with it).
+            chunk = self._build_chunk()
+            batch = self._build_decode()
+            if chunk is not None and self.sched.slots.get(chunk.idx) is not chunk.slot:
+                chunk = None
+            if chunk is not None or batch is not None:
+                self._dispatch_tick(chunk, batch)
             with annotate("serving.tick.publish", tick=self.ticks):
                 self._drain_scrubs()
                 self._publish_gauges()
@@ -1219,38 +1272,48 @@ class ServingEngine:
 
     def _note_bucket(self, kind: str, width: int) -> bool:
         """Record a dispatch at this table width; returns True when the
-        width is FRESH for ``kind`` — the per-width jit cache misses and the
-        dispatch pays a trace+compile in the request's latency path.  The
+        width is FRESH: the per-width jit cache misses and the dispatch pays
+        a trace+compile in the request's latency path.  The
         ``serving.bucket_compile`` event makes that TTFT spike attributable
-        even with tracing disabled."""
-        if width in self._seen_widths[kind]:
+        even with tracing disabled.
+
+        A fresh width compiles **every** program the engine can dispatch at
+        it, not only the one that met it (``kind``): each runs once on idle
+        lanes, whose rows land in the null block.  A warm-up of single
+        requests run alone meets a width with a chunk or with a decoder, never
+        with both; the first tick under load that holds both must not compile."""
+        if width in self._warm_widths:
             return False
-        self._seen_widths[kind].add(width)
+        self._warm_widths.add(width)
         tel = get_telemetry()
         if tel.enabled:
             # "dispatch" not "kind": event() reserves "kind" for the record
             # envelope, and a field named kind would shadow it in the JSONL.
             tel.event("serving.bucket_compile", dispatch=kind, width=width)
         self._tick["fresh"] = True
+        lanes = self._idle_lanes(width)
+        chunk = [
+            np.zeros((width,), np.int32), np.int32(0),
+            np.zeros((1, self.serving.prefill_chunk), np.int32), np.int32(1),
+        ]
+        poison = [] if self._poison_ordinal is None else [np.ones((self.serving.max_slots,), np.float32)]
+        for program, args in ((self.programs.decode, lanes), (self.programs.decode_chunk, lanes + chunk)):
+            _, self.cache.pool = program(self.params, self.cache.pool, *args, *poison)
         return True
 
-    def _table_row(self, blocks: List[int], width: int) -> np.ndarray:
-        row = np.zeros((width,), np.int32)
-        row[: len(blocks)] = blocks
-        return row
+    def _idle_lanes(self, width: int) -> list:
+        """``[tables, lengths, tokens, draft_len]`` of a dispatch none of whose
+        lanes is live: every table names the null block alone."""
+        s = self.serving.max_slots
+        return [
+            np.zeros((s, width), np.int32), np.zeros((s,), np.int32),
+            np.zeros((s, self.programs.window), np.int32), np.zeros((s,), np.int32),
+        ]
 
-    def _read_ok(self, ok, lanes: int) -> np.ndarray:
-        """The host sync point of a dispatch: its ``lanes`` finiteness flags.
-        What an expert family's program put behind them (``programs._ok_with_counters``)
-        is added to ``moe_counters`` from the same read-back."""
-        flags = np.asarray(ok)
-        if flags.size > lanes:
-            for name, value in zip(MOE_COUNTERS, flags[lanes:]):
-                self.moe_counters[name] += int(value)
-            flags = flags[:lanes]
-        return flags
-
-    def _prefill_tick(self, now: float) -> None:
+    def _build_chunk(self) -> Optional[_Chunk]:
+        """The tick's prefill chunk: the next ``prefill_chunk`` tokens of the
+        oldest prefilling slot, its blocks grown to hold them.  None when no
+        slot is prefilling, or the slot itself was preempted to find blocks."""
         sched = self.sched
         with _TickPhase(self, "prefill.build") as span:
             candidates = [
@@ -1259,63 +1322,26 @@ class ServingEngine:
                 if slot.request.state == RequestState.PREFILLING
             ]
             if not candidates:
-                return
+                return None
             _, idx = min(candidates)
             slot = sched.slots[idx]
-            req = slot.request
-            feed = req.to_feed
+            feed = slot.request.to_feed
             start = slot.cache_len
             chunk_len = self.serving.prefill_chunk
             n_real = min(chunk_len, len(feed) - start)
             if not sched.grow_to(idx, start + n_real):
-                return  # the slot itself was preempted to find blocks
-            chunk = np.zeros((1, chunk_len), np.int32)
-            chunk[0, :n_real] = feed[start : start + n_real]
+                return None
+            tokens = np.zeros((1, chunk_len), np.int32)
+            tokens[0, :n_real] = feed[start : start + n_real]
+            span.set_metadata(request=slot.request.id, start=start, rows=n_real)
             # The table covers the chunk's padded write extent: the gather
             # reads the blocks this prefill can actually touch.
-            width = self.programs.table_width(
-                blocks_for_tokens(start + chunk_len, self.serving.block_size)
-            )
-            fresh = self._note_bucket("prefill", width)
-            table_row = self._table_row(slot.blocks, width)
-            span.set_metadata(request=req.id, start=start, rows=n_real, width=width)
-        with _TickPhase(self, "prefill.wait", request=req.id):
-            next_tok, ok, self.cache.pool = self.programs.prefill(
-                self.params,
-                self.cache.pool,
-                table_row,
-                np.int32(start),
-                chunk,
-                np.int32(n_real),
-            )
-            self.prefill_dispatches += 1
-            req.prefill_dispatches += 1  # per-request: the zero-re-prefill oracle
-            tel = get_telemetry()
-            if tel.enabled:
-                tel.registry.counter("serving.prefill_dispatches").inc()
-            slot.cache_len = start + n_real
-            poisoned = not self._read_ok(ok, 1).all()  # host sync point: the dispatch is done here
-        with _TickPhase(self, "prefill.emit", request=req.id) as span:
-            if self.tracer is not None:
-                self.tracer.on_prefill(
-                    req, idx, time.monotonic(),
-                    padded_rows=chunk_len - n_real, width=width, fresh=fresh,
-                )
-            if poisoned:
-                self._quarantine(idx, time.monotonic())
-                return
-            self._register_prefix_blocks(idx)
-            final = slot.cache_len == len(feed)
-            span.set_metadata(first_token=int(final and not req.emitted))
-            if final:
-                # Final chunk: its last real logits row IS the next token — the
-                # first generated token of a fresh request (TTFT lands here) or
-                # the resume token of a re-prefilled one.
-                self._emit(idx, int(next_tok), time.monotonic())
-                if idx in sched.slots:
-                    sched.slots[idx].request.state = RequestState.DECODING
+            return _Chunk(idx, slot, start, n_real, tokens, blocks_for_tokens(start + chunk_len, self.serving.block_size))
 
-    def _decode_tick(self, now: float) -> None:
+    def _build_decode(self) -> Optional[_Lanes]:
+        """The tick's decode batch: every decoding slot grown by one window,
+        oldest first, and the survivors' tables, lengths and tokens.  None
+        when no lane is live."""
         sched = self.sched
         with _TickPhase(self, "decode.build") as span:
             decoding = sorted(
@@ -1338,17 +1364,14 @@ class ServingEngine:
             drafts: Dict[int, List[int]] = {}
             if k > 0:
                 for idx in decoding:
-                    slot = sched.slots.get(idx)
-                    if slot is None or slot.request.state != RequestState.DECODING:
-                        continue
-                    req = slot.request
+                    req = sched.slots[idx].request
                     want = min(k, req.remaining - 1)
                     if want <= 0:
                         continue
                     d = self._drafter.propose(req.to_feed, want)
                     if d:
                         drafts[idx] = [int(t) for t in d[:want]]
-            window = k + 1 if k > 0 else 1
+            window = self.programs.window
             # Grow oldest-first so older requests steal blocks from younger ones
             # (matching the LIFO victim policy), then re-collect the survivors.
             for idx in decoding:
@@ -1359,76 +1382,134 @@ class ServingEngine:
                 if idx in sched.slots and sched.slots[idx].request.state == RequestState.DECODING
             ]
             if not live:
-                return
+                return None
             s = self.serving.max_slots
-            # The tables are as wide as the widest live slot needs: gather
-            # traffic (and attention width) scale with the blocks requests own.
-            owned = [len(sched.slots[idx].blocks) for idx in live]
-            m = self.programs.table_width(max(owned))
-            gathered = self.programs.gathered_blocks(owned)
-            tables = np.zeros((s, m), np.int32)
-            lengths = np.zeros((s,), np.int32)
             tokens = np.zeros((s, window), np.int32)
             draft_len = np.zeros((s,), np.int32)
             for idx in live:
-                slot = sched.slots[idx]
-                tables[idx] = self._table_row(slot.blocks, m)
-                lengths[idx] = slot.cache_len
-                tokens[idx, 0] = slot.request.emitted[-1]
+                tokens[idx, 0] = sched.slots[idx].request.emitted[-1]
                 d = drafts.get(idx)
                 if d:
                     tokens[idx, 1 : 1 + len(d)] = d
                     draft_len[idx] = len(d)
-            self.decode_gather_bytes += gathered * self._block_bytes
-            fresh = self._note_bucket("decode_spec" if window > 1 else "decode", m)
-            span.set_metadata(live=len(live), width=m, drafted=len(drafts))
-            self._tick["live"], self._tick["width"] = len(live), m
-            dispatch_t0 = time.monotonic()
-            if window > 1:
-                args = [self.params, self.cache.pool, tables, lengths, tokens, draft_len]
-            else:
-                args = [self.params, self.cache.pool, tables, lengths, tokens[:, 0]]
-            if self._poison_ordinal is not None:
-                # Armed: the program was traced with the poison lane.  NaN rides
-                # into exactly one slot's logits on that request's first decode
-                # dispatch; every other lane multiplies by 1.0 (vmap lanes are
-                # independent, so their tokens are bit-identical to unarmed).
-                poison = np.ones((s,), np.float32)
-                for idx in live:
-                    req = sched.slots[idx].request
-                    if getattr(req, "_poison_pending", False):
-                        poison[idx] = np.nan
-                        req._poison_pending = False  # fires once
-                args.append(poison)
-        with _TickPhase(self, "decode.wait", live=len(live)):
-            if window > 1:
-                # The verify program REPLACES the single-token one this tick —
-                # still exactly one fused decode dispatch per bucket.
-                t_rows, m_counts, ok_flags, self.cache.pool = self.programs.decode_spec(*args)
-                out = np.asarray(t_rows)
-                accepts = np.asarray(m_counts)
-            else:
-                next_tokens, ok_flags, self.cache.pool = self.programs.decode(*args)
-                out = np.asarray(next_tokens)[:, None]
-                accepts = np.zeros((s,), np.int32)
-            self.decode_dispatches += 1
+            span.set_metadata(live=len(live), drafted=len(drafts))
+            return _Lanes(live, tokens, draft_len)
+
+    def _dispatch_tick(self, chunk: Optional[_Chunk], batch: Optional[_Lanes]) -> None:
+        """The tick's ONE dispatch, of whatever the two builds left: the
+        decoding lanes with the chunk riding in their forward
+        (``decode_chunk``), the lanes alone (``decode``), or a chunk with no
+        live decoder (``decode_chunk`` with the lanes idle: a cold start).
+        One launch, one sync, one read-back; then the chunk's bookkeeping and
+        the lanes'."""
+        sched, programs = self.sched, self.programs
+        live = batch.live if batch else []
+        # Both groups share one table width, the wider of the two needs: the
+        # tables are as wide as the widest lane needs, so gather traffic (and
+        # attention width) scale with the blocks requests own.
+        owned = [len(sched.slots[idx].blocks) for idx in live]
+        width = programs.table_width(max(owned + ([chunk.blocks] if chunk else [])))
+        fresh = self._note_bucket("decode_chunk" if chunk else "decode", width)
+        args = self._idle_lanes(width)
+        tables, lengths = args[:2]
+        for idx in live:
+            slot = sched.slots[idx]
+            tables[idx, : len(slot.blocks)] = slot.blocks
+            lengths[idx] = slot.cache_len
+        if batch:
+            args[2:] = [batch.tokens, batch.draft_len]
+        if chunk:
+            table_row = np.zeros((width,), np.int32)
+            table_row[: len(chunk.slot.blocks)] = chunk.slot.blocks
+            args += [table_row, np.int32(chunk.start), chunk.tokens, np.int32(chunk.n_real)]
+        if self._poison_ordinal is not None:
+            # Armed: the program was traced with the poison lane.  NaN rides
+            # into exactly one slot's logits on that request's first decode
+            # dispatch; every other lane multiplies by 1.0 (lanes are
+            # independent, so their tokens are bit-identical to unarmed).
+            poison = np.ones((self.serving.max_slots,), np.float32)
+            for idx in live:
+                req = sched.slots[idx].request
+                if getattr(req, "_poison_pending", False):
+                    poison[idx] = np.nan
+                    req._poison_pending = False  # fires once
+            args.append(poison)
+        gather_bytes = programs.gathered_blocks(owned) * self._block_bytes if live else 0
+        mixed = bool(chunk and live)
+        self._tick.update(live=len(live), width=width, mixed=mixed)
+        dispatch_t0 = time.monotonic()
+        # the readers' names: a dispatch with decoding lanes waits under
+        # decode.wait, a chunk dispatched alone under prefill.wait
+        with _TickPhase(self, "decode.wait" if live else "prefill.wait", live=len(live), width=width):
+            program = programs.decode_chunk if chunk else programs.decode
+            packed, self.cache.pool = program(self.params, self.cache.pool, *args)
             tel = get_telemetry()
+            # a mixed dispatch is a prefill dispatch and a decode dispatch too: what reads either says what it said
+            # (the telemetry names stand here as literals: tests/test_metric_names.py reads the emit sites)
+            if chunk:
+                self.prefill_dispatches += 1
+                chunk.slot.request.prefill_dispatches += 1  # per-request: the zero-re-prefill oracle
+                chunk.slot.cache_len = chunk.start + chunk.n_real
+            if live:
+                self.decode_dispatches += 1
+                self.decode_gather_bytes += gather_bytes
+                self._decode_widths.add(width)
+            self.mixed_dispatches += mixed
             if tel.enabled:
-                tel.registry.counter("serving.decode_dispatches").inc()
-                tel.registry.counter("serving.decode_gather_bytes").inc(
-                    gathered * self._block_bytes
+                if chunk:
+                    tel.registry.counter("serving.prefill_dispatches").inc()
+                if live:
+                    tel.registry.counter("serving.decode_dispatches").inc()
+                    tel.registry.counter("serving.decode_gather_bytes").inc(gather_bytes)
+                    tel.registry.gauge("serving.decode_bucket_width").set(width)
+                if mixed:
+                    tel.registry.counter("serving.mixed_dispatches").inc()
+            out = programs.unpack(packed, with_chunk=chunk is not None)  # host sync point: the dispatch is done here
+            for name, value in zip(MOE_COUNTERS, out["counters"]):
+                self.moe_counters[name] += int(value)
+        dispatch_ms = (time.monotonic() - dispatch_t0) * 1e3
+        if chunk:
+            self._emit_chunk(chunk, int(out["chunk_token"][0]), bool(out["chunk_ok"][0]), width, fresh)
+        if live:
+            self._emit_decode(batch, out, width, fresh, dispatch_ms)
+
+    def _emit_chunk(self, chunk: _Chunk, token: int, ok: bool, width: int, fresh: bool) -> None:
+        sched, idx, slot = self.sched, chunk.idx, chunk.slot
+        req = slot.request
+        with _TickPhase(self, "prefill.emit", request=req.id) as span:
+            if self.tracer is not None:
+                self.tracer.on_prefill(
+                    req, idx, time.monotonic(),
+                    padded_rows=self.serving.prefill_chunk - chunk.n_real, width=width, fresh=fresh,
                 )
-                tel.registry.gauge("serving.decode_bucket_width").set(m)
-            oks = self._read_ok(ok_flags, s)
+            if not ok:
+                self._quarantine(idx, time.monotonic())
+                return
+            self._register_prefix_blocks(idx)
+            final = slot.cache_len == len(req.to_feed)
+            span.set_metadata(first_token=int(final and not req.emitted))
+            if final:
+                # Final chunk: its last real logits row IS the next token — the
+                # first generated token of a fresh request (TTFT lands here) or
+                # the resume token of a re-prefilled one.  The request decodes
+                # from the next tick on: this tick's lanes were built before
+                # the host had the token.
+                self._emit(idx, token, time.monotonic())
+                if idx in sched.slots:
+                    sched.slots[idx].request.state = RequestState.DECODING
+
+    def _emit_decode(self, batch: _Lanes, out: dict, width: int, fresh: bool, dispatch_ms: float) -> None:
+        sched, live, window = self.sched, batch.live, self.programs.window
+        tokens, accepts, oks, draft_len = out["tokens"], out["accepts"], out["ok"], batch.draft_len
         with _TickPhase(self, "decode.emit") as span:
             emit_t = time.monotonic()
             if self.tracer is not None:
-                # emit_t is PAST the np.asarray sync point, so the interval
+                # emit_t is PAST the read-back's sync point, so the interval
                 # covers the real device work despite async dispatch.
                 self.tracer.on_decode(
                     [(sched.slots[idx].request, idx) for idx in live],
-                    emit_t, co_batch=len(live), width=m, fresh=fresh,
-                    dispatch_ms=(emit_t - dispatch_t0) * 1e3,
+                    emit_t, co_batch=len(live), width=width, fresh=fresh,
+                    dispatch_ms=dispatch_ms,
                     phase="verify" if window > 1 else "decode",
                 )
             # rounds counts verify DISPATCHES (with >= 1 healthy lane);
@@ -1437,15 +1518,13 @@ class ServingEngine:
             for idx in live:
                 slot = sched.slots[idx]
                 req = slot.request
-                if window > 1:
-                    # Accept bookkeeping: the emitted chunk is t[:count] where
-                    # count = accepted drafts + the correction/bonus row, capped
-                    # at remaining (count == remaining finishes the request on
-                    # its exact last token).  cache_len advances by count — the
-                    # rewind; rows past it are stale and re-written before read.
-                    count = min(int(accepts[idx]) + 1, req.remaining)
-                else:
-                    count = 1
+                # Accept bookkeeping: the emitted chunk is t[:count] where
+                # count = accepted drafts + the correction/bonus row, capped
+                # at remaining (count == remaining finishes the request on
+                # its exact last token).  cache_len advances by count — the
+                # rewind; rows past it are stale and re-written before read.
+                # Without speculation accepts are 0 and count is 1.
+                count = min(int(accepts[idx]) + 1, req.remaining)
                 slot.cache_len += count
                 if not bool(oks[idx]):
                     # Quarantine instead of emitting the garbage argmax; the
@@ -1460,11 +1539,12 @@ class ServingEngine:
                 self.decode_slot_ticks += 1
                 emitted += count
                 for j in range(count):
-                    self._emit(idx, int(out[idx, j]), emit_t)
+                    self._emit(idx, int(tokens[idx, j]), emit_t)
             if spec_rounds:
                 self.spec_rounds += spec_rounds
                 self.spec_proposed += spec_proposed
                 self.spec_accepted += spec_accepted
+                tel = get_telemetry()
                 if tel.enabled:
                     tel.registry.counter("serving.spec.rounds").inc(spec_rounds)
                     if spec_proposed:
@@ -1749,6 +1829,7 @@ class ServingEngine:
             "ticks": self.ticks,
             "decode_dispatches": self.decode_dispatches,
             "prefill_dispatches": self.prefill_dispatches,
+            "mixed_dispatches": self.mixed_dispatches,
             "active_slots": self.sched.active,
             "queue_depth": self.sched.pending,
             "blocks_used": alloc.used_blocks,
@@ -1767,7 +1848,7 @@ class ServingEngine:
             "prefix_blocks_reused": self.prefix_blocks_reused,
             "prefix_cow_copies": self.cow_copies,
             "prefix_cached_blocks": len(self._prefix) if self._prefix else 0,
-            "decode_bucket_widths": sorted(self._seen_widths["decode"]),
+            "decode_bucket_widths": sorted(self._decode_widths),
             "spec": {
                 "window": self.spec_tokens,
                 "rounds": self.spec_rounds,

@@ -1,45 +1,59 @@
 """The engine's compiled programs: how a dispatch reads the pool.
 
-A dispatch is one **forward** over the new tokens of its lanes and one
-**head** that turns the logits into what the tick reads back.  The forward
-depends on the cache back end, the head on the kind of dispatch; each is
-written once here.
+A tick issues **one** dispatch: one **forward** over the new tokens of all its
+lanes and one **head** that turns the logits into what the tick reads back.
+The forward depends on the cache back end, the head on what the tick holds;
+each is written once here.
 
-``forward(params, pool, tables [B, M], starts [B], tokens [B, T])`` gives
-``(logits [B, T, V], counters, rows {leaf: [B, L, T, ...]})``: lane ``b``'s
-tokens sit at positions ``starts[b] .. starts[b] + T - 1`` of the sequence
-its table row names, and ``rows`` are the cache rows those tokens wrote.
-The head scatters them into the donated pool (``_write_rows``) after it has
-read the logits, so the new pool is the last thing a program computes.
+``forward(params, pool, groups)`` takes a short tuple of groups ``(tokens [B,
+T], tables [B, M], starts [B])`` and gives ``(logits, counters, rows)``, logits
+``[B, T, V]`` and rows ``{leaf: [B, L, T, ...]}`` a group: lane ``b`` of a
+group has its tokens at positions ``starts[b] .. starts[b] + T - 1`` of the
+sequence its table row names, and ``rows`` are the cache rows those tokens
+wrote.  A group is what shares one ``T``: the decoding lanes (``T`` 1, or ``k
++ 1`` under speculation) are one, a prefilling slot's chunk (``B`` 1, ``T``
+``prefill_chunk``) another.  The head scatters the rows into the donated pool
+(``_write_rows``) after it has read the logits, so the new pool is the last
+thing a program computes.
 
 - **paged** (a family with an ``apply_paged``: gpt2, llama, deepseek_v3):
-  the family reads the pool in place through the block tables and returns
-  the written rows; no per-slot view of the cache exists on either side of
-  the dispatch.  An expert family returns its per-dispatch counters as a
-  third value; they ride out behind the ``ok`` flags.
+  the family runs everything that does not look at the cache (embedding,
+  norms, projections, the MLP or the experts, the head) once over the rows of
+  all groups, so the weights, and the experts the rows hit, stream once a
+  tick; attention runs a group at a time, reading the pool in place through
+  that group's block tables.  No per-slot view of the cache exists on either
+  side of the dispatch.  An expert family returns its per-dispatch counters
+  as a third value; they ride out behind the ``ok`` flags.
 - **dense** (a family without one: mixtral, whose capacity routing depends
   on who shares the batch, so lanes must not be batched together): gather
   each lane's whole view through its table row, run the family's
   ``apply_cached`` lane by lane under ``vmap``, cut the written rows out of
-  the updated views.
+  the updated views; a group at a time inside the one program.
 
-The family decides the back end; nothing selects it.  The three heads:
-``decode`` (one token a lane: argmax of the last row, ``ok`` per lane),
-``decode_spec`` (a ``k+1`` window a lane through
-``speculative_verify_greedy``, ``ok`` per lane; built when ``spec_tokens >
-0``) and ``prefill`` (one lane's padded chunk: argmax at ``n_real - 1``,
-one ``ok``).  ``decode`` and ``decode_spec`` take a trailing per-lane poison
-vector when the NaN fault is armed; an unarmed program is traced without it.
+The family decides the back end; nothing selects it.  The two programs of an
+engine, each compiled once per table width: ``decode`` (the decoding lanes
+alone: argmax of the one row a lane, or with ``spec_tokens > 0`` the ``k + 1``
+window through ``speculative_verify_greedy``) and ``decode_chunk`` (the same
+lanes **and** one lane's padded chunk: also the argmax at ``n_real - 1`` and
+the chunk's own ``ok``; a chunk with no live decoder rides it with the lanes
+idle, which only a cold start sees).  Both groups of ``decode_chunk`` share
+one table width, the wider of the two needs.  What a program returns beside
+the pool is one int32 vector, so a tick reads one array back
+(:meth:`ServingPrograms.unpack`).  Both take a trailing per-lane poison vector
+when the NaN fault is armed; an unarmed program is traced without it.
 
 The profile names a program after its Python function (``jit_decode``,
-``jit_prefill``) and ``chipbench/`` selects operations by those names.
+``jit_decode_chunk``) and ``chipbench/`` selects operations by the prefix
+``jit_decode``.
 """
 
 from __future__ import annotations
 
 import inspect
 from dataclasses import dataclass
-from typing import Callable, Optional, Sequence
+from typing import Callable, Sequence
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +71,12 @@ __all__ = ["MOE_COUNTERS", "ServingPrograms", "build_programs"]
 # summed over its expert layers: token-expert pairs computed, experts with at least one row, the hottest expert's rows.
 MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows")
 
+# No block table is narrower than this many rows.  Every width an engine meets compiles both of its programs, and on the
+# host of a v5e that is one to two seconds of a process's set-up a width even with a warm compile cache (tracing and
+# lowering are not cached; PERF.md section 6, PR 31), while under 256 rows of context the block gather of sixteen lanes is
+# 0.4 ms of an 11 ms dispatch (the probe of PERF.md section 6, PR 29).
+MIN_TABLE_ROWS = 256
+
 
 @dataclass(frozen=True)
 class ServingPrograms:
@@ -64,21 +84,23 @@ class ServingPrograms:
     what a tick has to know about the back end they were built for."""
 
     backend: str  # "paged" | "dense": what the family decided
-    decode: Callable
-    prefill: Callable
-    decode_spec: Optional[Callable]
+    decode: Callable  # (params, pool, tables [S, M], lengths [S], tokens [S, W], draft_len [S], *poison)
+    decode_chunk: Callable  # (..., draft_len [S], table_row [M], start, chunk [1, C], n_real, *poison)
+    window: int  # W: 1, or k + 1 under speculation
     max_slots: int
+    min_blocks: int  # the narrowest table: MIN_TABLE_ROWS in blocks, a power of two
     max_blocks: int
 
     def table_width(self, blocks_needed: int) -> int:
         """The block-table width of a dispatch whose widest lane needs
-        ``blocks_needed`` blocks.  Paged: the next power of two, capped at
-        the configured maximum — each width compiles once (jit caches per
-        shape) and gather traffic scales with what live requests own.
-        Dense: the one static width, the view is always whole."""
+        ``blocks_needed`` blocks.  Paged: the next power of two from
+        ``min_blocks`` up, capped at the configured maximum — each width
+        compiles once (jit caches per shape) and gather traffic scales with
+        what live requests own.  Dense: the one static width, the view is
+        always whole."""
         if self.backend == "dense":
             return self.max_blocks
-        width = 1
+        width = self.min_blocks
         while width < blocks_needed:
             width *= 2
         return min(width, self.max_blocks)
@@ -91,6 +113,25 @@ class ServingPrograms:
             return self.max_slots * self.max_blocks
         return sum(owned)
 
+    def unpack(self, packed, with_chunk: bool) -> dict:
+        """The host's view of the vector a program returned (the one sync
+        point and the one read-back of a tick): ``tokens [S, W]``, ``accepts
+        [S]`` (zeros without speculation), ``ok [S]``, with a chunk
+        ``chunk_token`` and ``chunk_ok``, and ``counters`` (what an expert
+        family put behind them, else empty)."""
+        flat = np.asarray(packed)
+        s, w = self.max_slots, self.window
+        sizes = [("tokens", s * w), ("accepts", s if w > 1 else 0), ("ok", s)]
+        sizes += [("chunk_token", 1), ("chunk_ok", 1)] if with_chunk else []
+        out, at = {}, 0
+        for name, n in sizes:
+            out[name] = flat[at : at + n]
+            at += n
+        out["tokens"] = out["tokens"].reshape(s, w)
+        out["accepts"] = out["accepts"] if w > 1 else np.zeros((s,), np.int32)
+        out["counters"] = flat[at:]
+        return out
+
 
 def build_programs(apply_cached: Callable, config, leaf_names, serving, spec_tokens: int) -> ServingPrograms:
     """The programs of an engine that serves ``apply_cached``'s family over a
@@ -100,12 +141,14 @@ def build_programs(apply_cached: Callable, config, leaf_names, serving, spec_tok
         backend, forward = "paged", _paged_forward(apply_paged, config)
     else:
         backend, forward = "dense", _dense_forward(apply_cached, config, leaf_names)
+    decode, decode_chunk = _heads(forward)
     return ServingPrograms(
         backend=backend,
-        decode=jax.jit(_decode_head(forward), donate_argnums=(1,)),
-        prefill=jax.jit(_prefill_head(forward, serving.prefill_chunk), donate_argnums=(1,)),
-        decode_spec=jax.jit(_verify_head(forward), donate_argnums=(1,)) if spec_tokens > 0 else None,
+        decode=jax.jit(decode, donate_argnums=(1,)),
+        decode_chunk=jax.jit(decode_chunk, donate_argnums=(1,)),
+        window=spec_tokens + 1,
         max_slots=serving.max_slots,
+        min_blocks=1 << max(0, -(-MIN_TABLE_ROWS // serving.block_size) - 1).bit_length(),
         max_blocks=serving.resolved_max_blocks(),
     )
 
@@ -114,15 +157,15 @@ def build_programs(apply_cached: Callable, config, leaf_names, serving, spec_tok
 
 
 def _paged_forward(apply_paged: Callable, config) -> Callable:
-    def forward(params, pool, tables, starts, tokens):
-        logits, rows, *counters = apply_paged(params, tokens, config, pool, tables, starts)
+    def forward(params, pool, groups):
+        logits, rows, *counters = apply_paged(params, groups, config, pool)
         return logits, counters, rows
 
     return forward
 
 
 def _dense_forward(apply_cached: Callable, config, names) -> Callable:
-    def forward(params, pool, tables, starts, tokens):
+    def one_group(params, pool, tokens, tables, starts):
         caches = {n: gather_block_view(pool[n], tables) for n in names}
         caches["index"] = starts
 
@@ -131,36 +174,38 @@ def _dense_forward(apply_cached: Callable, config, names) -> Callable:
             return logits[0], new_cache
 
         logits, new_caches = jax.vmap(one)(caches, tokens)
-        rows = {n: extract_token_rows(new_caches[n], starts, tokens.shape[1]) for n in names}
+        return logits, {n: extract_token_rows(new_caches[n], starts, tokens.shape[1]) for n in names}
+
+    def forward(params, pool, groups):
+        logits, rows = zip(*(one_group(params, pool, *group) for group in groups))
         return logits, [], rows
 
     return forward
 
 
 def _write_rows(pool: dict, rows: dict, tables, starts, count: int) -> dict:
-    """The pool with the rows a forward wrote scattered in.  Rows past a
-    lane's accepted length (a verify window) or past a chunk's real tokens
-    are stale by construction: the next dispatch at that position re-writes
-    them before any mask admits them."""
+    """The pool with the rows a forward wrote for one group scattered in.
+    Rows past a lane's accepted length (a verify window) or past a chunk's
+    real tokens are stale by construction: the next dispatch at that position
+    re-writes them before any mask admits them."""
     new_pool = dict(pool)
     for n, r in rows.items():
         new_pool[n] = scatter_token_rows(pool[n], r, tables, starts, count)
     return new_pool
 
 
-# -- the three heads ----------------------------------------------------------
+# -- the heads ----------------------------------------------------------------
 
 
-def _ok_with_counters(ok, counters):
-    """A dispatch's finiteness flags and, for a family whose ``apply_paged``
-    returns expert counters as a third value, those counters behind them in
-    one int32 vector: the read-back of ``ok`` that a tick makes anyway carries
-    them to the host.  A family without experts returns two values, keeps its
-    flags as they are and compiles to the program it always had."""
-    if not counters:
-        return ok
-    behind = jnp.stack([counters[0][name] for name in MOE_COUNTERS]).astype(jnp.int32)
-    return jnp.concatenate([jnp.atleast_1d(ok).astype(jnp.int32), behind])
+def _packed(parts, counters):
+    """What a tick reads back, as one int32 vector (:meth:`ServingPrograms.unpack`
+    is its inverse): the heads' parts in order and, for a family whose
+    ``apply_paged`` returns expert counters as a third value, those counters
+    behind them.  A family without experts returns two values and compiles to
+    a program without them."""
+    if counters:
+        parts = [*parts, jnp.stack([counters[0][name] for name in MOE_COUNTERS])]
+    return jnp.concatenate([jnp.ravel(p).astype(jnp.int32) for p in parts])
 
 
 def _poisoned(logits, poison):
@@ -172,39 +217,40 @@ def _poisoned(logits, poison):
     return logits * jnp.expand_dims(poison[0], tuple(range(1, logits.ndim)))
 
 
-def _decode_head(forward: Callable) -> Callable:
-    def decode(params, pool, tables, lengths, tokens, *poison):
-        logits, counters, rows = forward(params, pool, tables, lengths, tokens[:, None])
-        logits = _poisoned(logits[:, -1], poison)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        # Per-lane finiteness, folded into the same dispatch: a poisoned lane
-        # is detected the tick it happens, before its token is emitted.
-        ok = jnp.all(jnp.isfinite(logits), axis=-1)
-        new_pool = _write_rows(pool, rows, tables, lengths, 1)
-        return next_tok, _ok_with_counters(ok, counters), new_pool
+def _lanes_head(logits, tokens, draft_len, poison):
+    """The decoding lanes' part of a head: ``(tokens [S, W], accepts [S] or
+    nothing, ok [S])`` from their logits ``[S, W, V]``.  One row a lane emits
+    its argmax; a ``k + 1`` window goes through the greedy accept rule.  The
+    finiteness flag is per lane, folded into the same dispatch: a poisoned
+    lane is detected the tick it happens, before its token is emitted."""
+    logits = _poisoned(logits, poison)
+    ok = jnp.all(jnp.isfinite(logits), axis=(1, 2))
+    if logits.shape[1] == 1:
+        return [jnp.argmax(logits[:, -1], axis=-1)], ok
+    t, m = speculative_verify_greedy(logits, tokens[:, 1:], draft_len)
+    return [t, m], ok
 
-    return decode
 
+def _heads(forward: Callable):
+    """``decode`` and ``decode_chunk`` over ``forward``: the names are the
+    profile's (``jit_decode``, ``jit_decode_chunk``).  ``draft_len`` is read
+    by a ``k + 1`` window only (jit drops an argument nothing reads)."""
 
-def _verify_head(forward: Callable) -> Callable:
-    def decode(params, pool, tables, lengths, tokens, draft_len, *poison):  # a decode to the profile too
-        logits, counters, rows = forward(params, pool, tables, lengths, tokens)  # [S, W, V]
-        logits = _poisoned(logits, poison)
-        t, m = speculative_verify_greedy(logits, tokens[:, 1:], draft_len)
-        ok = jnp.all(jnp.isfinite(logits), axis=(1, 2))
+    def decode(params, pool, tables, lengths, tokens, draft_len, *poison):
+        (logits,), counters, (rows,) = forward(params, pool, ((tokens, tables, lengths),))
+        parts, ok = _lanes_head(logits, tokens, draft_len, poison)
         new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1])
-        return t, m, _ok_with_counters(ok, counters), new_pool
+        return _packed([*parts, ok], counters), new_pool
 
-    return decode
+    def decode_chunk(params, pool, tables, lengths, tokens, draft_len, table_row, start, chunk, n_real, *poison):
+        chunk_tables, chunk_starts = table_row[None], start[None]
+        groups = ((tokens, tables, lengths), (chunk, chunk_tables, chunk_starts))
+        (logits, chunk_logits), counters, (rows, chunk_rows) = forward(params, pool, groups)
+        parts, ok = _lanes_head(logits, tokens, draft_len, poison)
+        chunk_token = jnp.argmax(chunk_logits[0, n_real - 1], axis=-1)
+        chunk_ok = jnp.all(jnp.isfinite(chunk_logits))
+        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1])
+        new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1])
+        return _packed([*parts, ok, chunk_token, chunk_ok], counters), new_pool
 
-
-def _prefill_head(forward: Callable, chunk_len: int) -> Callable:
-    def prefill(params, pool, table_row, length, chunk, n_real):
-        tables, starts = table_row[None], length[None]
-        logits, counters, rows = forward(params, pool, tables, starts, chunk)
-        next_tok = jnp.argmax(logits[0, n_real - 1], axis=-1).astype(jnp.int32)
-        ok = jnp.all(jnp.isfinite(logits))
-        new_pool = _write_rows(pool, rows, tables, starts, chunk_len)
-        return next_tok, _ok_with_counters(ok, counters), new_pool
-
-    return prefill
+    return decode, decode_chunk

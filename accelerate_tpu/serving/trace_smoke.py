@@ -95,8 +95,8 @@ def main() -> int:
     # Scenario 1 — queue delay: submit, then hold the engine for 120 ms
     # before the first tick.  queue_wait must dominate the request.
     # max_new=12 keeps the request in a slot across the /debug scrape below
-    # (a prefill-completing tick also decodes once, so small budgets finish
-    # within the first few ticks) while keeping the decode window short
+    # (a request decodes from the tick after its last chunk on, so small
+    # budgets finish within the first few ticks) while keeping the decode window short
     # enough that the injected delay clears the blame floor.
     rid_queue = engine.submit(prompt(6), 12, tag="slow-queue")
     time.sleep(0.12)

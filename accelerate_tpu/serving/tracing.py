@@ -433,8 +433,8 @@ class ServingTracer:
         turn records a ``waiting`` prefill interval — the co-batched-behind-
         another-prefill time the blame question asks about.  ``tick`` is the
         engine's record of the tick (``total_ms``, ``phase_ms`` by the names
-        of its ``serving.tick.*`` spans, the decode dispatch's ``live`` and
-        ``width``): kept if it is among the slowest, see :meth:`slow_ticks`."""
+        of its ``serving.tick.*`` spans, the dispatch's ``live`` lanes and
+        ``width``, ``mixed`` when a chunk rode with them): kept if it is among the slowest, see :meth:`slow_ticks`."""
         if self._tick_t0 is None:
             return
         # A tick that met a table width for the first time compiles, and is
@@ -477,6 +477,7 @@ class ServingTracer:
             "live": tick["live"],
             "prefilling": tick["prefilling"],
             "width": tick["width"],
+            "mixed": tick["mixed"],
             "gc_count": list(gc.get_count()),
         }
         entry = (tick["total_ms"], tick["tick"], record)

@@ -58,6 +58,7 @@ COUNTERS = frozenset({
     "serving.decode_gather_bytes",
     "serving.drains",
     "serving.journal_recoveries",
+    "serving.mixed_dispatches",
     "serving.preempted",
     "serving.prefill_dispatches",
     "serving.prefix_blocks_reused",

@@ -51,6 +51,18 @@ def without_apply_paged(family):
 
 
 @pytest.fixture(autouse=True)
+def _block_tables_from_one_block_up(monkeypatch):
+    """The tiny engines of these suites bucket their block tables from one
+    block up, as every engine did until PR 31, so that they keep crossing
+    table widths at contexts of tens of rows.  The production floor
+    (``serving/programs.py:MIN_TABLE_ROWS``, 256 rows) would hold them all at
+    their one widest table; ``tests/test_serving_programs.py`` holds it."""
+    from accelerate_tpu.serving import programs
+
+    monkeypatch.setattr(programs, "MIN_TABLE_ROWS", 1)
+
+
+@pytest.fixture(autouse=True)
 def _reset_singletons():
     """Reference parity: ``AccelerateTestCase.tearDown`` (``test_utils/testing.py:
     610-621``) resets the three state singletons between tests — and takes the

@@ -130,7 +130,7 @@ def paged_logits(c, params, seqs, prompt, chunk, block_size=4, num_blocks=64):
 
     @jax.jit
     def step(pool, ids, tab, starts):
-        logits, rows, _ = ds.apply_paged(params, ids, c, pool, tab, starts)
+        (logits,), (rows,), _ = ds.apply_paged(params, ((ids, tab, starts),), c, pool)
         # the padded rows are written too, as the engine writes them: later rows overwrite them before any mask admits them
         return logits, {n: scatter_token_rows(pool[n], r, tab, starts, ids.shape[1]) for n, r in rows.items()}
 
@@ -333,10 +333,13 @@ def engine_against_reference(model, fam, chunk, mix):
         for t in range(len(prompt), len(reply.tokens)):  # each served token is the reference's best, to round-off
             assert want[t - 1].max() - want[t - 1, reply.tokens[t]] < TOL
     stats = engine.stats()
-    # hand count: 2 expert layers x top-2 for every row of every dispatch, the padded rows and idle slots among them
-    assert stats["moe_rows"] == 2 * 2 * (stats["prefill_dispatches"] * chunk + stats["decode_dispatches"] * 4)
-    assert 2 * (stats["prefill_dispatches"] + stats["decode_dispatches"]) <= stats["moe_max_rows"] <= stats["moe_rows"]
-    assert 2 * 2 * (stats["prefill_dispatches"] + stats["decode_dispatches"]) <= stats["moe_experts_hit"] <= stats["moe_rows"]
+    # hand count: 2 expert layers x top-2 for every row of every dispatch, the padded rows and idle slots among them;
+    # a chunk rides with the 4 lanes whether they are live (a mixed dispatch) or idle (a chunk alone)
+    dispatches = stats["prefill_dispatches"] + stats["decode_dispatches"] - stats["mixed_dispatches"]
+    assert stats["mixed_dispatches"] > 0 and dispatches == stats["ticks"]
+    assert stats["moe_rows"] == 2 * 2 * (stats["prefill_dispatches"] * chunk + dispatches * 4)
+    assert 2 * dispatches <= stats["moe_max_rows"] <= stats["moe_rows"]
+    assert 2 * 2 * dispatches <= stats["moe_experts_hit"] <= stats["moe_rows"]
 
 
 def test_expert_counters_against_hand_counted_values():
@@ -354,8 +357,9 @@ def test_a_family_without_experts_keeps_its_program():
         llama.apply_cached, llama.init_cache, llama.init_params(c, jax.random.key(0)), c, block_size=4, num_blocks=32,
         max_slots=2, max_blocks_per_seq=8, prefill_chunk=4)
     tables, lengths = np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)
-    out = jax.eval_shape(engine.programs.decode, engine.params, engine.cache.pool, tables, lengths, np.zeros((2,), np.int32))
-    assert out[1].dtype == jnp.bool_ and out[1].shape == (2,)
+    packed, _ = jax.eval_shape(
+        engine.programs.decode, engine.params, engine.cache.pool, tables, lengths, np.zeros((2, 1), np.int32), np.zeros((2,), np.int32))
+    assert packed.shape == (2 + 2,)  # a token and a flag a lane, nothing behind them
     engine.submit(np.arange(6), 3)
     engine.run()
     assert {engine.stats()[k] for k in ("moe_rows", "moe_experts_hit", "moe_max_rows")} == {0}

@@ -1,5 +1,5 @@
-"""``serving/programs.py``: one forward per cache back end, three heads, and
-the family as the only thing that decides the back end.
+"""``serving/programs.py``: one forward per cache back end over the groups of a
+tick, two heads, and the family as the only thing that decides the back end.
 
 The engine's token-identity matrices (``test_serving.py``,
 ``test_spec_serving.py``, ``test_serving_tiering.py``) hold both back ends to
@@ -88,8 +88,8 @@ def test_the_two_forwards_agree_on_logits_and_pool(name, quant, kind):
     tables, starts, tokens = _dispatch(kind, cfg.vocab_size, seed=5)
     paged = jax.jit(P._paged_forward(family.apply_paged, cfg))
     dense = jax.jit(P._dense_forward(family.apply_cached, cfg, list(pool)))
-    logits_p, counters_p, rows_p = paged(params, pool, tables, starts, tokens)
-    logits_d, counters_d, rows_d = dense(params, pool, tables, starts, tokens)
+    (logits_p,), counters_p, (rows_p,) = paged(params, pool, ((tokens, tables, starts),))
+    (logits_d,), counters_d, (rows_d,) = dense(params, pool, ((tokens, tables, starts),))
     assert not counters_p and not counters_d
     assert logits_p.shape == tokens.shape + (cfg.vocab_size,)
     live = tables[:, 0] != 0  # a dead lane reads the null block alone: its logits are nobody's
@@ -107,24 +107,107 @@ def test_the_two_forwards_agree_on_logits_and_pool(name, quant, kind):
             assert not np.array_equal(np.asarray(new_p[leaf])[:, blk, off], np.asarray(pool[leaf])[:, blk, off])
 
 
+def _disjoint_mixed_dispatch(kind, vocab):
+    """The lanes of ``kind`` and a chunk as one tick holds them: the chunk's blocks are no lane's."""
+    tables, starts, tokens = _dispatch(kind, vocab, seed=5)
+    chunk_tables, chunk_starts, chunk = _dispatch("prefill", vocab, seed=6)
+    free = [b for b in range(1, BLOCKS) if b not in set(tables.ravel().tolist())]
+    chunk_tables[0, :4] = free[:4]
+    return (tokens, tables, starts), (chunk, chunk_tables, chunk_starts)
+
+
+@pytest.mark.parametrize("backend", ["paged", "dense"])
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("name", ["gpt2", "llama_gqa"])
+def test_a_forward_over_two_groups_is_the_two_forwards_side_by_side(name, quant, kind, backend):
+    """The mixed dispatch's forward: the decoding lanes and a chunk in one call give, a group each, the logits and
+    the written rows that each gives in a call of its own, whatever shares the matmuls."""
+    family, cfg, params = _family(name, quant)
+    pool = _random_pool(family, cfg, seed=3)
+    groups = _disjoint_mixed_dispatch(kind, cfg.vocab_size)
+    make = {"paged": lambda: P._paged_forward(family.apply_paged, cfg), "dense": lambda: P._dense_forward(family.apply_cached, cfg, list(pool))}
+    forward = jax.jit(make[backend]())
+    logits, counters, rows = forward(params, pool, groups)
+    assert len(logits) == len(rows) == 2 and not counters
+    new_pool = pool
+    for group, got_logits, got_rows in zip(groups, logits, rows):
+        tokens, tables, starts = group
+        (want_logits,), _, (want_rows,) = forward(params, pool, (group,))
+        assert got_logits.shape == tokens.shape + (cfg.vocab_size,)
+        live = tables[:, 0] != 0
+        np.testing.assert_allclose(np.asarray(got_logits)[live], np.asarray(want_logits)[live], rtol=2e-4, atol=2e-4)
+        _assert_pools_match(P._write_rows(pool, got_rows, tables, starts, tokens.shape[1]),
+                            P._write_rows(pool, want_rows, tables, starts, tokens.shape[1]))
+        new_pool = P._write_rows(new_pool, got_rows, tables, starts, tokens.shape[1])
+    # both groups' rows landed in the one pool: the chunk's first real row and a lane's
+    (_, tables, starts), (_, chunk_tables, chunk_starts) = groups
+    for tab, pos in ((tables[0], int(starts[0])), (chunk_tables[0], int(chunk_starts[0]))):
+        blk, off = tab[pos // BLOCK], pos % BLOCK
+        assert not np.array_equal(np.asarray(new_pool["k"])[:, blk, off], np.asarray(pool["k"])[:, blk, off])
+
+
+def _run_program(built, program, family, cfg, params, lanes, chunk=None):
+    """One dispatch of ``program`` over a fresh random pool -> (unpacked read-back, new pool)."""
+    tokens, tables, starts = lanes
+    args = [tables, starts, tokens, np.zeros((tables.shape[0],), np.int32)]
+    if chunk is not None:
+        chunk_tokens, chunk_tables, chunk_starts = chunk
+        args += [chunk_tables[0], chunk_starts[0], chunk_tokens, np.int32(3)]
+    packed, pool = program(params, _random_pool(family, cfg, seed=3), *args)
+    return built.unpack(packed, with_chunk=chunk is not None), pool
+
+
+@pytest.mark.parametrize("backend", ["paged", "dense"])
+@pytest.mark.parametrize("spec", [0, WINDOW - 1], ids=["greedy", "spec"])
+@pytest.mark.parametrize("name", ["gpt2", "llama_gqa"])
+def test_decode_chunk_is_decode_and_the_chunk_in_one_dispatch(name, spec, backend):
+    """The mixed program returns what the two dispatches it replaces return: the lanes' tokens (and accepts) of
+    ``decode``, the chunk's token and flag of a chunk run alone on idle lanes, and a pool with both groups' rows."""
+    family, cfg, params = _family(name, quant=False)
+    apply_cached = family.apply_cached if backend == "paged" else without_apply_paged(family)
+    built = P.build_programs(apply_cached, cfg, ["k", "v"], SERVING, spec_tokens=spec)
+    assert built.backend == backend and built.window == spec + 1
+    lanes, chunk = _disjoint_mixed_dispatch("verify" if spec else "decode", cfg.vocab_size)
+    idle = tuple(np.zeros_like(a) for a in lanes)
+    mixed, pool_m = _run_program(built, built.decode_chunk, family, cfg, params, lanes, chunk)
+    alone, pool_d = _run_program(built, built.decode, family, cfg, params, lanes)
+    chunk_alone, pool_c = _run_program(built, built.decode_chunk, family, cfg, params, idle, chunk)
+    live = lanes[1][:, 0] != 0
+    assert mixed["tokens"].shape == (4, spec + 1)
+    assert mixed["tokens"][live].tolist() == alone["tokens"][live].tolist()
+    assert mixed["accepts"][live].tolist() == alone["accepts"][live].tolist()
+    assert mixed["ok"][live].all() and mixed["chunk_ok"][0] == 1
+    assert mixed["chunk_token"].tolist() == chunk_alone["chunk_token"].tolist()
+    assert not mixed["counters"].size  # no expert family here
+    # the lanes' rows as decode wrote them, the chunk's as the chunk alone wrote them
+    (_, tables, starts), (_, chunk_tables, chunk_starts) = lanes, chunk
+    for lane in np.flatnonzero(live):
+        blk, off = tables[lane, starts[lane] // BLOCK], starts[lane] % BLOCK
+        np.testing.assert_allclose(np.asarray(pool_m["k"])[:, blk, off], np.asarray(pool_d["k"])[:, blk, off], rtol=2e-5, atol=2e-5)
+    for pos in range(int(chunk_starts[0]), int(chunk_starts[0]) + 3):
+        blk, off = chunk_tables[0, pos // BLOCK], pos % BLOCK
+        np.testing.assert_allclose(np.asarray(pool_m["v"])[:, blk, off], np.asarray(pool_c["v"])[:, blk, off], rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("backend", ["paged", "dense"])
 @pytest.mark.parametrize("name", ["gpt2", "llama_gqa"])
 def test_greedy_is_the_draftless_case_of_the_verify_head(name, backend):
-    """A verify window whose lanes carry no draft emits, in its first column, the token the decode head emits,
+    """A verify window whose lanes carry no draft emits, in its first column, the token the one-row head emits,
     accepts nothing, and writes the same first row."""
     family, cfg, params = _family(name, quant=False)
     apply_cached = family.apply_cached if backend == "paged" else without_apply_paged(family)
-    built = P.build_programs(apply_cached, cfg, ["k", "v"], SERVING, spec_tokens=WINDOW - 1)
-    assert built.backend == backend and built.decode_spec is not None
+    greedy = P.build_programs(apply_cached, cfg, ["k", "v"], SERVING, spec_tokens=0)
+    spec = P.build_programs(apply_cached, cfg, ["k", "v"], SERVING, spec_tokens=WINDOW - 1)
+    assert greedy.backend == spec.backend == backend and (greedy.window, spec.window) == (1, WINDOW)
     tables, starts, tokens = _dispatch("verify", cfg.vocab_size, seed=7)
     tokens[:, 1:] = 0  # no drafts: the window is the last token and padding
-    next_tok, ok, pool_1 = built.decode(params, _random_pool(family, cfg, seed=3), tables, starts, tokens[:, 0])
-    t, m, ok_w, pool_w = built.decode_spec(
-        params, _random_pool(family, cfg, seed=3), tables, starts, tokens, np.zeros((4,), np.int32))
+    one, pool_1 = _run_program(greedy, greedy.decode, family, cfg, params, (tokens[:, :1], tables, starts))
+    win, pool_w = _run_program(spec, spec.decode, family, cfg, params, (tokens, tables, starts))
     live = tables[:, 0] != 0
-    assert np.asarray(m).tolist() == [0, 0, 0, 0]
-    assert np.asarray(t)[live, 0].tolist() == np.asarray(next_tok)[live].tolist()
-    assert np.asarray(ok)[live].all() and np.asarray(ok_w)[live].all()
+    assert win["accepts"].tolist() == one["accepts"].tolist() == [0, 0, 0, 0]
+    assert win["tokens"][live, 0].tolist() == one["tokens"][live, 0].tolist()
+    assert one["ok"][live].all() and win["ok"][live].all()
     for lane in np.flatnonzero(live):
         blk, off = tables[lane, starts[lane] // BLOCK], starts[lane] % BLOCK
         for leaf in ("k", "v"):
@@ -142,10 +225,25 @@ def test_greedy_is_the_draftless_case_of_the_verify_head(name, backend):
 def test_the_family_decides_the_back_end(family, config, backend):
     apply_cached = without_apply_paged(gpt2) if family is None else family.apply_cached
     built = P.build_programs(apply_cached, config, ["k", "v"], SERVING, spec_tokens=0)
-    assert built.backend == backend and built.decode_spec is None
+    assert built.backend == backend and built.window == 1
     # what the tick asks of the back end: the table width of a dispatch, the blocks a decode gathers
     assert [built.table_width(n) for n in (1, 2, 3, 4, 9)] == ([1, 2, 4, 4, 4] if backend == "paged" else [WIDTH] * 5)
     assert built.gathered_blocks([2, 1]) == (3 if backend == "paged" else SERVING.max_slots * WIDTH)
+
+
+@pytest.mark.parametrize("block_size,max_blocks,widths", [
+    (16, 256, [16, 16, 16, 32, 64, 256, 256]),  # the serving cells' geometry: 256 rows are 16 blocks
+    (64, 64, [4, 4, 16, 32, 64, 64, 64]),
+    (4, 16, [16] * 7),  # a table cannot be wider than max_blocks_per_seq: one width
+], ids=["bs16", "bs64", "bs4"])
+def test_no_table_is_narrower_than_256_rows(monkeypatch, block_size, max_blocks, widths):
+    """Each width compiles both programs, a second or two of set-up a width on a v5e's host, and under 256 rows the
+    gather is noise: the narrowest table is the power of two of blocks that holds ``MIN_TABLE_ROWS`` (tests/conftest.py
+    sets it to one row for every other test)."""
+    monkeypatch.setattr(P, "MIN_TABLE_ROWS", 256)
+    serving = ServingConfig(block_size=block_size, num_blocks=512, max_slots=4, max_blocks_per_seq=max_blocks)
+    built = P.build_programs(gpt2.apply_cached, gpt2.GPT2Config.tiny(), ["k", "v"], serving, spec_tokens=0)
+    assert [built.table_width(n) for n in (1, 2, 16, 17, 64, 200, 256)] == widths
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])

@@ -306,7 +306,7 @@ def test_bucket_compile_event_and_width_gauge_without_tracing(
                 if rec.get("kind") == "event" and rec.get("name") == "serving.bucket_compile":
                     events.append(rec)
     assert events, "no serving.bucket_compile event landed in telemetry"
-    assert {e["dispatch"] for e in events} <= {"prefill", "decode"}
+    assert {e["dispatch"] for e in events} <= {"decode_chunk", "decode"}  # the program that met the width first
     assert all(isinstance(e["width"], int) for e in events)
 
 
@@ -316,6 +316,8 @@ def test_bucket_compile_event_and_width_gauge_without_tracing(
 
 TICK_PHASES = ["admit", "prefill.build", "prefill.wait", "prefill.emit",
                "decode.build", "decode.wait", "decode.emit", "publish"]
+# a tick with a chunk and a live decoder: both builds, then ONE dispatch (under decode.wait), then both emits
+MIXED_TICK_PHASES = ["admit", "prefill.build", "decode.build", "decode.wait", "prefill.emit", "decode.emit", "publish"]
 
 
 def _busy_engine(cfg, params, **overrides):
@@ -331,8 +333,9 @@ def _busy_engine(cfg, params, **overrides):
 
 
 def test_tick_spans_in_a_profiler_session(gpt2_setup, tmp_path):
-    """A profiler session round three ticks holds every serving.tick.* span:
-    nested in its serving.tick, in the tick's own order, children sharing the
+    """A profiler session round three ticks, each with a chunk and a live
+    decoder, holds the spans of a mixed tick: nested in its serving.tick, in
+    the tick's own order (one wait: the one dispatch), children sharing the
     parent's ``tick``, the counts the issue's table gives as their stats."""
     import glob
 
@@ -357,15 +360,16 @@ def test_tick_spans_in_a_profiler_session(gpt2_setup, tmp_path):
     assert ticks[0][3]["prefilling"] == 1 and ticks[0][3]["decoding"] == 1
     for start, end, _, stats in ticks:
         children = [e for e in events if e[2] != "serving.tick" and e[3]["tick"] == stats["tick"]]
-        assert [e[2] for e in children] == ["serving.tick." + p for p in TICK_PHASES]
+        assert [e[2] for e in children] == ["serving.tick." + p for p in MIXED_TICK_PHASES]
         assert all(start <= e[0] and e[1] <= end for e in children)
         assert all(a[1] <= b[0] for a, b in zip(children, children[1:]))  # one after the other
         by_name = {e[2].removeprefix("serving.tick."): e[3] for e in children}
         assert by_name["admit"]["admitted"] == 0
-        assert set(by_name["prefill.build"]) == {"tick", "request", "start", "rows", "width"}
-        assert by_name["prefill.wait"]["request"] == by_name["prefill.emit"]["request"] == by_name["prefill.build"]["request"]
+        assert set(by_name["prefill.build"]) == {"tick", "request", "start", "rows"}
+        assert by_name["prefill.emit"]["request"] == by_name["prefill.build"]["request"]
         assert by_name["prefill.emit"]["first_token"] in (0, 1)
         assert by_name["decode.build"]["live"] == by_name["decode.wait"]["live"] >= 1
+        assert set(by_name["decode.wait"]) == {"tick", "live", "width"} and by_name["decode.wait"]["width"] >= 1
         assert by_name["decode.emit"]["tokens"] == by_name["decode.build"]["live"]
     assert sum(e[3]["first_token"] for e in events if e[2] == "serving.tick.prefill.emit") == 1
 
@@ -395,7 +399,8 @@ def test_slow_ticks_keep_the_slowest_and_say_which_phase(gpt2_setup, tmp_path, m
     assert len(slow) == SLOW_TICKS == 8
     assert [t["total_ms"] for t in slow] == sorted((t["total_ms"] for t in slow), reverse=True)
     for t in slow:
-        assert set(t) == {"tick", "total_ms", "phase_ms", "live", "prefilling", "width", "gc_count"}
+        assert set(t) == {"tick", "total_ms", "phase_ms", "live", "prefilling", "width", "mixed", "gc_count"}
+        assert t["mixed"] == ("prefill.emit" in t["phase_ms"] and "decode.emit" in t["phase_ms"])
         assert abs(sum(t["phase_ms"].values()) - t["total_ms"]) < 1.0
         assert set(t["phase_ms"]) <= set(TICK_PHASES) and len(t["gc_count"]) == 3
     assert slow[0]["tick"] == held and slow[0]["phase_ms"]["admit"] >= 50.0

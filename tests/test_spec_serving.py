@@ -179,19 +179,24 @@ def test_mixed_acceptance_across_lanes_in_one_dispatch(gpt2_setup):
         return {ids[s.request.id]: len(s.request.emitted)
                 for s in eng.sched.slots.values()}
 
-    # tick 1: good prefills (first token) and verifies alone — full
-    # acceptance lands k+1 = 4 more in that one dispatch.
+    # tick 1: good's one chunk rides alone and lands its first token; it
+    # decodes from the next tick on (the lanes are built before the dispatch).
+    eng.step()
+    assert emitted() == {"good": 1, "junk": 0}
+    # tick 2: ONE dispatch holds junk's chunk (its first token) and good's
+    # verify window — full acceptance lands k+1 = 4 in it.
     eng.step()
     before = emitted()
-    assert before["good"] == 5, "solo full-accept tick should land 1 + (k+1)"
-    # tick 2: junk finishes prefill (its first token) and BOTH lanes share
-    # the verify dispatch — good lands k+1, junk's rejected drafts land 1.
+    assert before == {"good": 5, "junk": 1}
+    assert eng.stats()["mixed_dispatches"] == 1 and eng.decode_dispatches == 1 and eng.prefill_dispatches == 2
+    # tick 3: BOTH lanes share the verify dispatch — good lands k+1, junk's
+    # rejected drafts land 1.
     eng.step()
     after = emitted()
     assert after["good"] - before["good"] == 4, \
         "full acceptance should land k+1 tokens in one dispatch"
-    assert after["junk"] - before["junk"] == 2, \
-        "rejected drafts must land exactly 1 decode token (plus the prefill token) in the same dispatch"
+    assert after["junk"] - before["junk"] == 1, \
+        "rejected drafts must land exactly 1 token in the same dispatch"
     outputs = eng.run(max_ticks=200)
     for rid, out in outputs.items():
         assert out == (want_good if ids[rid] == "good" else want_junk)

@@ -68,13 +68,16 @@ STEP_LAYERS, STEP_BLOCKS, STEP_SLOTS = 4, 6144, 4  # 6144 and 4 * 6144 are sizes
 SCANNED_POOL_TEMP_BYTES = {
     "paged_step:64:8": 211_563_008, "paged_step:64:12": 467_623_936, "paged_step:128:3": 0, "paged_step:256:2": 101_179_392,
     "paged_step_int8:128:2": 0, "paged_step_int8:128:8": 4_710_400,
+    # two groups in one forward (PR 31): the same two slices and two re-tilings a layer as one group has (counted below),
+    # scheduled so that three slice-sized buffers are alive at once where the one-group step has two
+    "paged_step_mixed:64:8": 303_724_032,
 }
 
 
 def paged_step(case, hd, kv_heads):
-    # llama.apply_paged at a decode shape (one token a slot) or a prefill
-    # shape (one row of 32) over a pool of [4, 6144, 16, K, hd] a leaf, bf16
-    # or int8 codes with bf16 scales
+    # llama.apply_paged at a decode shape (one token a slot), a prefill
+    # shape (one row of 32) or both as the two groups of a mixed dispatch, over
+    # a pool of [4, 6144, 16, K, hd] a leaf, bf16 or int8 codes with bf16 scales
     from accelerate_tpu.models import llama
     from accelerate_tpu.models.generation import make_paged_pool
 
@@ -85,9 +88,9 @@ def paged_step(case, hd, kv_heads):
     place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
     params = place(jax.eval_shape(lambda: llama.init_params(c, jax.random.key(0))))
     pool = place(jax.eval_shape(lambda: make_paged_pool(llama.init_cache, c, STEP_BLOCKS, 16)))
-    rows, tokens = (1, 32) if case == "paged_step_prefill" else (STEP_SLOTS, 1)
-    f = lambda p, pl, i, t, s: llama.apply_paged(p, i, c, pl, t, s)
-    return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, STEP_WIDTH), jnp.int32), sds((rows,), jnp.int32))
+    group = lambda rows, tokens: (sds((rows, tokens), jnp.int32), sds((rows, STEP_WIDTH), jnp.int32), sds((rows,), jnp.int32))
+    groups = {"paged_step_prefill": (group(1, 32),), "paged_step_mixed": (group(STEP_SLOTS, 1), group(1, 32))}.get(case, (group(STEP_SLOTS, 1),))
+    return (lambda p, pl, g: llama.apply_paged(p, g, c, pl)), (params, pool, groups)
 
 
 LATENT_EXPERTS = (8, 256, 128)  # one layer's routed experts [E, d, f] in latent_step
@@ -112,7 +115,7 @@ def latent_step(case):
     rows, tokens = (1, 32) if case == "latent_step_prefill" else (STEP_SLOTS, 1)
 
     def f(p, pl, i, t, s):
-        logits, new_rows, counters = ds.apply_paged(p, i, c, pl, t, s)
+        (logits,), (new_rows,), counters = ds.apply_paged(p, ((i, t, s),), c, pl)
         return logits, counters, {n: scatter_token_rows(pl[n], r, t, s, tokens) for n, r in new_rows.items()}
 
     f.donate = (1,)  # the engine donates the pool: the scatter writes it where it lies
@@ -140,6 +143,84 @@ def check_latent_step(compiled):
     if experts:
         raise AssertionError("a layer's experts are cut out of the stack: " + " ;; ".join(experts[:3]))
     return f"temp_bytes={compiled.memory_analysis().temp_size_in_bytes}"
+
+
+MIXED_CELLS = {  # the two serving cells of BENCHMARK.json: family, published widths at the depth served, the engine's geometry
+    "mixed_step_chat": ("llama", dict(
+        vocab_size=151936, hidden_size=2048, intermediate_size=11008, num_layers=36, num_heads=16, num_kv_heads=2, head_dim=128,
+        max_seq_len=32768, tie_embeddings=True, attention_bias=True), 256),
+    "mixed_step_agent": ("deepseek_v3", dict(
+        vocab_size=128256, hidden_size=2048, intermediate_size=6144, moe_intermediate_size=768, num_layers=8, first_k_dense_replace=1,
+        num_heads=32, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=128,
+        num_experts_per_tok=6, n_shared_experts=2, max_seq_len=32768), 128),
+}
+MIXED_BLOCKS, MIXED_SLOTS, MIXED_CHUNK = 8192, 16, 32
+
+
+def counted_weight_bytes(params):
+    # What XLA's cost analysis counts of the weights in ONE program: a scanned stack's body once (one layer of it), the
+    # leaves a body holds whole (the routed experts) whole, and the head's matrix (the embedding where it is tied)
+    size = lambda a: a.size * a.dtype.itemsize
+    total = size(params["lm_head"] if "lm_head" in params else params["embed"])
+    for stack in (v for v in params.values() if isinstance(v, dict)):
+        for name, leaf in stack.items():
+            held = "router" in stack and name in ("w_gate", "w_up", "w_down")
+            total += size(leaf) if held else size(leaf) // leaf.shape[0]
+    return total
+
+
+def check_mixed_step(case, width):
+    # serving/programs.py's decode_chunk at a cell's shapes against the two dispatches it replaces (decode, and the chunk
+    # through the one-group forward with the old prefill head).  The forward runs what does not look at the cache once
+    # over all 48 rows: XLA's bytes accessed of the mixed program lie below the sum of the two by the weights one program
+    # reads, to a tenth (the mixed program passes over its 48 rows of float32 logits once more, to hand each group its
+    # own).  And each group still reads every pool leaf where it lies: no result of the program is as large as a leaf or
+    # a layer's slice of one but the scatters of the new rows.
+    import importlib, re
+    from accelerate_tpu.models.generation import make_paged_pool
+    from accelerate_tpu.serving import ServingConfig, programs as P
+
+    family_name, widths, max_blocks = MIXED_CELLS[case]
+    family = importlib.import_module("accelerate_tpu.models." + family_name)
+    config_cls = next(v for k, v in vars(family).items() if k.endswith("Config") and isinstance(v, type))
+    c = config_cls(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False, **widths)
+    place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: family.init_params(c, jax.random.key(0))))
+    pool = place(jax.eval_shape(lambda: make_paged_pool(family.init_cache, c, MIXED_BLOCKS, 16)))
+    serving = ServingConfig(block_size=16, num_blocks=MIXED_BLOCKS, max_slots=MIXED_SLOTS, max_blocks_per_seq=max_blocks, prefill_chunk=MIXED_CHUNK)
+    built = P.build_programs(family.apply_cached, c, list(pool), serving, 0)
+    forward = P._paged_forward(family.apply_paged, c)
+
+    def prefill(params, pool, table_row, start, chunk, n_real):
+        tables, starts = table_row[None], start[None]
+        (logits,), counters, (rows,) = forward(params, pool, ((chunk, tables, starts),))
+        parts = [jnp.argmax(logits[0, n_real - 1], axis=-1), jnp.all(jnp.isfinite(logits))]
+        return P._packed(parts, counters), P._write_rows(pool, rows, tables, starts, MIXED_CHUNK)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    lanes = (i32(MIXED_SLOTS, width), i32(MIXED_SLOTS), i32(MIXED_SLOTS, 1), i32(MIXED_SLOTS))
+    chunk = (i32(width), i32(), i32(1, MIXED_CHUNK), i32())
+    compiled = {
+        "decode": built.decode.lower(params, pool, *lanes).compile(),
+        "prefill": jax.jit(prefill, donate_argnums=(1,)).lower(params, pool, *chunk).compile(),
+        "mixed": built.decode_chunk.lower(params, pool, *lanes, *chunk).compile(),
+    }
+    read = {name: comp.cost_analysis()["bytes accessed"] for name, comp in compiled.items()}
+    saved, weights = read["decode"] + read["prefill"] - read["mixed"], counted_weight_bytes(params)
+    if saved < 0.9 * weights:
+        raise AssertionError(f"the mixed program reads {read['mixed']:.4g} B, {saved:.4g} under decode + prefill "
+                             f"({read['decode']:.4g} + {read['prefill']:.4g}): the weights are {weights:.4g}")
+    sizes = set()
+    for leaf in pool.values():
+        layers, blocks = leaf.shape[:2]
+        sizes |= {str(layers * blocks), "%d,%d" % (layers, blocks), str(blocks)}
+    sized = re.compile(r"= \w+\[(%s)," % "|".join(sorted(sizes)))
+    moved = [line.strip()[:160] for line in compiled["mixed"].as_text().splitlines()
+             if sized.search(line) and not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)
+             and "scatter(" not in line and "kv_pool.write/scatter" not in line]
+    if moved:
+        raise AssertionError("the mixed program moves pool-sized arrays besides the scatters: " + " ;; ".join(moved[:4]))
+    return "read_bytes=" + "/".join(f"{read[k]:.4g}" for k in ("decode", "prefill", "mixed")) + f" weights={weights:.4g}"
 
 
 def check_context_assembly(text):
@@ -182,6 +263,10 @@ def check_paged_step(spec, compiled):
         raise AssertionError("the step moves pool-sized arrays: " + " ;; ".join(moved[:4]))
     if sliced and temp > 1.01 * SCANNED_POOL_TEMP_BYTES[spec] + 2**16:
         raise AssertionError(f"temporaries {temp} bytes, {SCANNED_POOL_TEMP_BYTES[spec]} with the pool as a scanned input")
+    import re
+    cuts = [line for line in text.splitlines() if re.search(r"= \w+\[1,%d," % STEP_BLOCKS, line) and " fusion(" in line]
+    if spec.startswith("paged_step_mixed") and sliced and len(cuts) != 2:
+        raise AssertionError(f"two groups cut {len(cuts)} layer slices out of the pool's two leaves, not one a leaf")
     return f"temp_bytes={temp}"
 
 
@@ -189,6 +274,9 @@ for spec in sys.argv[2:]:
     case, hd, b = spec.split(":")
     print("BEGIN", spec, flush=True)   # an abort after this line belongs to this case
     try:
+        if case.startswith("mixed_step"):
+            print("COMPILED", spec, check_mixed_step(case, int(hd)), flush=True)
+            continue
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
         if "tpu_custom_call" not in compiled.as_text() and not case.startswith("paged_step"):
@@ -218,6 +306,7 @@ CASES = [
     # decode and a prefill shape (PERF.md section 6, PR 27) ...
     ("paged_step", 128, 2),
     ("paged_step_prefill", 128, 2),
+    ("paged_step_mixed", 128, 2),  # the decode lanes and the chunk as the two groups of one forward (PR 31)
     ("paged_step", 128, 1),
     ("paged_step", 128, 4),
     ("paged_step", 128, 8),
@@ -226,6 +315,7 @@ CASES = [
     # section 7.0a), the layer's slice costs what it did as a scanned input: Llama-3.2-1B's heads, GPT-2 small's,
     # an odd K, a wide head with few K, an int8 pool at the chat cell's heads and at Llama-3-8B's
     ("paged_step", 64, 8),
+    ("paged_step_mixed", 64, 8),  # two groups slice their layer out of the pool once, not once a group
     ("paged_step", 64, 12),
     ("paged_step", 128, 3),
     ("paged_step", 256, 2),
@@ -235,8 +325,15 @@ CASES = [
     # of the new rows, the grouped expert product a Mosaic kernel fed from the stacked experts where they lie
     ("latent_step", 512, 64),
     ("latent_step_prefill", 512, 64),
+    # serving/programs.py's mixed program (PR 31) at the two serving cells' shapes; the second field is the table width both
+    # groups share: it reads the weights once where decode + prefill read them twice, and every pool leaf in place
+    ("mixed_step_chat", 64, 0),
+    ("mixed_step_chat", 256, 0),
+    ("mixed_step_agent", 16, 0),
+    ("mixed_step_agent", 64, 0),
 ]
-IDS = [f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}" for c, h, b in CASES]
+IDS = [f"{c}-w{h}" if c.startswith("mixed_step") else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}"
+       for c, h, b in CASES]
 
 
 # ``python -c`` puts its working directory first on sys.path: the child
