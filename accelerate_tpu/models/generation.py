@@ -22,6 +22,7 @@ __all__ = [
     "scatter_token_rows", "paged_cache_write", "gather_paged_context", "overlay_new_rows",
     "address_paged_pool_by_layer", "address_paged_leaf_by_layer",
     "unpack_paged_rows_from_scan", "demote_pool_blocks", "promote_pool_blocks",
+    "STATE", "token_leaves", "state_leaves", "with_token_leaves", "read_state_rows", "write_state_rows",
 ]
 
 
@@ -114,18 +115,43 @@ def cache_write(cache_leaf, new_rows: jax.Array, index, dtype):
 # ---------------------------------------------------------------------------
 
 
-def make_paged_pool(init_cache: Callable, config, num_blocks: int, block_size: int) -> dict:
-    """Zeroed block pool derived from a family's own ``init_cache``: every
-    non-``index`` leaf ``[L, 1, block_size, *rest]`` of the batch-1 template
-    becomes ``[L, num_blocks, block_size, *rest]`` (so the int8 codes+scale
-    layout pages exactly like the fp one).  Block 0 is the engine's reserved
-    NULL block — table padding and inactive-slot writes route there, and no
-    allocated region ever reads it."""
+# The two kinds of cache leaf, and the one place that tells them apart.  A family's ``init_cache`` yields **token
+# rows**, ``[L, B, max_len, ...]`` at its top level (K and V per head, latent rows, int8 codes and scales): a row a
+# token, paged by block.  And, where a layer carries something from position to position whatever the length (a
+# short convolution's last inputs), **state** leaves ``[L, B, ...]`` under the key ``STATE``: one entry a sequence,
+# held by decode slot.  The number of layers may differ from leaf to leaf.  A family declares a leaf's kind by where it
+# puts it; nothing is guessed from a shape.
+STATE = "state"
+
+
+def token_leaves(pool: dict) -> dict:
+    """The leaves of a cache or a pool that hold a row a token."""
+    return {name: leaf for name, leaf in pool.items() if name not in (STATE, "index")}
+
+
+def state_leaves(pool: dict) -> dict:
+    """The leaves that hold one entry a sequence (empty for most families)."""
+    return pool.get(STATE, {})
+
+
+def with_token_leaves(pool: dict, fn: Callable) -> dict:
+    """The pool with ``fn`` applied to every token leaf, the state as it is:
+    what moves, copies or scrubs **blocks** goes through here."""
+    return {name: leaf if name == STATE else fn(leaf) for name, leaf in pool.items()}
+
+
+def make_paged_pool(init_cache: Callable, config, num_blocks: int, block_size: int, num_slots: int = 0) -> dict:
+    """Zeroed pool derived from a family's own ``init_cache``.  Every token
+    leaf ``[L, 1, block_size, *rest]`` of the batch-1 template becomes ``[L,
+    num_blocks, block_size, *rest]`` (so the int8 codes+scale layout pages
+    exactly like the fp one).  Block 0 is the engine's reserved NULL block:
+    table padding and inactive-slot writes route there, and no allocated region
+    ever reads it.  Every state leaf ``[L, 1, *rest]`` becomes ``[L, num_slots,
+    *rest]`` under ``STATE``: entry ``s`` belongs to the sequence in decode slot
+    ``s``.  A family without a state gets the pool it always got."""
     template = init_cache(config, 1, block_size)
     pool = {}
-    for name, leaf in template.items():
-        if name == "index":
-            continue
+    for name, leaf in token_leaves(template).items():
         if leaf.ndim < 3 or leaf.shape[1] != 1 or leaf.shape[2] != block_size:
             raise ValueError(
                 f"cache leaf {name!r} has shape {leaf.shape}; paged serving needs "
@@ -137,7 +163,42 @@ def make_paged_pool(init_cache: Callable, config, num_blocks: int, block_size: i
         )
     if not pool:
         raise ValueError("init_cache produced no pageable KV leaves")
+    state = state_leaves(template)
+    if state:
+        if num_slots < 1:
+            raise ValueError(f"the cache holds a state a sequence ({sorted(state)}): the pool needs its number of slots")
+        for name, leaf in state.items():
+            if leaf.ndim < 2 or leaf.shape[1] != 1:
+                raise ValueError(f"state leaf {name!r} has shape {leaf.shape}; a state leaf is [L, B, ...] (batch axis 1)")
+        pool[STATE] = {name: jnp.zeros((leaf.shape[0], num_slots) + leaf.shape[2:], leaf.dtype) for name, leaf in state.items()}
     return pool
+
+
+@jax.named_scope("kv_pool.gather")
+def read_state_rows(leaf: jax.Array, layer: jax.Array, slots: jax.Array, starts: jax.Array) -> jax.Array:
+    """What the sequences in ``slots [B]`` carry into layer ``layer`` of a state
+    leaf ``[L, S, *r]`` -> ``[B, *r]``.  A lane at ``starts == 0`` starts its
+    sequence and reads zeros, whatever its slot's last owner left there (a NaN
+    too: selected, not multiplied), so admission costs no host work."""
+    with jax.named_scope("state_pool"):
+        rows = leaf.at[layer, slots].get(mode="clip")
+        fresh = (starts == 0).reshape((-1,) + (1,) * (rows.ndim - 1))
+        return jnp.where(fresh, jnp.zeros((), rows.dtype), rows)
+
+
+@jax.named_scope("kv_pool.write")
+def write_state_rows(state: dict, rows: dict, slots: jax.Array, counts: jax.Array) -> dict:
+    """The state leaves ``{name: [L, S, *r]}`` with ``rows {name: [B, L, *r]}``
+    written at ``slots [B]``, for the lanes that advanced (``counts > 0``: rows of
+    theirs were real).  Any other lane's entry stays bit for bit: an idle lane
+    computed on padding, and so did the decoding lane of the slot whose chunk
+    rides in the same dispatch as another group."""
+    with jax.named_scope("state_pool"):
+        out = {}
+        for name, leaf in state.items():
+            dst = jnp.where(counts > 0, slots, leaf.shape[1])  # past the leaf: dropped
+            out[name] = leaf.at[:, dst].set(jnp.moveaxis(rows[name], 0, 1).astype(leaf.dtype), mode="drop")
+        return out
 
 
 @jax.named_scope("kv_pool.gather")
@@ -213,7 +274,7 @@ def demote_pool_blocks(pool: dict, blocks) -> dict:
     import numpy as np
 
     idx = jnp.asarray(blocks, jnp.int32)
-    gathered = {name: jnp.take(leaf, idx, axis=1) for name, leaf in pool.items()}
+    gathered = {name: jnp.take(leaf, idx, axis=1) for name, leaf in token_leaves(pool).items()}
     return {name: np.asarray(jax.device_get(g)) for name, g in gathered.items()}
 
 
@@ -224,8 +285,8 @@ def promote_pool_blocks(pool: dict, host_rows: dict, dst_blocks) -> dict:
     :func:`demote_pool_blocks`."""
     dst = jnp.asarray(dst_blocks, jnp.int32)
     return {
-        name: leaf.at[:, dst].set(jnp.asarray(host_rows[name], leaf.dtype))
-        for name, leaf in pool.items()
+        **pool,
+        **{name: leaf.at[:, dst].set(jnp.asarray(host_rows[name], leaf.dtype)) for name, leaf in token_leaves(pool).items()},
     }
 
 

@@ -39,6 +39,17 @@ Supported families and their HF architectures:
                 of ``n_shared_experts`` widths), ``e_score_correction_bias``
                 as ``router_bias``; the leading dense layers and the
                 expert layers land in the ``dense`` and ``moe`` stacks
+- ``lfm2_moe`` — Lfm2MoeForCausalLM (LFM2-8B-A1B): each layer's operator into
+                the stack of its kind (``conv.in_proj`` / ``conv.conv`` /
+                ``conv.out_proj`` -> ``conv/{w_in, taps, w_out}``, the
+                depthwise kernel ``[d, 1, 3]`` as its three taps ``[3, d]``;
+                ``self_attn.*`` with ``q_layernorm`` / ``k_layernorm`` ->
+                ``attn``), ``operator_norm`` / ``ffn_norm`` and the
+                feed-forward part (``feed_forward.w1/w3/w2`` -> gate/up/down;
+                ``feed_forward.gate``, ``expert_bias`` as ``router_bias``, the
+                experts stacked ``[L, E, ...]``) into ``dense`` or ``moe``;
+                ``embedding_norm`` is the final norm and the head is the
+                embedding (a convolution bias or an untied head is refused)
 - ``vit``     — ViTForImageClassification / ViTModel (patch-conv kernel
                 [d, C, p, p] -> the patchify matmul's [p*p*C, d])
 - ``resnet``  — ResNetForImageClassification / ResNetModel (HF's v1.5
@@ -93,7 +104,7 @@ def _stack_cat(sd: dict, fmts: list, n: int, transpose: bool = False) -> np.ndar
 
 def _detect_family(hf_config) -> str:
     mt = getattr(hf_config, "model_type", "")
-    known = {"llama", "gpt2", "bert", "t5", "mixtral", "deepseek_v3", "vit", "resnet"}
+    known = {"llama", "gpt2", "bert", "t5", "mixtral", "deepseek_v3", "lfm2_moe", "vit", "resnet"}
     if mt in ("qwen2", "mistral", "gemma", "phi3"):
         # llama-architecture variants: qwen2 adds Q/K/V biases, mistral is
         # llama-shaped GQA, gemma swaps in GeGLU + (1+w) RMSNorm + sqrt(d)
@@ -319,6 +330,34 @@ def config_from_hf(hf_config, **overrides):
         )
         kw.update(overrides)
         return DeepseekV3Config(**kw)
+    if family == "lfm2_moe":
+        from .lfm2_moe import Lfm2MoeConfig
+
+        if getattr(c, "conv_bias", False) or not getattr(c, "tie_word_embeddings", True):
+            raise ValueError("lfm2_moe import: a convolution bias and an untied head are not implemented (models/lfm2_moe.py)")
+        kw = dict(
+            vocab_size=c.vocab_size,
+            hidden_size=c.hidden_size,
+            intermediate_size=c.intermediate_size,
+            moe_intermediate_size=c.moe_intermediate_size,
+            num_layers=c.num_hidden_layers,
+            layer_types=tuple(c.layer_types),
+            num_dense_layers=c.num_dense_layers,
+            num_heads=c.num_attention_heads,
+            num_kv_heads=c.num_key_value_heads,
+            head_dim=getattr(c, "head_dim", None) or c.hidden_size // c.num_attention_heads,
+            num_experts=c.num_experts,
+            num_experts_per_tok=c.num_experts_per_tok,
+            norm_topk_prob=bool(c.norm_topk_prob),
+            routed_scaling_factor=float(c.routed_scaling_factor),
+            use_expert_bias=bool(c.use_expert_bias),
+            conv_L_cache=c.conv_L_cache,
+            max_seq_len=c.max_position_embeddings,
+            rope_theta=float(c.rope_theta),
+            norm_eps=float(c.norm_eps),
+        )
+        kw.update(overrides)
+        return Lfm2MoeConfig(**kw)
     if family == "resnet":
         from .resnet import ResNetConfig
 
@@ -671,6 +710,65 @@ def _import_deepseek_v3(sd: dict, cfg) -> dict:
     return params
 
 
+def _import_lfm2_moe(sd: dict, cfg) -> dict:
+    from .lfm2_moe import ATTENTION, CONV
+
+    c = cfg
+
+    def stack(layers, fmt, transpose=False):
+        mats = [_np(sd[f"layers.{i}." + fmt]) for i in layers]
+        return np.stack([m.T for m in mats] if transpose else mats)
+
+    of_kind = lambda kind: [i for i, k in enumerate(c.layer_types) if k == kind]
+    dense, moe = range(c.num_dense_layers), range(c.num_dense_layers, c.num_layers)
+    norms = lambda layers: {"ln_op": stack(layers, "operator_norm.weight"), "ln_ffn": stack(layers, "ffn_norm.weight")}
+
+    def experts(which: str) -> np.ndarray:
+        return np.stack([
+            np.stack([_np(sd[f"layers.{i}.feed_forward.experts.{j}.{which}.weight"]).T for j in range(c.num_experts)])
+            for i in moe
+        ])  # [L, E, in, out]
+
+    params = {
+        "embed": _np(sd["embed_tokens.weight"]),
+        "moe": {
+            **norms(moe),
+            "router": stack(moe, "feed_forward.gate.weight", transpose=True),
+            "router_bias": stack(moe, "feed_forward.expert_bias"),
+            "w_gate": experts("w1"), "w_up": experts("w3"), "w_down": experts("w2"),
+        },
+        "final_norm": _np(sd["embedding_norm.weight"]),
+    }
+    head = sd.get("lm_head.weight")  # a torch module's state dict carries the tied head beside the embedding
+    if head is not None and not np.array_equal(_np(head), params["embed"]):
+        raise ValueError("lfm2_moe import: lm_head.weight differs from embed_tokens.weight; the family's head is the embedding")
+    conv, attn = of_kind(CONV), of_kind(ATTENTION)
+    if conv:
+        params["conv"] = {
+            "w_in": stack(conv, "conv.in_proj.weight", transpose=True),  # columns B || C || z
+            # the depthwise kernel [d, 1, 3]: tap j multiplies u of position t - 2 + j (Conv1d, padding 2, cut to T)
+            "taps": np.stack([_np(sd[f"layers.{i}.conv.conv.weight"])[:, 0, :].T for i in conv]),
+            "w_out": stack(conv, "conv.out_proj.weight", transpose=True),
+        }
+    if attn:
+        params["attn"] = {
+            "wq": stack(attn, "self_attn.q_proj.weight", transpose=True),
+            "wk": stack(attn, "self_attn.k_proj.weight", transpose=True),
+            "wv": stack(attn, "self_attn.v_proj.weight", transpose=True),
+            "wo": stack(attn, "self_attn.out_proj.weight", transpose=True),
+            "ln_q": stack(attn, "self_attn.q_layernorm.weight"),
+            "ln_k": stack(attn, "self_attn.k_layernorm.weight"),
+        }
+    if len(dense):
+        params["dense"] = {
+            **norms(dense),
+            "w_gate": stack(dense, "feed_forward.w1.weight", transpose=True),
+            "w_up": stack(dense, "feed_forward.w3.weight", transpose=True),
+            "w_down": stack(dense, "feed_forward.w2.weight", transpose=True),
+        }
+    return params
+
+
 def _import_vit(sd: dict, cfg) -> dict:
     L = cfg.num_layers
     p = cfg.patch_size
@@ -796,6 +894,7 @@ _IMPORTERS = {
     "t5": _import_t5,
     "mixtral": _import_mixtral,
     "deepseek_v3": _import_deepseek_v3,
+    "lfm2_moe": _import_lfm2_moe,
     "vit": _import_vit,
     "resnet": _import_resnet,
 }
@@ -809,6 +908,7 @@ _PREFIXES = {
     "t5": (),
     "mixtral": ("model.",),
     "deepseek_v3": ("model.",),
+    "lfm2_moe": ("model.",),
     "vit": ("vit.",),
     "resnet": ("resnet.",),
 }

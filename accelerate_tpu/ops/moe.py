@@ -160,6 +160,7 @@ def routed_experts(
     scoring: str = "softmax",
     select_bias: Optional[jax.Array] = None,
     normalize: bool = True,
+    normalize_eps: float = 1e-20,
     scale: float = 1.0,
     first_expert: Any = 0,
     compute_dtype: Any = jnp.bfloat16,
@@ -176,8 +177,8 @@ def routed_experts(
     1.2 GB a layer at 128 experts of 2048 x 768, before a row is multiplied).
     Scores are ``softmax`` or ``sigmoid`` of the fp32 router logits; the experts
     are the ``top_k`` of ``scores + select_bias`` (the bias only chooses), the
-    weights are the chosen experts' scores, divided by their sum when
-    ``normalize`` and multiplied by ``scale``.  Rows are sorted by expert and
+    weights are the chosen experts' scores, divided by (their sum +
+    ``normalize_eps``) when ``normalize`` and multiplied by ``scale``.  Rows are sorted by expert and
     each expert's rows run as one group of ``lax.ragged_dot`` (on a TPU a
     grouped-matmul kernel that streams only the experts that have rows):
     compute is exactly ``rows * top_k`` pairs, no capacity, no drops, and a
@@ -204,7 +205,7 @@ def routed_experts(
         _, idx = jax.lax.top_k(choice, top_k)  # [N, k]
         weights = jnp.take_along_axis(scores, idx, axis=-1)
         if normalize:
-            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + normalize_eps)
         weights = weights * scale
 
         n = tokens.shape[0] * top_k

@@ -562,7 +562,12 @@ class PagedKVCache:
     ``init_cache`` is a model family's cache constructor (``models/*.py``);
     the pool leaves are derived from its batch-1 template, so the fp and
     int8-quantized layouts both page without special cases
-    (:func:`accelerate_tpu.models.generation.make_paged_pool`).
+    (:func:`accelerate_tpu.models.generation.make_paged_pool`).  Where the
+    family's cache also holds a **state** a sequence (``generation.STATE``), the
+    pool carries it by decode slot, ``num_slots`` entries a leaf, beside the
+    token rows by block.  ``generation.py`` tells the kinds apart; everything
+    here that counts, copies or mirrors **blocks** asks it for the token leaves
+    (:meth:`token_leaves`), and a state leaf is no block.
 
     With ``num_host_blocks > 0`` (or a later :meth:`enable_host_tier`) the
     cache carries a second, host-DRAM tier mirroring the pool's leaf layout;
@@ -577,6 +582,7 @@ class PagedKVCache:
         num_blocks: int,
         block_size: int,
         num_host_blocks: int = 0,
+        num_slots: int = 0,
     ):
         from ..models.generation import make_paged_pool
 
@@ -584,7 +590,7 @@ class PagedKVCache:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.block_size = block_size
         self.allocator = BlockAllocator(num_blocks)
-        self.pool = make_paged_pool(init_cache, config, num_blocks, block_size)
+        self.pool = make_paged_pool(init_cache, config, num_blocks, block_size, num_slots)
         self.host: Optional[HostBlockPool] = None
         if num_host_blocks:
             self.enable_host_tier(num_host_blocks)
@@ -594,7 +600,7 @@ class PagedKVCache:
         the same leaf layout as the device pool."""
         if self.host is not None:
             raise ValueError("host tier already enabled")
-        self.host = HostBlockPool(self.pool, num_host_blocks)
+        self.host = HostBlockPool(self.token_leaves(), num_host_blocks)
         return self.host
 
     def host_can_fit(self, n: int) -> bool:
@@ -660,18 +666,34 @@ class PagedKVCache:
             self.pool = promote_pool_blocks(self.pool, rows, dst_blocks)
             self.host.free(host_ids)
 
+    def token_leaves(self) -> dict:
+        """The pool's leaves that are paged by block (every leaf, for most families)."""
+        from ..models.generation import token_leaves
+
+        return token_leaves(self.pool)
+
+    def state_leaves(self) -> dict:
+        """The pool's leaves held by decode slot: one entry a sequence (most families have none)."""
+        from ..models.generation import state_leaves
+
+        return state_leaves(self.pool)
+
     @property
     def leaf_names(self) -> list:
-        return sorted(self.pool)
+        return sorted(self.token_leaves())
 
     def pool_bytes(self) -> int:
-        return sum(leaf.size * leaf.dtype.itemsize for leaf in self.pool.values())
+        return sum(leaf.size * leaf.dtype.itemsize for leaf in self.token_leaves().values())
+
+    def state_bytes(self) -> int:
+        return sum(leaf.size * leaf.dtype.itemsize for leaf in self.state_leaves().values())
 
     def block_bytes(self) -> int:
-        """Bytes of pool data behind ONE block across every leaf and layer —
-        the unit of the ``serving.decode_gather_bytes`` accounting."""
-        num_blocks = next(iter(self.pool.values())).shape[1]
+        """Bytes of pool data behind ONE block across every token leaf and
+        layer — the unit of the ``serving.decode_gather_bytes`` accounting."""
+        leaves = self.token_leaves().values()
+        num_blocks = next(iter(leaves)).shape[1]
         return sum(
             (leaf.size // num_blocks) * leaf.dtype.itemsize
-            for leaf in self.pool.values()
+            for leaf in leaves
         )

@@ -143,6 +143,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from ..models.generation import with_token_leaves
 from ..telemetry import annotate, get_telemetry
 from .blocks import (
     NULL_BLOCK,
@@ -349,9 +350,23 @@ class ServingEngine:
     ``apply_cached``/``init_cache`` pair: any family whose cache leaves are
     token rows ``[L, B, max_len, ...]`` — K and V per head (gpt2, llama,
     mixtral; fp or int8) or latent rows without a head axis (deepseek_v3:
-    ``ckv`` and ``kr``, 576 values a token a layer).  A family with an
+    ``ckv`` and ``kr``, 576 values a token a layer).  A family's cache may
+    hold a second kind of leaf beside them, under ``generation.STATE``: a
+    **state** ``[L, B, ...]``, one entry a sequence whatever its length
+    (lfm2_moe: the last two inputs of each short convolution, and K/V rows
+    for its attention layers only).  The pool then carries token rows by
+    block and the state by decode slot, one cache manager for both; a
+    sequence that starts reads a zero state inside the program, so admission
+    and preemption by free-and-re-prefill cost no host work.  What takes a
+    sequence's whole past to be its blocks does not hold for such a family:
+    no prefix cache is built (``stats()["prefix_cache_off"]`` says why;
+    ``ServingConfig.prefix_cache`` keeps its meaning for every other
+    family), and ``host_blocks > 0`` or ``spec_tokens > 0`` is refused at
+    construction.  Whether a family has a state is read from its cache,
+    nowhere else; ``stats()`` then carries ``state_bytes`` and
+    ``state_resets``.  A family with an
     ``apply_paged`` serves on the paged path, experts or not (llama, gpt2,
-    deepseek_v3: its dropless routing is row by row, so a token gets the
+    deepseek_v3, lfm2_moe: dropless routing is row by row, so a token gets the
     same experts whatever the chunk and the batch); one without (mixtral)
     is served by the dense gather program (``serving/programs.py``),
     ``stats()["decode_path"]`` says which.  The token-identity-vs-``generate_loop`` guarantee needs a
@@ -398,8 +413,23 @@ class ServingEngine:
         self.spec_tokens = int(sc.spec_tokens)
         self.cache = PagedKVCache(
             init_cache, config, sc.num_blocks, sc.block_size,
-            num_host_blocks=sc.host_blocks,
+            num_host_blocks=sc.host_blocks, num_slots=sc.max_slots,
         )
+        # Whether the family carries a state a sequence is read from its cache (generation.STATE), nowhere else.
+        # Three features take a sequence's whole past to be its blocks; with a state it is not.  The prefix cache
+        # is not built (a hit would resume at a block boundary, where the state of that boundary is needed:
+        # stats()["prefix_cache_off"] says so).  The host tier and the verify window are refused here rather than
+        # served wrong: a demoted request would come back to another slot's state, and the state after a window
+        # would be the one after its last row, not after the accepted ones.
+        self._state_names = sorted(self.cache.state_leaves())
+        if self._state_names and (sc.host_blocks or sc.spec_tokens):
+            raise ValueError(
+                f"this family's cache holds a state a sequence ({', '.join(self._state_names)}) beside its token rows: "
+                f"host_blocks > 0 (the state does not ride with demoted blocks) and spec_tokens > 0 (the state after "
+                f"the accepted rows is not kept) are not served for it; got host_blocks={sc.host_blocks}, "
+                f"spec_tokens={sc.spec_tokens}"
+            )
+        self.state_resets = 0  # lanes dispatched at position 0, which read a zero state whatever their slot held
         self.sched = Scheduler(
             self.cache.allocator,
             num_slots=sc.max_slots,
@@ -463,7 +493,7 @@ class ServingEngine:
         self._block_bytes = self.cache.block_bytes()
         self._prefix: Optional[PrefixCache] = (
             PrefixCache(self.cache.allocator, sc.block_size)
-            if sc.prefix_cache else None
+            if sc.prefix_cache and not self._state_names else None
         )
         if self.cache.host is not None:
             # Wire the tiering policies in: eviction pressure demotes cold
@@ -508,7 +538,7 @@ class ServingEngine:
         ledger = get_memory_ledger()
         pool_token = ledger.register(
             "serving.kv_pool",
-            tree=self.cache.pool,
+            tree=self.cache.token_leaves(),
             detail={
                 "num_blocks": sc.num_blocks,
                 "block_size": sc.block_size,
@@ -523,6 +553,15 @@ class ServingEngine:
         weakref.finalize(self, ledger.unregister, "serving.kv_pool", pool_token)
         weakref.finalize(self, ledger.unregister, "serving.prefix_cache", prefix_token)
         self._memledger_tokens = (pool_token, prefix_token)
+        if self._state_names:
+            # The state leaves are a reservation of their own: by slot, not by block, so no part of kv_pool.
+            state_token = ledger.register(
+                "serving.state_pool",
+                tree=self.cache.state_leaves(),
+                detail={"max_slots": sc.max_slots, "leaves": self._state_names},
+            )
+            weakref.finalize(self, ledger.unregister, "serving.state_pool", state_token)
+            self._memledger_tokens += (state_token,)
         if self.cache.host is not None:
             # The host tier's backing arrays live for the engine's life, so
             # the reservation is static — and it charges host DRAM, not HBM
@@ -539,7 +578,7 @@ class ServingEngine:
                 },
             )
             weakref.finalize(self, ledger.unregister, "serving.kv_host", host_token)
-            self._memledger_tokens = (pool_token, prefix_token, host_token)
+            self._memledger_tokens += (host_token,)
         self._low_headroom = False
         try:
             self._headroom_watermark_frac = float(
@@ -559,7 +598,7 @@ class ServingEngine:
         # matching the live bucket.  With speculation on, the lanes carry the
         # k+1-window INSTEAD of one token, fed by a host-side drafter.
         self.programs = build_programs(
-            apply_cached, config, self.cache.leaf_names, sc, self.spec_tokens
+            apply_cached, config, self.cache.leaf_names, sc, self.spec_tokens, stateful=bool(self._state_names)
         )
         self.decode_path = self.programs.backend
         self._drafter = None
@@ -1168,10 +1207,10 @@ class ServingEngine:
         # the logits-only injection — can land in the one block every slot's
         # gathered view shares.  Zero is always safe there: null-block rows
         # are only ever read at masked positions.
+        # A state leaf is no block and needs no scrub: its slot's next request starts at position 0 and reads
+        # zeros by a select (generation.read_state_rows), a NaN left there included.
         idx = jnp.asarray(sorted(set(blocks) | {NULL_BLOCK}), jnp.int32)
-        self.cache.pool = {
-            n: leaf.at[:, idx].set(0) for n, leaf in self.cache.pool.items()
-        }
+        self.cache.pool = with_token_leaves(self.cache.pool, lambda leaf: leaf.at[:, idx].set(0))
 
     def _drain_scrubs(self, always_null: bool = False) -> None:
         """Scrub-on-last-release: zero the dirty blocks whose final reference
@@ -1242,10 +1281,7 @@ class ServingEngine:
         leaf so the new owner can keep writing where the shared prefix
         stops.  Runs on the admission path, never inside the decode
         dispatch."""
-        self.cache.pool = {
-            n: leaf.at[:, dst].set(leaf[:, src])
-            for n, leaf in self.cache.pool.items()
-        }
+        self.cache.pool = with_token_leaves(self.cache.pool, lambda leaf: leaf.at[:, dst].set(leaf[:, src]))
 
     def _register_prefix_blocks(self, idx: int) -> None:
         """Publish the slot's freshly prefilled FULL blocks under their chain
@@ -1297,9 +1333,23 @@ class ServingEngine:
             np.zeros((1, self.serving.prefill_chunk), np.int32), np.int32(1),
         ]
         poison = [] if self._poison_ordinal is None else [np.ones((self.serving.max_slots,), np.float32)]
-        for program, args in ((self.programs.decode, lanes), (self.programs.decode_chunk, lanes + chunk)):
+        # with a state: no lane live, and a chunk of no real row in slot 0: nothing is written by slot
+        state, chunk_state = self._state_args([], None), self._state_args([], 0)
+        if state:
+            chunk[-1] = np.int32(0)
+        for program, args in ((self.programs.decode, lanes + state), (self.programs.decode_chunk, lanes + chunk + chunk_state)):
             _, self.cache.pool = program(self.params, self.cache.pool, *args, *poison)
         return True
+
+    def _state_args(self, live: List[int], chunk_slot: Optional[int]) -> list:
+        """What a program of a family with a state takes behind its other
+        arguments: ``live [S]`` (1 where a lane decodes) and, with a chunk, the
+        slot it prefills.  Nothing for any other family."""
+        if not self.programs.stateful:
+            return []
+        flags = np.zeros((self.serving.max_slots,), np.int32)
+        flags[live] = 1
+        return [flags] if chunk_slot is None else [flags, np.int32(chunk_slot)]
 
     def _idle_lanes(self, width: int) -> list:
         """``[tables, lengths, tokens, draft_len]`` of a dispatch none of whose
@@ -1422,6 +1472,9 @@ class ServingEngine:
             table_row = np.zeros((width,), np.int32)
             table_row[: len(chunk.slot.blocks)] = chunk.slot.blocks
             args += [table_row, np.int32(chunk.start), chunk.tokens, np.int32(chunk.n_real)]
+        if programs.stateful:
+            args += self._state_args(live, chunk.idx if chunk else None)
+            self.state_resets += bool(chunk and chunk.start == 0)
         if self._poison_ordinal is not None:
             # Armed: the program was traced with the poison lane.  NaN rides
             # into exactly one slot's logits on that request's first decode
@@ -1823,6 +1876,19 @@ class ServingEngine:
             raise RuntimeError("tracing is disabled on this engine")
         return export_chrome_trace(path, self.tracer.traces())
 
+    def _state_stats(self) -> dict:
+        """What ``stats()`` says of a family whose cache holds a state a
+        sequence; a family without one carries none of these keys."""
+        if not self._state_names:
+            return {}
+        out = {"state_bytes": self.cache.state_bytes(), "state_resets": self.state_resets}
+        if self.serving.prefix_cache:
+            out["prefix_cache_off"] = (
+                f"the cache holds a state a sequence ({', '.join(self._state_names)}): a prefix hit would resume at a "
+                f"block boundary without the state of that boundary, so no prefix cache is built"
+            )
+        return out
+
     def stats(self) -> dict:
         alloc = self.cache.allocator
         return {
@@ -1840,6 +1906,7 @@ class ServingEngine:
             "deadline_expired": self.deadline_expired_count,
             "quarantined": self.quarantined_count,
             "pool_bytes": self.cache.pool_bytes(),
+            **self._state_stats(),
             "free_pool_bytes": alloc.free_blocks * self._block_bytes,
             "decode_path": self.decode_path,
             "decode_gather_bytes": self.decode_gather_bytes,

@@ -16,7 +16,24 @@ wrote.  A group is what shares one ``T``: the decoding lanes (``T`` 1, or ``k
 (``_write_rows``) after it has read the logits, so the new pool is the last
 thing a program computes.
 
-- **paged** (a family with an ``apply_paged``: gpt2, llama, deepseek_v3):
+The pool holds two kinds of leaf (``models/generation.py`` tells them apart):
+**token rows** by block, which every family has, and, for a family whose cache
+says so (``lfm2_moe``: its short convolutions), a **state** by decode slot: one
+entry a sequence, whatever its length.  For such a family a group carries two
+things more, ``(tokens, tables, starts, slots [B], counts [B])``: which slot's
+state a lane reads and writes (the decoding lanes are the slots in order; the
+chunk's lane is told its slot), and how many of its ``T`` rows are real (a
+decoding lane 1, a chunk its ``n_real``, a lane that is not live 0).  What the
+forward returns under ``generation.STATE`` is the state *after row ``counts -
+1``*, and ``_write_rows`` writes it by slot for the lanes with ``counts > 0``
+alone: an idle lane, and the decoding lane of the slot whose chunk rides in
+the same dispatch, computed on padding and leave their entry bit for bit.  A
+lane at ``starts == 0`` reads a zero state inside the program, so a slot's
+next request never sees its predecessor and admission costs no host work.  A
+family without a state compiles to the programs it always had: no argument,
+no operation more.
+
+- **paged** (a family with an ``apply_paged``: gpt2, llama, deepseek_v3, lfm2_moe):
   the family runs everything that does not look at the cache (embedding,
   norms, projections, the MLP or the experts, the head) once over the rows of
   all groups, so the weights, and the experts the rows hit, stream once a
@@ -39,8 +56,10 @@ the chunk's own ``ok``; a chunk with no live decoder rides it with the lanes
 idle, which only a cold start sees).  Both groups of ``decode_chunk`` share
 one table width, the wider of the two needs.  What a program returns beside
 the pool is one int32 vector, so a tick reads one array back
-(:meth:`ServingPrograms.unpack`).  Both take a trailing per-lane poison vector
-when the NaN fault is armed; an unarmed program is traced without it.
+(:meth:`ServingPrograms.unpack`).  A family with a state takes its lanes' ``live``
+flags (and ``decode_chunk`` the chunk's slot) behind those arguments.  Both
+take a trailing per-lane poison vector when the NaN fault is armed; an unarmed
+program is traced without it.
 
 The profile names a program after its Python function (``jit_decode``,
 ``jit_decode_chunk``) and ``chipbench/`` selects operations by the prefix
@@ -59,10 +78,12 @@ import jax
 import jax.numpy as jnp
 
 from ..models.generation import (
+    STATE,
     extract_token_rows,
     gather_block_view,
     scatter_token_rows,
     speculative_verify_greedy,
+    write_state_rows,
 )
 
 __all__ = ["MOE_COUNTERS", "ServingPrograms", "build_programs"]
@@ -84,8 +105,9 @@ class ServingPrograms:
     what a tick has to know about the back end they were built for."""
 
     backend: str  # "paged" | "dense": what the family decided
-    decode: Callable  # (params, pool, tables [S, M], lengths [S], tokens [S, W], draft_len [S], *poison)
-    decode_chunk: Callable  # (..., draft_len [S], table_row [M], start, chunk [1, C], n_real, *poison)
+    decode: Callable  # (params, pool, tables [S, M], lengths [S], tokens [S, W], draft_len [S], *state, *poison)
+    decode_chunk: Callable  # (..., draft_len [S], table_row [M], start, chunk [1, C], n_real, *state, *poison)
+    stateful: bool  # the pool holds a state by slot: *state is (live [S],), with a chunk (live [S], slot)
     window: int  # W: 1, or k + 1 under speculation
     max_slots: int
     min_blocks: int  # the narrowest table: MIN_TABLE_ROWS in blocks, a power of two
@@ -133,19 +155,25 @@ class ServingPrograms:
         return out
 
 
-def build_programs(apply_cached: Callable, config, leaf_names, serving, spec_tokens: int) -> ServingPrograms:
+def build_programs(
+    apply_cached: Callable, config, leaf_names, serving, spec_tokens: int, stateful: bool = False
+) -> ServingPrograms:
     """The programs of an engine that serves ``apply_cached``'s family over a
-    pool with leaves ``leaf_names``, at ``serving``'s geometry."""
+    pool with token leaves ``leaf_names`` (and, ``stateful``, a state by slot),
+    at ``serving``'s geometry."""
     apply_paged = getattr(inspect.getmodule(apply_cached), "apply_paged", None)
     if apply_paged is not None:
         backend, forward = "paged", _paged_forward(apply_paged, config)
+    elif stateful:
+        raise ValueError("a family whose cache holds a state a sequence is served by its apply_paged: it has none")
     else:
         backend, forward = "dense", _dense_forward(apply_cached, config, leaf_names)
-    decode, decode_chunk = _heads(forward)
+    decode, decode_chunk = _heads(forward, stateful)
     return ServingPrograms(
         backend=backend,
         decode=jax.jit(decode, donate_argnums=(1,)),
         decode_chunk=jax.jit(decode_chunk, donate_argnums=(1,)),
+        stateful=stateful,
         window=spec_tokens + 1,
         max_slots=serving.max_slots,
         min_blocks=1 << max(0, -(-MIN_TABLE_ROWS // serving.block_size) - 1).bit_length(),
@@ -183,14 +211,21 @@ def _dense_forward(apply_cached: Callable, config, names) -> Callable:
     return forward
 
 
-def _write_rows(pool: dict, rows: dict, tables, starts, count: int) -> dict:
-    """The pool with the rows a forward wrote for one group scattered in.
-    Rows past a lane's accepted length (a verify window) or past a chunk's
-    real tokens are stale by construction: the next dispatch at that position
-    re-writes them before any mask admits them."""
+def _write_rows(pool: dict, rows: dict, tables, starts, count: int, *lanes) -> dict:
+    """The pool with what a forward wrote for one group written in.  Token
+    rows are scattered through the group's tables: rows past a lane's accepted
+    length (a verify window) or past a chunk's real tokens are stale by
+    construction, the next dispatch at that position re-writes them before any
+    mask admits them.  A state is no row: nothing re-writes it, so what comes
+    back is already the state after the lane's last *real* row, and it is
+    written by slot (``lanes`` = the group's slots and counts) for the lanes
+    that advanced alone (``write_state_rows``)."""
     new_pool = dict(pool)
     for n, r in rows.items():
-        new_pool[n] = scatter_token_rows(pool[n], r, tables, starts, count)
+        if n == STATE:
+            new_pool[n] = write_state_rows(pool[n], r, *lanes)
+        else:
+            new_pool[n] = scatter_token_rows(pool[n], r, tables, starts, count)
     return new_pool
 
 
@@ -231,26 +266,36 @@ def _lanes_head(logits, tokens, draft_len, poison):
     return [t, m], ok
 
 
-def _heads(forward: Callable):
+def _heads(forward: Callable, stateful: bool = False):
     """``decode`` and ``decode_chunk`` over ``forward``: the names are the
     profile's (``jit_decode``, ``jit_decode_chunk``).  ``draft_len`` is read
-    by a ``k + 1`` window only (jit drops an argument nothing reads)."""
+    by a ``k + 1`` window only (jit drops an argument nothing reads).  With a
+    state, ``rest`` leads with ``live [S]`` (1 where a lane decodes) and, in
+    ``decode_chunk``, the chunk's slot, and the groups carry (slots, counts)."""
 
-    def decode(params, pool, tables, lengths, tokens, draft_len, *poison):
-        (logits,), counters, (rows,) = forward(params, pool, ((tokens, tables, lengths),))
-        parts, ok = _lanes_head(logits, tokens, draft_len, poison)
-        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1])
+    def decode(params, pool, tables, lengths, tokens, draft_len, *rest):
+        state = ()
+        if stateful:
+            live, *rest = rest
+            state = (jnp.arange(tokens.shape[0], dtype=jnp.int32), live)
+        (logits,), counters, (rows,) = forward(params, pool, ((tokens, tables, lengths, *state),))
+        parts, ok = _lanes_head(logits, tokens, draft_len, rest)
+        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state)
         return _packed([*parts, ok], counters), new_pool
 
-    def decode_chunk(params, pool, tables, lengths, tokens, draft_len, table_row, start, chunk, n_real, *poison):
+    def decode_chunk(params, pool, tables, lengths, tokens, draft_len, table_row, start, chunk, n_real, *rest):
         chunk_tables, chunk_starts = table_row[None], start[None]
-        groups = ((tokens, tables, lengths), (chunk, chunk_tables, chunk_starts))
+        state = chunk_state = ()
+        if stateful:
+            live, slot, *rest = rest
+            state, chunk_state = (jnp.arange(tokens.shape[0], dtype=jnp.int32), live), (slot[None], n_real[None])
+        groups = ((tokens, tables, lengths, *state), (chunk, chunk_tables, chunk_starts, *chunk_state))
         (logits, chunk_logits), counters, (rows, chunk_rows) = forward(params, pool, groups)
-        parts, ok = _lanes_head(logits, tokens, draft_len, poison)
+        parts, ok = _lanes_head(logits, tokens, draft_len, rest)
         chunk_token = jnp.argmax(chunk_logits[0, n_real - 1], axis=-1)
         chunk_ok = jnp.all(jnp.isfinite(chunk_logits))
-        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1])
-        new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1])
+        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state)
+        new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1], *chunk_state)
         return _packed([*parts, ok, chunk_token, chunk_ok], counters), new_pool
 
     return decode, decode_chunk

@@ -223,6 +223,49 @@ def check_mixed_step(case, width):
     return "read_bytes=" + "/".join(f"{read[k]:.4g}" for k in ("decode", "prefill", "mixed")) + f" weights={weights:.4g}"
 
 
+def check_state_step(width):
+    # serving/programs.py's decode and decode_chunk for models/lfm2_moe.py at the cut the benchmark serves (14 of the 24
+    # published layers, published widths, the cell's geometry): K/V token rows for the 3 attention layers as rows of 512 =
+    # 8 heads x 64 without a head axis, [3, 8192, 16, 512], beside the state by slot [11, 32, 2, 2048].  The rows of 512 are
+    # read where they lie (the other geometry, [.., 8, 64], is paged_step:64:8 above: the block axis in the lanes, a layer's
+    # slice cut out a dispatch): no result of either program is as large as a K/V leaf or a layer's slice of one but the
+    # scatters of the new rows.  The state is written by slot, a leaf of 2.9 MB.  And the experts' grouped product reads
+    # the stack where it lies, under the lax.cond that picks the layer's operator.
+    import re
+    from accelerate_tpu.models import lfm2_moe as lf
+    from accelerate_tpu.models.generation import STATE, make_paged_pool
+    from accelerate_tpu.serving import ServingConfig, programs as P
+
+    blocks, slots, chunk_rows = 8192, 32, 32
+    c = lf.Lfm2MoeConfig(num_layers=14, layer_types=lf.PUBLISHED_LAYER_TYPES[:14], dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False)
+    place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: lf.init_params(c, jax.random.key(0))))
+    pool = place(jax.eval_shape(lambda: make_paged_pool(lf.init_cache, c, blocks, 16, slots)))
+    assert {k: v.shape for k, v in pool.items() if k != STATE} == {"k": (3, blocks, 16, 512), "v": (3, blocks, 16, 512)}
+    assert pool[STATE]["conv"].shape == (11, slots, 2, 2048)
+    serving = ServingConfig(block_size=16, num_blocks=blocks, max_slots=slots, max_blocks_per_seq=128, prefill_chunk=chunk_rows)
+    built = P.build_programs(lf.apply_cached, c, ["k", "v"], serving, 0, stateful=True)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    lanes = (i32(slots, width), i32(slots), i32(slots, 1), i32(slots))
+    chunk = (i32(width), i32(), i32(1, chunk_rows), i32())
+    programs = {"decode": (built.decode, (*lanes, i32(slots))), "decode_chunk": (built.decode_chunk, (*lanes, *chunk, i32(slots), i32()))}
+    sized = re.compile(r"= \w+\[(%d|3,%d|%d)," % (3 * blocks, blocks, blocks))
+    cut = re.compile(r"= \w+\[32,(2048,1792|1792,2048)\]")
+    temps = []
+    for name, (program, args) in programs.items():
+        compiled = program.lower(params, pool, *args).compile()
+        text = compiled.as_text()
+        lines = [line for line in text.splitlines() if not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
+        moved = [line.strip()[:160] for line in lines if sized.search(line) and "scatter(" not in line and "kv_pool.write/scatter" not in line]
+        if moved:
+            raise AssertionError(f"{name} moves pool-sized arrays besides the scatter of the new rows: " + " ;; ".join(moved[:4]))
+        experts = [line.strip()[:160] for line in lines if cut.search(line)]
+        if experts or "ragged-dot" not in text:
+            raise AssertionError(f"{name}: a layer's experts are cut out of the stack, or no grouped product: " + " ;; ".join(experts[:3]))
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+    return "temp_bytes=" + "/".join(map(str, temps))
+
+
 def check_context_assembly(text):
     # A decode step assembles its context in one pass (PR 29): under kv_pool.gather the blocks are gathered and the new
     # rows scattered into them, a row-sized write.  Nothing else there is as large as the context: no select over it (the
@@ -276,6 +319,9 @@ for spec in sys.argv[2:]:
     try:
         if case.startswith("mixed_step"):
             print("COMPILED", spec, check_mixed_step(case, int(hd)), flush=True)
+            continue
+        if case == "state_step":
+            print("COMPILED", spec, check_state_step(int(hd)), flush=True)
             continue
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
@@ -331,8 +377,11 @@ CASES = [
     ("mixed_step_chat", 256, 0),
     ("mixed_step_agent", 16, 0),
     ("mixed_step_agent", 64, 0),
+    # models/lfm2_moe.py through serving/programs.py at the cut the benchmark serves (PR 32; the second field is the table
+    # width): K/V rows of 512 without a head axis beside a state by slot; nothing pool-sized but the scatters of the new rows
+    ("state_step", 64, 0),
 ]
-IDS = [f"{c}-w{h}" if c.startswith("mixed_step") else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}"
+IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step")) else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}"
        for c, h, b in CASES]
 
 
