@@ -23,8 +23,55 @@ One engine **tick** (:meth:`ServingEngine.step`) is:
    along; ``prefill_dispatches + decode_dispatches - mixed_dispatches`` is
    the number of dispatches, one a tick).  A prompt whose last chunk rode
    in this dispatch starts decoding in the next tick;
-4. **emit** — the chunk's bookkeeping (prefix registration, the first token
-   after a last chunk), then every lane's tokens.
+4. **read back the tick before** — the one sync of the step waits for tick
+   N - 1's vector while tick N runs;
+5. **emit** — of tick N - 1: the chunk's bookkeeping (prefix registration,
+   the first token after a last chunk), then every lane's tokens.
+
+**A tick is read back one dispatch late.**  A request finishes by count (the
+engine has no stop token), a decoding lane advances by one row a tick, block
+growth and tables depend on lengths and not on token values, and a chunk's
+rows are the prompt's: so everything tick N + 1's build needs from tick N is
+known when N is *dispatched*, except the token values, and those the device
+has.  They stay there: each program returns a small ``feed`` (every lane's
+next token, and the chunk's) and takes the previous dispatch's with a
+per-lane ``source`` (``serving/programs.py``).  So ``step()`` builds tick N
+from what is known at N - 1's dispatch, launches it, and only then reads
+N - 1's vector and runs N - 1's emit with the values; while the host reads,
+emits, publishes, returns to the caller, takes new requests and builds, the
+device has a program queued behind the one it runs.  Booked at dispatch, by
+count: a lane's ``cache_len`` + 1 and its token (``_Slot.unread``), a final
+chunk's slot turning ``DECODING`` (it rides the next tick reading the chunk's
+entry of the feed), and the slot whose last token this is **retiring**: out
+of the next tick, its index free for admission, its blocks its own until the
+token is read.  Left to the read-back: the values (``Request.emitted``,
+``note_token`` with the real clock, the journal, the tracer, completion:
+**a reply is handed over when its last token is read, one dispatch after
+that token's program was launched**), the ``ok`` flags, prefix registration
+(a chunk's blocks are published once its flag has read true) and the expert
+counters.
+
+**Settles.**  One method, ``_settle(reason)``, reads the tick in flight back
+and applies it; every path that needs a token's value, or re-queues or
+releases a request with a token unread, calls it first: a preemption or
+migration victim (``preempt``: it re-prefills ``prompt + emitted``; also a
+block shortage before anyone is evicted, since the read-back returns the
+retiring lanes' blocks), deadline expiry of a live request (``deadline``),
+:meth:`~ServingEngine.drain` (``drain``), :meth:`~ServingEngine.recover_from_journal`
+(``recover``), a poisoned lane (``quarantine``: its flag is read one
+dispatch late, the tick in flight computed one row more for it, which went
+to its own blocks and is dropped; the scrub is ordered behind it on the
+device's one stream), a step that leaves nothing to dispatch behind the tick
+in flight (``idle``: the last replies are handed over at once, so
+:meth:`~ServingEngine.run` ends with nothing unread) and
+:meth:`~ServingEngine.stats` (``stats``).
+These are rare: the steady state never settles
+(``stats()["pipelined_ticks"]``, ``["settles"]``).  **A verify-window engine
+is the synchronous one**: with ``spec_tokens > 0`` the accepted count decides
+``cache_len`` and the drafter reads the tokens, so every tick settles
+(``spec``) before the next is built.  That is observed
+(``programs.window > 1``), not configured: one engine, whose depth (one tick
+ahead, or none) follows from what it sees.
 
 ``serving/programs.py`` builds the two programs and owns how a dispatch reads
 the pool; the family decides which of its two back ends serves.  A family
@@ -153,7 +200,7 @@ from .blocks import (
     blocks_for_tokens,
 )
 from .journal import JournalError, ServingJournal
-from .programs import MOE_COUNTERS, build_programs
+from .programs import FEED_CHUNK, FEED_LANE, MOE_COUNTERS, build_programs
 from .scheduler import Request, RequestState, Scheduler
 from .tracing import ServingTracer, resolve_trace_dir, tracing_enabled
 
@@ -313,12 +360,30 @@ class _Chunk(NamedTuple):
 
 
 class _Lanes(NamedTuple):
-    """The decode batch a tick built: the live slots, oldest first, and the
-    window of tokens (last emitted, then drafts) of every lane."""
+    """The decode batch a tick built: the live slots, oldest first, the window
+    of tokens (last emitted, then drafts) of every lane, and where each lane's
+    first token comes from (``programs.FEED_*``: the host's value here, or the
+    feed of the tick in flight, for a token the host has not read)."""
 
     live: List[int]
     tokens: np.ndarray
     draft_len: np.ndarray
+    source: np.ndarray
+
+
+class _Flight(NamedTuple):
+    """A tick dispatched and not yet read back: what its program returned (on
+    the device, the vector's copy to the host started) and what the read-back
+    needs of the tick's shape."""
+
+    packed: object  # the program's one int32 vector (ServingPrograms.unpack)
+    chunk: Optional[_Chunk]
+    final: bool  # the chunk ended its prompt: its token is the request's next
+    lanes: list  # the decoding lanes' slots, oldest first
+    draft_len: np.ndarray
+    width: int
+    fresh: bool
+    t0: float  # the launch, for dispatch_ms
 
 
 class _TickPhase:
@@ -326,7 +391,8 @@ class _TickPhase:
     profiler's timeline (``telemetry.annotate``; ``with`` gives the span, for
     ``set_metadata``), and its milliseconds in the tick's record for
     ``ServingTracer``'s slow ticks.  Phases are counted back to back, each
-    from the end of the one before, so they sum to the tick."""
+    from the end of the one before, so they sum to the tick; a name met twice
+    in a tick (a settle inside it reads a second time) adds up."""
 
     __slots__ = ("engine", "name", "span")
 
@@ -340,7 +406,8 @@ class _TickPhase:
     def __exit__(self, *exc) -> bool:
         self.span.__exit__(*exc)
         engine, now = self.engine, time.monotonic()
-        engine._tick["phase_ms"][self.name] = (now - engine._phase_t0) * 1e3
+        phase_ms = engine._tick.setdefault("phase_ms", {})
+        phase_ms[self.name] = phase_ms.get(self.name, 0.0) + (now - engine._phase_t0) * 1e3
         engine._phase_t0 = now
         return False
 
@@ -461,6 +528,14 @@ class ServingEngine:
         # Ticks whose chunk rode in the decode's forward.  A tick issues
         # (prefill + decode - mixed) dispatches: one, whatever it holds.
         self.mixed_dispatches = 0
+        # The tick dispatched and not yet read back (None after a settle), the dispatches made with the one before
+        # them still unread, and the settles by reason.
+        self._flight: Optional[_Flight] = None
+        # What the last dispatch returned as its feed, on the device: the next dispatch's, whose lanes read it where
+        # their source says so (after a settle none does).  Zeros made as the pool's leaves are, until the first.
+        self._feed = jnp.zeros((sc.max_slots + 1,), jnp.int32)
+        self.pipelined_ticks = 0
+        self.settles: Dict[str, int] = {}
         self.shed_count = 0
         self.deadline_expired_count = 0
         self.quarantined_count = 0
@@ -503,6 +578,7 @@ class ServingEngine:
             if self._prefix is not None:
                 self._prefix.attach_tier(self.cache)
             self.sched.on_migrate_out = self._migrate_out
+        self.sched.settle = self._settle
         # Per-request phase tracing (host-side interval bookkeeping only).
         # The scheduler's preemption callback is the one eviction site every
         # preemption flavor funnels through (drain, block pressure, LIFO
@@ -621,7 +697,7 @@ class ServingEngine:
                 "serving.quarantined", "serving.journal_recoveries",
                 "serving.prefix_hits", "serving.prefix_blocks_reused",
                 "serving.prefix_cow_copies", "serving.decode_gather_bytes",
-                "serving.mixed_dispatches",
+                "serving.mixed_dispatches", "serving.pipelined_ticks", "serving.settles",
                 "serving.spec.proposed", "serving.spec.accepted",
                 "serving.spec.rounds",
                 "serving.tier.demotions", "serving.tier.promotions",
@@ -736,15 +812,17 @@ class ServingEngine:
 
     def step(self) -> List[CompletedRequest]:
         """One engine tick: admit, build one prefill chunk and the decode
-        batch, ONE fused dispatch of both.  Returns the requests that
-        completed this tick.  With an
+        batch, ONE fused dispatch of both, then the read-back of the tick
+        *before* (under a verify window: of this one too).  Returns the
+        requests that completed during the call: those whose last token was
+        read.  With an
         installed :class:`PreemptionGuard` whose signal has arrived, the
         tick drains instead (no admission, no dispatch)."""
         now = time.monotonic()
         done_before = len(self._finished)
         if self._drained or self._drain_requested():
             self.drain()
-            return []
+            return self._finished[done_before:]
         self.ticks += 1
         states = [slot.request.state for slot in self.sched.slots.values()]
         # What the tick was doing, for ServingTracer's slow-tick record:
@@ -752,7 +830,8 @@ class ServingEngine:
         self._tick = tick = {
             "tick": self.ticks,
             "prefilling": states.count(RequestState.PREFILLING),
-            "live": 0, "width": None, "fresh": False, "mixed": False, "phase_ms": {},
+            "live": 0, "width": None, "fresh": False, "mixed": False, "pipelined": False, "settle": None,
+            "phase_ms": {},
         }
         self._phase_t0 = now
         with annotate(
@@ -793,8 +872,14 @@ class ServingEngine:
             batch = self._build_decode()
             if chunk is not None and self.sched.slots.get(chunk.idx) is not chunk.slot:
                 chunk = None
-            if chunk is not None or batch is not None:
+            dispatched = chunk is not None or batch is not None
+            if dispatched:
                 self._dispatch_tick(chunk, batch)
+            if self._flight is not None and not (dispatched and (self.sched.slots or self.sched.queue)):
+                # Nothing was, or nothing is left to be, dispatched behind the tick in flight (its lanes are
+                # retiring; or the queue's head waits for the blocks they hold): the next step() could only wait
+                # for it, so it is read now and the replies are handed over.
+                self._settle("idle")
             with annotate("serving.tick.publish", tick=self.ticks):
                 self._drain_scrubs()
                 self._publish_gauges()
@@ -804,7 +889,7 @@ class ServingEngine:
                     end = time.monotonic()
                     tick["phase_ms"]["publish"] = (end - self._phase_t0) * 1e3
                     tick["total_ms"] = (end - now) * 1e3
-                    self.tracer.end_tick(end, self.sched.slots, tick)
+                    self.tracer.end_tick(end, self.sched.slots, tick, unread=self._unread_requests())
         return self._finished[done_before:]
 
     def run(self, max_ticks: Optional[int] = None) -> Dict[int, List[int]]:
@@ -850,6 +935,7 @@ class ServingEngine:
         ``serving.drained`` event.  Idempotent; returns the journal."""
         if self._drained:
             return self.requeue_journal or []
+        self._settle("drain")  # the journal carries every token dispatched
         # Migration is pointless past this line: host DRAM dies with the
         # process, so demoting a drained slot would spend a D2H copy on
         # bytes no successor can read — and leak the host blocks at exit.
@@ -930,6 +1016,7 @@ class ServingEngine:
                 "this engine already overwrote the journal at "
                 f"{path!r}; recover_from_journal must run before the first submit"
             )
+        self._settle("recover")
         state = ServingJournal.load(path)
         pending = ServingJournal.pending(state)
         mapping: Dict[int, int] = {}
@@ -1133,6 +1220,10 @@ class ServingEngine:
         for req in expired_queued:
             self.sched.cancel_queued(req)
             self._finish_expired(req, now)
+        if any(slot.request.expired(now) for slot in self.sched.slots.values()):
+            # A live request is cancelled with every token dispatched for it: read the tick in flight first (a
+            # first token among them meets a TTFT deadline late rather than never).  A queued one has none unread.
+            self._settle("deadline")
         for idx in list(self.sched.slots):
             req = self.sched.slots[idx].request
             if req.expired(now):
@@ -1159,7 +1250,7 @@ class ServingEngine:
                 )
         self._complete(req, status="deadline_expired")
 
-    def _quarantine(self, idx: int, now: float) -> None:
+    def _quarantine(self, slot, now: float) -> None:
         """A slot's logits came back non-finite: complete its request with an
         error status and mark its pool blocks for a zero-scrub.  The scrub is
         load-bearing, not hygiene — the attention mask zeroes a hidden row's
@@ -1173,12 +1264,17 @@ class ServingEngine:
         reader's own finiteness check guards it — if the shared content were
         truly poisoned, that reader quarantines itself the same way).  The
         block is dropped from the prefix cache immediately, so no NEW reader
-        can attach to it."""
-        slot = self.sched.slots[idx]
+        can attach to it.
+
+        The flag is read one dispatch late: the tick already in flight
+        computed one row more for this slot (or its next chunk).  That row
+        went to the slot's own blocks, the scrub is ordered behind it on the
+        device's one stream, and its tokens are dropped at their read-back
+        (the request is ``DONE``)."""
         if self._prefix is not None:
             self._prefix.invalidate_blocks(slot.blocks)
         self.cache.allocator.mark_dirty(slot.blocks)
-        req = self.sched.finish(idx, now)
+        req = self.sched.release(slot, now)
         # Defensive: a slotted request holds no demoted blocks by invariant
         # (promotion clears them at admission), but if any exist they route
         # through the host tier's dirty scrub — the two-tier contract.
@@ -1283,20 +1379,18 @@ class ServingEngine:
         dispatch."""
         self.cache.pool = with_token_leaves(self.cache.pool, lambda leaf: leaf.at[:, dst].set(leaf[:, src]))
 
-    def _register_prefix_blocks(self, idx: int) -> None:
+    def _register_prefix_blocks(self, slot, rows: int) -> None:
         """Publish the slot's freshly prefilled FULL blocks under their chain
-        hashes.  Only blocks entirely below ``cache_len`` (real rows — the
-        padded tail of a chunk never counts) are registered, and writes only
-        move forward from ``cache_len``, so a registered block is never
+        hashes.  Only blocks entirely below ``rows`` (the real rows of the
+        chunk just read back — the padded tail of a chunk never counts, nor
+        a later chunk dispatched and not yet read) are registered, and writes only
+        move forward from there, so a registered block is never
         written again."""
         if self._prefix is None:
             return
-        slot = self.sched.slots.get(idx)
-        if slot is None:
-            return
         bs = self.serving.block_size
         feed = slot.request.to_feed
-        full = min(slot.cache_len, len(feed)) // bs
+        full = min(rows, len(feed)) // bs
         if full <= slot.registered_blocks:
             return
         keys = PrefixCache.chain_keys(feed, bs, limit=full)
@@ -1338,7 +1432,7 @@ class ServingEngine:
         if state:
             chunk[-1] = np.int32(0)
         for program, args in ((self.programs.decode, lanes + state), (self.programs.decode_chunk, lanes + chunk + chunk_state)):
-            _, self.cache.pool = program(self.params, self.cache.pool, *args, *poison)
+            _, _, self.cache.pool = program(self.params, self.cache.pool, *args, *poison)
         return True
 
     def _state_args(self, live: List[int], chunk_slot: Optional[int]) -> list:
@@ -1352,12 +1446,15 @@ class ServingEngine:
         return [flags] if chunk_slot is None else [flags, np.int32(chunk_slot)]
 
     def _idle_lanes(self, width: int) -> list:
-        """``[tables, lengths, tokens, draft_len]`` of a dispatch none of whose
-        lanes is live: every table names the null block alone."""
+        """``[tables, lengths, tokens, draft_len, feed, source]`` of a dispatch
+        none of whose lanes is live: every table names the null block alone,
+        every lane reads the host's token (``FEED_HOST`` is 0), and the feed is
+        the last dispatch's, as in every dispatch (one signature a program)."""
         s = self.serving.max_slots
         return [
             np.zeros((s, width), np.int32), np.zeros((s,), np.int32),
             np.zeros((s, self.programs.window), np.int32), np.zeros((s,), np.int32),
+            self._feed, np.zeros((s,), np.int32),
         ]
 
     def _build_chunk(self) -> Optional[_Chunk]:
@@ -1436,22 +1533,33 @@ class ServingEngine:
             s = self.serving.max_slots
             tokens = np.zeros((s, window), np.int32)
             draft_len = np.zeros((s,), np.int32)
+            source = np.zeros((s,), np.int32)
+            flight = self._flight  # read here, after the growth: a preemption in it settles
             for idx in live:
-                tokens[idx, 0] = sched.slots[idx].request.emitted[-1]
+                slot = sched.slots[idx]
+                if slot.unread:
+                    # The lane's last token is a value on the device alone: the tick in flight holds it, in the
+                    # chunk's place if the lane's prompt ended there, else in the lane's own.
+                    source[idx] = FEED_CHUNK if flight.final and flight.chunk.slot is slot else FEED_LANE
+                else:
+                    tokens[idx, 0] = slot.request.emitted[-1]
                 d = drafts.get(idx)
                 if d:
                     tokens[idx, 1 : 1 + len(d)] = d
                     draft_len[idx] = len(d)
             span.set_metadata(live=len(live), drafted=len(drafts))
-            return _Lanes(live, tokens, draft_len)
+            return _Lanes(live, tokens, draft_len, source)
 
     def _dispatch_tick(self, chunk: Optional[_Chunk], batch: Optional[_Lanes]) -> None:
         """The tick's ONE dispatch, of whatever the two builds left: the
         decoding lanes with the chunk riding in their forward
         (``decode_chunk``), the lanes alone (``decode``), or a chunk with no
         live decoder (``decode_chunk`` with the lanes idle: a cold start).
-        One launch, one sync, one read-back; then the chunk's bookkeeping and
-        the lanes'."""
+        One launch; what the dispatch decides by count alone is booked here
+        (a lane's row, a final chunk's turn to decode, the slot whose last
+        token this is); then the ONE read-back of the step, of the tick
+        dispatched *before* this one, and that tick's emit.  Under a verify
+        window this tick is read back too, before the next is built."""
         sched, programs = self.sched, self.programs
         live = batch.live if batch else []
         # Both groups share one table width, the wider of the two needs: the
@@ -1466,8 +1574,10 @@ class ServingEngine:
             slot = sched.slots[idx]
             tables[idx, : len(slot.blocks)] = slot.blocks
             lengths[idx] = slot.cache_len
+        prev = self._flight
         if batch:
-            args[2:] = [batch.tokens, batch.draft_len]
+            args[2:4] = [batch.tokens, batch.draft_len]
+            args[5] = batch.source
         if chunk:
             table_row = np.zeros((width,), np.int32)
             table_row[: len(chunk.slot.blocks)] = chunk.slot.blocks
@@ -1489,25 +1599,42 @@ class ServingEngine:
             args.append(poison)
         gather_bytes = programs.gathered_blocks(owned) * self._block_bytes if live else 0
         mixed = bool(chunk and live)
-        self._tick.update(live=len(live), width=width, mixed=mixed)
-        dispatch_t0 = time.monotonic()
+        pipelined = prev is not None
+        self._tick.update(live=len(live), width=width, mixed=mixed, pipelined=pipelined)
         # the readers' names: a dispatch with decoding lanes waits under
-        # decode.wait, a chunk dispatched alone under prefill.wait
+        # decode.wait, a chunk dispatched alone under prefill.wait; the span
+        # covers this tick's launch and the read of the one before
         with _TickPhase(self, "decode.wait" if live else "prefill.wait", live=len(live), width=width):
             program = programs.decode_chunk if chunk else programs.decode
-            packed, self.cache.pool = program(self.params, self.cache.pool, *args)
+            t0 = time.monotonic()
+            packed, self._feed, self.cache.pool = program(self.params, self.cache.pool, *args)
+            packed.copy_to_host_async()  # the read-back comes one dispatch later: the copy starts when the program ends
+            lanes = [sched.slots[idx] for idx in live]
+            final = False
             tel = get_telemetry()
             # a mixed dispatch is a prefill dispatch and a decode dispatch too: what reads either says what it said
             # (the telemetry names stand here as literals: tests/test_metric_names.py reads the emit sites)
             if chunk:
                 self.prefill_dispatches += 1
-                chunk.slot.request.prefill_dispatches += 1  # per-request: the zero-re-prefill oracle
+                req = chunk.slot.request
+                req.prefill_dispatches += 1  # per-request: the zero-re-prefill oracle
                 chunk.slot.cache_len = chunk.start + chunk.n_real
+                # Final chunk: its last real logits row IS the next token (a prefilling request has no token
+                # unread, so its feed is whole here).  The slot decodes from the next tick on, its first input the
+                # chunk's entry of this dispatch's feed.
+                final = chunk.slot.cache_len == len(req.to_feed)
+                if final:
+                    req.state = RequestState.DECODING
+                    self._sent(chunk.slot)
+            for slot in lanes:
+                slot.cache_len += 1  # a verify window's accepted drafts are added when they are read
+                self._sent(slot)
             if live:
                 self.decode_dispatches += 1
                 self.decode_gather_bytes += gather_bytes
                 self._decode_widths.add(width)
             self.mixed_dispatches += mixed
+            self.pipelined_ticks += pipelined
             if tel.enabled:
                 if chunk:
                     tel.registry.counter("serving.prefill_dispatches").inc()
@@ -1517,72 +1644,133 @@ class ServingEngine:
                     tel.registry.gauge("serving.decode_bucket_width").set(width)
                 if mixed:
                     tel.registry.counter("serving.mixed_dispatches").inc()
-            out = programs.unpack(packed, with_chunk=chunk is not None)  # host sync point: the dispatch is done here
-            for name, value in zip(MOE_COUNTERS, out["counters"]):
-                self.moe_counters[name] += int(value)
-        dispatch_ms = (time.monotonic() - dispatch_t0) * 1e3
-        if chunk:
-            self._emit_chunk(chunk, int(out["chunk_token"][0]), bool(out["chunk_ok"][0]), width, fresh)
-        if live:
-            self._emit_decode(batch, out, width, fresh, dispatch_ms)
+                if pipelined:
+                    tel.registry.counter("serving.pipelined_ticks").inc()
+            draft_len = batch.draft_len if batch else None
+            self._flight = _Flight(packed, chunk, final, lanes, draft_len, width, fresh, t0)
+            out = self._read(prev) if pipelined else None  # host sync point: the tick BEFORE this one is done here
+        if pipelined:
+            self._apply(prev, out)
+        if programs.window > 1:
+            # The accepted count decides cache_len and the drafter reads the tokens: a verify-window engine is the
+            # synchronous one, every tick read back before the next is built.
+            self._settle("spec")
 
-    def _emit_chunk(self, chunk: _Chunk, token: int, ok: bool, width: int, fresh: bool) -> None:
-        sched, idx, slot = self.sched, chunk.idx, chunk.slot
+    def _sent(self, slot) -> None:
+        """One more token of the slot's request is dispatched.  A request
+        finishes by count: when this is its last, the lane retires (it rides
+        no later tick and its index is free for admission) and the request
+        completes when the token is read."""
+        slot.unread += 1
+        if slot.request.remaining == slot.unread:
+            self.sched.retire(slot.idx)
+
+    def _read(self, flight: _Flight) -> dict:
+        """The host's view of what ``flight``'s program returned: the sync
+        point of that tick alone, whatever is queued behind it."""
+        out = self.programs.unpack(flight.packed, with_chunk=flight.chunk is not None)
+        for name, value in zip(MOE_COUNTERS, out["counters"]):
+            self.moe_counters[name] += int(value)
+        return out
+
+    def _apply(self, flight: _Flight, out: dict) -> None:
+        """A tick's read-back applied: the chunk's bookkeeping, then the
+        lanes' tokens as values, each with the moment it was read."""
+        quarantined = self.quarantined_count
+        dispatch_ms = (time.monotonic() - flight.t0) * 1e3
+        if flight.chunk:
+            self._emit_chunk(flight, int(out["chunk_token"][0]), bool(out["chunk_ok"][0]))
+        if flight.lanes:
+            self._emit_decode(flight, out, dispatch_ms)
+        if self.quarantined_count > quarantined:
+            # The tick in flight computed a row more for the poisoned slot: read it now, so that the dropped row
+            # is off the books before anything else is built.
+            self._settle("quarantine")
+
+    def _settle(self, reason: str) -> bool:
+        """Read the tick in flight back and apply it, so that every token
+        dispatched is a value on the host and every count exact.  Whatever
+        needs a token's value, or re-queues or releases a request with a token
+        unread, calls this first; the steady state never does.  True when a
+        tick was in flight."""
+        flight = self._flight
+        if flight is None:
+            return False
+        self._flight = None
+        self.settles[reason] = self.settles.get(reason, 0) + 1
+        self._tick["settle"] = reason  # in the record of the tick that paid for it
+        tel = get_telemetry()
+        if tel.enabled:
+            tel.registry.counter("serving.settles").inc()
+        with _TickPhase(self, "decode.wait" if flight.lanes else "prefill.wait", settle=reason):
+            out = self._read(flight)
+        self._apply(flight, out)
+        return True
+
+    def _unread_requests(self) -> set:
+        """The requests with a token (or a chunk) in the tick in flight."""
+        flight = self._flight
+        if flight is None:
+            return set()
+        slots = flight.lanes + ([flight.chunk.slot] if flight.chunk else [])
+        return {slot.request.id for slot in slots}
+
+    def _emit_chunk(self, flight: _Flight, token: int, ok: bool) -> None:
+        chunk = flight.chunk
+        slot = chunk.slot
         req = slot.request
+        if req.state == RequestState.DONE:
+            return  # quarantined by an earlier chunk's flag, read after this one was dispatched: dropped
         with _TickPhase(self, "prefill.emit", request=req.id) as span:
             if self.tracer is not None:
                 self.tracer.on_prefill(
-                    req, idx, time.monotonic(),
-                    padded_rows=self.serving.prefill_chunk - chunk.n_real, width=width, fresh=fresh,
+                    req, slot.idx, time.monotonic(),
+                    padded_rows=self.serving.prefill_chunk - chunk.n_real, width=flight.width, fresh=flight.fresh,
                 )
             if not ok:
-                self._quarantine(idx, time.monotonic())
+                self._quarantine(slot, time.monotonic())
                 return
-            self._register_prefix_blocks(idx)
-            final = slot.cache_len == len(req.to_feed)
-            span.set_metadata(first_token=int(final and not req.emitted))
-            if final:
-                # Final chunk: its last real logits row IS the next token — the
-                # first generated token of a fresh request (TTFT lands here) or
-                # the resume token of a re-prefilled one.  The request decodes
-                # from the next tick on: this tick's lanes were built before
-                # the host had the token.
-                self._emit(idx, token, time.monotonic())
-                if idx in sched.slots:
-                    sched.slots[idx].request.state = RequestState.DECODING
+            self._register_prefix_blocks(slot, chunk.start + chunk.n_real)
+            span.set_metadata(first_token=int(flight.final and not req.emitted))
+            if flight.final:
+                # The first generated token of a fresh request (TTFT lands
+                # here) or the resume token of a re-prefilled one.
+                self._emit(slot, [token], time.monotonic())
 
-    def _emit_decode(self, batch: _Lanes, out: dict, width: int, fresh: bool, dispatch_ms: float) -> None:
-        sched, live, window = self.sched, batch.live, self.programs.window
-        tokens, accepts, oks, draft_len = out["tokens"], out["accepts"], out["ok"], batch.draft_len
+    def _emit_decode(self, flight: _Flight, out: dict, dispatch_ms: float) -> None:
+        window, draft_len = self.programs.window, flight.draft_len
+        tokens, accepts, oks = out["tokens"], out["accepts"], out["ok"]
+        # a lane quarantined at the read-back before this one computed a row too many here: dropped
+        lanes = [slot for slot in flight.lanes if slot.request.state != RequestState.DONE]
         with _TickPhase(self, "decode.emit") as span:
             emit_t = time.monotonic()
             if self.tracer is not None:
                 # emit_t is PAST the read-back's sync point, so the interval
                 # covers the real device work despite async dispatch.
                 self.tracer.on_decode(
-                    [(sched.slots[idx].request, idx) for idx in live],
-                    emit_t, co_batch=len(live), width=width, fresh=fresh,
+                    [(slot.request, slot.idx) for slot in lanes],
+                    emit_t, co_batch=len(flight.lanes), width=flight.width, fresh=flight.fresh,
                     dispatch_ms=dispatch_ms,
                     phase="verify" if window > 1 else "decode",
                 )
             # rounds counts verify DISPATCHES (with >= 1 healthy lane);
             # proposed/accepted are per-slot sums over the healthy lanes.
             spec_rounds = spec_proposed = spec_accepted = emitted = 0
-            for idx in live:
-                slot = sched.slots[idx]
-                req = slot.request
+            for slot in lanes:
+                idx, req = slot.idx, slot.request
                 # Accept bookkeeping: the emitted chunk is t[:count] where
                 # count = accepted drafts + the correction/bonus row, capped
                 # at remaining (count == remaining finishes the request on
                 # its exact last token).  cache_len advances by count — the
                 # rewind; rows past it are stale and re-written before read.
-                # Without speculation accepts are 0 and count is 1.
+                # Without speculation accepts are 0 and count is 1, the row
+                # the dispatch booked.
                 count = min(int(accepts[idx]) + 1, req.remaining)
-                slot.cache_len += count
+                slot.cache_len += count - 1
                 if not bool(oks[idx]):
                     # Quarantine instead of emitting the garbage argmax; the
                     # other slots' emissions proceed untouched.
-                    self._quarantine(idx, emit_t)
+                    self._quarantine(slot, emit_t)
                     continue
                 if window > 1:
                     spec_rounds = 1
@@ -1591,8 +1779,7 @@ class ServingEngine:
                 self.decode_emitted_tokens += count
                 self.decode_slot_ticks += 1
                 emitted += count
-                for j in range(count):
-                    self._emit(idx, int(tokens[idx, j]), emit_t)
+                self._emit(slot, [int(t) for t in tokens[idx, :count]], emit_t)
             if spec_rounds:
                 self.spec_rounds += spec_rounds
                 self.spec_proposed += spec_proposed
@@ -1608,24 +1795,27 @@ class ServingEngine:
 
     # -- completion / metrics ------------------------------------------------
 
-    def _emit(self, idx: int, token: int, now: float) -> None:
-        slot = self.sched.slots[idx]
+    def _emit(self, slot, tokens: List[int], now: float) -> None:
+        """The values of the tokens one dispatch produced for ``slot``, read at
+        ``now``.  The request completes when its last token is a value."""
         req = slot.request
-        req.emitted.append(token)
-        req.note_token(now)
+        slot.unread -= 1
         tel = get_telemetry()
-        if tel.enabled:
-            tel.registry.counter("serving.tokens").inc()
-            if len(req.emitted) == 1 and req.arrival_t is not None:
-                tel.registry.histogram("serving.ttft_ms").observe(
-                    (now - req.arrival_t) * 1e3
-                )
-            elif req.inter_token_ms:
-                tel.registry.histogram("serving.inter_token_ms").observe(
-                    req.inter_token_ms[-1]
-                )
+        for token in tokens:
+            req.emitted.append(token)
+            req.note_token(now)
+            if tel.enabled:
+                tel.registry.counter("serving.tokens").inc()
+                if len(req.emitted) == 1 and req.arrival_t is not None:
+                    tel.registry.histogram("serving.ttft_ms").observe(
+                        (now - req.arrival_t) * 1e3
+                    )
+                elif req.inter_token_ms:
+                    tel.registry.histogram("serving.inter_token_ms").observe(
+                        req.inter_token_ms[-1]
+                    )
         if req.remaining == 0:
-            self.sched.finish(idx, now)
+            self.sched.release(slot, now)  # live, or retiring since its last token was dispatched
             self._complete(req)
 
     def _complete(self, req: Request, status: str = "ok") -> None:
@@ -1784,14 +1974,16 @@ class ServingEngine:
         """Live request snapshot for the ``/debug/requests`` endpoint: every
         queued and slotted request with its state, age, and (when tracing is
         on) its phase-so-far decomposition.  Host-side reads only — safe to
-        call from the metrics server thread between ticks."""
+        call from the metrics server thread between ticks, which is why it
+        does not settle: ``emitted`` counts the tokens read, ``unread`` those
+        dispatched and still on the device (at most one, the tick in flight)."""
         now = time.monotonic()
         out = []
         seen = set()
-        for idx, slot in sorted(self.sched.slots.items()):
+        for slot in sorted([*self.sched.slots.values(), *self.sched.retiring], key=lambda slot: slot.idx):
             req = slot.request
             seen.add(req.id)
-            out.append(self._debug_request(req, now, slot=idx))
+            out.append(dict(self._debug_request(req, now, slot=slot.idx), unread=slot.unread))
         for req in self.sched.queue:
             if req.id not in seen:
                 out.append(self._debug_request(req, now, slot=None))
@@ -1890,12 +2082,20 @@ class ServingEngine:
         return out
 
     def stats(self) -> dict:
+        """The engine's counters, exact: a tick in flight is read back first
+        (``settles["stats"]``), so every token dispatched is counted and every
+        reply due is in ``completed``.  Poll it a few times a window, not a tick."""
+        self._settle("stats")
         alloc = self.cache.allocator
         return {
             "ticks": self.ticks,
             "decode_dispatches": self.decode_dispatches,
             "prefill_dispatches": self.prefill_dispatches,
             "mixed_dispatches": self.mixed_dispatches,
+            # dispatches made with the tick before them still unread (the device had a program queued while the
+            # host read, emitted and built), and the read-backs forced early, by what forced them
+            "pipelined_ticks": self.pipelined_ticks,
+            "settles": dict(self.settles),
             "active_slots": self.sched.active,
             "queue_depth": self.sched.pending,
             "blocks_used": alloc.used_blocks,

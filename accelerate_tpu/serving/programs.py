@@ -56,10 +56,26 @@ the chunk's own ``ok``; a chunk with no live decoder rides it with the lanes
 idle, which only a cold start sees).  Both groups of ``decode_chunk`` share
 one table width, the wider of the two needs.  What a program returns beside
 the pool is one int32 vector, so a tick reads one array back
-(:meth:`ServingPrograms.unpack`).  A family with a state takes its lanes' ``live``
+(:meth:`ServingPrograms.unpack`), and the **feed**.  A family with a state takes its lanes' ``live``
 flags (and ``decode_chunk`` the chunk's slot) behind those arguments.  Both
 take a trailing per-lane poison vector when the NaN fault is armed; an unarmed
 program is traced without it.
+
+**The feed stays on the device.**  The engine dispatches tick N + 1 before it
+has read tick N back (``serving/engine.py``), so the token a decoding lane
+feeds next is, as a value, known to the device alone.  Each program returns
+``feed``, int32 ``[max_slots + 1]``: every lane's next token and, in the last
+place, the chunk's token (0 from ``decode``): one shape from both programs, so
+either's output is the other's input and no program compiles twice for it.
+Each takes the previous dispatch's ``feed`` and a per-lane ``source [S]`` and
+selects the lanes' input tokens on the device: ``FEED_HOST`` (the host's
+``tokens[:, 0]``, what every lane reads after a settle: the feed passed is
+still the last dispatch's, so that a program has one signature, and no lane
+reads it), ``FEED_LANE`` (the lane's own entry: it decoded in the previous
+dispatch) or ``FEED_CHUNK`` (the last entry: the lane's prompt ended in the
+previous dispatch's chunk).  A chunk's tokens are the prompt's and come from
+the host always.  Under a verify window the entry is the last token a lane
+accepted, which an engine that settles every tick never reads.
 
 The profile names a program after its Python function (``jit_decode``,
 ``jit_decode_chunk``) and ``chipbench/`` selects operations by the prefix
@@ -86,7 +102,11 @@ from ..models.generation import (
     write_state_rows,
 )
 
-__all__ = ["MOE_COUNTERS", "ServingPrograms", "build_programs"]
+__all__ = ["FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "ServingPrograms", "build_programs"]
+
+# Where a decoding lane's input token comes from (a program's per-lane ``source``): the host's ``tokens``, the lane's own
+# entry of the previous dispatch's ``feed``, or that feed's last entry, the chunk's token.
+FEED_HOST, FEED_LANE, FEED_CHUNK = 0, 1, 2
 
 # What an expert family's ``apply_paged`` counts in a dispatch (``models/deepseek_v3.py:expert_counters``), each
 # summed over its expert layers: token-expert pairs computed, experts with at least one row, the hottest expert's rows.
@@ -105,8 +125,8 @@ class ServingPrograms:
     what a tick has to know about the back end they were built for."""
 
     backend: str  # "paged" | "dense": what the family decided
-    decode: Callable  # (params, pool, tables [S, M], lengths [S], tokens [S, W], draft_len [S], *state, *poison)
-    decode_chunk: Callable  # (..., draft_len [S], table_row [M], start, chunk [1, C], n_real, *state, *poison)
+    decode: Callable  # (params, pool, tables [S, M], lengths [S], tokens [S, W], draft_len [S], feed [S + 1], source [S], *state, *poison)
+    decode_chunk: Callable  # (..., source [S], table_row [M], start, chunk [1, C], n_real, *state, *poison) -> (packed, feed, pool), both
     stateful: bool  # the pool holds a state by slot: *state is (live [S],), with a chunk (live [S], slot)
     window: int  # W: 1, or k + 1 under speculation
     max_slots: int
@@ -137,7 +157,8 @@ class ServingPrograms:
 
     def unpack(self, packed, with_chunk: bool) -> dict:
         """The host's view of the vector a program returned (the one sync
-        point and the one read-back of a tick): ``tokens [S, W]``, ``accepts
+        point and the one read-back of a tick; it waits for that program
+        alone, a later one queued behind it runs on): ``tokens [S, W]``, ``accepts
         [S]`` (zeros without speculation), ``ok [S]``, with a chunk
         ``chunk_token`` and ``chunk_ok``, and ``counters`` (what an expert
         family put behind them, else empty)."""
@@ -254,16 +275,32 @@ def _poisoned(logits, poison):
 
 def _lanes_head(logits, tokens, draft_len, poison):
     """The decoding lanes' part of a head: ``(tokens [S, W], accepts [S] or
-    nothing, ok [S])`` from their logits ``[S, W, V]``.  One row a lane emits
+    nothing), ok [S], next [S]`` from their logits ``[S, W, V]``.  One row a lane emits
     its argmax; a ``k + 1`` window goes through the greedy accept rule.  The
     finiteness flag is per lane, folded into the same dispatch: a poisoned
-    lane is detected the tick it happens, before its token is emitted."""
+    lane's token is never emitted.  ``next`` is the token a lane feeds next,
+    the feed's entry: the argmax, or the last token a window accepted."""
     logits = _poisoned(logits, poison)
     ok = jnp.all(jnp.isfinite(logits), axis=(1, 2))
     if logits.shape[1] == 1:
-        return [jnp.argmax(logits[:, -1], axis=-1)], ok
+        token = jnp.argmax(logits[:, -1], axis=-1)
+        return [token], ok, token
     t, m = speculative_verify_greedy(logits, tokens[:, 1:], draft_len)
-    return [t, m], ok
+    return [t, m], ok, jnp.take_along_axis(t, m[:, None], axis=1)[:, 0]
+
+
+def _fed(tokens, feed, source):
+    """The lanes' tokens ``[S, W]`` with the first column taken from where
+    ``source`` says: the host's own value, the lane's entry of the previous
+    dispatch's ``feed``, or its last entry (the chunk's token)."""
+    s = tokens.shape[0]
+    first = jnp.where(source == FEED_LANE, feed[:s], jnp.where(source == FEED_CHUNK, feed[s], tokens[:, 0]))
+    return tokens.at[:, 0].set(first)
+
+
+def _feed(lanes_next, chunk_token):
+    """What the next dispatch may read in place of host tokens: ``[S + 1]``."""
+    return jnp.concatenate([lanes_next, jnp.reshape(chunk_token, (1,))]).astype(jnp.int32)
 
 
 def _heads(forward: Callable, stateful: bool = False):
@@ -271,19 +308,22 @@ def _heads(forward: Callable, stateful: bool = False):
     profile's (``jit_decode``, ``jit_decode_chunk``).  ``draft_len`` is read
     by a ``k + 1`` window only (jit drops an argument nothing reads).  With a
     state, ``rest`` leads with ``live [S]`` (1 where a lane decodes) and, in
-    ``decode_chunk``, the chunk's slot, and the groups carry (slots, counts)."""
+    ``decode_chunk``, the chunk's slot, and the groups carry (slots, counts).
+    Both return ``(packed, feed, pool)``."""
 
-    def decode(params, pool, tables, lengths, tokens, draft_len, *rest):
+    def decode(params, pool, tables, lengths, tokens, draft_len, feed, source, *rest):
+        tokens = _fed(tokens, feed, source)
         state = ()
         if stateful:
             live, *rest = rest
             state = (jnp.arange(tokens.shape[0], dtype=jnp.int32), live)
         (logits,), counters, (rows,) = forward(params, pool, ((tokens, tables, lengths, *state),))
-        parts, ok = _lanes_head(logits, tokens, draft_len, rest)
+        parts, ok, lanes_next = _lanes_head(logits, tokens, draft_len, rest)
         new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state)
-        return _packed([*parts, ok], counters), new_pool
+        return _packed([*parts, ok], counters), _feed(lanes_next, 0), new_pool
 
-    def decode_chunk(params, pool, tables, lengths, tokens, draft_len, table_row, start, chunk, n_real, *rest):
+    def decode_chunk(params, pool, tables, lengths, tokens, draft_len, feed, source, table_row, start, chunk, n_real, *rest):
+        tokens = _fed(tokens, feed, source)
         chunk_tables, chunk_starts = table_row[None], start[None]
         state = chunk_state = ()
         if stateful:
@@ -291,11 +331,11 @@ def _heads(forward: Callable, stateful: bool = False):
             state, chunk_state = (jnp.arange(tokens.shape[0], dtype=jnp.int32), live), (slot[None], n_real[None])
         groups = ((tokens, tables, lengths, *state), (chunk, chunk_tables, chunk_starts, *chunk_state))
         (logits, chunk_logits), counters, (rows, chunk_rows) = forward(params, pool, groups)
-        parts, ok = _lanes_head(logits, tokens, draft_len, rest)
+        parts, ok, lanes_next = _lanes_head(logits, tokens, draft_len, rest)
         chunk_token = jnp.argmax(chunk_logits[0, n_real - 1], axis=-1)
         chunk_ok = jnp.all(jnp.isfinite(chunk_logits))
         new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state)
         new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1], *chunk_state)
-        return _packed([*parts, ok, chunk_token, chunk_ok], counters), new_pool
+        return _packed([*parts, ok, chunk_token, chunk_ok], counters), _feed(lanes_next, chunk_token), new_pool
 
     return decode, decode_chunk

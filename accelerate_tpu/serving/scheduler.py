@@ -26,7 +26,17 @@ fallback whenever the host tier cannot take the blocks.
 
 The scheduler is pure host-side bookkeeping: admission/preemption decisions
 happen between dispatches and the jitted decode step never sees them (slots
-simply flip their active mask)."""
+simply flip their active mask).
+
+The engine reads a tick back one dispatch late (``serving/engine.py``), so a
+slot's bookkeeping runs ahead of the tokens the host has read: ``cache_len``
+counts the rows *dispatched*, ``unread`` the tokens dispatched and not yet
+read.  A request finishes by count, so the slot whose last token is in flight
+**retires** when that token is dispatched (:meth:`Scheduler.retire`): its lane
+is free for admission at once, its blocks stay its own until the token is read
+and :meth:`Scheduler.release` completes it.  Whatever evicts a request needs
+its tokens' values: :attr:`Scheduler.settle`, the engine's, reads the tick in
+flight back first."""
 
 from __future__ import annotations
 
@@ -164,18 +174,24 @@ class Request:
 
 class _Slot:
     """One decode-batch lane: the bound request, its block table, and how many
-    cache rows have been written.  ``registered_blocks`` is the prefix-cache
+    cache rows have been written (dispatched: the engine reads a tick back one
+    dispatch late).  ``registered_blocks`` is the prefix-cache
     registration cursor — leading full blocks up to it are already published
-    (or were attached FROM the cache) and are never re-registered."""
+    (or were attached FROM the cache) and are never re-registered.
+    ``unread`` counts the request's tokens that were dispatched and whose
+    values the host has not read: ``request.remaining - unread`` is what is
+    left to dispatch."""
 
-    __slots__ = ("request", "blocks", "cache_len", "admit_seq", "registered_blocks")
+    __slots__ = ("request", "idx", "blocks", "cache_len", "admit_seq", "registered_blocks", "unread")
 
-    def __init__(self, request: Request, admit_seq: int):
+    def __init__(self, request: Request, admit_seq: int, idx: int):
         self.request = request
+        self.idx = idx
         self.blocks: List[int] = []
         self.cache_len = 0
         self.admit_seq = admit_seq
         self.registered_blocks = 0
+        self.unread = 0
 
 
 class Scheduler:
@@ -200,6 +216,8 @@ class Scheduler:
         self.spec_overshoot = max(int(spec_overshoot), 0)
         self.queue: Deque[Request] = deque()
         self.slots: Dict[int, _Slot] = {}  # slot index -> lane
+        # Lanes whose last token is dispatched and not yet read: out of ``slots`` (the index is free for admission), blocks held.
+        self.retiring: List[_Slot] = []
         self._admit_seq = itertools.count()
         self.preempted_count = 0
         # Observer hook: called with the evicted Request on every preemption
@@ -212,6 +230,9 @@ class Scheduler:
         # carries ``demoted_blocks``); False falls through to the plain
         # free-and-re-prefill preemption.
         self.on_migrate_out: Optional[Callable[[_Slot], bool]] = None
+        # The engine's settle (read the tick in flight back and apply it; True when there was one), called with its
+        # reason before anything that needs a request's tokens as values or gives its blocks away.
+        self.settle: Optional[Callable[[str], bool]] = None
 
     # -- capacity validation -------------------------------------------------
 
@@ -277,7 +298,7 @@ class Scheduler:
             if head.requeued_t is not None:
                 head.requeue_waits_ms.append((now - head.requeued_t) * 1e3)
                 head.requeued_t = None
-            self.slots[idx] = _Slot(head, next(self._admit_seq))
+            self.slots[idx] = _Slot(head, next(self._admit_seq), idx)
             admitted.append(idx)
         return admitted
 
@@ -293,6 +314,8 @@ class Scheduler:
         blocks, push it back onto the queue FRONT (it keeps priority — it
         already waited), carrying its emitted tokens.  Returns the freed slot
         index, or None when nothing is in flight."""
+        if self.settle is not None:
+            self.settle("preempt")  # the victim re-prefills prompt + emitted: every token read first
         if not self.slots:
             return None
         return self.preempt_slot(max(self.slots, key=lambda i: self.slots[i].admit_seq))
@@ -303,7 +326,11 @@ class Scheduler:
         demote its blocks to the host tier when the ``on_migrate_out`` hook
         accepts the victim, else free them; either way the request re-enters
         the queue FRONT, emitted tokens carried."""
-        slot = self.slots.pop(idx)
+        if self.settle is not None:
+            self.settle("preempt")
+        slot = self.slots.pop(idx, None)
+        if slot is None:  # the settle completed it
+            return idx
         migrated = False
         if slot.blocks and self.on_migrate_out is not None:
             migrated = self.on_migrate_out(slot)
@@ -333,6 +360,10 @@ class Scheduler:
                 slot.blocks.extend(self.allocator.alloc(need))
                 return True
             except BlockOutOfMemory as exc:
+                if self.settle is not None and self.settle("preempt"):
+                    # The tick read back returned its retiring lanes' blocks: ask again before evicting anyone.
+                    slot = self.slots.get(idx)
+                    continue
                 victim = self.preempt_one()
                 if victim is None:
                     # Terminal pool exhaustion (nothing left to evict —
@@ -354,7 +385,22 @@ class Scheduler:
 
     def finish(self, idx: int, now: float) -> Request:
         """Release slot ``idx``; the request is complete."""
+        return self.release(self.slots[idx], now)
+
+    def retire(self, idx: int) -> _Slot:
+        """The slot's last token is dispatched: the lane leaves ``slots`` (a
+        queued request may take the index in the next tick), the blocks stay
+        the slot's until the token is read and :meth:`release` is called."""
         slot = self.slots.pop(idx)
+        self.retiring.append(slot)
+        return slot
+
+    def release(self, slot: _Slot, now: float) -> Request:
+        """The request is complete, live or retiring: free its blocks."""
+        if self.slots.get(slot.idx) is slot:
+            del self.slots[slot.idx]
+        else:
+            self.retiring.remove(slot)
         if slot.blocks:
             self.allocator.free(slot.blocks)
         req = slot.request
@@ -373,4 +419,4 @@ class Scheduler:
         return len(self.queue)
 
     def idle(self) -> bool:
-        return not self.slots and not self.queue
+        return not self.slots and not self.queue and not self.retiring

@@ -392,7 +392,10 @@ class ServingTracer:
         draft-then-verify dispatch — both productive (never blamed); the
         phase key keeps greedy and speculative runs from coalescing into one
         interval, so a trace shows exactly where the engine ran verify
-        windows."""
+        windows.  The hook runs when the dispatch is *read back*, one dispatch
+        after its launch unless the engine settled: ``end`` is that moment and
+        ``dispatch_ms`` the time from the tick's launch to its read-back (the
+        host's work of the step between them included, while the device ran)."""
         for req, slot in reqs_slots:
             t = self.live.get(req.id)
             if t is None:
@@ -426,15 +429,20 @@ class ServingTracer:
             self._ticked.add(req.id)
         self._note_event()
 
-    def end_tick(self, now: float, slots: dict, tick: Optional[dict] = None) -> None:
+    def end_tick(self, now: float, slots: dict, tick: Optional[dict] = None, unread=()) -> None:
         """Close the tick for every resident request: dispatched requests'
         last interval stretches to the tick boundary (the emit/bookkeeping
         tail stays attributed); a prefilling slot that never got its chunk
         turn records a ``waiting`` prefill interval — the co-batched-behind-
-        another-prefill time the blame question asks about.  ``tick`` is the
+        another-prefill time the blame question asks about.  ``unread`` are
+        the ids of the requests with a token or a chunk in the tick in flight
+        (the engine reads a tick back one dispatch late): they had their turn,
+        and its interval is written when it is read, from their cursor.  ``tick`` is the
         engine's record of the tick (``total_ms``, ``phase_ms`` by the names
         of its ``serving.tick.*`` spans, the dispatch's ``live`` lanes and
-        ``width``, ``mixed`` when a chunk rode with them): kept if it is among the slowest, see :meth:`slow_ticks`."""
+        ``width``, ``mixed`` when a chunk rode with them, ``pipelined`` when it
+        was dispatched with the tick before it still unread, ``settle`` the
+        reason if a tick in flight was read back early in it): kept if it is among the slowest, see :meth:`slow_ticks`."""
         if self._tick_t0 is None:
             return
         # A tick that met a table width for the first time compiles, and is
@@ -451,6 +459,8 @@ class ServingTracer:
                 if now > last.end:
                     last.end = now
                     t.cursor = max(t.cursor, now)
+                continue
+            if t.rid in unread:
                 continue
             last = t.intervals[-1] if t.intervals else None
             if (
@@ -478,6 +488,8 @@ class ServingTracer:
             "prefilling": tick["prefilling"],
             "width": tick["width"],
             "mixed": tick["mixed"],
+            "pipelined": tick["pipelined"],
+            "settle": tick["settle"],
             "gc_count": list(gc.get_count()),
         }
         entry = (tick["total_ms"], tick["tick"], record)

@@ -59,6 +59,7 @@ COUNTERS = frozenset({
     "serving.drains",
     "serving.journal_recoveries",
     "serving.mixed_dispatches",
+    "serving.pipelined_ticks",
     "serving.preempted",
     "serving.prefill_dispatches",
     "serving.prefix_blocks_reused",
@@ -66,6 +67,7 @@ COUNTERS = frozenset({
     "serving.prefix_hits",
     "serving.quarantined",
     "serving.requests",
+    "serving.settles",
     "serving.shed",
     "serving.spec.accepted",
     "serving.spec.proposed",

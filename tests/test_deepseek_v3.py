@@ -357,9 +357,10 @@ def test_a_family_without_experts_keeps_its_program():
         llama.apply_cached, llama.init_cache, llama.init_params(c, jax.random.key(0)), c, block_size=4, num_blocks=32,
         max_slots=2, max_blocks_per_seq=8, prefill_chunk=4)
     tables, lengths = np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)
-    packed, _ = jax.eval_shape(
-        engine.programs.decode, engine.params, engine.cache.pool, tables, lengths, np.zeros((2, 1), np.int32), np.zeros((2,), np.int32))
-    assert packed.shape == (2 + 2,)  # a token and a flag a lane, nothing behind them
+    packed, feed, _ = jax.eval_shape(
+        engine.programs.decode, engine.params, engine.cache.pool, tables, lengths, np.zeros((2, 1), np.int32), np.zeros((2,), np.int32),
+        np.zeros((3,), np.int32), np.zeros((2,), np.int32))
+    assert packed.shape == (2 + 2,) and feed.shape == (2 + 1,)  # a token and a flag a lane, nothing behind them
     engine.submit(np.arange(6), 3)
     engine.run()
     assert {engine.stats()[k] for k in ("moe_rows", "moe_experts_hit", "moe_max_rows")} == {0}

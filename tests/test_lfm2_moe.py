@@ -292,19 +292,20 @@ def test_rule_3_idle_lanes_and_the_prefilling_slots_own_lane_keep_their_state(cu
     i32 = lambda x: np.asarray(x, np.int32)
     tables = np.zeros((SLOTS, WIDTH), np.int32)
     tables[0, :3] = [1, 2, 3]
-    lengths, tokens, draft = i32([9, 0, 0, 0]), i32([[5], [0], [0], [0]]), np.zeros((SLOTS,), np.int32)
+    lengths, tokens = i32([9, 0, 0, 0]), i32([[5], [0], [0], [0]])
+    draft = (np.zeros((SLOTS,), np.int32), np.zeros((SLOTS + 1,), np.int32), np.zeros((SLOTS,), np.int32))  # no draft; no feed, every lane the host's token
     chunk = (i32([9, 10, 11, 12, 0, 0, 0, 0]), np.int32(8), i32(np.arange(8)[None] + 30), np.int32(6))
     before = np.asarray(junk_pool(c)[STATE]["conv"])
-    _, mixed = built.decode_chunk(params, junk_pool(c), tables, lengths, tokens, draft, *chunk, i32([1, 0, 0, 0]), np.int32(1))
+    *_, mixed = built.decode_chunk(params, junk_pool(c), tables, lengths, tokens, *draft, *chunk, i32([1, 0, 0, 0]), np.int32(1))
     after = np.asarray(mixed[STATE]["conv"])
     assert np.array_equal(after[:, 2:], before[:, 2:])  # the idle lanes: bit for bit
     assert not np.array_equal(after[:, 0], before[:, 0])  # the decoding lane advanced
     # slot 1 holds what its chunk wrote and nothing of its idle decoding lane: the same chunk dispatched with every lane idle
-    _, alone = built.decode_chunk(params, junk_pool(c), tables * 0, lengths * 0, tokens * 0, draft, *chunk, i32([0, 0, 0, 0]), np.int32(1))
+    *_, alone = built.decode_chunk(params, junk_pool(c), tables * 0, lengths * 0, tokens * 0, *draft, *chunk, i32([0, 0, 0, 0]), np.int32(1))
     assert np.array_equal(after[:, 1], np.asarray(alone[STATE]["conv"])[:, 1])
     assert np.array_equal(np.asarray(alone[STATE]["conv"])[:, [0, 2, 3]], before[:, [0, 2, 3]])
     # decode alone: a lane that is not live keeps its state, whatever its table row and length say
-    _, decoded = built.decode(params, junk_pool(c), tables, lengths, tokens, draft, i32([1, 0, 0, 0]))
+    *_, decoded = built.decode(params, junk_pool(c), tables, lengths, tokens, *draft, i32([1, 0, 0, 0]))
     assert np.array_equal(np.asarray(decoded[STATE]["conv"])[:, 1:], before[:, 1:])
     assert np.array_equal(np.asarray(decoded[STATE]["conv"])[:, 0], after[:, 0])  # and the chunk beside it changed nothing of lane 0
     # the rule broken (every lane written) would show: the write itself, counts ignored
@@ -328,14 +329,14 @@ def test_a_family_without_a_state_keeps_its_programs_and_its_stats():
         ServingConfig(block_size=4, num_blocks=32, max_slots=2, max_blocks_per_seq=8, prefill_chunk=4))
     assert not engine.programs.stateful and STATE not in engine.cache.pool and engine._state_args([0], 1) == []
     tables, lengths = np.zeros((2, 2), np.int32), np.zeros((2,), np.int32)
-    lanes = (tables, lengths, np.zeros((2, 1), np.int32), np.zeros((2,), np.int32))
-    packed, _ = jax.eval_shape(engine.programs.decode, engine.params, engine.cache.pool, *lanes)
-    assert packed.shape == (2 + 2,)
-    # the traced programs take the parameters, the pool and their own arguments: no input more (draft_len is among them)
+    lanes = (tables, lengths, np.zeros((2, 1), np.int32), np.zeros((2,), np.int32), np.zeros((3,), np.int32), np.zeros((2,), np.int32))
+    packed, feed, _ = jax.eval_shape(engine.programs.decode, engine.params, engine.cache.pool, *lanes)
+    assert packed.shape == (2 + 2,) and feed.shape == (2 + 1,)
+    # the traced programs take the parameters, the pool and their own arguments: no input more (draft_len, the feed and the lanes' sources are among them)
     chunk = (tables[0], np.int32(0), np.zeros((1, 4), np.int32), np.int32(1))
     leaves = len(jax.tree.leaves((engine.params, engine.cache.pool)))
-    assert len(jax.make_jaxpr(engine.programs.decode)(engine.params, engine.cache.pool, *lanes).jaxpr.invars) == leaves + 4
-    assert len(jax.make_jaxpr(engine.programs.decode_chunk)(engine.params, engine.cache.pool, *lanes, *chunk).jaxpr.invars) == leaves + 8
+    assert len(jax.make_jaxpr(engine.programs.decode)(engine.params, engine.cache.pool, *lanes).jaxpr.invars) == leaves + 6
+    assert len(jax.make_jaxpr(engine.programs.decode_chunk)(engine.params, engine.cache.pool, *lanes, *chunk).jaxpr.invars) == leaves + 10
     engine.submit(np.arange(6), 3)
     engine.run()
     assert not {"state_bytes", "state_resets", "prefix_cache_off"} & set(engine.stats())

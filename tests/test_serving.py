@@ -351,6 +351,52 @@ def test_scheduler_self_preemption_returns_false():
     assert s.active == 0 and s.queue[0] is solo
 
 
+def test_scheduler_retire_frees_the_lane_and_keeps_the_blocks():
+    """A request finishes by count, so the slot whose last token is dispatched
+    retires before that token is read: its lane is free for the queue's head
+    at once, its blocks stay its own, the scheduler is not idle, and
+    ``release`` completes it from wherever it stands."""
+    s = _sched(num_blocks=9, slots=1)
+    first, second = Request([1] * 4, 2), Request([2] * 4, 2)
+    s.submit(first), s.submit(second)
+    (idx,) = s.admit(now=0.0)
+    assert s.grow_to(idx, 8) and s.allocator.free_blocks == 6
+    slot = s.retire(idx)
+    assert slot.idx == idx and slot.request is first and s.retiring == [slot] and idx not in s.slots
+    assert s.allocator.free_blocks == 6 and first.state == RequestState.PREFILLING  # blocks held, nothing decided yet
+    assert s.admit(now=1.0) == [idx] and s.slots[idx].request is second  # the lane is taken while the token is unread
+    s.queue.clear()
+    assert not s.idle()
+    assert s.release(slot, now=2.0) is first and first.state == RequestState.DONE and first.finish_t == 2.0
+    assert s.retiring == [] and s.allocator.free_blocks == 8 and s.slots[idx].request is second
+    assert s.finish(idx, now=3.0) is second and s.idle()
+
+
+def test_scheduler_asks_the_engine_to_settle_before_it_evicts():
+    """Whatever re-queues a request needs its tokens as values, and a
+    read-back returns the retiring lanes' blocks: ``grow_to`` settles on a
+    shortage and asks again before it evicts anyone; ``preempt_one`` and
+    ``preempt_slot`` settle first however they are called."""
+    s = _sched(num_blocks=5, slots=2, bs=4, chunk=4)  # 4 usable blocks
+    old, young = Request([1] * 4, 8), Request([1] * 4, 8)
+    s.submit(old), s.submit(young)
+    oi, yi = s.admit(now=0.0)
+    assert s.grow_to(oi, 8) and s.grow_to(yi, 8) and s.allocator.free_blocks == 0
+    retired, calls = s.retire(yi), []
+
+    def settle(reason):
+        calls.append(reason)
+        if retired in s.retiring:  # the tick in flight held the young request's last token
+            s.release(retired, now=1.0)
+            return True
+        return False
+
+    s.settle = settle
+    assert s.grow_to(oi, 12) and calls == ["preempt"]  # the read-back freed two blocks: nobody was evicted
+    assert s.preempted_count == 0 and young.state == RequestState.DONE and len(s.slots[oi].blocks) == 3
+    assert s.preempt_one() == oi and calls == ["preempt"] * 3 and old.state == RequestState.QUEUED  # by preempt_one, then by preempt_slot
+
+
 # ---------------------------------------------------------------------------
 # Engine equivalence (the acceptance oracle)
 # ---------------------------------------------------------------------------

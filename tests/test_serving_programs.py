@@ -147,15 +147,18 @@ def test_a_forward_over_two_groups_is_the_two_forwards_side_by_side(name, quant,
         assert not np.array_equal(np.asarray(new_pool["k"])[:, blk, off], np.asarray(pool["k"])[:, blk, off])
 
 
-def _run_program(built, program, family, cfg, params, lanes, chunk=None):
-    """One dispatch of ``program`` over a fresh random pool -> (unpacked read-back, new pool)."""
+def _run_program(built, program, family, cfg, params, lanes, chunk=None, feed=None):
+    """One dispatch of ``program`` over a fresh random pool -> (unpacked read-back with the program's ``feed``, new
+    pool).  ``feed``: the previous dispatch's feed and the lanes' sources; every lane reads the host's token without."""
     tokens, tables, starts = lanes
-    args = [tables, starts, tokens, np.zeros((tables.shape[0],), np.int32)]
+    lanes = tables.shape[0]
+    args = [tables, starts, tokens, np.zeros((lanes,), np.int32)]
+    args += [np.zeros((lanes + 1,), np.int32), np.zeros((lanes,), np.int32)] if feed is None else list(feed)
     if chunk is not None:
         chunk_tokens, chunk_tables, chunk_starts = chunk
         args += [chunk_tables[0], chunk_starts[0], chunk_tokens, np.int32(3)]
-    packed, pool = program(params, _random_pool(family, cfg, seed=3), *args)
-    return built.unpack(packed, with_chunk=chunk is not None), pool
+    packed, new_feed, pool = program(params, _random_pool(family, cfg, seed=3), *args)
+    return dict(built.unpack(packed, with_chunk=chunk is not None), feed=np.asarray(new_feed)), pool
 
 
 @pytest.mark.parametrize("backend", ["paged", "dense"])
@@ -188,6 +191,51 @@ def test_decode_chunk_is_decode_and_the_chunk_in_one_dispatch(name, spec, backen
     for pos in range(int(chunk_starts[0]), int(chunk_starts[0]) + 3):
         blk, off = chunk_tables[0, pos // BLOCK], pos % BLOCK
         np.testing.assert_allclose(np.asarray(pool_m["v"])[:, blk, off], np.asarray(pool_c["v"])[:, blk, off], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("backend", ["paged", "dense"])
+@pytest.mark.parametrize("spec", [0, WINDOW - 1], ids=["greedy", "spec"])
+def test_both_programs_return_one_feed_and_read_it_where_the_source_says(spec, backend):
+    """The feed that keeps the decoders' tokens on the device: int32 ``[max_slots + 1]`` from ``decode`` and from
+    ``decode_chunk`` alike (every lane's next token, then the chunk's token, 0 without a chunk), so either's output is
+    the other's input.  A lane whose ``source`` names its own entry, or the chunk's, computes what it computes from
+    the same token passed by the host, and a lane on ``FEED_HOST`` ignores the feed; passing a program's own output
+    back compiles nothing more at the width."""
+    family, cfg, params = _family("llama_gqa", quant=False)
+    apply_cached = family.apply_cached if backend == "paged" else without_apply_paged(family)
+    built = P.build_programs(apply_cached, cfg, ["k", "v"], SERVING, spec_tokens=spec)
+    lanes, chunk = _disjoint_mixed_dispatch("verify" if spec else "decode", cfg.vocab_size)
+    tokens, tables, starts = lanes
+    by_host, _ = _run_program(built, built.decode, family, cfg, params, lanes)
+    mixed, _ = _run_program(built, built.decode_chunk, family, cfg, params, lanes, chunk)
+    for out, chunk_entry in ((by_host, 0), (mixed, int(mixed["chunk_token"][0]))):
+        assert out["feed"].shape == (5,) and out["feed"].dtype == np.int32
+        last = out["tokens"][np.arange(4), out["accepts"]]  # a lane's next input: its argmax, or the last token its window accepted
+        assert out["feed"].tolist() == last.tolist() + [chunk_entry]
+    # lanes 0 and 3 take their first token from their own entries of a feed, lane 1 from the chunk's, lane 2 from the host
+    feed = np.asarray([tokens[0, 0], 7777, 8888, tokens[3, 0], tokens[1, 0]], np.int32)
+    source = np.asarray([P.FEED_LANE, P.FEED_CHUNK, P.FEED_HOST, P.FEED_LANE], np.int32)
+    blanked = tokens.copy()
+    blanked[[0, 1, 3], 0] = 0  # the host does not know these values
+    for program, group in ((built.decode, None), (built.decode_chunk, chunk)):
+        want, want_pool = _run_program(built, program, family, cfg, params, lanes, group)
+        got, got_pool = _run_program(built, program, family, cfg, params, (blanked, tables, starts), group, feed=(feed, source))
+        for key in ("tokens", "accepts", "ok", "feed"):
+            assert got[key].tolist() == want[key].tolist(), key
+        _assert_pools_match(got_pool, want_pool)
+        ignored, _ = _run_program(built, program, family, cfg, params, lanes, group, feed=(feed, np.zeros((4,), np.int32)))
+        assert ignored["tokens"].tolist() == want["tokens"].tolist()
+    # one executable a program at the width, whichever program's feed comes back in (as device arrays, as the engine passes them)
+    pool = _random_pool(family, cfg, seed=3)
+    zeros, draft, host = jnp.zeros((5,), jnp.int32), np.zeros((4,), np.int32), np.zeros((4,), np.int32)
+    chunk_args = (chunk[1][0], chunk[2][0], chunk[0], np.int32(3))
+    _, feed_d, pool = built.decode(params, pool, tables, starts, tokens, draft, zeros, host)
+    _, feed_c, pool = built.decode_chunk(params, pool, tables, starts, tokens, draft, feed_d, source, *chunk_args)
+    sizes = (built.decode._cache_size(), built.decode_chunk._cache_size())
+    _, feed_d, pool = built.decode(params, pool, tables, starts, tokens, draft, feed_c, source)
+    _, feed_c, pool = built.decode_chunk(params, pool, tables, starts, tokens, draft, feed_c, host, *chunk_args)
+    assert (built.decode._cache_size(), built.decode_chunk._cache_size()) == sizes
+    assert feed_d.shape == feed_c.shape == (5,) and feed_d.dtype == feed_c.dtype == jnp.int32
 
 
 @pytest.mark.parametrize("backend", ["paged", "dense"])

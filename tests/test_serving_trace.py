@@ -322,21 +322,26 @@ MIXED_TICK_PHASES = ["admit", "prefill.build", "decode.build", "decode.wait", "p
 
 def _busy_engine(cfg, params, **overrides):
     """An engine in mid-flight with its programs warm: one request decoding,
-    one with prompt chunks still to prefill, so a tick runs every phase."""
+    one with prompt chunks still to prefill, and the tick in flight a mixed
+    one, so a tick runs every phase (its emits are of the tick before it)."""
     eng = _engine(cfg, params, prefix_cache=False, **overrides)
     for _ in range(2):  # the first pair runs to its end and leaves every table width compiled
         eng.run()
         eng.submit(list(range(1, 6)), 24)
         eng.submit(list(range(1, 25)), 4)
-    eng.step()
+    eng.step()  # the short prompt's one chunk
+    eng.step()  # the long prompt's first chunk rides with the first decode; reads the tick before back
     return eng
 
 
 def test_tick_spans_in_a_profiler_session(gpt2_setup, tmp_path):
-    """A profiler session round three ticks, each with a chunk and a live
-    decoder, holds the spans of a mixed tick: nested in its serving.tick, in
-    the tick's own order (one wait: the one dispatch), children sharing the
-    parent's ``tick``, the counts the issue's table gives as their stats."""
+    """A profiler session round three ticks, each reading back a tick with a
+    chunk and a live decoder, holds the spans of a mixed tick: nested in its
+    serving.tick, in the tick's own order (one wait: the launch of this tick
+    and the read-back of the one before; the emits are that tick's), children
+    sharing the parent's ``tick``, the counts the issue's table gives as their
+    stats.  The long prompt's third and last chunk rides in the second tick
+    traced: the third builds no chunk and emits that one's first token."""
     import glob
 
     from jax.profiler import ProfileData
@@ -358,20 +363,22 @@ def test_tick_spans_in_a_profiler_session(gpt2_setup, tmp_path):
     assert [e[3]["tick"] for e in ticks] == [first, first + 1, first + 2]
     assert set(ticks[0][3]) == {"tick", "queued", "prefilling", "decoding"}
     assert ticks[0][3]["prefilling"] == 1 and ticks[0][3]["decoding"] == 1
-    for start, end, _, stats in ticks:
+    live_before, prefilled = 1, None  # of the tick in flight when the session opened
+    for i, (start, end, _, stats) in enumerate(ticks):
         children = [e for e in events if e[2] != "serving.tick" and e[3]["tick"] == stats["tick"]]
         assert [e[2] for e in children] == ["serving.tick." + p for p in MIXED_TICK_PHASES]
         assert all(start <= e[0] and e[1] <= end for e in children)
         assert all(a[1] <= b[0] for a, b in zip(children, children[1:]))  # one after the other
         by_name = {e[2].removeprefix("serving.tick."): e[3] for e in children}
         assert by_name["admit"]["admitted"] == 0
-        assert set(by_name["prefill.build"]) == {"tick", "request", "start", "rows"}
-        assert by_name["prefill.emit"]["request"] == by_name["prefill.build"]["request"]
-        assert by_name["prefill.emit"]["first_token"] in (0, 1)
-        assert by_name["decode.build"]["live"] == by_name["decode.wait"]["live"] >= 1
+        assert set(by_name["prefill.build"]) == ({"tick", "request", "start", "rows"} if i < 2 else {"tick"})
+        prefilled = by_name["prefill.build"].get("request", prefilled)
+        assert by_name["prefill.emit"]["request"] == prefilled
+        assert by_name["prefill.emit"]["first_token"] == int(i == 2)
+        assert by_name["decode.build"]["live"] == by_name["decode.wait"]["live"] == (1 if i < 2 else 2)
         assert set(by_name["decode.wait"]) == {"tick", "live", "width"} and by_name["decode.wait"]["width"] >= 1
-        assert by_name["decode.emit"]["tokens"] == by_name["decode.build"]["live"]
-    assert sum(e[3]["first_token"] for e in events if e[2] == "serving.tick.prefill.emit") == 1
+        assert by_name["decode.emit"]["tokens"] == live_before  # the emits are of the tick dispatched before this one
+        live_before = by_name["decode.build"]["live"]
 
 
 def test_slow_ticks_keep_the_slowest_and_say_which_phase(gpt2_setup, tmp_path, monkeypatch):
@@ -398,9 +405,14 @@ def test_slow_ticks_keep_the_slowest_and_say_which_phase(gpt2_setup, tmp_path, m
     slow = eng.stats()["slow_ticks"]
     assert len(slow) == SLOW_TICKS == 8
     assert [t["total_ms"] for t in slow] == sorted((t["total_ms"] for t in slow), reverse=True)
+    by_tick = {t["tick"]: t for t in slow}
+    assert by_tick[held]["pipelined"] is True and by_tick[held]["settle"] is None
     for t in slow:
-        assert set(t) == {"tick", "total_ms", "phase_ms", "live", "prefilling", "width", "mixed", "gc_count"}
-        assert t["mixed"] == ("prefill.emit" in t["phase_ms"] and "decode.emit" in t["phase_ms"])
+        assert set(t) == {"tick", "total_ms", "phase_ms", "live", "prefilling", "width", "mixed", "pipelined", "settle", "gc_count"}
+        assert isinstance(t["pipelined"], bool) and t["settle"] in (None, "idle")  # "idle": the last tick of a warm-up run
+        before = by_tick.get(t["tick"] - 1)
+        if before is not None and t["settle"] is None:  # a tick's emits are of the tick dispatched before it
+            assert before["mixed"] == ("prefill.emit" in t["phase_ms"] and "decode.emit" in t["phase_ms"])
         assert abs(sum(t["phase_ms"].values()) - t["total_ms"]) < 1.0
         assert set(t["phase_ms"]) <= set(TICK_PHASES) and len(t["gc_count"]) == 3
     assert slow[0]["tick"] == held and slow[0]["phase_ms"]["admit"] >= 50.0

@@ -426,6 +426,43 @@ def test_spec_counters_and_verify_phase_conservation(gpt2_setup, tmp_path):
     assert saw_verify, "no verify interval reached the traces"
 
 
+@pytest.mark.parametrize("spec_tokens", [0, 2], ids=["greedy", "window"])
+def test_a_verify_window_engine_settles_every_tick(gpt2_setup, spec_tokens):
+    """The accepted count decides ``cache_len`` and the drafter reads the
+    tokens, so with ``spec_tokens > 0`` every tick is read back before the
+    next is built: no dispatch is made with a tick unread, one ``spec``
+    settle a dispatch, no tick in flight between steps, and the tokens and
+    the accepted counts are the synchronous engine's (the oracle's).  The
+    same engine without a window pipelines every tick but the first: the
+    depth follows from ``programs.window``, nothing is configured."""
+    cfg, params = gpt2_setup
+    rng = np.random.default_rng(47)
+    pattern = [int(t) for t in rng.integers(0, cfg.vocab_size, size=4)]
+    eng = ServingEngine(
+        gpt2.apply_cached, gpt2.init_cache, params, cfg,
+        serving=ServingConfig(block_size=4, num_blocks=40, max_slots=2,
+                              prefill_chunk=8, max_blocks_per_seq=8,
+                              prefix_cache=False, spec_tokens=spec_tokens),
+    )
+    prompts = [pattern * 2 + pattern[:j] for j in (0, 2)]
+    rids = [eng.submit(p, 9) for p in prompts]
+    while not eng.sched.idle():
+        eng.step()
+        assert spec_tokens == 0 or eng._flight is None
+    stats = eng.stats()
+    dispatches = stats["prefill_dispatches"] + stats["decode_dispatches"] - stats["mixed_dispatches"]
+    assert dispatches == stats["ticks"]
+    if spec_tokens:
+        assert stats["pipelined_ticks"] == 0 and stats["settles"] == {"spec": dispatches}
+        assert stats["spec"]["rounds"] == stats["decode_dispatches"] and stats["spec"]["accepted"] > 0
+        assert stats["spec"]["tokens_per_dispatch"] > 1.0
+    else:
+        assert stats["pipelined_ticks"] == dispatches - 1 and stats["settles"] == {"idle": 1}
+    done = {c.id: c.tokens for c in eng.pop_finished()}
+    for rid, prompt in zip(rids, prompts):
+        assert done[rid] == _oracle(cfg, params, prompt, 9)
+
+
 def test_spec_report_block_renders(gpt2_setup, tmp_path):
     """The telemetry report's serving block includes the speculative line
     when verify rounds ran."""

@@ -509,7 +509,7 @@ def _lowered_paged_decode():
     assert eng.decode_path == "paged"
     return eng.programs.decode.lower(
         params, eng.cache.pool, np.zeros((2, 2), np.int32), np.zeros((2,), np.int32), np.zeros((2, 1), np.int32),
-        np.zeros((2,), np.int32),
+        np.zeros((2,), np.int32), np.zeros((3,), np.int32), np.zeros((2,), np.int32),
     )
 
 

@@ -198,7 +198,7 @@ def check_mixed_step(case, width):
         return P._packed(parts, counters), P._write_rows(pool, rows, tables, starts, MIXED_CHUNK)
 
     i32 = lambda *shape: sds(shape, jnp.int32)
-    lanes = (i32(MIXED_SLOTS, width), i32(MIXED_SLOTS), i32(MIXED_SLOTS, 1), i32(MIXED_SLOTS))
+    lanes = (i32(MIXED_SLOTS, width), i32(MIXED_SLOTS), i32(MIXED_SLOTS, 1), i32(MIXED_SLOTS), i32(MIXED_SLOTS + 1), i32(MIXED_SLOTS))
     chunk = (i32(width), i32(), i32(1, MIXED_CHUNK), i32())
     compiled = {
         "decode": built.decode.lower(params, pool, *lanes).compile(),
@@ -246,7 +246,7 @@ def check_state_step(width):
     serving = ServingConfig(block_size=16, num_blocks=blocks, max_slots=slots, max_blocks_per_seq=128, prefill_chunk=chunk_rows)
     built = P.build_programs(lf.apply_cached, c, ["k", "v"], serving, 0, stateful=True)
     i32 = lambda *shape: sds(shape, jnp.int32)
-    lanes = (i32(slots, width), i32(slots), i32(slots, 1), i32(slots))
+    lanes = (i32(slots, width), i32(slots), i32(slots, 1), i32(slots), i32(slots + 1), i32(slots))  # the feed and the lanes' sources among them
     chunk = (i32(width), i32(), i32(1, chunk_rows), i32())
     programs = {"decode": (built.decode, (*lanes, i32(slots))), "decode_chunk": (built.decode_chunk, (*lanes, *chunk, i32(slots), i32()))}
     sized = re.compile(r"= \w+\[(%d|3,%d|%d)," % (3 * blocks, blocks, blocks))
