@@ -8,6 +8,8 @@ reference's torch generation loop, BASELINE.md s/token tables)."""
 
 from __future__ import annotations
 
+import contextlib
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -23,6 +25,7 @@ __all__ = [
     "address_paged_pool_by_layer", "address_paged_leaf_by_layer",
     "unpack_paged_rows_from_scan", "demote_pool_blocks", "promote_pool_blocks",
     "STATE", "token_leaves", "state_leaves", "with_token_leaves", "read_state_rows", "write_state_rows",
+    "MASKED", "block_end", "denoise_schedule", "block_unmask", "block_generate_loop",
 ]
 
 
@@ -236,18 +239,23 @@ def scatter_token_rows(
     tables: jax.Array,
     start: jax.Array,
     count: int,
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Write token rows ``[S, L, count, *r]`` back into the pool at positions
     ``start[s] + arange(count)`` through block tables ``[S, M]``.  Positions
     past the table extent (chunked-prefill padding) are routed to the null
     block explicitly — ``take_along_axis`` would otherwise CLAMP the block
-    index and corrupt a real block."""
+    index and corrupt a real block.  With ``keep [S]`` the rows of the lanes
+    where it is 0 are written nowhere (a block past the pool: dropped), not
+    even to the null block: a denoising pass leaves the pool as it was."""
     bs = pool_leaf.shape[2]
     m = tables.shape[1]
     pos = _token_positions(start, count)  # [S, count]
     blk_idx = pos // bs
     blk = jnp.take_along_axis(tables, jnp.clip(blk_idx, 0, m - 1), axis=1)
     blk = jnp.where(blk_idx < m, blk, 0)
+    if keep is not None:
+        blk = jnp.where(keep[:, None] > 0, blk, pool_leaf.shape[1])
     off = pos % bs
     rows = jnp.moveaxis(rows, 0, 1)  # [L, S, count, *r]
     if pool_leaf.ndim == 4:
@@ -259,7 +267,7 @@ def scatter_token_rows(
         # written where it lies.
         layer = jnp.arange(pool_leaf.shape[0], dtype=jnp.int32)[:, None, None]
         return pool_leaf.at[layer, blk[None], off[None]].set(rows)
-    return pool_leaf.at[:, blk, off].set(rows)
+    return pool_leaf.at[:, blk, off].set(rows)  # an index past the leaf (``keep``) is dropped: a scatter's default
 
 
 def demote_pool_blocks(pool: dict, blocks) -> dict:
@@ -462,15 +470,27 @@ def address_paged_pool_by_layer(pool: dict, tables: jax.Array, layer: jax.Array)
     return leaves["k"], leaves["v"], tables
 
 
-def group_positions(groups, block_size: int):
+def block_end(positions: jax.Array, block_length: int) -> jax.Array:
+    """The last position of the block of ``block_length`` a position lies in:
+    the last key it sees under the block-causal mask (itself, at length 1)."""
+    return positions if block_length == 1 else positions // block_length * block_length + (block_length - 1)
+
+
+def group_positions(groups, block_size: int, block_length: int = 1):
     """Of every group ``(tokens [B, T], tables [B, M], starts [B])`` of an
     ``apply_paged`` call: the positions of its new tokens ``[B, T]`` (row
     ``b``'s sit at ``starts[b] .. starts[b] + T - 1``) and its attention mask
-    over the context its own tables name, ``[B, T, M * block_size]``."""
+    over the context its own tables name, ``[B, T, M * block_size]``.  A
+    position sees the keys up to its own; with ``block_length`` ``B > 1`` (a
+    family generated by diffusion over blocks) up to the end of its block, ``(p
+    // B) * B + B - 1``: causal over blocks, full inside one, for the chunk's
+    group (the prompt is block-causal) and for the lanes' (a block sees all of
+    itself) alike."""
     positions = tuple(_token_positions(starts, tokens.shape[1]) for tokens, _, starts in groups)
-    masks = tuple(
-        pos[:, :, None] >= jnp.arange(tables.shape[1] * block_size, dtype=jnp.int32)[None, None, :]
-        for pos, (_, tables, _) in zip(positions, groups))
+    with jax.named_scope("attn.block") if block_length > 1 else contextlib.nullcontext():
+        masks = tuple(
+            block_end(pos, block_length)[:, :, None] >= jnp.arange(tables.shape[1] * block_size, dtype=jnp.int32)[None, None, :]
+            for pos, (_, tables, _) in zip(positions, groups))
     return positions, masks
 
 
@@ -637,6 +657,134 @@ def generate_loop(
         jnp.concatenate([toks.T, last[:, None]], axis=1) if max_new_tokens > 1 else last[:, None]
     )
     return jnp.concatenate([input_ids, generated], axis=1)
+
+
+# ---------------------------------------------------------------------------
+# Generation by diffusion over blocks (models/sdar_moe.py): a block of
+# ``block_length`` positions starts masked, denoising passes unmask the most
+# confident positions a few at a time against the cache of everything before
+# the block, writing nothing, and one commit pass writes the finished block.
+# ---------------------------------------------------------------------------
+
+MASKED = -1  # a masked position of a block's state; the model reads ``mask_token_id`` there
+
+
+def denoise_schedule(block_length: int, denoise_steps: Optional[int]) -> list:
+    """Positions a denoising pass unmasks under the static schedule, pass by
+    pass: ``block_length // T`` each, one more in the first ``block_length mod
+    T`` (``T`` = ``denoise_steps``, default the block's length: one a pass).  A
+    pass never unmasks more than are still masked, so a first block that opens
+    on prompt tokens may finish in fewer passes."""
+    steps = block_length if denoise_steps is None else int(denoise_steps)
+    if not 1 <= steps <= block_length:
+        raise ValueError(f"denoise_steps must lie in 1..block_length ({block_length}), got {denoise_steps}")
+    return [block_length // steps + (t < block_length % steps) for t in range(steps)]
+
+
+def block_unmask(state: jax.Array, logits: jax.Array, count: jax.Array, threshold: jax.Array) -> jax.Array:
+    """One denoising pass's choice: ``state [B, W]`` (token ids, ``MASKED`` where
+    masked), ``logits [B, W, V]`` of that state -> the new state.  At every masked
+    position the candidate is the argmax and its confidence the softmax's value
+    there, in float32.  Unmasked: the ``count [B]`` masked positions of highest
+    confidence (ties to the lower position; never more than are masked), or every
+    masked position whose confidence exceeds ``threshold [B]`` where those are
+    more (a threshold no confidence reaches, 2.0, is the static schedule).
+    Unmasked positions keep their token for good.  The one rule, shared by
+    :func:`block_generate_loop` and the serving programs' head."""
+    masked = state == MASKED
+    logits = logits.astype(jnp.float32)
+    best = jnp.max(logits, axis=-1)
+    token = jnp.argmax(logits, axis=-1).astype(state.dtype)
+    confidence = jnp.exp(best - jax.nn.logsumexp(logits, axis=-1))
+    score = jnp.where(masked, confidence, -1.0)
+    at = jnp.arange(state.shape[1])
+    ahead = (score[:, None, :] > score[:, :, None]) | ((score[:, None, :] == score[:, :, None]) & (at[None, None, :] < at[None, :, None]))
+    by_count = masked & (jnp.sum(ahead, axis=-1) < count[:, None])
+    over = masked & (confidence > threshold[:, None])
+    chosen = jnp.where((jnp.sum(over, axis=-1) > count)[:, None], over, by_count)
+    return jnp.where(chosen, token, state)
+
+
+@functools.lru_cache(maxsize=16)
+def _jitted_cached_step(apply_cached: Callable, config):
+    return jax.jit(lambda params, ids, cache: apply_cached(params, ids, config, cache))
+
+
+def block_generate_loop(
+    apply_cached: Callable,
+    init_cache: Callable,
+    params,
+    input_ids: jax.Array,
+    config,
+    max_new_tokens: int,
+    denoise_steps: Optional[int] = None,
+    confidence_threshold: Optional[float] = None,
+    max_len: Optional[int] = None,
+    prefill_chunk: Optional[int] = None,
+    return_passes: bool = False,
+):
+    """Greedy generation by diffusion over blocks of ``config.block_length``:
+    dense prompt ``[B, S]`` -> ``[B, S + max_new_tokens]``, the serving engine's
+    equivalence oracle for such a family (``generate_loop``'s place).
+
+    The first ``(S // B) * B`` prompt tokens are prefilled (whole blocks, in
+    chunks of ``prefill_chunk`` if given: a multiple of the block).  Block ``k``
+    covers the next ``B`` positions; its state opens on the remaining prompt
+    tokens (block 0 only) followed by masks.  A denoising pass runs the state
+    (``config.mask_token_id`` at the masks) through ``apply_cached`` and drops
+    the returned cache; :func:`block_unmask` unmasks by :func:`denoise_schedule`
+    (and by ``confidence_threshold``, if given).  When no mask is left, a commit
+    pass of the final tokens keeps its cache.  The last block's tail past
+    ``max_new_tokens`` is dropped.  A plain Python loop over jitted forwards:
+    an oracle, not a fast path.  ``return_passes`` also gives, for every new
+    token, the pass of its block that unmasked it ``[B, max_new_tokens]``."""
+    width = int(config.block_length)
+    b, s = input_ids.shape
+    if width == 1 and not return_passes:
+        # A block of one is the causal mask and the engine serves it one row a tick: the autoregressive loop.
+        return generate_loop(apply_cached, init_cache, params, input_ids, config, max_new_tokens, max_len=max_len,
+                             prefill_chunk=prefill_chunk)
+    if max_new_tokens < 0:
+        raise ValueError(f"max_new_tokens must be >= 0, got {max_new_tokens}")
+    schedule = denoise_schedule(width, denoise_steps)
+    if prefill_chunk is not None and (prefill_chunk < 1 or prefill_chunk % width):
+        raise ValueError(f"prefill_chunk must be a multiple of the block length {width}, got {prefill_chunk}")
+    if max_new_tokens == 0:
+        return (input_ids, jnp.zeros((b, 0), jnp.int32)) if return_passes else input_ids
+    prefilled = s // width * width
+    blocks = -(-(s - prefilled + max_new_tokens) // width)
+    total = prefilled + blocks * width
+    if max_len is None:
+        max_len = total
+    if total > max_len:
+        raise ValueError(f"prompt ({s}) + max_new_tokens ({max_new_tokens}) in whole blocks ({total}) > max_len ({max_len})")
+    step = _jitted_cached_step(apply_cached, config)
+    unmask = jax.jit(block_unmask)
+    cache = init_cache(config, b, max_len)
+    chunk = prefilled if prefill_chunk is None else prefill_chunk
+    for start in range(0, prefilled, max(chunk, 1)):
+        _, cache = step(params, input_ids[:, start : min(start + chunk, prefilled)], cache)
+    threshold = jnp.full((b,), 2.0 if confidence_threshold is None else confidence_threshold, jnp.float32)
+    out, passes = [input_ids[:, :prefilled]], []
+    state = jnp.concatenate(
+        [input_ids[:, prefilled:].astype(jnp.int32), jnp.full((b, width - (s - prefilled)), MASKED, jnp.int32)], axis=1)
+    for _ in range(blocks):
+        unmasked_at = jnp.where(state == MASKED, -1, 0)
+        t = 0
+        while bool(jnp.any(state == MASKED)):
+            logits, _ = step(params, jnp.where(state == MASKED, config.mask_token_id, state), cache)  # writes nothing
+            count = jnp.full((b,), schedule[t], jnp.int32)  # masks are left: fewer than T passes have run
+            new_state = unmask(state, logits, count, threshold)
+            unmasked_at = jnp.where((state == MASKED) & (new_state != MASKED), t, unmasked_at)
+            state, t = new_state, t + 1
+        _, cache = step(params, state, cache)  # the commit: the block's rows, from its final tokens
+        out.append(state)
+        passes.append(unmasked_at)
+        state = jnp.full((b, width), MASKED, jnp.int32)
+    tokens = jnp.concatenate(out, axis=1)[:, : s + max_new_tokens].astype(input_ids.dtype)
+    if return_passes:
+        return tokens, jnp.concatenate(passes, axis=1)[:, s - prefilled : s - prefilled + max_new_tokens]
+    return tokens
 
 
 def speculative_verify_greedy(

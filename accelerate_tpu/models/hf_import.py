@@ -39,6 +39,13 @@ Supported families and their HF architectures:
                 of ``n_shared_experts`` widths), ``e_score_correction_bias``
                 as ``router_bias``; the leading dense layers and the
                 expert layers land in the ``dense`` and ``moe`` stacks
+- ``sdar_moe`` — SDARMoeForCausalLM (SDAR-30B-A3B-Chat): the Qwen3-MoE parameter
+                names (``self_attn.{q,k,v,o}_proj``, ``q_norm`` / ``k_norm``,
+                ``mlp.gate`` as ``router``, ``mlp.experts.N.{gate,up,down}_proj``
+                stacked ``[L, E, ...]``), an untied ``lm_head``;
+                ``block_length`` and ``mask_token_id`` from the config where it
+                has them, else the family's defaults; held by a synthetic
+                round trip only (no published checkpoint is in the repository)
 - ``lfm2_moe`` — Lfm2MoeForCausalLM (LFM2-8B-A1B): each layer's operator into
                 the stack of its kind (``conv.in_proj`` / ``conv.conv`` /
                 ``conv.out_proj`` -> ``conv/{w_in, taps, w_out}``, the
@@ -104,7 +111,7 @@ def _stack_cat(sd: dict, fmts: list, n: int, transpose: bool = False) -> np.ndar
 
 def _detect_family(hf_config) -> str:
     mt = getattr(hf_config, "model_type", "")
-    known = {"llama", "gpt2", "bert", "t5", "mixtral", "deepseek_v3", "lfm2_moe", "vit", "resnet"}
+    known = {"llama", "gpt2", "bert", "t5", "mixtral", "deepseek_v3", "lfm2_moe", "sdar_moe", "vit", "resnet"}
     if mt in ("qwen2", "mistral", "gemma", "phi3"):
         # llama-architecture variants: qwen2 adds Q/K/V biases, mistral is
         # llama-shaped GQA, gemma swaps in GeGLU + (1+w) RMSNorm + sqrt(d)
@@ -358,6 +365,36 @@ def config_from_hf(hf_config, **overrides):
         )
         kw.update(overrides)
         return Lfm2MoeConfig(**kw)
+    if family == "sdar_moe":
+        from .sdar_moe import SdarMoeConfig
+
+        if getattr(c, "mlp_only_layers", None) or getattr(c, "decoder_sparse_step", 1) != 1:
+            raise ValueError("sdar_moe import: dense layers between the sparse ones are not implemented (models/sdar_moe.py)")
+        if getattr(c, "attention_bias", False) or getattr(c, "tie_word_embeddings", False) or getattr(c, "rope_scaling", None):
+            raise ValueError("sdar_moe import: attention biases, a tied head and a scaled RoPE are not implemented (models/sdar_moe.py)")
+        if getattr(c, "use_sliding_window", False):
+            raise ValueError("sdar_moe import requires use_sliding_window=False: attention is block-causal over the whole context")
+        kw = dict(
+            vocab_size=c.vocab_size,
+            hidden_size=c.hidden_size,
+            moe_intermediate_size=c.moe_intermediate_size,
+            num_layers=c.num_hidden_layers,
+            num_heads=c.num_attention_heads,
+            num_kv_heads=c.num_key_value_heads,
+            head_dim=getattr(c, "head_dim", None) or c.hidden_size // c.num_attention_heads,
+            num_experts=c.num_experts,
+            num_experts_per_tok=c.num_experts_per_tok,
+            norm_topk_prob=bool(c.norm_topk_prob),
+            max_seq_len=c.max_position_embeddings,
+            rope_theta=float(c.rope_theta),
+            rms_eps=float(c.rms_norm_eps),
+        )
+        # the generation's two sizes, where the config carries them (the published config.json does not: the defaults)
+        for ours, theirs in (("block_length", "block_length"), ("mask_token_id", "mask_token_id")):
+            if getattr(c, theirs, None) is not None:
+                kw[ours] = int(getattr(c, theirs))
+        kw.update(overrides)
+        return SdarMoeConfig(**kw)
     if family == "resnet":
         from .resnet import ResNetConfig
 
@@ -710,6 +747,38 @@ def _import_deepseek_v3(sd: dict, cfg) -> dict:
     return params
 
 
+def _import_sdar_moe(sd: dict, cfg) -> dict:
+    layers = range(cfg.num_layers)
+
+    def stack(fmt, transpose=False):
+        mats = [_np(sd[f"layers.{i}." + fmt]) for i in layers]
+        return np.stack([m.T for m in mats] if transpose else mats)
+
+    def experts(which: str) -> np.ndarray:
+        return np.stack([
+            np.stack([_np(sd[f"layers.{i}.mlp.experts.{j}.{which}.weight"]).T for j in range(cfg.num_experts)])
+            for i in layers
+        ])  # [L, E, in, out]
+
+    return {
+        "embed": _np(sd["embed_tokens.weight"]),
+        "layers": {
+            "ln_attn": stack("input_layernorm.weight"),
+            "wq": stack("self_attn.q_proj.weight", transpose=True),
+            "wk": stack("self_attn.k_proj.weight", transpose=True),
+            "wv": stack("self_attn.v_proj.weight", transpose=True),
+            "wo": stack("self_attn.o_proj.weight", transpose=True),
+            "ln_q": stack("self_attn.q_norm.weight"),
+            "ln_k": stack("self_attn.k_norm.weight"),
+            "ln_mlp": stack("post_attention_layernorm.weight"),
+            "router": stack("mlp.gate.weight", transpose=True),
+            "w_gate": experts("gate_proj"), "w_up": experts("up_proj"), "w_down": experts("down_proj"),
+        },
+        "final_norm": _np(sd["norm.weight"]),
+        "lm_head": _np(sd["lm_head.weight"]).T,
+    }
+
+
 def _import_lfm2_moe(sd: dict, cfg) -> dict:
     from .lfm2_moe import ATTENTION, CONV
 
@@ -895,6 +964,7 @@ _IMPORTERS = {
     "mixtral": _import_mixtral,
     "deepseek_v3": _import_deepseek_v3,
     "lfm2_moe": _import_lfm2_moe,
+    "sdar_moe": _import_sdar_moe,
     "vit": _import_vit,
     "resnet": _import_resnet,
 }
@@ -909,6 +979,7 @@ _PREFIXES = {
     "mixtral": ("model.",),
     "deepseek_v3": ("model.",),
     "lfm2_moe": ("model.",),
+    "sdar_moe": ("model.",),
     "vit": ("vit.",),
     "resnet": ("resnet.",),
 }

@@ -488,6 +488,12 @@ class PrefixCache:
         writes only move forward from there."""
         if chain_key in self._entries or block in self._by_block:
             return False
+        stale = self._host_entries.pop(chain_key, None)
+        if stale is not None and self._kv is not None and self._kv.host is not None:
+            # The chain was demoted, a lookup could not bring it back (no device block to promote into), and its
+            # rows were prefilled anew: the device copy is the entry now, and the host copy's block goes back to the
+            # tier (left in the map it was overwritten, and its block lost, at this entry's next demotion).
+            self._kv.host.free([stale])
         self.allocator.retain(block)
         self._entries[chain_key] = block
         self._by_block[block] = chain_key

@@ -29,7 +29,8 @@ One engine **tick** (:meth:`ServingEngine.step`) is:
    the first token after a last chunk), then every lane's tokens.
 
 **A tick is read back one dispatch late.**  A request finishes by count (the
-engine has no stop token), a decoding lane advances by one row a tick, block
+engine has no stop token), a decoding lane advances by a count known at
+dispatch (one row a tick; a block-diffusion lane by its schedule, below), block
 growth and tables depend on lengths and not on token values, and a chunk's
 rows are the prompt's: so everything tick N + 1's build needs from tick N is
 known when N is *dispatched*, except the token values, and those the device
@@ -63,8 +64,9 @@ dispatch late, the tick in flight computed one row more for it, which went
 to its own blocks and is dropped; the scrub is ordered behind it on the
 device's one stream), a step that leaves nothing to dispatch behind the tick
 in flight (``idle``: the last replies are handed over at once, so
-:meth:`~ServingEngine.run` ends with nothing unread) and
-:meth:`~ServingEngine.stats` (``stats``).
+:meth:`~ServingEngine.run` ends with nothing unread),
+:meth:`~ServingEngine.stats` (``stats``) and, for a block-diffusion family, a
+tick in which a live request has a confidence threshold (``blocks``).
 These are rare: the steady state never settles
 (``stats()["pipelined_ticks"]``, ``["settles"]``).  **A verify-window engine
 is the synchronous one**: with ``spec_tokens > 0`` the accepted count decides
@@ -72,6 +74,29 @@ is the synchronous one**: with ``spec_tokens > 0`` the accepted count decides
 (``spec``) before the next is built.  That is observed
 (``programs.window > 1``), not configured: one engine, whose depth (one tick
 ahead, or none) follows from what it sees.
+
+**Generation by diffusion over blocks.**  A family whose model config carries
+``block_length`` ``B > 1`` (``models/sdar_moe.py``) does not yield one token a
+lane a tick.  ``(P // B) * B`` prompt tokens are prefilled under the
+block-causal mask (``prefill_chunk`` and ``block_size`` multiples of ``B``); the
+last chunk **yields no token**, and the prompt's remainder opens the first
+block.  A ``DECODING`` lane carries a block (``_Block`` on its slot): ``B``
+positions, ``MASKED`` where masked.  Each tick it runs a **denoising pass** (the
+head unmasks the most confident masked positions, ``count`` by the static
+schedule of the request's ``denoise_steps``; nothing is written to the pool;
+``cache_len`` stays) or, when no mask is left, the **commit pass** (the block's
+rows written; ``cache_len`` += ``B``; the next block opens on masks).  A block's
+tokens are emitted together when its last denoising pass is read back, those
+past ``max_new_tokens`` dropped, so TTFT is the first block's; a request's last
+block needs no commit.  The feed is every lane's block state ``[max_slots,
+B]``.  Under the static schedule every one of these is known **by count** when
+the tick is dispatched (``_book_pass``), so the pipeline above keeps running;
+with a ``confidence_threshold`` on a live request the count is a value, and the
+engine settles every tick (``blocks``), observed and not configured, as the
+verify window does.  Preemption re-prefills ``prompt + emitted`` (whole blocks;
+the block in progress restarts to the same tokens), the prefix cache reuses
+whole pool blocks below the prefilled rows, ``spec_tokens > 0`` is refused.
+``generation.block_generate_loop`` is this path's equivalence oracle.
 
 ``serving/programs.py`` builds the two programs and owns how a dispatch reads
 the pool; the family decides which of its two back ends serves.  A family
@@ -127,7 +152,8 @@ the last reference drops — never under a live reader.
 Token selection is **greedy** (argmax, inside the fused program): outputs are
 token-identical to the offline ``generate_loop`` with ``temperature=0`` per
 request, which is the engine's equivalence oracle (``tests/test_serving.py``,
-``make serving-smoke``).
+``make serving-smoke``); for a block-diffusion family to
+``block_generate_loop`` (``tests/test_serving_blocks.py``).
 
 Chunked-prefill padding contract: chunks are padded to the static
 ``prefill_chunk`` length.  Padded queries produce ignored logits; padded K/V
@@ -190,7 +216,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..models.generation import with_token_leaves
+from ..models.generation import MASKED, denoise_schedule, with_token_leaves
 from ..telemetry import annotate, get_telemetry
 from .blocks import (
     NULL_BLOCK,
@@ -344,6 +370,44 @@ class CompletedRequest:
     migrations: int = 0
     fallback_reprefills: int = 0
     prefill_dispatches: int = 0
+    # Generation by diffusion over blocks: of every new token, the denoising pass (0-based) of its block that
+    # unmasked it, so that a checker can rebuild what each pass saw.  Empty for an autoregressive family.
+    token_passes: List[int] = field(default_factory=list)
+
+
+class _Block:
+    """The block a decoding lane of a block-diffusion family carries.
+    ``state`` is the host's copy of its ``W`` positions as last read back
+    (token ids, ``MASKED`` where masked); ``passes`` the denoising pass that
+    unmasked each position (-1: a prompt token, or still masked); ``new`` how
+    many of the positions are the request's new tokens (all but the prompt's
+    remainder, which opens the first block).  ``masked`` and ``t`` run ahead of
+    ``state`` under the static schedule: positions still masked after, and
+    denoising passes among, the passes *dispatched*; with a confidence
+    threshold they are booked when the pass is read."""
+
+    __slots__ = ("state", "passes", "new", "masked", "t", "schedule")
+
+    def __init__(self, width: int, opening: List[int], schedule: List[int]):
+        self.state = list(opening) + [MASKED] * (width - len(opening))
+        self.passes = [-1] * width
+        self.new = self.masked = width - len(opening)
+        self.t = 0
+        self.schedule = schedule
+
+    def next_count(self) -> int:
+        """Positions the next denoising pass unmasks by the static schedule; 0: no mask is left, the block commits."""
+        return min(self.schedule[self.t], self.masked) if self.masked else 0
+
+
+class _Pass(NamedTuple):
+    """One lane's part of a dispatched tick of a block-diffusion family."""
+
+    block: _Block
+    commit: bool  # the block was final: its rows were written, nothing is unmasked
+    t: int  # the denoising pass's number within its block
+    emit: Optional[int]  # booked at dispatch, by count: None while the block has masks left, else the tokens it emits
+    by_count: bool  # False for a request with a confidence threshold: what a pass unmasks is known when it is read
 
 
 class _Chunk(NamedTuple):
@@ -367,8 +431,9 @@ class _Lanes(NamedTuple):
 
     live: List[int]
     tokens: np.ndarray
-    draft_len: np.ndarray
+    draft_len: np.ndarray  # a block-diffusion family: the positions each lane's pass unmasks
     source: np.ndarray
+    extra: tuple = ()  # a block-diffusion family: (commit [S], threshold [S])
 
 
 class _Flight(NamedTuple):
@@ -384,6 +449,7 @@ class _Flight(NamedTuple):
     width: int
     fresh: bool
     t0: float  # the launch, for dispatch_ms
+    passes: Optional[List[_Pass]] = None  # a block-diffusion family: what each of ``lanes`` did
 
 
 class _TickPhase:
@@ -478,6 +544,8 @@ class ServingEngine:
         enable_compile_cache()
         self.params = params
         self.spec_tokens = int(sc.spec_tokens)
+        # A family generated by diffusion over blocks says so in its model config (1: every other family).
+        self.block_length = int(getattr(config, "block_length", 1))
         self.cache = PagedKVCache(
             init_cache, config, sc.num_blocks, sc.block_size,
             num_host_blocks=sc.host_blocks, num_slots=sc.max_slots,
@@ -503,7 +571,7 @@ class ServingEngine:
             block_size=sc.block_size,
             max_blocks_per_seq=sc.resolved_max_blocks(),
             prefill_chunk=sc.prefill_chunk,
-            spec_overshoot=self.spec_tokens,
+            spec_overshoot=self.block_length if self.block_length > 1 else self.spec_tokens,
         )
         max_len = sc.resolved_max_blocks() * sc.block_size
         model_max = getattr(config, "max_seq_len", None)
@@ -521,6 +589,12 @@ class ServingEngine:
         self.decode_dispatches = 0
         self.decode_emitted_tokens = 0
         self.decode_slot_ticks = 0
+        # Of decode_slot_ticks, for a block-diffusion family: lane-ticks that denoised and that committed, blocks
+        # written to the pool, and new tokens emitted (whole blocks, the last one's tail dropped).
+        self.denoise_slot_ticks = 0
+        self.commit_slot_ticks = 0
+        self.blocks_committed = 0
+        self.block_tokens_emitted = 0
         self.spec_rounds = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -533,7 +607,8 @@ class ServingEngine:
         self._flight: Optional[_Flight] = None
         # What the last dispatch returned as its feed, on the device: the next dispatch's, whose lanes read it where
         # their source says so (after a settle none does).  Zeros made as the pool's leaves are, until the first.
-        self._feed = jnp.zeros((sc.max_slots + 1,), jnp.int32)
+        feed_shape = (sc.max_slots, self.block_length) if self.block_length > 1 else (sc.max_slots + 1,)
+        self._feed = jnp.zeros(feed_shape, jnp.int32)
         self.pipelined_ticks = 0
         self.settles: Dict[str, int] = {}
         self.shed_count = 0
@@ -704,6 +779,12 @@ class ServingEngine:
                 "serving.tier.demoted_blocks", "serving.tier.fallback_reprefills",
             ):
                 tel.registry.counter(name)
+            if self.block_length > 1:
+                for name in (
+                    "serving.denoise_slot_ticks", "serving.commit_slot_ticks",
+                    "serving.blocks_committed", "serving.block_tokens_emitted",
+                ):
+                    tel.registry.counter(name)
             tel.registry.gauge("serving.spec.acceptance_rate").set(0.0)
             tel.registry.gauge("serving.tokens_per_dispatch").set(0.0)
             tel.registry.gauge("serving.tier.host_bytes").set(0)
@@ -742,9 +823,19 @@ class ServingEngine:
         tag: Optional[str] = None,
         ttft_deadline_ms: Optional[float] = None,
         deadline_ms: Optional[float] = None,
+        denoise_steps: Optional[int] = None,
+        confidence_threshold: Optional[float] = None,
     ) -> int:
         """Queue one request; returns its id.  ``max_new_tokens == 0``
         completes immediately (the offline loop's contract).
+
+        ``denoise_steps`` and ``confidence_threshold`` are for a family
+        generated by diffusion over blocks alone (``ValueError`` for any
+        other): the denoising passes a block takes (1 .. block length; default
+        one a position) and the confidence over which a masked position is
+        unmasked ahead of that schedule (default none: the static schedule, by
+        which the engine stays one tick ahead; with a threshold on any live
+        lane it settles every tick, ``settles["blocks"]``).
 
         Raises :class:`AdmissionRejected` when the queue is at
         ``max_queue_depth`` (load shedding — ``serving.shed``); ``ValueError``
@@ -775,11 +866,20 @@ class ServingEngine:
                 f"admission queue full ({self.sched.pending} >= "
                 f"max_queue_depth {sc.max_queue_depth}): request shed"
             )
+        if self.block_length > 1:
+            denoise_schedule(self.block_length, denoise_steps)  # refuses a count outside 1 .. block length
+        elif denoise_steps is not None or confidence_threshold is not None:
+            raise ValueError(
+                "denoise_steps and confidence_threshold are for a family generated by diffusion over blocks "
+                "(a model config with block_length > 1); this engine's family decodes one token a step"
+            )
         req = Request(
             list(np.asarray(prompt_ids).reshape(-1)),
             max_new_tokens,
             arrival_t,
             tag=tag,
+            denoise_steps=denoise_steps,
+            confidence_threshold=confidence_threshold,
             ttft_deadline_ms=(
                 ttft_deadline_ms if ttft_deadline_ms is not None
                 else sc.default_ttft_deadline_ms
@@ -1038,6 +1138,8 @@ class ServingEngine:
                         tag=rec.get("tag"),
                         ttft_deadline_ms=rec.get("ttft_deadline_ms"),
                         deadline_ms=rec.get("deadline_ms"),
+                        denoise_steps=rec.get("denoise_steps"),
+                        confidence_threshold=rec.get("confidence_threshold"),
                     )
                     mapping[rec["id"]] = rid
                     if self.tracer is not None:
@@ -1338,6 +1440,11 @@ class ServingEngine:
             return
         feed = slot.request.to_feed
         max_rows = len(feed) - 1
+        if self.block_length > 1:
+            # Whole pool blocks of the rows that are prefilled: under the block-causal mask a pool block's rows depend
+            # on nothing past its own end (block_size is a multiple of the block length), and the chunk yields no
+            # token, so nothing has to be left to process.  No copy-on-write tail: a lane's rows start a block.
+            max_rows = self._prefill_rows(feed) // self.serving.block_size * self.serving.block_size
         if max_rows < self.serving.block_size:
             return
         blocks, rows, cow_src = self._prefix.lookup(feed, max_rows)
@@ -1451,11 +1558,29 @@ class ServingEngine:
         every lane reads the host's token (``FEED_HOST`` is 0), and the feed is
         the last dispatch's, as in every dispatch (one signature a program)."""
         s = self.serving.max_slots
-        return [
+        lanes = [
             np.zeros((s, width), np.int32), np.zeros((s,), np.int32),
             np.zeros((s, self.programs.window), np.int32), np.zeros((s,), np.int32),
             self._feed, np.zeros((s,), np.int32),
         ]
+        if self.block_length > 1:  # no lane commits, no threshold a confidence reaches
+            lanes += [np.zeros((s,), np.int32), np.full((s,), 2.0, np.float32)]
+        return lanes
+
+    def _prefill_rows(self, feed: List[int]) -> int:
+        """How many of a request's ``to_feed`` rows are prefilled in chunks: all
+        of them, or for a block-diffusion family its whole blocks (the
+        remainder opens the first block a lane carries)."""
+        return len(feed) // self.block_length * self.block_length
+
+    def _open_blocks(self, slot) -> None:
+        """The slot's prompt is in the pool up to its last whole block: it
+        decodes from the next tick on, carrying the first block, which opens on
+        the rest of the prompt and then masks."""
+        req = slot.request
+        req.state = RequestState.DECODING
+        slot.block = _Block(
+            self.block_length, req.to_feed[slot.cache_len :], denoise_schedule(self.block_length, req.denoise_steps))
 
     def _build_chunk(self) -> Optional[_Chunk]:
         """The tick's prefill chunk: the next ``prefill_chunk`` tokens of the
@@ -1463,6 +1588,12 @@ class ServingEngine:
         slot is prefilling, or the slot itself was preempted to find blocks."""
         sched = self.sched
         with _TickPhase(self, "prefill.build") as span:
+            if self.block_length > 1:
+                # nothing left to prefill (a prompt shorter than a block, a prefix hit on every whole block, a
+                # promoted migration victim): the slot decodes at once
+                for slot in sched.slots.values():
+                    if slot.request.state == RequestState.PREFILLING and slot.cache_len >= self._prefill_rows(slot.request.to_feed):
+                        self._open_blocks(slot)
             candidates = [
                 (slot.admit_seq, idx)
                 for idx, slot in sched.slots.items()
@@ -1475,7 +1606,7 @@ class ServingEngine:
             feed = slot.request.to_feed
             start = slot.cache_len
             chunk_len = self.serving.prefill_chunk
-            n_real = min(chunk_len, len(feed) - start)
+            n_real = min(chunk_len, self._prefill_rows(feed) - start)
             if not sched.grow_to(idx, start + n_real):
                 return None
             tokens = np.zeros((1, chunk_len), np.int32)
@@ -1530,6 +1661,9 @@ class ServingEngine:
             ]
             if not live:
                 return None
+            if self.block_length > 1:
+                span.set_metadata(live=len(live), drafted=0)
+                return self._block_lanes(live)
             s = self.serving.max_slots
             tokens = np.zeros((s, window), np.int32)
             draft_len = np.zeros((s,), np.int32)
@@ -1549,6 +1683,32 @@ class ServingEngine:
                     draft_len[idx] = len(d)
             span.set_metadata(live=len(live), drafted=len(drafts))
             return _Lanes(live, tokens, draft_len, source)
+
+    def _block_lanes(self, live: List[int]) -> _Lanes:
+        """The decode batch of a block-diffusion family: of every live lane
+        its block's state (the host's copy, or ``FEED_LANE`` where a pass of
+        this block is in the tick in flight and the newer state is on the
+        device alone), how many positions this pass unmasks by the static
+        schedule (0: no mask is left, the lane commits), and the request's
+        confidence threshold (2.0, which no confidence reaches, without one)."""
+        s = self.serving.max_slots
+        tokens = np.zeros((s, self.block_length), np.int32)
+        count, source, commit = (np.zeros((s,), np.int32) for _ in range(3))
+        threshold = np.full((s,), 2.0, np.float32)
+        flight = self._flight
+        unread = {id(p.block) for p in flight.passes or ()} if flight is not None else ()
+        for idx in live:
+            slot = self.sched.slots[idx]
+            block = slot.block
+            if id(block) in unread:
+                source[idx] = FEED_LANE
+            else:
+                tokens[idx] = block.state
+            count[idx] = block.next_count()
+            commit[idx] = block.masked == 0
+            if slot.request.confidence_threshold is not None:
+                threshold[idx] = slot.request.confidence_threshold
+        return _Lanes(live, tokens, count, source, (commit, threshold))
 
     def _dispatch_tick(self, chunk: Optional[_Chunk], batch: Optional[_Lanes]) -> None:
         """The tick's ONE dispatch, of whatever the two builds left: the
@@ -1578,6 +1738,7 @@ class ServingEngine:
         if batch:
             args[2:4] = [batch.tokens, batch.draft_len]
             args[5] = batch.source
+            args[6:] = batch.extra
         if chunk:
             table_row = np.zeros((width,), np.int32)
             table_row[: len(chunk.slot.blocks)] = chunk.slot.blocks
@@ -1622,13 +1783,21 @@ class ServingEngine:
                 # Final chunk: its last real logits row IS the next token (a prefilling request has no token
                 # unread, so its feed is whole here).  The slot decodes from the next tick on, its first input the
                 # chunk's entry of this dispatch's feed.
-                final = chunk.slot.cache_len == len(req.to_feed)
-                if final:
+                final = chunk.slot.cache_len == self._prefill_rows(req.to_feed)
+                if final and self.block_length > 1:
+                    self._open_blocks(chunk.slot)  # the chunk's head yields no token: the first block opens on masks
+                elif final:
                     req.state = RequestState.DECODING
                     self._sent(chunk.slot)
-            for slot in lanes:
-                slot.cache_len += 1  # a verify window's accepted drafts are added when they are read
-                self._sent(slot)
+            passes = None
+            if self.block_length > 1:
+                passes = [self._book_pass(slot) for slot in lanes]
+                self._tick.update(
+                    denoising=sum(not p.commit for p in passes), committing=sum(p.commit for p in passes))
+            else:
+                for slot in lanes:
+                    slot.cache_len += 1  # a verify window's accepted drafts are added when they are read
+                    self._sent(slot)
             if live:
                 self.decode_dispatches += 1
                 self.decode_gather_bytes += gather_bytes
@@ -1647,11 +1816,16 @@ class ServingEngine:
                 if pipelined:
                     tel.registry.counter("serving.pipelined_ticks").inc()
             draft_len = batch.draft_len if batch else None
-            self._flight = _Flight(packed, chunk, final, lanes, draft_len, width, fresh, t0)
+            self._flight = _Flight(packed, chunk, final, lanes, draft_len, width, fresh, t0, passes)
             out = self._read(prev) if pipelined else None  # host sync point: the tick BEFORE this one is done here
         if pipelined:
             self._apply(prev, out)
-        if programs.window > 1:
+        if passes is not None:
+            if not all(p.by_count for p in passes):
+                # Under a confidence threshold what a pass unmasks depends on values: whether a lane's block is
+                # done, and with it the next tick's shape, is known when the pass is read.  Observed, not configured.
+                self._settle("blocks")
+        elif programs.window > 1:
             # The accepted count decides cache_len and the drafter reads the tokens: a verify-window engine is the
             # synchronous one, every tick read back before the next is built.
             self._settle("spec")
@@ -1664,6 +1838,38 @@ class ServingEngine:
         slot.unread += 1
         if slot.request.remaining == slot.unread:
             self.sched.retire(slot.idx)
+
+    def _book_pass(self, slot) -> _Pass:
+        """One lane of a block-diffusion family is dispatched: a commit of its
+        finished block (``cache_len`` advances by the block, the next block
+        opens on masks) or denoising pass ``t``, whose count the static
+        schedule gives: everything the next tick's build needs is booked
+        here.  Under a confidence threshold the count is a value: booked at the
+        read-back (:meth:`_emit_blocks`), before which nothing else is built."""
+        block = slot.block
+        t, by_count = block.t, slot.request.confidence_threshold is None
+        if block.masked == 0:
+            slot.cache_len += self.block_length
+            slot.block = _Block(self.block_length, [], block.schedule)
+            return _Pass(block, True, t, None, by_count)
+        emit = self._advance_block(slot, block, block.next_count()) if by_count else None
+        return _Pass(block, False, t, emit, by_count)
+
+    def _advance_block(self, slot, block: _Block, unmasked: int) -> Optional[int]:
+        """A denoising pass unmasked ``unmasked`` positions of the slot's block.
+        None while masks are left; else the block is done and the number of
+        tokens it emits: its new positions, cut at the request's last.  They
+        are booked as sent, and the lane retires if they end the request (a
+        request's last block needs no commit)."""
+        block.masked -= unmasked
+        block.t += 1
+        if block.masked:
+            return None
+        count = min(block.new, slot.request.remaining - slot.unread)
+        slot.unread += count
+        if slot.request.remaining == slot.unread:
+            self.sched.retire(slot.idx)
+        return count
 
     def _read(self, flight: _Flight) -> dict:
         """The host's view of what ``flight``'s program returned: the sync
@@ -1680,7 +1886,9 @@ class ServingEngine:
         dispatch_ms = (time.monotonic() - flight.t0) * 1e3
         if flight.chunk:
             self._emit_chunk(flight, int(out["chunk_token"][0]), bool(out["chunk_ok"][0]))
-        if flight.lanes:
+        if flight.lanes and flight.passes is not None:
+            self._emit_blocks(flight, out, dispatch_ms)
+        elif flight.lanes:
             self._emit_decode(flight, out, dispatch_ms)
         if self.quarantined_count > quarantined:
             # The tick in flight computed a row more for the poisoned slot: read it now, so that the dropped row
@@ -1731,8 +1939,9 @@ class ServingEngine:
                 self._quarantine(slot, time.monotonic())
                 return
             self._register_prefix_blocks(slot, chunk.start + chunk.n_real)
-            span.set_metadata(first_token=int(flight.final and not req.emitted))
-            if flight.final:
+            yields = flight.final and self.block_length == 1  # a block-diffusion family's chunk yields no token
+            span.set_metadata(first_token=int(yields and not req.emitted))
+            if yields:
                 # The first generated token of a fresh request (TTFT lands
                 # here) or the resume token of a re-prefilled one.
                 self._emit(slot, [token], time.monotonic())
@@ -1793,13 +2002,65 @@ class ServingEngine:
                         tel.registry.counter("serving.spec.accepted").inc(spec_accepted)
             span.set_metadata(tokens=emitted)
 
+    def _emit_blocks(self, flight: _Flight, out: dict, dispatch_ms: float) -> None:
+        """The read-back of a block-diffusion family's lanes: every lane's new
+        block state.  A denoising pass's newly unmasked positions get its
+        number; a pass that left no mask emits the block's new tokens together,
+        those past the request's last dropped."""
+        states, oks = out["tokens"], out["ok"]
+        # a lane quarantined at the read-back before this one computed a pass too many here: dropped
+        lanes = [(slot, p) for slot, p in zip(flight.lanes, flight.passes) if slot.request.state != RequestState.DONE]
+        denoised = committed = emitted = 0
+        with _TickPhase(self, "decode.emit") as span:
+            emit_t = time.monotonic()
+            if self.tracer is not None:
+                self.tracer.on_decode(
+                    [(slot.request, slot.idx) for slot, _ in lanes],
+                    emit_t, co_batch=len(flight.lanes), width=flight.width, fresh=flight.fresh, dispatch_ms=dispatch_ms,
+                    denoising=sum(not p.commit for _, p in lanes), committing=sum(p.commit for _, p in lanes),
+                )
+            for slot, p in lanes:
+                if not bool(oks[slot.idx]):
+                    self._quarantine(slot, emit_t)
+                    continue
+                committed += p.commit
+                denoised += not p.commit
+                if p.commit:
+                    continue
+                block, state = p.block, states[slot.idx].tolist()
+                fresh = [i for i, (old, new) in enumerate(zip(block.state, state)) if old == MASKED and new != MASKED]
+                for i in fresh:
+                    block.passes[i] = p.t
+                block.state = state
+                count = p.emit if p.by_count else self._advance_block(slot, block, len(fresh))
+                if count is not None:
+                    first = self.block_length - block.new
+                    emitted += count
+                    self._emit(slot, state[first : first + count], emit_t, sent=count, passes=block.passes[first : first + count])
+            span.set_metadata(tokens=emitted)
+        self.decode_slot_ticks += denoised + committed
+        self.denoise_slot_ticks += denoised
+        self.commit_slot_ticks += committed
+        self.blocks_committed += committed
+        self.decode_emitted_tokens += emitted
+        self.block_tokens_emitted += emitted
+        tel = get_telemetry()
+        if tel.enabled:
+            tel.registry.counter("serving.denoise_slot_ticks").inc(denoised)
+            tel.registry.counter("serving.commit_slot_ticks").inc(committed)
+            tel.registry.counter("serving.blocks_committed").inc(committed)
+            tel.registry.counter("serving.block_tokens_emitted").inc(emitted)
+
     # -- completion / metrics ------------------------------------------------
 
-    def _emit(self, slot, tokens: List[int], now: float) -> None:
+    def _emit(self, slot, tokens: List[int], now: float, sent: int = 1, passes: Optional[List[int]] = None) -> None:
         """The values of the tokens one dispatch produced for ``slot``, read at
-        ``now``.  The request completes when its last token is a value."""
+        ``now`` (``sent`` of them were booked as dispatched: one, or a block's).
+        The request completes when its last token is a value."""
         req = slot.request
-        slot.unread -= 1
+        slot.unread -= sent
+        if passes is not None:
+            req.token_passes.extend(passes)
         tel = get_telemetry()
         for token in tokens:
             req.emitted.append(token)
@@ -1856,6 +2117,7 @@ class ServingEngine:
             migrations=req.migrations,
             fallback_reprefills=req.fallback_reprefills,
             prefill_dispatches=req.prefill_dispatches,
+            token_passes=list(req.token_passes),
         )
         self._finished.append(rec)
         if self.journal is not None:
@@ -2081,6 +2343,19 @@ class ServingEngine:
             )
         return out
 
+    def _block_stats(self) -> dict:
+        """What ``stats()`` says of a family generated by diffusion over
+        blocks; any other family carries none of these keys."""
+        if self.block_length == 1:
+            return {}
+        return {
+            "block_length": self.block_length,
+            "denoise_slot_ticks": self.denoise_slot_ticks,
+            "commit_slot_ticks": self.commit_slot_ticks,
+            "blocks_committed": self.blocks_committed,
+            "block_tokens_emitted": self.block_tokens_emitted,
+        }
+
     def stats(self) -> dict:
         """The engine's counters, exact: a tick in flight is read back first
         (``settles["stats"]``), so every token dispatched is counted and every
@@ -2107,6 +2382,7 @@ class ServingEngine:
             "quarantined": self.quarantined_count,
             "pool_bytes": self.cache.pool_bytes(),
             **self._state_stats(),
+            **self._block_stats(),
             "free_pool_bytes": alloc.free_blocks * self._block_bytes,
             "decode_path": self.decode_path,
             "decode_gather_bytes": self.decode_gather_bytes,

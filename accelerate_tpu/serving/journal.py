@@ -116,6 +116,8 @@ class ServingJournal:
             "tag": req.tag,
             "ttft_deadline_ms": req.ttft_deadline_ms,
             "deadline_ms": req.deadline_ms,
+            "denoise_steps": req.denoise_steps,
+            "confidence_threshold": req.confidence_threshold,
             "emitted": [],
             # Wall-clock admission anchor: the tracer's cross-life stitcher
             # dates the victim's life from it even when the victim never

@@ -47,7 +47,17 @@ no operation more.
   ``apply_cached`` lane by lane under ``vmap``, cut the written rows out of
   the updated views; a group at a time inside the one program.
 
-The family decides the back end; nothing selects it.  The two programs of an
+The family decides the back end, and the window of a decoding lane: a family
+generated by diffusion over blocks (a model config with ``block_length`` ``B >
+1`` and ``mask_token_id``: ``models/sdar_moe.py``) gets the **block heads**
+(:func:`_block_heads`): a lane's ``W = B`` rows are the block it carries, the
+feed is every lane's block state ``[max_slots, B]``, the head is "confidence and
+argmax at every masked position, unmask the ``count`` most confident", and the
+lanes' rows are written to the pool for the lanes that **commit** in this
+dispatch alone (``count``, ``commit``, ``threshold`` are per-lane inputs: still
+two programs a table width, still named ``decode*``); the chunk's head yields
+no token.  Every other family compiles to what is described below, argument
+for argument.  Nothing selects any of it.  The two programs of an
 engine, each compiled once per table width: ``decode`` (the decoding lanes
 alone: argmax of the one row a lane, or with ``spec_tokens > 0`` the ``k + 1``
 window through ``speculative_verify_greedy``) and ``decode_chunk`` (the same
@@ -94,7 +104,9 @@ import jax
 import jax.numpy as jnp
 
 from ..models.generation import (
+    MASKED,
     STATE,
+    block_unmask,
     extract_token_rows,
     gather_block_view,
     scatter_token_rows,
@@ -128,10 +140,11 @@ class ServingPrograms:
     decode: Callable  # (params, pool, tables [S, M], lengths [S], tokens [S, W], draft_len [S], feed [S + 1], source [S], *state, *poison)
     decode_chunk: Callable  # (..., source [S], table_row [M], start, chunk [1, C], n_real, *state, *poison) -> (packed, feed, pool), both
     stateful: bool  # the pool holds a state by slot: *state is (live [S],), with a chunk (live [S], slot)
-    window: int  # W: 1, or k + 1 under speculation
+    window: int  # W: 1, or k + 1 under speculation, or the family's block length
     max_slots: int
     min_blocks: int  # the narrowest table: MIN_TABLE_ROWS in blocks, a power of two
     max_blocks: int
+    block: int = 1  # B > 1: a lane carries a block of B positions through denoising passes and a commit (the block heads)
 
     def table_width(self, blocks_needed: int) -> int:
         """The block-table width of a dispatch whose widest lane needs
@@ -164,14 +177,15 @@ class ServingPrograms:
         family put behind them, else empty)."""
         flat = np.asarray(packed)
         s, w = self.max_slots, self.window
-        sizes = [("tokens", s * w), ("accepts", s if w > 1 else 0), ("ok", s)]
+        spec = w > 1 and self.block == 1  # a block family's [S, W] is every lane's block state: nothing is accepted
+        sizes = [("tokens", s * w), ("accepts", s if spec else 0), ("ok", s)]
         sizes += [("chunk_token", 1), ("chunk_ok", 1)] if with_chunk else []
         out, at = {}, 0
         for name, n in sizes:
             out[name] = flat[at : at + n]
             at += n
         out["tokens"] = out["tokens"].reshape(s, w)
-        out["accepts"] = out["accepts"] if w > 1 else np.zeros((s,), np.int32)
+        out["accepts"] = out["accepts"] if spec else np.zeros((s,), np.int32)
         out["counters"] = flat[at:]
         return out
 
@@ -189,17 +203,42 @@ def build_programs(
         raise ValueError("a family whose cache holds a state a sequence is served by its apply_paged: it has none")
     else:
         backend, forward = "dense", _dense_forward(apply_cached, config, leaf_names)
-    decode, decode_chunk = _heads(forward, stateful)
+    # The window of a decoding lane is the family's to say: a family generated by diffusion over blocks carries its
+    # block length (and the id its masked positions read) in its model config; any other compiles to what it had.
+    block = int(getattr(config, "block_length", 1))
+    if block > 1:
+        _check_block_geometry(block, backend, serving, spec_tokens, stateful)
+        decode, decode_chunk = _block_heads(forward, int(config.mask_token_id))
+    else:
+        decode, decode_chunk = _heads(forward, stateful)
     return ServingPrograms(
         backend=backend,
         decode=jax.jit(decode, donate_argnums=(1,)),
         decode_chunk=jax.jit(decode_chunk, donate_argnums=(1,)),
         stateful=stateful,
-        window=spec_tokens + 1,
+        window=block if block > 1 else spec_tokens + 1,
         max_slots=serving.max_slots,
         min_blocks=1 << max(0, -(-MIN_TABLE_ROWS // serving.block_size) - 1).bit_length(),
         max_blocks=serving.resolved_max_blocks(),
+        block=block,
     )
+
+
+def _check_block_geometry(block: int, backend: str, serving, spec_tokens: int, stateful: bool) -> None:
+    """What block generation cannot be served with, refused by name."""
+    if spec_tokens:
+        raise ValueError(
+            f"spec_tokens={spec_tokens} with a family generated by diffusion over blocks (block_length {block}): a lane's "
+            f"window is its block of masked positions, denoised in place and written once at the commit; a verify window "
+            f"of drafted tokens accepted by prefix has no meaning there"
+        )
+    if serving.prefill_chunk % block or serving.block_size % block:
+        raise ValueError(
+            f"prefill_chunk ({serving.prefill_chunk}) and block_size ({serving.block_size}) must be multiples of the "
+            f"family's block_length ({block}): a chunk and a pool block hold whole blocks, so that no row sees past them"
+        )
+    if stateful or backend != "paged":
+        raise ValueError("block generation is served by a family's apply_paged over token rows alone (no state a sequence)")
 
 
 # -- the two forwards ---------------------------------------------------------
@@ -232,7 +271,7 @@ def _dense_forward(apply_cached: Callable, config, names) -> Callable:
     return forward
 
 
-def _write_rows(pool: dict, rows: dict, tables, starts, count: int, *lanes) -> dict:
+def _write_rows(pool: dict, rows: dict, tables, starts, count: int, *lanes, keep=None) -> dict:
     """The pool with what a forward wrote for one group written in.  Token
     rows are scattered through the group's tables: rows past a lane's accepted
     length (a verify window) or past a chunk's real tokens are stale by
@@ -240,13 +279,15 @@ def _write_rows(pool: dict, rows: dict, tables, starts, count: int, *lanes) -> d
     mask admits them.  A state is no row: nothing re-writes it, so what comes
     back is already the state after the lane's last *real* row, and it is
     written by slot (``lanes`` = the group's slots and counts) for the lanes
-    that advanced alone (``write_state_rows``)."""
+    that advanced alone (``write_state_rows``).  With ``keep [B]`` only those
+    lanes' token rows are written (a block family's committing lanes): a
+    denoising pass leaves the pool bit for bit."""
     new_pool = dict(pool)
     for n, r in rows.items():
         if n == STATE:
             new_pool[n] = write_state_rows(pool[n], r, *lanes)
         else:
-            new_pool[n] = scatter_token_rows(pool[n], r, tables, starts, count)
+            new_pool[n] = scatter_token_rows(pool[n], r, tables, starts, count, keep)
     return new_pool
 
 
@@ -337,5 +378,59 @@ def _heads(forward: Callable, stateful: bool = False):
         new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state)
         new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1], *chunk_state)
         return _packed([*parts, ok, chunk_token, chunk_ok], counters), _feed(lanes_next, chunk_token), new_pool
+
+    return decode, decode_chunk
+
+
+def _block_heads(forward: Callable, mask_token_id: int):
+    """``decode`` and ``decode_chunk`` for a family generated by diffusion over
+    blocks (``models/sdar_moe.py``; ``generation.block_generate_loop`` is the
+    offline twin).  A decoding lane carries a **block state** ``[W]``: token ids,
+    ``generation.MASKED`` where a position is still masked (the model reads
+    ``mask_token_id`` there).  Per lane beside the tables and lengths:
+    ``tokens [S, W]`` (the host's copy of the state: a block's opening state, or
+    any state after a settle), ``count [S]`` (positions this pass unmasks, by
+    the static schedule; 0 for a lane that commits or idles), ``feed [S, W]``
+    and ``source [S]`` (``FEED_LANE``: the state the previous dispatch returned
+    for this lane, on the device alone; else the host's), ``commit [S]`` (1: the
+    lane's block is final, its ``W`` rows are written to the pool; 0: a
+    denoising pass, **nothing of it is written**) and ``threshold [S]`` (float32;
+    2.0 where the request has none).  The head (scope ``head.unmask``) is
+    :func:`generation.block_unmask`: confidence and argmax at every masked
+    position, the ``count`` most confident unmasked (or all over the threshold,
+    if more).  What comes back: the packed vector (every lane's new state ``[S,
+    W]``, ``ok [S]``, with a chunk a 0 in the chunk token's place and
+    ``chunk_ok``; the expert counters) and the feed, the new states.  The chunk
+    is the prompt's whole blocks under the same block-causal mask; **its head
+    yields no token** (the first block opens on masks, not on a prediction) and
+    its rows are written as any family's."""
+
+    def opened(tokens, feed, source):
+        state = jnp.where((source == FEED_LANE)[:, None], feed, tokens)
+        return state, jnp.where(state == MASKED, jnp.int32(mask_token_id), state)
+
+    def lanes_head(logits, state, count, threshold, poison):
+        logits = _poisoned(logits, poison)
+        ok = jnp.all(jnp.isfinite(logits), axis=(1, 2))
+        with jax.named_scope("head.unmask"):
+            return block_unmask(state, logits, count, threshold), ok
+
+    def decode(params, pool, tables, lengths, tokens, count, feed, source, commit, threshold, *rest):
+        state, ids = opened(tokens, feed, source)
+        (logits,), counters, (rows,) = forward(params, pool, ((ids, tables, lengths),))
+        state, ok = lanes_head(logits, state, count, threshold, rest)
+        new_pool = _write_rows(pool, rows, tables, lengths, ids.shape[1], keep=commit)
+        return _packed([state, ok], counters), state, new_pool
+
+    def decode_chunk(params, pool, tables, lengths, tokens, count, feed, source, commit, threshold, table_row, start, chunk, n_real, *rest):
+        state, ids = opened(tokens, feed, source)
+        chunk_tables, chunk_starts = table_row[None], start[None]
+        groups = ((ids, tables, lengths), (chunk, chunk_tables, chunk_starts))
+        (logits, chunk_logits), counters, (rows, chunk_rows) = forward(params, pool, groups)
+        state, ok = lanes_head(logits, state, count, threshold, rest)
+        chunk_ok = jnp.all(jnp.isfinite(chunk_logits))
+        new_pool = _write_rows(pool, rows, tables, lengths, ids.shape[1], keep=commit)
+        new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1])
+        return _packed([state, ok, 0, chunk_ok], counters), state, new_pool
 
     return decode, decode_chunk

@@ -6,6 +6,12 @@ State machine per request::
        ^                  |                          |
        +---- preempt -----+------------ preempt ----+
 
+For a family generated by diffusion over blocks a ``DECODING`` lane has a phase
+of its own, which the engine keeps on the slot (``_Slot.block``): denoising pass
+``t`` of the block it carries, or that block's commit; ``cache_len`` advances by
+a whole block at a commit and by nothing otherwise, and a preempted request
+re-prefills ``prompt + emitted`` (whole blocks; the block in progress restarts).
+
 A fixed number of **slots** (the fused decode step's static batch axis) holds
 the in-flight requests; new requests join as others finish — the decode batch
 never drains to refill.  Preemption is the block-pressure valve: when the
@@ -82,6 +88,8 @@ class Request:
         tag: Optional[str] = None,
         ttft_deadline_ms: Optional[float] = None,
         deadline_ms: Optional[float] = None,
+        denoise_steps: Optional[int] = None,
+        confidence_threshold: Optional[float] = None,
     ):
         if max_new_tokens < 0:
             raise ValueError(f"max_new_tokens must be >= 0, got {max_new_tokens}")
@@ -95,6 +103,12 @@ class Request:
         self.ttft_deadline_ms = ttft_deadline_ms
         self.deadline_ms = deadline_ms
         self.emitted: List[int] = []
+        # For a family generated by diffusion over blocks (serving/engine.py): how many denoising passes a block
+        # takes (None: one a position), the confidence over which a position is unmasked ahead of the schedule
+        # (None: the static schedule alone), and of every emitted token the pass of its block that unmasked it.
+        self.denoise_steps = denoise_steps
+        self.confidence_threshold = confidence_threshold
+        self.token_passes: List[int] = []
         self.state = RequestState.QUEUED
         # SLO timeline (monotonic seconds; None until the event happens).
         self.admit_t: Optional[float] = None  # FIRST admission only
@@ -180,9 +194,11 @@ class _Slot:
     (or were attached FROM the cache) and are never re-registered.
     ``unread`` counts the request's tokens that were dispatched and whose
     values the host has not read: ``request.remaining - unread`` is what is
-    left to dispatch."""
+    left to dispatch.  ``block`` is the block a lane of a block-diffusion
+    family carries (the engine's; None for every other family): ``cache_len``
+    then counts the rows *committed*, whole blocks."""
 
-    __slots__ = ("request", "idx", "blocks", "cache_len", "admit_seq", "registered_blocks", "unread")
+    __slots__ = ("request", "idx", "blocks", "cache_len", "admit_seq", "registered_blocks", "unread", "block")
 
     def __init__(self, request: Request, admit_seq: int, idx: int):
         self.request = request
@@ -192,6 +208,7 @@ class _Slot:
         self.admit_seq = admit_seq
         self.registered_blocks = 0
         self.unread = 0
+        self.block = None
 
 
 class Scheduler:
@@ -242,7 +259,8 @@ class Scheduler:
         plus the speculative verify window's overshoot (``spec_overshoot`` is
         the engine's draft window ``k`` — a verify dispatch writes ``k+1``
         rows starting at the last fed position, so the final dispatch can
-        write ``k`` rows past the plain-greedy extent), rounded up to the
+        write ``k`` rows past the plain-greedy extent; for a block-diffusion
+        family the block length: the last block is held whole), rounded up to the
         prefill-chunk boundary a re-admission after maximal preemption would
         pad to."""
         rows = (

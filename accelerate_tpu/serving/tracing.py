@@ -385,7 +385,7 @@ class ServingTracer:
     def on_decode(
         self, reqs_slots, end: float,
         co_batch: int, width: Optional[int], fresh: bool, dispatch_ms: float,
-        phase: str = "decode",
+        phase: str = "decode", **lanes: int,
     ) -> None:
         """One fused decode/verify dispatch.  ``phase`` is ``"decode"`` for
         the single-token program and ``"verify"`` for a speculative
@@ -395,7 +395,9 @@ class ServingTracer:
         windows.  The hook runs when the dispatch is *read back*, one dispatch
         after its launch unless the engine settled: ``end`` is that moment and
         ``dispatch_ms`` the time from the tick's launch to its read-back (the
-        host's work of the step between them included, while the device ran)."""
+        host's work of the step between them included, while the device ran).
+        ``lanes`` are counts of the tick's lanes by what they did, summed over
+        a coalesced run (a block-diffusion family: ``denoising``, ``committing``)."""
         for req, slot in reqs_slots:
             t = self.live.get(req.id)
             if t is None:
@@ -416,6 +418,8 @@ class ServingTracer:
                 last.end = end
                 last.meta["ticks"] += 1
                 last.meta["dispatch_ms"] = round(last.meta["dispatch_ms"] + dispatch_ms, 3)
+                for name, n in lanes.items():
+                    last.meta[name] = last.meta.get(name, 0) + n
                 t.cursor = end
             else:
                 # Cursor start (see on_prefill): in-slot residency across a
@@ -424,7 +428,7 @@ class ServingTracer:
                     "compile_in_path" if fresh else phase, end,
                     co_batch=co_batch, width=width, slot=slot,
                     ticks=1, dispatch_ms=round(dispatch_ms, 3),
-                    **({"kind": phase} if fresh else {}),
+                    **({"kind": phase} if fresh else {}), **lanes,
                 )
             self._ticked.add(req.id)
         self._note_event()
@@ -491,6 +495,8 @@ class ServingTracer:
             "pipelined": tick["pipelined"],
             "settle": tick["settle"],
             "gc_count": list(gc.get_count()),
+            # a block-diffusion family: how many of the live lanes denoised and how many committed in the tick
+            **{k: tick[k] for k in ("denoising", "committing") if k in tick},
         }
         entry = (tick["total_ms"], tick["tick"], record)
         if len(self._slow) < SLOW_TICKS:

@@ -52,10 +52,14 @@ COUNTERS = frozenset({
     "resilience.preempt_signals",
     "resilience.retries",
     "sentinel.anomalies",
+    "serving.block_tokens_emitted",
+    "serving.blocks_committed",
+    "serving.commit_slot_ticks",
     "serving.completed",
     "serving.deadline_expired",
     "serving.decode_dispatches",
     "serving.decode_gather_bytes",
+    "serving.denoise_slot_ticks",
     "serving.drains",
     "serving.journal_recoveries",
     "serving.mixed_dispatches",

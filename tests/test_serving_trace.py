@@ -396,7 +396,7 @@ def test_slow_ticks_keep_the_slowest_and_say_which_phase(gpt2_setup, tmp_path, m
 
     def slow_admit(now):
         if eng.ticks == held:
-            time.sleep(0.05)
+            time.sleep(0.25)  # long enough to stay among the eight slowest whatever six loaded workers do to the others
         return admit(now)
 
     monkeypatch.setattr(eng.sched, "admit", slow_admit)
@@ -415,9 +415,11 @@ def test_slow_ticks_keep_the_slowest_and_say_which_phase(gpt2_setup, tmp_path, m
             assert before["mixed"] == ("prefill.emit" in t["phase_ms"] and "decode.emit" in t["phase_ms"])
         assert abs(sum(t["phase_ms"].values()) - t["total_ms"]) < 1.0
         assert set(t["phase_ms"]) <= set(TICK_PHASES) and len(t["gc_count"]) == 3
-    assert slow[0]["tick"] == held and slow[0]["phase_ms"]["admit"] >= 50.0
-    assert slow[0]["total_ms"] - slow[0]["phase_ms"]["admit"] < slow[0]["phase_ms"]["admit"]
-    assert slow[0]["live"] >= 1 and slow[0]["width"] >= 1
+    # asserted on the held tick's own record, not on its rank: under a loaded machine any other tick may stall for longer
+    mine = by_tick[held]
+    assert mine["phase_ms"]["admit"] >= 250.0 and mine["phase_ms"]["admit"] == max(mine["phase_ms"].values())
+    assert mine["total_ms"] - mine["phase_ms"]["admit"] < mine["phase_ms"]["admit"]
+    assert mine["live"] >= 1 and mine["width"] >= 1
     assert 1 not in [t["tick"] for t in slow]  # the first tick compiled both programs: by far the slowest, and left out
     assert eng.stats()["ticks"] - 1 > SLOW_TICKS
     eng.tracer.flush()
