@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.moe import routed_experts, swiglu
+from ..ops.moe import expert_row_tile, routed_experts, swiglu
 from . import llama as _llama
 from .llama import cross_entropy, labels_and_weights
 
@@ -309,14 +309,18 @@ def _ffn(x, p, c: DeepseekV3Config, held=None):
         return x + y + shared.astype(x.dtype), routing["group_sizes"]
 
 
-def expert_counters(group_sizes: jax.Array) -> dict:
+def expert_counters(group_sizes: jax.Array, row_tile: int = 0) -> dict:
     """What a dispatch's expert layers did, from ``[layers, E]`` rows an expert
     computed: token-expert pairs, experts with at least one row and the hottest
-    expert's rows, each summed over the layers."""
+    expert's rows, each summed over the layers; and the row tiles of
+    ``row_tile`` rows that the fused kernel computed for them, 0 where the
+    layers ran ``lax.ragged_dot`` (``row_tile`` 0: ``ops/moe.py:expert_row_tile``
+    of the dispatch says which)."""
     return {
         "moe_rows": jnp.sum(group_sizes),
         "moe_experts_hit": jnp.sum(group_sizes > 0),
         "moe_max_rows": jnp.sum(jnp.max(group_sizes, axis=-1)),
+        "moe_row_tiles": jnp.sum(-(-group_sizes // row_tile)) if row_tile else jnp.zeros((), group_sizes.dtype),
     }
 
 
@@ -550,7 +554,9 @@ def apply_paged(params: dict, groups, config: DeepseekV3Config, pool: dict):
         for ckv_rows, kr_rows in stored)
     with jax.named_scope("head"):
         logits = _head(params, x, c)
-    return split_groups(logits, shapes), rows, expert_counters(group_sizes)
+    row_tile = expert_row_tile(  # which grouped product this dispatch's expert layers ran
+        x.size // c.hidden_size * c.num_experts_per_tok, c.n_routed_experts, c.hidden_size, c.moe_intermediate_size, c.dtype)
+    return split_groups(logits, shapes), rows, expert_counters(group_sizes, row_tile)
 
 
 def generate(

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.moe import routed_experts, swiglu
+from ..ops.moe import expert_row_tile, routed_experts, swiglu
 from . import llama as _llama
 from .deepseek_v3 import EXPERT_LEAVES, expert_counters
 from .llama import cross_entropy, labels_and_weights
@@ -572,7 +572,9 @@ def apply_paged(params: dict, groups, config: Lfm2MoeConfig, pool: dict):
             written[STATE] = {"conv": jnp.moveaxis(outs[CONV][g], 0, 1)}
     with jax.named_scope("head"):
         logits = _head(params, x, c)
-    return split_groups(logits, shapes), tuple(rows), expert_counters(group_sizes)
+    row_tile = expert_row_tile(  # which grouped product this dispatch's expert layers ran
+        x.size // c.hidden_size * c.num_experts_per_tok, c.num_experts, c.hidden_size, c.moe_intermediate_size, c.dtype)
+    return split_groups(logits, shapes), tuple(rows), expert_counters(group_sizes, row_tile)
 
 
 def generate(

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.moe import routed_experts
+from ..ops.moe import expert_row_tile, routed_experts
 from . import llama as _llama
 from .deepseek_v3 import EXPERT_LEAVES, expert_counters
 from .llama import cross_entropy, labels_and_weights
@@ -374,7 +374,9 @@ def apply_paged(params: dict, groups, config: SdarMoeConfig, pool: dict):
     with jax.named_scope("head"):
         logits = _head(params, x, c)
     rows = tuple(unpack_paged_rows_from_scan(k, v, False) for k, v in stored)
-    return split_groups(logits, shapes), rows, expert_counters(group_sizes)
+    row_tile = expert_row_tile(  # which grouped product this dispatch's expert layers ran
+        x.size // c.hidden_size * c.num_experts_per_tok, c.num_experts, c.hidden_size, c.moe_intermediate_size, c.dtype)
+    return split_groups(logits, shapes), rows, expert_counters(group_sizes, row_tile)
 
 
 def generate(

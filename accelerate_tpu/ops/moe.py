@@ -25,6 +25,8 @@ x token fraction per expert) and router z-loss.
 
 from __future__ import annotations
 
+import functools
+import sys
 from typing import Any, Optional
 
 import numpy as np
@@ -33,9 +35,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.sharding import constrain
+from ..parallel.sharding import _abstract_mesh, constrain
 
-__all__ = ["router", "dispatch_combine", "moe_ffn", "moe_ffn_ragged", "routed_experts", "swiglu", "expert_capacity"]
+__all__ = [
+    "router", "dispatch_combine", "moe_ffn", "moe_ffn_ragged", "routed_experts", "swiglu", "expert_capacity",
+    "expert_row_tile",
+]
 
 
 def expert_capacity(seq_len: int, num_experts: int, top_k: int, capacity_factor: float) -> int:
@@ -149,6 +154,104 @@ def moe_ffn(
     return y.astype(x.dtype), aux
 
 
+# Up to how many rows an expert on average (token-expert pairs / experts) the fused kernel is taken, and its widest row
+# tile.  From the probe of PR 35 (one TPU v5e, bf16, `routed_experts` alone over a merged stack, ms a layer, fused at a
+# tile of 16 / at its best tile against `lax.ragged_dot`; PERF.md section 6): 128 experts of 2048 x 768 top-8 at 8, 16,
+# 32, 64, 128, 256 rows an expert 1.61 / 3.66, 2.02 / 4.36, 2.64 (2.42 at 32) / 5.03, 3.94 (3.45 at 64) / 6.05, 6.14
+# (5.25 at 32) / 7.98, 12.68 (10.90 at 64) / 12.59; 32 experts of 2048 x 1792 top-4 at 4, 16, 32, 64, 128 rows 1.07 /
+# 1.67, 1.22 / 2.74, 1.46 (1.23 at 64) / 2.87, 1.85 (1.44 at 64) / 3.15, 2.84 (2.05 at 64) / 3.82.  The kernel wins by a
+# fifth and more up to 128 rows an expert at both widths and ties at 256: 128 is the last point measured on its side.
+FUSED_MAX_MEAN_ROWS = 128
+FUSED_MAX_ROW_TILE = 64
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+# ``jax._src.pallas.pallas_call`` imports the Mosaic *GPU* interpreter if it is there (``try: ... except ImportError``):
+# two thirds of what importing Pallas costs a process (0.9 of 1.4 s of a serving cell's set-up on a v5e host, where nothing
+# is compiled to bytecode; PERF.md section 6, PR 35).
+_GPU_INTERPRETER = "jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call"
+
+
+def _pallas_moe():
+    """``ops/pallas_moe.py``, imported where the kernel is first asked for (as
+    ``models/llama.py`` imports the flash kernel): only a TPU program of few
+    rows an expert needs Pallas.  A process whose backend is a TPU has no use
+    for the Mosaic GPU interpreter: if nobody has imported Pallas yet, that one
+    import is told the module is absent, and the entry is taken back at once."""
+    skip = _on_tpu() and "jax._src.pallas.pallas_call" not in sys.modules and _GPU_INTERPRETER not in sys.modules
+    if skip:
+        sys.modules[_GPU_INTERPRETER] = None
+    try:
+        from . import pallas_moe
+    finally:
+        if skip:
+            del sys.modules[_GPU_INTERPRETER]
+    return pallas_moe
+
+
+def expert_row_tile(pairs: int, experts: int, d: int, f: int, dtype: Any) -> int:
+    """Which grouped product :func:`routed_experts` runs for ``pairs``
+    token-expert pairs over ``experts`` experts of ``d x f``: the row tile of
+    the fused Pallas kernel (``ops/pallas_moe.py``), or 0 for
+    ``lax.ragged_dot``.  From static facts alone, no option anywhere: the
+    kernel where a TPU runs the program on one device, the pairs average at
+    most ``FUSED_MAX_MEAN_ROWS`` an expert and a weight tile fits its VMEM
+    budget; its row tile is 16 (``pallas_moe.ROW_TILE``) up to 16 rows an
+    expert on average, 32 up to 32, 64 beyond.  Off the TPU ``lax.ragged_dot``
+    stays (the kernel would run in the Pallas interpreter); so it does under a
+    mesh of more than one device (``pallas_call`` takes no part in GSPMD's
+    partitioning and the group sizes depend on the data: a sharded caller would
+    need a ``shard_map`` of its own) and at many rows an expert, where the
+    kernel's small row tiles no longer win.  Both products compute every pair:
+    no capacity, no drops."""
+    mesh = _abstract_mesh()
+    if not _on_tpu() or (not mesh.empty and mesh.size > 1) or pairs > FUSED_MAX_MEAN_ROWS * experts:
+        return 0
+    kernel = _pallas_moe()
+    if kernel.f_tile(d, f, jnp.dtype(dtype).itemsize) is None:
+        return 0
+    tm = kernel.ROW_TILE
+    while tm < FUSED_MAX_ROW_TILE and tm * experts < pairs:
+        tm *= 2
+    return tm
+
+
+def _ragged_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert):
+    """Every expert's SwiGLU over its rows as three ``lax.ragged_dot`` (on a
+    TPU three Mosaic grouped matmuls that stream the experts with rows); the
+    stack's other experts are groups of no rows."""
+    groups = group_sizes
+    if w_gate.shape[0] != group_sizes.shape[0]:
+        groups = jax.lax.dynamic_update_slice(jnp.zeros((w_gate.shape[0],), jnp.int32), group_sizes, (first_expert,))
+    gate = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, groups))
+    up = jax.lax.ragged_dot(rows, w_up, groups)
+    return jax.lax.ragged_dot(gate * up, w_down, groups)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _fused_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm):
+    """The same product as one Pallas kernel; differentiated as
+    :func:`_ragged_swiglu` is, so a gradient through it is that path's."""
+    return _pallas_moe().grouped_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm=tm)
+
+
+def _fused_swiglu_fwd(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm):
+    out = _fused_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm)
+    return out, (rows, w_gate, w_up, w_down, group_sizes, first_expert)
+
+
+def _fused_swiglu_bwd(tm, residuals, g):
+    *operands, group_sizes, first_expert = residuals
+    _, vjp = jax.vjp(lambda *a: _ragged_swiglu(*a, group_sizes, first_expert), *operands)
+    return (*vjp(g), None, None)
+
+
+_fused_swiglu.defvjp(_fused_swiglu_fwd, _fused_swiglu_bwd)
+
+
 def routed_experts(
     x: jax.Array,
     w_router: jax.Array,
@@ -179,12 +282,14 @@ def routed_experts(
     are the ``top_k`` of ``scores + select_bias`` (the bias only chooses), the
     weights are the chosen experts' scores, divided by (their sum +
     ``normalize_eps``) when ``normalize`` and multiplied by ``scale``.  Rows are sorted by expert and
-    each expert's rows run as one group of ``lax.ragged_dot`` (on a TPU a
-    grouped-matmul kernel that streams only the experts that have rows):
-    compute is exactly ``rows * top_k`` pairs, no capacity, no drops, and a
-    row's result does not depend on the other rows.  Group sizes depend on
-    the data, so this runs per device (replicated experts); the ``ep``-sharded
-    path is ``moe_ffn``.
+    each expert's rows run as one group of a grouped product that streams only
+    the experts that have rows: the fused Pallas kernel of ``ops/pallas_moe.py``
+    at a few rows an expert on one TPU device, ``lax.ragged_dot`` elsewhere
+    (:func:`expert_row_tile` decides from shapes, backend and placement; no
+    argument chooses).  Compute is exactly ``rows * top_k`` pairs, no capacity,
+    no drops, and a row's result does not depend on the other rows.  Group
+    sizes depend on the data, so this runs per device (replicated experts); the
+    ``ep``-sharded path is ``moe_ffn``.
 
     Returns (y [..., d] in x.dtype, routing dict: ``scores`` and ``logits``
     [..., E] fp32, ``experts`` [..., top_k], ``weights`` [..., top_k] fp32,
@@ -216,12 +321,11 @@ def routed_experts(
 
     with jax.named_scope("moe.experts"):
         rows = tokens.astype(compute_dtype)[token_of[order]]  # [N*k, d] grouped by expert
-        groups = group_sizes
-        if w_gate.shape[0] != e:  # the other layers' experts are groups of no rows
-            groups = jax.lax.dynamic_update_slice(jnp.zeros((w_gate.shape[0],), jnp.int32), group_sizes, (first_expert,))
-        gate = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate.astype(compute_dtype), groups))
-        up = jax.lax.ragged_dot(rows, w_up.astype(compute_dtype), groups)
-        y_rows = jax.lax.ragged_dot(gate * up, w_down.astype(compute_dtype), groups)
+        tm = expert_row_tile(n, e, d, w_gate.shape[-1], compute_dtype)
+        product = functools.partial(_fused_swiglu, tm=tm) if tm else _ragged_swiglu
+        y_rows = product(
+            rows, w_gate.astype(compute_dtype), w_up.astype(compute_dtype), w_down.astype(compute_dtype), group_sizes,
+            jnp.asarray(first_expert, jnp.int32))
         weighted = y_rows.astype(jnp.float32) * weights.reshape(n)[order][:, None]
         y = jnp.zeros(tokens.shape, jnp.float32).at[token_of[order]].add(weighted)
 

@@ -127,7 +127,9 @@ compile under load.  A pool need not be K and V per head:
 and an expert family serves paged too when its routing is row by row, as
 ``ops/moe.py:routed_experts`` is (its per-dispatch expert counters ride out
 behind the ``ok`` flags into ``stats()["moe_rows"]``, ``"moe_experts_hit"``,
-``"moe_max_rows"``; a mixed dispatch streams the experts its chunk and its
+``"moe_max_rows"``, ``"moe_row_tiles"`` -- the last 0 unless the dispatch ran the
+fused kernel of ``ops/pallas_moe.py``, which the telemetry counter
+``serving.moe_row_tiles`` carries too --; a mixed dispatch streams the experts its chunk and its
 decoders hit once).  A family without an ``apply_paged``
 (``models/mixtral.py``: capacity routing depends on who shares the batch) is
 served **dense**: gather each slot's whole view at the one static table
@@ -1877,6 +1879,8 @@ class ServingEngine:
         out = self.programs.unpack(flight.packed, with_chunk=flight.chunk is not None)
         for name, value in zip(MOE_COUNTERS, out["counters"]):
             self.moe_counters[name] += int(value)
+            if name == "moe_row_tiles" and value and get_telemetry().enabled:
+                get_telemetry().registry.counter("serving.moe_row_tiles").inc(int(value))
         return out
 
     def _apply(self, flight: _Flight, out: dict) -> None:

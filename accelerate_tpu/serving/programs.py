@@ -121,8 +121,11 @@ __all__ = ["FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "ServingProgr
 FEED_HOST, FEED_LANE, FEED_CHUNK = 0, 1, 2
 
 # What an expert family's ``apply_paged`` counts in a dispatch (``models/deepseek_v3.py:expert_counters``), each
-# summed over its expert layers: token-expert pairs computed, experts with at least one row, the hottest expert's rows.
-MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows")
+# summed over its expert layers: token-expert pairs computed, experts with at least one row, the hottest expert's rows,
+# and the row tiles the fused grouped product computed (0 for a dispatch whose expert layers ran ``lax.ragged_dot``: the
+# counter says which product a program runs; ``moe_rows`` / (``moe_row_tiles`` x ``ops/pallas_moe.py:ROW_TILE``) is the
+# tiles' fill).
+MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows", "moe_row_tiles")
 
 # No block table is narrower than this many rows.  Every width an engine meets compiles both of its programs, and on the
 # host of a v5e that is one to two seconds of a process's set-up a width even with a warm compile cache (tracing and

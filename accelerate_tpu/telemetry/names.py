@@ -63,6 +63,7 @@ COUNTERS = frozenset({
     "serving.drains",
     "serving.journal_recoveries",
     "serving.mixed_dispatches",
+    "serving.moe_row_tiles",
     "serving.pipelined_ticks",
     "serving.preempted",
     "serving.prefill_dispatches",

@@ -285,7 +285,9 @@ def judge_divergence(logits, *, offline: int, engine: int) -> dict:
 
 def kernels_phase(cfg, phase, *, interpret) -> None:
     """The compiled flash kernel with a padding mask against the einsum path,
-    at this model's head geometry."""
+    at this model's head geometry; the compiled fused expert kernel
+    (``ops/pallas_moe.py``) against the ``lax.ragged_dot`` path it replaces at a
+    few rows an expert."""
     import jax
     import jax.numpy as jnp
 
@@ -327,6 +329,18 @@ def kernels_phase(cfg, phase, *, interpret) -> None:
     want = jax.jit(jax.grad(scalar(einsum), argnums=(0, 1, 2)))(q, k, v)
     for name, g, w in zip("qkv", got, want):
         close(g, w, f"flash_kv_valid_d{name}")
+
+    # The fused grouped SwiGLU over the middle layer of a three-layer expert stack: an idle expert, an expert with more
+    # rows than a tile, one row alone; against the three ragged_dot it stands in for.
+    from accelerate_tpu.ops import moe, pallas_moe
+
+    experts, d, f = 8, cfg.hidden_size, 512
+    sizes = jnp.asarray([3, 0, 37, 1, 0, 9, 5, 7], jnp.int32)
+    rows = normal((int(sizes.sum()), d))
+    stack = [normal(shape) * fan ** -0.5 for shape, fan in (((3 * experts, d, f), d), ((3 * experts, d, f), d), ((3 * experts, f, d), f))]
+    first = jnp.int32(experts)
+    fused = jax.jit(lambda *a: pallas_moe.grouped_swiglu(*a, interpret=interpret))(rows, *stack, sizes, first)
+    close(fused, jax.jit(moe._ragged_swiglu)(rows, *stack, sizes, first), "moe_grouped_swiglu")
 
 
 def main() -> int:

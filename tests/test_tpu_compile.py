@@ -56,8 +56,40 @@ def program(case, hd, b):
     if case.startswith("paged_step"):
         return paged_step(case, hd, b)
     if case.startswith("latent_step"):
-        return latent_step(case)
+        return latent_step(case.removesuffix("_fused"))
+    if case == "moe_kernel":
+        return moe_kernel(hd, b)
     raise ValueError(case)
+
+
+MOE_STACKS = {128: (7, 2048, 768), 32: (12, 2048, 1792)}  # experts a layer -> (expert layers served, d, f): kanana and SDAR; LFM2
+FUSED_KERNEL = "moe_grouped_swiglu"  # ops/pallas_moe.py's pallas_call(name=): the custom call's name and the last scope of its op_name
+
+
+def moe_kernel(pairs, experts):
+    # ops/pallas_moe.py's fused grouped SwiGLU alone, interpret=False, at a serving cell's widths over the merged stack of
+    # all its expert layers: Mosaic accepts the whole-expert weight blocks under the kernel's own VMEM limit
+    from accelerate_tpu.ops.moe import expert_row_tile
+    from accelerate_tpu.ops.pallas_moe import grouped_swiglu
+    layers, d, f = MOE_STACKS[experts]
+    g, tm = layers * experts, expert_row_tile(pairs, experts, d, f, jnp.bfloat16)  # 16 rows a tile at a few rows an expert, 64 at many
+    assert tm, "the rule does not take the kernel at this shape"
+    args = (sds((pairs, d)), sds((g, d, f)), sds((g, d, f)), sds((g, f, d)), sds((experts,), jnp.int32), sds((), jnp.int32))
+    return (lambda rows, wg, wu, wd, sizes, first: grouped_swiglu(rows, wg, wu, wd, sizes, first, tm=tm, interpret=False)), args
+
+
+def check_moe_kernel(experts, compiled):
+    # the stack is an operand of the custom call as it lies: no result of the program is as large as a matrix of the stack
+    import re
+    layers, d, f = MOE_STACKS[experts]
+    text = compiled.as_text()
+    if FUSED_KERNEL not in text:
+        raise AssertionError("the executable does not hold the kernel by its name")
+    sized = re.compile(r"= \w+\[%d,(%d,%d|%d,%d)\]" % (layers * experts, d, f, f, d))
+    copies = [line.strip()[:160] for line in text.splitlines() if sized.search(line) and "} parameter(" not in line]
+    if copies:
+        raise AssertionError("the stack is copied for the kernel: " + " ;; ".join(copies[:3]))
+    return f"temp_bytes={compiled.memory_analysis().temp_size_in_bytes}"
 
 
 STEP_WIDTH = 64  # table width of every step: a context of 64 * 16 = 1024 rows a slot
@@ -122,7 +154,16 @@ def latent_step(case):
     return f, (params, pool, sds((rows, tokens), jnp.int32), sds((rows, STEP_WIDTH), jnp.int32), sds((rows,), jnp.int32))
 
 
-def check_latent_step(compiled):
+def check_grouped_product(text, fused, where=""):
+    # Which grouped product the program holds: the three Mosaic kernels XLA:TPU makes of lax.ragged_dot, or, with the rule
+    # of ops/moe.py answering as on a TPU (the compile-only client's default backend is the CPU), the one fused kernel
+    if fused and ("ragged-dot" in text or FUSED_KERNEL not in text):
+        raise AssertionError(where + "the expert product is not the fused kernel alone")
+    if not fused and ("ragged-dot" not in text or "tpu_custom_call" not in text or FUSED_KERNEL in text):
+        raise AssertionError(where + "the expert product is not the grouped-matmul kernel")
+
+
+def check_latent_step(compiled, fused=False):
     # The latent leaves are read where they lie: nothing but the scatter of the new rows has a result as large as a
     # leaf or a layer's slice of one.  The routed experts are read where the stack lies too: the grouped product is the
     # Mosaic kernel XLA:TPU makes of lax.ragged_dot, and no layer's experts are cut out of the stack for it.
@@ -135,8 +176,7 @@ def check_latent_step(compiled):
              and "scatter(" not in line and "kv_pool.write/scatter" not in line]
     if moved:
         raise AssertionError("the step moves pool-sized arrays besides the scatter: " + " ;; ".join(moved[:4]))
-    if "ragged-dot" not in text or "tpu_custom_call" not in text:
-        raise AssertionError("the expert product is not the grouped-matmul kernel")
+    check_grouped_product(text, fused)
     cut = re.compile(r"= \w+\[%d,(%d,%d|%d,%d)\]" % (LATENT_EXPERTS[0], *LATENT_EXPERTS[1:], *LATENT_EXPERTS[:0:-1]))
     experts = [line.strip()[:160] for line in text.splitlines()
                if cut.search(line) and not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
@@ -223,7 +263,7 @@ def check_mixed_step(case, width):
     return "read_bytes=" + "/".join(f"{read[k]:.4g}" for k in ("decode", "prefill", "mixed")) + f" weights={weights:.4g}"
 
 
-def check_state_step(width):
+def check_state_step(width, fused=False):
     # serving/programs.py's decode and decode_chunk for models/lfm2_moe.py at the cut the benchmark serves (14 of the 24
     # published layers, published widths, the cell's geometry): K/V token rows for the 3 attention layers as rows of 512 =
     # 8 heads x 64 without a head axis, [3, 8192, 16, 512], beside the state by slot [11, 32, 2, 2048].  The rows of 512 are
@@ -260,8 +300,9 @@ def check_state_step(width):
         if moved:
             raise AssertionError(f"{name} moves pool-sized arrays besides the scatter of the new rows: " + " ;; ".join(moved[:4]))
         experts = [line.strip()[:160] for line in lines if cut.search(line)]
-        if experts or "ragged-dot" not in text:
-            raise AssertionError(f"{name}: a layer's experts are cut out of the stack, or no grouped product: " + " ;; ".join(experts[:3]))
+        if experts:
+            raise AssertionError(f"{name}: a layer's experts are cut out of the stack: " + " ;; ".join(experts[:3]))
+        check_grouped_product(text, fused, name + ": ")
         temps.append(compiled.memory_analysis().temp_size_in_bytes)
     return "temp_bytes=" + "/".join(map(str, temps))
 
@@ -313,22 +354,27 @@ def check_paged_step(spec, compiled):
     return f"temp_bytes={temp}"
 
 
+from accelerate_tpu.ops import moe
+
 for spec in sys.argv[2:]:
     case, hd, b = spec.split(":")
     print("BEGIN", spec, flush=True)   # an abort after this line belongs to this case
+    fused = case.endswith("_fused") or case == "moe_kernel"
+    moe._on_tpu = (lambda: True) if fused else (lambda: False)  # the one fact of ops/moe.py's rule that this client cannot show
     try:
         if case.startswith("mixed_step"):
             print("COMPILED", spec, check_mixed_step(case, int(hd)), flush=True)
             continue
-        if case == "state_step":
-            print("COMPILED", spec, check_state_step(int(hd)), flush=True)
+        if case.startswith("state_step"):
+            print("COMPILED", spec, check_state_step(int(hd), fused), flush=True)
             continue
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
         if "tpu_custom_call" not in compiled.as_text() and not case.startswith("paged_step"):
             raise AssertionError("compiled, but the executable holds no Mosaic kernel")
         note = check_paged_step(spec, compiled) if case.startswith("paged_step") else ""
-        note = check_latent_step(compiled) if case.startswith("latent_step") else note
+        note = check_latent_step(compiled, fused) if case.startswith("latent_step") else note
+        note = check_moe_kernel(int(b), compiled) if case == "moe_kernel" else note
         if case in ("paged_step", "latent_step") and spec not in SCANNED_POOL_TEMP_BYTES:  # a decode over a pool read in place
             check_context_assembly(compiled.as_text())
     except Exception:
@@ -380,9 +426,21 @@ CASES = [
     # models/lfm2_moe.py through serving/programs.py at the cut the benchmark serves (PR 32; the second field is the table
     # width): K/V rows of 512 without a head axis beside a state by slot; nothing pool-sized but the scatters of the new rows
     ("state_step", 64, 0),
+    # ops/pallas_moe.py (PR 35): the fused grouped SwiGLU alone at the three expert cells' widths and stack sizes (the fields
+    # are the token-expert pairs of a dispatch and the experts a layer: kanana's decode, SDAR's mixed dispatch, LFM2's), at the
+    # row tile ops/moe.py's rule gives the shape ...
+    ("moe_kernel", 96, 128),
+    ("moe_kernel", 1280, 128),
+    ("moe_kernel", 256, 32),
+    ("moe_kernel", 4096, 32),  # 128 rows an expert, the most the rule gives the kernel: its widest row tile at the widest experts
+    # ... and the latent step and LFM2's two programs as a TPU builds them, the rule's backend test answered for it: the one
+    # fused kernel in place of the three ragged-dot kernels, fed from the stack where it lies like them
+    ("latent_step_fused", 512, 64),
+    ("latent_step_prefill_fused", 512, 64),
+    ("state_step_fused", 64, 0),
 ]
-IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step")) else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}"
-       for c, h, b in CASES]
+IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
+       else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}" for c, h, b in CASES]
 
 
 # ``python -c`` puts its working directory first on sys.path: the child
