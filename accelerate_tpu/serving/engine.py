@@ -98,6 +98,20 @@ the block in progress restarts to the same tokens), the prefix cache reuses
 whole pool blocks below the prefilled rows, ``spec_tokens > 0`` is refused.
 ``generation.block_generate_loop`` is this path's equivalence oracle.
 
+**The tick's record.**  A tick keeps ONE record of itself (``_tick``): its
+milliseconds by phase, under the names of its ``serving.tick.*`` profiler
+spans, and what its dispatch held.  The host's half of a dispatch is told
+apart from its blocked time: inside the ``wait`` span ``launch`` is the jitted
+call alone and ``read`` the blocking read (of the tick before, or a settle's),
+so the wait's self time is the booking of what was sent.  ``_close_tick``
+writes what the dispatch held once, as integers, onto the ``serving.tick`` span
+(``rows_live`` of ``rows_computed``, ``width`` against ``width_lanes``,
+``mixed``, ``pipelined``, ``settles``) and counts the dispatch's kind from it;
+the times stand in the spans themselves and, without a profile, in
+``phase_ms`` of the slowest ticks, which ``ServingTracer`` keeps.  The emit
+span that yields a request's first token carries that token's account
+(``_first_tokens``).  ``docs/usage_guides/telemetry.md`` has the tables.
+
 ``serving/programs.py`` builds the two programs and owns how a dispatch reads
 the pool; the family decides which of its two back ends serves.  A family
 with an ``apply_paged`` (gpt2, llama, deepseek_v3) is served **paged**:
@@ -451,6 +465,7 @@ class _Flight(NamedTuple):
     width: int
     fresh: bool
     t0: float  # the launch, for dispatch_ms
+    tick: int  # the tick that dispatched it
     passes: Optional[List[_Pass]] = None  # a block-diffusion family: what each of ``lanes`` did
 
 
@@ -459,24 +474,38 @@ class _TickPhase:
     profiler's timeline (``telemetry.annotate``; ``with`` gives the span, for
     ``set_metadata``), and its milliseconds in the tick's record for
     ``ServingTracer``'s slow ticks.  Phases are counted back to back, each
-    from the end of the one before, so they sum to the tick; a name met twice
-    in a tick (a settle inside it reads a second time) adds up."""
+    from the end of the one before, so they sum to the tick; a phase opened
+    inside another (``launch`` and ``read`` inside a ``wait``; a settle's
+    ``wait`` inside the phase that asked for it) takes its own time out of the
+    outer one's; a name met twice in a tick (a settle inside it reads a second
+    time) adds up."""
 
-    __slots__ = ("engine", "name", "span")
+    __slots__ = ("engine", "name", "span", "outer")
 
     def __init__(self, engine: "ServingEngine", name: str, **meta):
         self.engine, self.name = engine, name
         self.span = annotate("serving.tick." + name, tick=engine.ticks, **meta)
 
+    def _book(self, name: str) -> None:
+        engine = self.engine
+        if engine._phase_t0 is None:  # a settle between two ticks (stats(), a cancel): no tick's record is open
+            return
+        now = time.monotonic()
+        phase_ms = engine._tick.setdefault("phase_ms", {})
+        phase_ms[name] = phase_ms.get(name, 0.0) + (now - engine._phase_t0) * 1e3
+        engine._phase_t0 = now
+
     def __enter__(self):
+        engine = self.engine
+        self.outer, engine._phase = engine._phase, self.name
+        if self.outer is not None:
+            self._book(self.outer)  # what has passed of the outer phase so far is its own
         return self.span.__enter__()
 
     def __exit__(self, *exc) -> bool:
         self.span.__exit__(*exc)
-        engine, now = self.engine, time.monotonic()
-        phase_ms = engine._tick.setdefault("phase_ms", {})
-        phase_ms[self.name] = phase_ms.get(self.name, 0.0) + (now - engine._phase_t0) * 1e3
-        engine._phase_t0 = now
+        self._book(self.name)
+        self.engine._phase = self.outer
         return False
 
 
@@ -672,7 +701,8 @@ class ServingEngine:
         self._warm_widths: set = set()
         self._decode_widths: set = set()  # widths the decoding lanes ran at: stats()["decode_bucket_widths"]
         self._tick: dict = {}  # what the running tick is doing: step() starts one
-        self._phase_t0 = 0.0
+        self._phase_t0: Optional[float] = None  # the end of the phase before: None while no tick's record is open
+        self._phase: Optional[str] = None  # the _TickPhase that is open
         # Live /debug endpoints: the metrics HTTP server asks registered
         # engines for request/block snapshots (weakly — a collected engine
         # just drops off the page).
@@ -927,19 +957,21 @@ class ServingEngine:
             return self._finished[done_before:]
         self.ticks += 1
         states = [slot.request.state for slot in self.sched.slots.values()]
-        # What the tick was doing, for ServingTracer's slow-tick record:
-        # _TickPhase fills phase_ms, _dispatch_tick the dispatch's shape.
+        # The tick's ONE record: _TickPhase fills phase_ms, _dispatch_tick the dispatch's shape, _settle the reads
+        # beyond the pipelined one; _close_tick writes the serving.tick span's stats and the counters from it, and
+        # ServingTracer keeps it if it is among the slowest.
         self._tick = tick = {
             "tick": self.ticks,
             "prefilling": states.count(RequestState.PREFILLING),
-            "live": 0, "width": None, "fresh": False, "mixed": False, "pipelined": False, "settle": None,
+            "live": 0, "width": None, "width_lanes": 0, "rows_live": 0, "rows_computed": 0,
+            "fresh": False, "mixed": False, "pipelined": False, "settle": None, "settles": 0,
             "phase_ms": {},
         }
         self._phase_t0 = now
         with annotate(
             "serving.tick", tick=self.ticks, queued=self.sched.pending,
             prefilling=tick["prefilling"], decoding=states.count(RequestState.DECODING),
-        ):
+        ) as tick_span:
             with _TickPhase(self, "admit") as span:
                 if self.tracer is not None:
                     self.tracer.begin_tick(now)
@@ -953,6 +985,10 @@ class ServingEngine:
                 # dropping cached content on demand.
                 self._pressure_relief()
                 admitted = self.sched.admit(now)
+                for idx in admitted:
+                    req = self.sched.slots[idx].request
+                    if req.admit_tick is None:
+                        req.admit_tick = self.ticks
                 if self.tracer is not None:
                     admit_t = time.monotonic()
                     for idx in admitted:
@@ -985,14 +1021,39 @@ class ServingEngine:
             with annotate("serving.tick.publish", tick=self.ticks):
                 self._drain_scrubs()
                 self._publish_gauges()
+                # Last, so that the tick's record holds all of the tick but the tracer's own bookkeeping.
+                end = time.monotonic()
+                account = self._close_tick(now, end)
                 if self.tracer is not None:
-                    # Last, so that the tick's record holds all of the tick
-                    # but the tracer's own bookkeeping.
-                    end = time.monotonic()
-                    tick["phase_ms"]["publish"] = (end - self._phase_t0) * 1e3
-                    tick["total_ms"] = (end - now) * 1e3
                     self.tracer.end_tick(end, self.sched.slots, tick, unread=self._unread_requests())
+            tick_span.set_metadata(**account)
         return self._finished[done_before:]
+
+    def _close_tick(self, start: float, end: float) -> dict:
+        """The tick's record closed at ``end``: ``publish`` takes what is left,
+        ``phase_ms`` sums to ``total_ms``, and no later read books into it.
+        From the record, and from nothing else, come the ``serving.tick``
+        span's stats (returned: integers, what the dispatch held; the times are
+        the spans' own) and the counters of what the dispatch was."""
+        tick = self._tick
+        phase_ms = tick["phase_ms"]
+        phase_ms["publish"] = phase_ms.get("publish", 0.0) + (end - self._phase_t0) * 1e3
+        tick["total_ms"] = (end - start) * 1e3
+        self._phase_t0 = None
+        account = {
+            "rows_live": tick["rows_live"], "rows_computed": tick["rows_computed"],
+            "width": tick["width"] or 0, "width_lanes": tick["width_lanes"],
+            "mixed": int(tick["mixed"]), "pipelined": int(tick["pipelined"]), "settles": tick["settles"],
+        }
+        self.mixed_dispatches += account["mixed"]
+        self.pipelined_ticks += account["pipelined"]
+        tel = get_telemetry()
+        # (the telemetry names stand here as literals: tests/test_metric_names.py reads the emit sites)
+        if tel.enabled and account["mixed"]:
+            tel.registry.counter("serving.mixed_dispatches").inc()
+        if tel.enabled and account["pipelined"]:
+            tel.registry.counter("serving.pipelined_ticks").inc()
+        return account
 
     def run(self, max_ticks: Optional[int] = None) -> Dict[int, List[int]]:
         """Drive ticks until every submitted request completes; returns
@@ -1721,14 +1782,32 @@ class ServingEngine:
         (a lane's row, a final chunk's turn to decode, the slot whose last
         token this is); then the ONE read-back of the step, of the tick
         dispatched *before* this one, and that tick's emit.  Under a verify
-        window this tick is read back too, before the next is built."""
-        sched, programs = self.sched, self.programs
+        window this tick is read back too, before the next is built.
+
+        On the profiler's timeline the tables are filled under no span of their
+        own (the tick's self time), and the ``wait`` span holds two children,
+        ``launch`` (the jitted call alone) and ``read`` (the blocking read of
+        the tick before), and the booking between them as its self time."""
+        sched, programs, sc = self.sched, self.programs, self.serving
         live = batch.live if batch else []
         # Both groups share one table width, the wider of the two needs: the
         # tables are as wide as the widest lane needs, so gather traffic (and
         # attention width) scale with the blocks requests own.
         owned = [len(sched.slots[idx].blocks) for idx in live]
-        width = programs.table_width(max(owned + ([chunk.blocks] if chunk else [])))
+        width_lanes = programs.table_width(max(owned)) if live else 0
+        width = max(width_lanes, programs.table_width(chunk.blocks) if chunk else 0)
+        # Rows of the dispatch that belong to a request: the lanes' window (under a verify window a lane's token and
+        # its drafts), and the chunk's real rows; the program computes every lane's window and the whole padded chunk.
+        if live and programs.window > 1 and self.block_length == 1:
+            rows_live = len(live) + int(batch.draft_len[live].sum())
+        else:
+            rows_live = len(live) * programs.window
+        prev = self._flight
+        self._tick.update(
+            live=len(live), width=width, width_lanes=width_lanes, mixed=bool(chunk and live), pipelined=prev is not None,
+            rows_live=rows_live + (chunk.n_real if chunk else 0),
+            rows_computed=sc.max_slots * programs.window + (sc.prefill_chunk if chunk else 0),
+        )
         fresh = self._note_bucket("decode_chunk" if chunk else "decode", width)
         args = self._idle_lanes(width)
         tables, lengths = args[:2]
@@ -1736,7 +1815,6 @@ class ServingEngine:
             slot = sched.slots[idx]
             tables[idx, : len(slot.blocks)] = slot.blocks
             lengths[idx] = slot.cache_len
-        prev = self._flight
         if batch:
             args[2:4] = [batch.tokens, batch.draft_len]
             args[5] = batch.source
@@ -1753,25 +1831,24 @@ class ServingEngine:
             # into exactly one slot's logits on that request's first decode
             # dispatch; every other lane multiplies by 1.0 (lanes are
             # independent, so their tokens are bit-identical to unarmed).
-            poison = np.ones((self.serving.max_slots,), np.float32)
+            poison = np.ones((sc.max_slots,), np.float32)
             for idx in live:
                 req = sched.slots[idx].request
                 if getattr(req, "_poison_pending", False):
                     poison[idx] = np.nan
                     req._poison_pending = False  # fires once
             args.append(poison)
-        gather_bytes = programs.gathered_blocks(owned) * self._block_bytes if live else 0
-        mixed = bool(chunk and live)
-        pipelined = prev is not None
-        self._tick.update(live=len(live), width=width, mixed=mixed, pipelined=pipelined)
         # the readers' names: a dispatch with decoding lanes waits under
-        # decode.wait, a chunk dispatched alone under prefill.wait; the span
-        # covers this tick's launch and the read of the one before
+        # decode.wait, a chunk dispatched alone under prefill.wait.  The span
+        # opens at the launch and no earlier: the benchmark's idle metrics
+        # intersect device idle with it, so what it covers is their yardstick
+        # (the tables above are filled under no span of their own)
         with _TickPhase(self, "decode.wait" if live else "prefill.wait", live=len(live), width=width):
-            program = programs.decode_chunk if chunk else programs.decode
-            t0 = time.monotonic()
-            packed, self._feed, self.cache.pool = program(self.params, self.cache.pool, *args)
-            packed.copy_to_host_async()  # the read-back comes one dispatch later: the copy starts when the program ends
+            with _TickPhase(self, "launch", program="decode_chunk" if chunk else "decode", fresh=int(fresh)):
+                t0 = time.monotonic()
+                program = programs.decode_chunk if chunk else programs.decode
+                packed, self._feed, self.cache.pool = program(self.params, self.cache.pool, *args)
+                packed.copy_to_host_async()  # the read-back comes one dispatch later: the copy starts when the program ends
             lanes = [sched.slots[idx] for idx in live]
             final = False
             tel = get_telemetry()
@@ -1791,6 +1868,8 @@ class ServingEngine:
                 elif final:
                     req.state = RequestState.DECODING
                     self._sent(chunk.slot)
+                if tel.enabled:
+                    tel.registry.counter("serving.prefill_dispatches").inc()
             passes = None
             if self.block_length > 1:
                 passes = [self._book_pass(slot) for slot in lanes]
@@ -1801,26 +1880,18 @@ class ServingEngine:
                     slot.cache_len += 1  # a verify window's accepted drafts are added when they are read
                     self._sent(slot)
             if live:
+                gather_bytes = programs.gathered_blocks(owned) * self._block_bytes
                 self.decode_dispatches += 1
                 self.decode_gather_bytes += gather_bytes
                 self._decode_widths.add(width)
-            self.mixed_dispatches += mixed
-            self.pipelined_ticks += pipelined
-            if tel.enabled:
-                if chunk:
-                    tel.registry.counter("serving.prefill_dispatches").inc()
-                if live:
+                if tel.enabled:
                     tel.registry.counter("serving.decode_dispatches").inc()
                     tel.registry.counter("serving.decode_gather_bytes").inc(gather_bytes)
                     tel.registry.gauge("serving.decode_bucket_width").set(width)
-                if mixed:
-                    tel.registry.counter("serving.mixed_dispatches").inc()
-                if pipelined:
-                    tel.registry.counter("serving.pipelined_ticks").inc()
             draft_len = batch.draft_len if batch else None
-            self._flight = _Flight(packed, chunk, final, lanes, draft_len, width, fresh, t0, passes)
-            out = self._read(prev) if pipelined else None  # host sync point: the tick BEFORE this one is done here
-        if pipelined:
+            self._flight = _Flight(packed, chunk, final, lanes, draft_len, width, fresh, t0, self.ticks, passes)
+            out = self._read(prev) if prev is not None else None  # host sync point: the tick BEFORE this one is done here
+        if prev is not None:
             self._apply(prev, out)
         if passes is not None:
             if not all(p.by_count for p in passes):
@@ -1873,10 +1944,14 @@ class ServingEngine:
             self.sched.retire(slot.idx)
         return count
 
-    def _read(self, flight: _Flight) -> dict:
+    def _read(self, flight: _Flight, settle: Optional[str] = None) -> dict:
         """The host's view of what ``flight``'s program returned: the sync
-        point of that tick alone, whatever is queued behind it."""
-        out = self.programs.unpack(flight.packed, with_chunk=flight.chunk is not None)
+        point of that tick alone, whatever is queued behind it.  All of the
+        host's blocked time lies in the ``read`` span; it says which tick it
+        read, and why if a settle asked."""
+        reason = {"settle": settle} if settle else {}
+        with _TickPhase(self, "read", of=flight.tick, **reason):
+            out = self.programs.unpack(flight.packed, with_chunk=flight.chunk is not None)
         for name, value in zip(MOE_COUNTERS, out["counters"]):
             self.moe_counters[name] += int(value)
             if name == "moe_row_tiles" and value and get_telemetry().enabled:
@@ -1910,12 +1985,14 @@ class ServingEngine:
             return False
         self._flight = None
         self.settles[reason] = self.settles.get(reason, 0) + 1
-        self._tick["settle"] = reason  # in the record of the tick that paid for it
+        if self._phase_t0 is not None:  # in the record of the tick that paid for it; between two ticks no record is open
+            self._tick["settle"] = reason
+            self._tick["settles"] += 1
         tel = get_telemetry()
         if tel.enabled:
             tel.registry.counter("serving.settles").inc()
         with _TickPhase(self, "decode.wait" if flight.lanes else "prefill.wait", settle=reason):
-            out = self._read(flight)
+            out = self._read(flight, settle=reason)
         self._apply(flight, out)
         return True
 
@@ -1944,11 +2021,12 @@ class ServingEngine:
                 return
             self._register_prefix_blocks(slot, chunk.start + chunk.n_real)
             yields = flight.final and self.block_length == 1  # a block-diffusion family's chunk yields no token
-            span.set_metadata(first_token=int(yields and not req.emitted))
+            firsts = [(req, req.prefill_dispatches)] if yields and not req.emitted else []
             if yields:
                 # The first generated token of a fresh request (TTFT lands
                 # here) or the resume token of a re-prefilled one.
                 self._emit(slot, [token], time.monotonic())
+            span.set_metadata(first_token=len(firsts), **self._first_tokens(flight, firsts))
 
     def _emit_decode(self, flight: _Flight, out: dict, dispatch_ms: float) -> None:
         window, draft_len = self.programs.window, flight.draft_len
@@ -2015,6 +2093,7 @@ class ServingEngine:
         # a lane quarantined at the read-back before this one computed a pass too many here: dropped
         lanes = [(slot, p) for slot, p in zip(flight.lanes, flight.passes) if slot.request.state != RequestState.DONE]
         denoised = committed = emitted = 0
+        firsts = []  # the requests whose first block this read yields, each with the dispatches that carried its rows
         with _TickPhase(self, "decode.emit") as span:
             emit_t = time.monotonic()
             if self.tracer is not None:
@@ -2040,8 +2119,10 @@ class ServingEngine:
                 if count is not None:
                     first = self.block_length - block.new
                     emitted += count
+                    if count and not slot.request.emitted:
+                        firsts.append((slot.request, slot.request.prefill_dispatches + p.t + 1))
                     self._emit(slot, state[first : first + count], emit_t, sent=count, passes=block.passes[first : first + count])
-            span.set_metadata(tokens=emitted)
+            span.set_metadata(tokens=emitted, **self._first_tokens(flight, firsts))
         self.decode_slot_ticks += denoised + committed
         self.denoise_slot_ticks += denoised
         self.commit_slot_ticks += committed
@@ -2056,6 +2137,23 @@ class ServingEngine:
             tel.registry.counter("serving.block_tokens_emitted").inc(emitted)
 
     # -- completion / metrics ------------------------------------------------
+
+    @staticmethod
+    def _first_tokens(flight: _Flight, firsts: list) -> dict:
+        """The first token's account, for the emit span that yields it: sums
+        over ``firsts``, the requests whose first token ``flight``'s read gave,
+        each with the dispatches that carried its own rows up to it (its
+        chunks; a block family's passes of the first block).  ``held_ticks``:
+        the ticks from its admission to the one read, both counted;
+        ``own_ticks`` of them were its own, the rest it waited for its turn.
+        Nothing without a first token."""
+        if not firsts:
+            return {}
+        return {
+            "first_tokens": len(firsts),
+            "held_ticks": sum(flight.tick - req.admit_tick + 1 for req, _ in firsts),
+            "own_ticks": sum(own for _, own in firsts),
+        }
 
     def _emit(self, slot, tokens: List[int], now: float, sent: int = 1, passes: Optional[List[int]] = None) -> None:
         """The values of the tokens one dispatch produced for ``slot``, read at

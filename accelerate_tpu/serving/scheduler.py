@@ -112,6 +112,7 @@ class Request:
         self.state = RequestState.QUEUED
         # SLO timeline (monotonic seconds; None until the event happens).
         self.admit_t: Optional[float] = None  # FIRST admission only
+        self.admit_tick: Optional[int] = None  # the engine's tick of that admission (the first token's account)
         self.first_token_t: Optional[float] = None
         self.finish_t: Optional[float] = None
         self.last_token_t: Optional[float] = None

@@ -443,7 +443,9 @@ class ServingTracer:
         (the engine reads a tick back one dispatch late): they had their turn,
         and its interval is written when it is read, from their cursor.  ``tick`` is the
         engine's record of the tick (``total_ms``, ``phase_ms`` by the names
-        of its ``serving.tick.*`` spans, the dispatch's ``live`` lanes and
+        of its ``serving.tick.*`` spans, ``launch`` and ``read`` apart from the
+        ``wait`` round them, so that a stalled tick says whether the jitted call
+        or the blocking read held it, the dispatch's ``live`` lanes and
         ``width``, ``mixed`` when a chunk rode with them, ``pipelined`` when it
         was dispatched with the tick before it still unread, ``settle`` the
         reason if a tick in flight was read back early in it): kept if it is among the slowest, see :meth:`slow_ticks`."""

@@ -34,6 +34,9 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
+import contextlib  # noqa: E402
+import time  # noqa: E402
+
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
@@ -48,6 +51,41 @@ def without_apply_paged(family):
         return family.apply_cached(params, input_ids, config, cache)
 
     return apply_cached
+
+
+@contextlib.contextmanager
+def recorded_spans():
+    """Every span the serving engine opens through ``telemetry.annotate``
+    while the context is open, without a profiler session: a list of objects
+    with ``name``, ``meta`` (the keywords, and what ``set_metadata`` added),
+    ``start`` / ``end`` (``time.monotonic``) and ``parent`` (the span open
+    around it, or None), in the order they were opened."""
+    from accelerate_tpu.serving import engine
+
+    spans, stack = [], []
+
+    class Span:
+        def __init__(self, name, **meta):
+            self.name, self.meta, self.start, self.end, self.parent = name, meta, None, None, None
+
+        def __enter__(self):
+            self.parent = stack[-1] if stack else None
+            stack.append(self)
+            spans.append(self)
+            self.start = time.monotonic()
+            return self
+
+        def __exit__(self, *exc):
+            self.end = time.monotonic()
+            stack.pop()
+            return False
+
+        def set_metadata(self, **meta):
+            self.meta.update(meta)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(engine, "annotate", Span)
+        yield spans
 
 
 @pytest.fixture(autouse=True)

@@ -24,6 +24,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from conftest import recorded_spans
 
 from accelerate_tpu.models import gpt2, llama
 from accelerate_tpu.models import sdar_moe as sd
@@ -370,6 +371,28 @@ def test_the_tick_record_says_how_many_lanes_denoised_and_committed(model, tmp_p
     assert decode and all("denoising" in iv.meta and "committing" in iv.meta for iv in decode)
     assert sum(iv.meta["ticks"] for iv in decode) == 5  # the first request's 4 denoising ticks and 1 commit (a first tick at a fresh width is compile_in_path's)
     assert s["denoise_slot_ticks"] + s["commit_slot_ticks"] == engine.decode_slot_ticks
+
+
+def test_the_tick_account_of_a_block_family(model):
+    """Two requests admitted in tick 1 (blocks of 4, 4 slots, chunks of 8).  A (13-token prompt, two passes a block)
+    prefills 8 and 4 rows in ticks 1 and 2 and denoises its first block in ticks 3 and 4; B (8-token prompt, one pass a
+    block) prefills in tick 3, riding with A's lane, and denoises its first block in tick 4.  Tick 5 reads tick 4 and
+    yields both first blocks at once: each was held four ticks, A's rows rode in all four, B's in two.  A lane's rows
+    of a dispatch are its block, whatever the pass unmasks; every read says which tick it read."""
+    with recorded_spans() as spans:
+        engine = engine_of(model)
+        run_all(engine, requests_of(np.random.default_rng(1), [(13, 10, 2), (8, 7, 1)]))
+    ticks = [s for s in spans if s.name == "serving.tick"]
+    rows = [(t.meta["rows_live"], t.meta["rows_computed"], t.meta["mixed"]) for t in ticks]
+    assert rows[:5] == [(8, 24, 0), (4, 24, 0), (4 + 8, 24, 1), (8, 16, 0), (8, 16, 0)]
+    assert all(0 < t.meta["width_lanes"] <= t.meta["width"] for t in ticks[2:]) and ticks[0].meta["width_lanes"] == 0
+    emits = [s for s in spans if s.name == "serving.tick.decode.emit" and "first_tokens" in s.meta]
+    (first,) = emits
+    assert first.meta == {"tick": 5, "tokens": 3 + 4, "first_tokens": 2, "held_ticks": 4 + 4, "own_ticks": 4 + 2}
+    assert all("first_tokens" not in s.meta for s in spans if s.name == "serving.tick.prefill.emit")  # a chunk yields no token
+    reads = [s for s in spans if s.name == "serving.tick.read"]
+    assert all(set(s.meta) <= {"tick", "of", "settle"} for s in reads)  # the expert counters stand in stats(), not here
+    assert [(s.meta["tick"], s.meta["of"]) for s in reads[:4]] == [(2, 1), (3, 2), (4, 3), (5, 4)]
 
 
 def test_telemetry_counters_follow_the_engines(model, tmp_path):

@@ -18,6 +18,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from conftest import recorded_spans
 
 from accelerate_tpu import telemetry
 from accelerate_tpu.models import gpt2
@@ -315,9 +316,12 @@ def test_bucket_compile_event_and_width_gauge_without_tracing(
 # ---------------------------------------------------------------------------
 
 TICK_PHASES = ["admit", "prefill.build", "prefill.wait", "prefill.emit",
-               "decode.build", "decode.wait", "decode.emit", "publish"]
-# a tick with a chunk and a live decoder: both builds, then ONE dispatch (under decode.wait), then both emits
-MIXED_TICK_PHASES = ["admit", "prefill.build", "decode.build", "decode.wait", "prefill.emit", "decode.emit", "publish"]
+               "decode.build", "decode.wait", "launch", "read", "decode.emit", "publish"]
+# a tick with a chunk and a live decoder: both builds, then ONE dispatch (under decode.wait: its launch, and the read
+# of the tick before, are the wait's two children), then both emits
+MIXED_TICK_PHASES = ["admit", "prefill.build", "decode.build", "decode.wait", "launch", "read", "prefill.emit",
+                     "decode.emit", "publish"]
+TICK_ACCOUNT = {"rows_live", "rows_computed", "width", "width_lanes", "mixed", "pipelined", "settles"}
 
 
 def _busy_engine(cfg, params, **overrides):
@@ -361,15 +365,21 @@ def test_tick_spans_in_a_profiler_session(gpt2_setup, tmp_path):
     )
     ticks = [e for e in events if e[2] == "serving.tick"]
     assert [e[3]["tick"] for e in ticks] == [first, first + 1, first + 2]
-    assert set(ticks[0][3]) == {"tick", "queued", "prefilling", "decoding"}
+    assert set(ticks[0][3]) == {"tick", "queued", "prefilling", "decoding"} | TICK_ACCOUNT
     assert ticks[0][3]["prefilling"] == 1 and ticks[0][3]["decoding"] == 1
     live_before, prefilled = 1, None  # of the tick in flight when the session opened
     for i, (start, end, _, stats) in enumerate(ticks):
         children = [e for e in events if e[2] != "serving.tick" and e[3]["tick"] == stats["tick"]]
         assert [e[2] for e in children] == ["serving.tick." + p for p in MIXED_TICK_PHASES]
         assert all(start <= e[0] and e[1] <= end for e in children)
-        assert all(a[1] <= b[0] for a, b in zip(children, children[1:]))  # one after the other
+        wait, launch, read = children[3:6]
+        assert wait[0] <= launch[0] and launch[1] <= read[0] and read[1] <= wait[1]  # the wait's two children
+        phases = children[:4] + children[6:]
+        assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))  # one after the other
         by_name = {e[2].removeprefix("serving.tick."): e[3] for e in children}
+        assert by_name["launch"] == {"tick": stats["tick"], "program": "decode_chunk" if i < 2 else "decode", "fresh": 0}
+        assert by_name["read"] == {"tick": stats["tick"], "of": stats["tick"] - 1}  # no experts, no settle
+        assert (stats["mixed"], stats["pipelined"], stats["settles"]) == (int(i < 2), 1, 0)
         assert by_name["admit"]["admitted"] == 0
         assert set(by_name["prefill.build"]) == ({"tick", "request", "start", "rows"} if i < 2 else {"tick"})
         prefilled = by_name["prefill.build"].get("request", prefilled)
@@ -379,6 +389,121 @@ def test_tick_spans_in_a_profiler_session(gpt2_setup, tmp_path):
         assert set(by_name["decode.wait"]) == {"tick", "live", "width"} and by_name["decode.wait"]["width"] >= 1
         assert by_name["decode.emit"]["tokens"] == live_before  # the emits are of the tick dispatched before this one
         live_before = by_name["decode.build"]["live"]
+
+
+# One run of two requests under the span recorder, worked by hand (block_size 4, max_slots 2, prefill_chunk 8, tables
+# from one block up).  The short request (5-token prompt, 24 new) and the long one (24-token prompt, 4 new) are admitted
+# in tick 1.  Tick 1: the short prompt's one chunk alone.  Ticks 2-4: the long prompt's three chunks ride with the short
+# request's lane; the chunk's padded extent needs 2, 4 and 6 blocks, the lane 2: in ticks 3 and 4 the chunk forces the
+# width.  Ticks 5-7: both lanes (the long one owns 7 blocks: width 8).  Ticks 8-24: the short lane alone.  Tick 24
+# dispatches the last token, reads tick 23 and, nothing being left, settles ("idle"): a second read, of itself.
+ACCOUNT_TICKS = {  # tick: (rows_live, rows_computed, width, width_lanes, mixed, pipelined, settles)
+    1: (5, 10, 2, 0, 0, 0, 0), 2: (9, 10, 2, 2, 1, 1, 0), 3: (9, 10, 4, 2, 1, 1, 0), 4: (9, 10, 8, 2, 1, 1, 0),
+    5: (2, 2, 8, 8, 0, 1, 0), 7: (2, 2, 8, 8, 0, 1, 0), 8: (1, 2, 4, 4, 0, 1, 0), 24: (1, 2, 8, 8, 0, 1, 1),
+}
+
+
+@pytest.fixture(scope="module")
+def accounted(gpt2_setup):
+    from accelerate_tpu.serving import programs
+
+    cfg, params = gpt2_setup
+    with recorded_spans() as spans, pytest.MonkeyPatch.context() as patch:
+        patch.setattr(programs, "MIN_TABLE_ROWS", 1)  # conftest's, which a module's fixture is built ahead of
+        eng = _engine(cfg, params, prefix_cache=False)
+        rids = [eng.submit(list(range(1, 6)), 24), eng.submit(list(range(1, 25)), 4)]
+        records = []
+        while not eng.sched.idle():
+            eng.step()
+            records.append(dict(eng._tick, phase_ms=dict(eng._tick["phase_ms"])))
+        stats = eng.stats()
+    ticks = {s.meta["tick"]: s for s in spans if s.name == "serving.tick"}
+    done = {c.id: c for c in eng.pop_finished()}
+    return {"spans": spans, "ticks": ticks, "records": {r["tick"]: r for r in records}, "stats": stats,
+            "done": [done[rid] for rid in rids]}
+
+
+@pytest.mark.parametrize("tick", sorted(ACCOUNT_TICKS))
+def test_the_tick_span_carries_the_dispatchs_rows_and_widths(accounted, tick):
+    meta = accounted["ticks"][tick].meta
+    assert set(meta) == {"tick", "queued", "prefilling", "decoding"} | TICK_ACCOUNT
+    assert all(type(meta[k]) is int for k in TICK_ACCOUNT)
+    names = ("rows_live", "rows_computed", "width", "width_lanes", "mixed", "pipelined", "settles")
+    assert tuple(meta[k] for k in names) == ACCOUNT_TICKS[tick]
+
+
+def test_launch_and_read_are_phases_of_their_own_and_the_phases_still_sum_to_the_tick(accounted):
+    assert len(accounted["ticks"]) == 24
+    tails, edges = [], []
+    for tick, span in accounted["ticks"].items():
+        meta, record = span.meta, accounted["records"][tick]
+        assert abs(sum(record["phase_ms"].values()) - record["total_ms"]) < 1e-6
+        assert set(record["phase_ms"]) <= set(TICK_PHASES) and record["phase_ms"]["launch"] > 0
+        tails.append((span.end - span.start) * 1e3 - record["total_ms"])
+        children = [s for s in accounted["spans"] if s.parent is span]
+        waits = [s for s in children if s.name.endswith(".wait")]
+        inside = [s for s in accounted["spans"] if s.parent in waits]
+        assert [s.name for s in inside] == ["serving.tick.launch"] + ["serving.tick.read"] * (len(inside) - 1)
+        assert waits[0].start <= inside[0].start  # the wait opens at the launch: the tables are filled before it
+        reads = [s for s in inside if s.name == "serving.tick.read"]
+        assert len(reads) == meta["pipelined"] + meta["settles"] and ("read" in record["phase_ms"]) == bool(reads)
+        edges.append(abs(record["phase_ms"].get("read", 0.0) - sum(s.end - s.start for s in reads) * 1e3))
+    # The record's clock starts at step()'s first line, a few lines before the span opens, and the span closes after the
+    # record: the tracer's end of tick lies between (0.3 ms in all on a machine that does not pause; judged by the
+    # median, a loaded worker may stall in any one tick).  The read's phase is the time inside its spans but for the
+    # clock reads either side.
+    middle = lambda values: sorted(values)[len(values) // 2]  # noqa: E731
+    assert -0.3 < middle(tails) < 0.3 and middle(edges) < 0.1
+
+
+def test_a_settle_adds_a_second_read_with_its_reason(accounted):
+    last = accounted["ticks"][24]
+    reads = [s for s in accounted["spans"] if s.name == "serving.tick.read" and s.meta["tick"] == 24 and s.end <= last.end]
+    assert [(s.meta["of"], s.meta.get("settle")) for s in reads] == [(23, None), (24, "idle")]
+    assert [s.parent.name for s in reads] == ["serving.tick.decode.wait"] * 2 and reads[1].parent.meta["settle"] == "idle"
+    assert last.meta["settles"] == 1 and accounted["records"][24]["settle"] == "idle"
+    assert all(t.meta["settles"] == 0 for n, t in accounted["ticks"].items() if n != 24)
+    outside = [s for s in accounted["spans"] if s.name == "serving.tick.read" and s.start > last.end]
+    assert outside == [] and accounted["stats"]["settles"] == {"idle": 1}  # nothing was in flight for stats() to read
+
+
+def test_a_settle_between_two_ticks_leaves_the_closed_record_alone(gpt2_setup):
+    """``stats()`` between two ticks reads the tick in flight back and emits it under spans of their own; the record of
+    the tick before it is closed: no phase, no ``settle`` and no count is booked into it after its end."""
+    cfg, params = gpt2_setup
+    eng = _busy_engine(cfg, params)
+    closed = eng._tick
+    before = dict(closed, phase_ms=dict(closed["phase_ms"]))
+    with recorded_spans() as spans:
+        settles = eng.stats()["settles"]
+    assert [s.name for s in spans] == ["serving.tick." + p for p in ("decode.wait", "read", "prefill.emit", "decode.emit")]
+    assert spans[1].parent is spans[0] and spans[1].meta == {"tick": eng.ticks, "of": eng.ticks, "settle": "stats"}
+    assert settles["stats"] >= 1 and eng._tick is closed and closed == before
+    eng.step()  # the next tick finds nothing in flight: not pipelined, and its own record is whole
+    assert not eng._tick["pipelined"] and abs(sum(eng._tick["phase_ms"].values()) - eng._tick["total_ms"]) < 1e-6
+
+
+def test_the_first_tokens_account_of_a_one_token_family(accounted):
+    """The short prompt's chunk is dispatched in the tick that admits it: held one tick, its own.  The long prompt is
+    admitted in the same tick and prefills in ticks 2-4: held four ticks, three of them its own."""
+    emits = [s for s in accounted["spans"] if s.name == "serving.tick.prefill.emit"]
+    firsts = [s for s in emits if s.meta["first_token"]]
+    done = accounted["done"]
+    assert [(s.meta["tick"], s.meta["request"]) for s in firsts] == [(2, done[0].id), (5, done[1].id)]
+    assert [(s.meta["first_tokens"], s.meta["held_ticks"], s.meta["own_ticks"]) for s in firsts] == [(1, 1, 1), (1, 4, 3)]
+    assert all(set(s.meta) == {"tick", "request", "first_token", "first_tokens", "held_ticks", "own_ticks"} for s in firsts)
+    others = [s for s in emits if not s.meta["first_token"]]
+    assert others and all(set(s.meta) == {"tick", "request", "first_token"} for s in others)
+    assert all("first_tokens" not in s.meta for s in accounted["spans"] if s.name == "serving.tick.decode.emit")
+
+
+def test_the_counters_of_stats_equal_the_spans_sums(accounted):
+    ticks, stats = accounted["ticks"].values(), accounted["stats"]
+    assert sum(t.meta["rows_live"] for t in ticks) == 5 + 3 * 9 + 3 * 2 + 17
+    assert sum(t.meta["rows_computed"] for t in ticks) == 4 * 10 + 20 * 2
+    assert sum(t.meta["width"] > t.meta["width_lanes"] > 0 for t in ticks) == 2
+    assert stats["mixed_dispatches"] == sum(t.meta["mixed"] for t in ticks) == 3
+    assert stats["pipelined_ticks"] == sum(t.meta["pipelined"] for t in ticks) == 23
 
 
 def test_slow_ticks_keep_the_slowest_and_say_which_phase(gpt2_setup, tmp_path, monkeypatch):

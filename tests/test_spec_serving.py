@@ -11,7 +11,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from conftest import without_apply_paged
+from conftest import recorded_spans, without_apply_paged
 
 from accelerate_tpu import telemetry
 from accelerate_tpu.models import gpt2, llama
@@ -461,6 +461,34 @@ def test_a_verify_window_engine_settles_every_tick(gpt2_setup, spec_tokens):
     done = {c.id: c.tokens for c in eng.pop_finished()}
     for rid, prompt in zip(rids, prompts):
         assert done[rid] == _oracle(cfg, params, prompt, 9)
+
+
+def test_the_tick_account_counts_a_verify_windows_rows_and_its_settles(gpt2_setup):
+    """Under a verify window a lane's rows of a dispatch are its token and its
+    drafts (the drafter here always proposes all it is asked for: ``min(k,
+    remaining - 1)``), the program computes ``max_slots`` windows of ``k + 1``,
+    and every tick's one read is a settle's, of the tick itself."""
+    cfg, params = gpt2_setup
+    k, slots, chunk = 3, 2, 8
+    with recorded_spans() as spans:
+        eng = ServingEngine(
+            gpt2.apply_cached, gpt2.init_cache, params, cfg,
+            serving=ServingConfig(block_size=4, num_blocks=20, max_slots=slots, prefill_chunk=chunk,
+                                  max_blocks_per_seq=8, prefix_cache=False, spec_tokens=k),
+            drafter=_ScriptedDrafter({2: lambda feed, want: [0] * want}),
+        )
+        eng.submit([2, 7, 1, 8], 6)
+        want_rows = []
+        while not eng.sched.idle():
+            remaining = [s.request.remaining for s in eng.sched.slots.values() if s.request.emitted]
+            want_rows.append(sum(1 + min(k, r - 1) for r in remaining) or 4)  # the first tick: the prompt's one chunk
+            eng.step()
+    ticks = [s for s in spans if s.name == "serving.tick"]
+    assert [t.meta["rows_live"] for t in ticks] == want_rows and want_rows[:2] == [4, 4] and want_rows[-1] == 1
+    assert [t.meta["rows_computed"] for t in ticks] == [slots * (k + 1) + chunk] + [slots * (k + 1)] * (len(ticks) - 1)
+    assert all((t.meta["pipelined"], t.meta["settles"]) == (0, 1) for t in ticks)
+    reads = [s for s in spans if s.name == "serving.tick.read"]
+    assert [(s.meta["tick"], s.meta["of"], s.meta["settle"]) for s in reads] == [(t.meta["tick"],) * 2 + ("spec",) for t in ticks]
 
 
 def test_spec_report_block_renders(gpt2_setup, tmp_path):
