@@ -6,7 +6,11 @@ One engine **tick** (:meth:`ServingEngine.step`) is:
 2. **build** — at most ONE bounded chunk (``prefill_chunk`` tokens, padded
    to a static shape) of the oldest prefilling request, so a 10k-token
    prompt costs many small chunks interleaved with decode instead of one
-   huge dispatch that stalls every in-flight request; then the decode batch:
+   huge dispatch that stalls every in-flight request.  The chunk's size is
+   one integer an engine, settled at construction: the caller's, or by
+   default as many rows as ride in the decoders' dispatch nearly for free on
+   this device (:func:`resolve_prefill_chunk`; 32 for a family with routed
+   experts and off the TPU); then the decode batch:
    every decoding slot grown by one token (or one ``k + 1`` window),
    oldest first.  Growing the decoders may preempt the prefilling slot; its
    chunk is then dropped with it;
@@ -223,17 +227,19 @@ Production-robustness layer (overload / deadlines / quarantine / journal):
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..models.generation import MASKED, denoise_schedule, with_token_leaves
-from ..telemetry import annotate, get_telemetry
+from ..telemetry import annotate, get_telemetry, ridge_rows
 from .blocks import (
     NULL_BLOCK,
     BlockOutOfMemory,
@@ -249,6 +255,7 @@ from .tracing import ServingTracer, resolve_trace_dir, tracing_enabled
 __all__ = [
     "AdmissionRejected",
     "ServingConfig",
+    "resolve_prefill_chunk",
     "ServingEngine",
     "CompletedRequest",
 ]
@@ -279,6 +286,12 @@ class ServingConfig:
     - ``max_blocks_per_seq``: block-table width (static); caps any single
       request at ``max_blocks_per_seq * block_size`` cache rows.
     - ``prefill_chunk``: prompt tokens a tick's one chunk holds (static).
+      ``None`` (default): the engine chooses it once, at construction, by
+      :func:`resolve_prefill_chunk` — as many rows as ride in the decoders'
+      dispatch nearly for free on the device it runs on (64 beside sixteen
+      lanes on a TPU v5e), 32 for a family with routed experts and off the TPU.  An
+      integer is kept as given.  Either way ``engine.serving.prefill_chunk``
+      and ``stats()["prefill_chunk"]`` hold the integer the engine runs.
 
     Robustness knobs (all host-side policy, no effect on the compiled
     programs):
@@ -337,7 +350,7 @@ class ServingConfig:
     num_blocks: int = 64
     max_slots: int = 4
     max_blocks_per_seq: Optional[int] = None
-    prefill_chunk: int = 32
+    prefill_chunk: Optional[int] = None
     max_queue_depth: Optional[int] = None
     default_ttft_deadline_ms: Optional[float] = None
     default_deadline_ms: Optional[float] = None
@@ -355,6 +368,78 @@ class ServingConfig:
         if self.max_blocks_per_seq is not None:
             return self.max_blocks_per_seq
         return self.num_blocks - 1
+
+
+# The chunk of an engine that was given none and finds no free rows: what every engine ran before the rule.
+DEFAULT_PREFILL_CHUNK = 32
+
+# The share of the device's ridge that a tick's rows (every lane's window and the chunk) may fill.  Up to the ridge
+# (``telemetry.ridge_rows``: 240 rows on a v5e) a dense matmul's time is that of its weights' bytes, so by the matmuls
+# alone a dispatch could carry 240 rows for the price of 48.  What a chunk's rows do cost is what is not a matmul
+# against the weights: the gather of the chunk's blocks and its attention, both over the dispatch's whole table width
+# (in a trace of the chat cell 0.24 and 0.28 ms of the 0.58 ms that 32 more rows add).  On a v5e at the chat geometry (Qwen2.5-3B, sixteen lanes; PERF.md section 6, PR 37) the second 32 rows cost
+# 0.36 ms of an 11.3 ms dispatch at a table of 64 blocks and 0.64 ms of 18.0 at 256, nearly what the first 32 cost
+# (0.47 and 1.00).  What pays for them is the ticks they save, and what bounds them is the pace of the decoders' tokens,
+# which every row lengthens: against a chunk of 32, 64 rows serve 43% more tokens a second at 0.30 of the time to a
+# first token with token gaps 2.5% longer at their p95; 96 rows 53% more at gaps 6.1% longer; 128 rows 55% at 8.7%.
+# The gaps may lengthen by 3.5%, so the share is the largest that gives sixteen lanes 64 rows and not 96: 16 + 64 = 80
+# rows stay under a third of 240.5, 16 + 96 = 112 do not (any share from 0.333 to 0.465 gives the same chunk there).
+CHUNK_RIDGE_FRACTION = 1 / 3
+
+
+def resolve_prefill_chunk(
+    requested: Optional[int],
+    *,
+    device_kind: str,
+    max_slots: int,
+    window: int,
+    block_size: int,
+    block_length: int = 1,
+    routed_experts: int = 0,
+) -> int:
+    """The size of the one prefill chunk a tick carries (static: one size an
+    engine, so one ``decode_chunk`` program a table width).  Arguments in,
+    integer out; it asks no device anything, so it can be asked about any.
+
+    An integer ``requested`` is kept as given.  Else the chunk is as many rows
+    as the decoders' dispatch carries nearly for free:
+
+    1. The weights are read once a dispatch whatever its rows, and up to the
+       device's ridge their bytes set a dense matmul's time.  The chunk is the
+       largest multiple of ``DEFAULT_PREFILL_CHUNK`` (and of ``block_size``
+       and a block family's ``block_length``: a chunk and a pool block hold
+       whole blocks) such that ``max_slots * window + chunk`` stays under
+       ``CHUNK_RIDGE_FRACTION`` of the ridge; ``DEFAULT_PREFILL_CHUNK`` where
+       not even one such multiple fits.
+    2. With routed experts no row is free at these row counts: every further
+       row pulls its ``top_k`` experts' matrices in, so a dispatch's bytes grow
+       with its rows (a chunk of 32 rows costs an expert family 1.6-2.0 ms of
+       a 9-18 ms dispatch, PERF.md section 5, against 0.5 ms of 11.5 for a
+       dense one).  Weighing the experts more rows hit against the ticks they
+       save is another rule (ROADMAP A14): such a family keeps
+       ``DEFAULT_PREFILL_CHUNK``.
+    3. Off the TPU, or on a device kind whose peaks the library's table lacks,
+       nothing is known to be free: ``DEFAULT_PREFILL_CHUNK``.
+    """
+    if requested is not None:
+        return int(requested)
+    ridge = ridge_rows(device_kind)
+    if ridge is None or routed_experts:
+        return DEFAULT_PREFILL_CHUNK
+    step = math.lcm(DEFAULT_PREFILL_CHUNK, block_size, block_length)
+    free = math.ceil(CHUNK_RIDGE_FRACTION * ridge) - 1 - max_slots * window  # "under": the largest integer below
+    return max(free // step * step, DEFAULT_PREFILL_CHUNK)
+
+
+def _device_kind() -> str:
+    """The kind of the device this process computes on, as JAX names it."""
+    return jax.devices()[0].device_kind
+
+
+def _routed_experts(config) -> int:
+    """Routed experts a layer, as the family's model config counts them under
+    either of the two names the families use; 0 for a dense family."""
+    return int(getattr(config, "n_routed_experts", 0) or getattr(config, "num_experts", 0) or 0)
 
 
 @dataclass
@@ -557,9 +642,8 @@ class ServingEngine:
         serving: Optional[ServingConfig] = None,
         drafter=None,
     ):
-        self.serving = serving or ServingConfig()
-        sc = self.serving
-        if sc.prefill_chunk < 1:
+        sc = serving or ServingConfig()
+        if sc.prefill_chunk is not None and sc.prefill_chunk < 1:
             raise ValueError(f"prefill_chunk must be >= 1, got {sc.prefill_chunk}")
         if sc.resolved_max_blocks() < 1:
             raise ValueError("max_blocks_per_seq must be >= 1")
@@ -577,6 +661,17 @@ class ServingEngine:
         self.spec_tokens = int(sc.spec_tokens)
         # A family generated by diffusion over blocks says so in its model config (1: every other family).
         self.block_length = int(getattr(config, "block_length", 1))
+        # The chunk's size is settled here, once, and everything below reads the integer: the caller's config is
+        # left as it was given (it may build another engine, of another family or on another device).
+        self.serving = sc = replace(sc, prefill_chunk=resolve_prefill_chunk(
+            sc.prefill_chunk,
+            device_kind=_device_kind(),
+            max_slots=sc.max_slots,
+            window=self.block_length if self.block_length > 1 else self.spec_tokens + 1,
+            block_size=sc.block_size,
+            block_length=self.block_length,
+            routed_experts=_routed_experts(config),
+        ))
         self.cache = PagedKVCache(
             init_cache, config, sc.num_blocks, sc.block_size,
             num_host_blocks=sc.host_blocks, num_slots=sc.max_slots,
@@ -2483,6 +2578,7 @@ class ServingEngine:
             "deadline_expired": self.deadline_expired_count,
             "quarantined": self.quarantined_count,
             "pool_bytes": self.cache.pool_bytes(),
+            "prefill_chunk": self.serving.prefill_chunk,  # the integer the engine runs: given, or resolved at construction
             **self._state_stats(),
             **self._block_stats(),
             "free_pool_bytes": alloc.free_blocks * self._block_bytes,

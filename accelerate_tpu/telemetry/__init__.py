@@ -57,6 +57,7 @@ from .metrics import (
     StepTimer,
     collect_hbm,
     peak_flops_per_chip,
+    ridge_rows,
 )
 from .flightrec import FlightRecorder, get_flight_recorder
 from .hlo_scan import CollectiveOp, CommsLedger, parse_collectives, scan_hlo
@@ -98,6 +99,7 @@ __all__ = [
     "CompileWatcher",
     "collect_hbm",
     "peak_flops_per_chip",
+    "ridge_rows",
     "StallWatchdog",
     "thread_dump",
     # flight recorder + anomaly sentinel

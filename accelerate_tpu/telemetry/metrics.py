@@ -33,6 +33,7 @@ __all__ = [
     "CompileWatcher",
     "collect_hbm",
     "peak_flops_per_chip",
+    "ridge_rows",
 ]
 
 # jax.monitoring key emitted once per compile REQUEST that missed the in-memory
@@ -196,19 +197,30 @@ class MetricsRegistry:
 # Built-in collectors
 # ---------------------------------------------------------------------------
 
-# Per-chip bf16 peak FLOP/s by device kind, checked in order (the table
-# bench.py's MFU math uses — kept here so the live MFU gauge and the benchmark
-# can never disagree).  "v5 lite"/"v5e" before "v5" so the lite chip does not
-# match the v5p row.
+# Per-chip bf16 peak FLOP/s and HBM bytes/s by device kind, checked in order
+# (the table bench.py's MFU math uses — kept here so the live MFU gauge and the
+# benchmark can never disagree; the bandwidth stands beside the peak because
+# the two together say where a matmul stops being bound by its weights' bytes,
+# :func:`ridge_rows`).  "v5 lite"/"v5e" before "v5" so the lite chip does not
+# match the v5p row.  Sources: Google Cloud's TPU documentation (v5e: 197
+# TFLOP/s, 819 GB/s; v5p: 459, 2,765; v4: 275, 1,200; v6e: 918, 1,640).
 _PEAK_FLOPS_TABLE = (
-    ("v5 lite", 197e12),
-    ("v5e", 197e12),
-    ("v5p", 459e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-    ("v6", 918e12),
-    ("trillium", 918e12),
+    ("v5 lite", 197e12, 819e9),
+    ("v5e", 197e12, 819e9),
+    ("v5p", 459e12, 2765e9),
+    ("v5", 459e12, 2765e9),
+    ("v4", 275e12, 1200e9),
+    ("v6", 918e12, 1640e9),
+    ("trillium", 918e12, 1640e9),
 )
+
+
+def _peaks(device_kind: str) -> Optional[tuple]:
+    kind = device_kind.lower()
+    for key, flops, hbm in _PEAK_FLOPS_TABLE:
+        if key in kind:
+            return flops, hbm
+    return None
 
 
 def peak_flops_per_chip(device=None) -> float:
@@ -219,14 +231,23 @@ def peak_flops_per_chip(device=None) -> float:
         import jax
 
         device = jax.devices()[0]
-    kind = device.device_kind.lower()
-    for key, flops in _PEAK_FLOPS_TABLE:
-        if key in kind:
-            return flops
-    raise ValueError(
-        f"no peak FLOP/s known for device kind {device.device_kind!r} "
-        f"(table: {[k for k, _ in _PEAK_FLOPS_TABLE]})"
-    )
+    peaks = _peaks(device.device_kind)
+    if peaks is None:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device.device_kind!r} "
+            f"(table: {[row[0] for row in _PEAK_FLOPS_TABLE]})"
+        )
+    return peaks[0]
+
+
+def ridge_rows(device_kind: str) -> Optional[float]:
+    """Rows up to which a dense matmul against two-byte weights is bound by the
+    weights' bytes on a chip of this kind: ``n`` rows against ``[d, f]`` bf16
+    weights are ``2 n d f`` FLOPs over ``2 d f`` bytes, so the two times meet at
+    ``n`` = peak FLOP/s / HBM bytes/s (240 on a v5e).  ``None`` for a kind the
+    table lacks (a CPU among them): the caller keeps what it did without one."""
+    peaks = _peaks(device_kind)
+    return None if peaks is None else peaks[0] / peaks[1]
 
 
 def collect_hbm(registry: MetricsRegistry, device=None) -> dict:
