@@ -309,18 +309,23 @@ def _ffn(x, p, c: DeepseekV3Config, held=None):
         return x + y + shared.astype(x.dtype), routing["group_sizes"]
 
 
-def expert_counters(group_sizes: jax.Array, row_tile: int = 0) -> dict:
+def expert_counters(group_sizes: jax.Array, row_tile: int = 0, pairs_routed: Optional[int] = None) -> dict:
     """What a dispatch's expert layers did, from ``[layers, E]`` rows an expert
     computed: token-expert pairs, experts with at least one row and the hottest
-    expert's rows, each summed over the layers; and the row tiles of
-    ``row_tile`` rows that the fused kernel computed for them, 0 where the
-    layers ran ``lax.ragged_dot`` (``row_tile`` 0: ``ops/moe.py:expert_row_tile``
-    of the dispatch says which)."""
+    expert's rows, each summed over the layers; the row tiles of ``row_tile``
+    rows that the fused kernel computed for them, 0 where the layers ran
+    ``lax.ragged_dot`` (``row_tile`` 0: ``ops/moe.py:expert_row_tile`` of the
+    dispatch says which); and ``moe_pairs_routed``, every pair the routers made:
+    the pairs computed where a layer's experts are all held, ``pairs_routed``
+    (layers x rows x top_k, a static count) where ``group_sizes`` are a share's
+    ``[layers, held]``, so that ``moe_rows / moe_pairs_routed`` is the share's load."""
+    rows = jnp.sum(group_sizes)
     return {
-        "moe_rows": jnp.sum(group_sizes),
+        "moe_rows": rows,
         "moe_experts_hit": jnp.sum(group_sizes > 0),
         "moe_max_rows": jnp.sum(jnp.max(group_sizes, axis=-1)),
         "moe_row_tiles": jnp.sum(-(-group_sizes // row_tile)) if row_tile else jnp.zeros((), group_sizes.dtype),
+        "moe_pairs_routed": rows if pairs_routed is None else jnp.asarray(pairs_routed, group_sizes.dtype),
     }
 
 

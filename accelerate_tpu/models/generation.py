@@ -25,6 +25,8 @@ __all__ = [
     "address_paged_pool_by_layer", "address_paged_leaf_by_layer",
     "unpack_paged_rows_from_scan", "demote_pool_blocks", "promote_pool_blocks",
     "STATE", "token_leaves", "state_leaves", "with_token_leaves", "read_state_rows", "write_state_rows",
+    "WINDOW", "window_leaves", "with_window_leaves", "window_ring_blocks", "window_group_masks", "paged_window_write",
+    "scatter_window_rows",
     "MASKED", "block_end", "denoise_schedule", "block_unmask", "block_generate_loop",
 ]
 
@@ -124,12 +126,19 @@ def cache_write(cache_leaf, new_rows: jax.Array, index, dtype):
 # short convolution's last inputs), **state** leaves ``[L, B, ...]`` under the key ``STATE``: one entry a sequence,
 # held by decode slot.  The number of layers may differ from leaf to leaf.  A family declares a leaf's kind by where it
 # puts it; nothing is guessed from a shape.
+#
+# Token rows are of two kinds, by layer.  The leaves at the top level hold a sequence's rows for its whole length (a
+# **full** layer's).  Leaves under ``WINDOW`` are token rows too, ``[L, B, max_len, ...]`` in a family's own cache, but
+# of layers that attend over the last ``config.sliding_window`` positions alone: in the pool they are a group of their
+# own with its own number of blocks, and a sequence holds of them a **ring** of ``window_ring_blocks`` blocks at most,
+# logical block ``j`` at entry ``j mod width`` of its window table, overwritten in place once the ring is full.
 STATE = "state"
+WINDOW = "window"
 
 
 def token_leaves(pool: dict) -> dict:
-    """The leaves of a cache or a pool that hold a row a token."""
-    return {name: leaf for name, leaf in pool.items() if name not in (STATE, "index")}
+    """The leaves of a cache or a pool that hold a row a token for the whole sequence."""
+    return {name: leaf for name, leaf in pool.items() if name not in (STATE, WINDOW, "index")}
 
 
 def state_leaves(pool: dict) -> dict:
@@ -137,13 +146,35 @@ def state_leaves(pool: dict) -> dict:
     return pool.get(STATE, {})
 
 
+def window_leaves(pool: dict) -> dict:
+    """The token leaves of the layers that keep a window of rows (empty for most families)."""
+    return pool.get(WINDOW, {})
+
+
 def with_token_leaves(pool: dict, fn: Callable) -> dict:
-    """The pool with ``fn`` applied to every token leaf, the state as it is:
-    what moves, copies or scrubs **blocks** goes through here."""
-    return {name: leaf if name == STATE else fn(leaf) for name, leaf in pool.items()}
+    """The pool with ``fn`` applied to every full token leaf, the state and the
+    window leaves as they are: what moves, copies or scrubs **blocks** of the
+    full kind goes through here."""
+    return {name: leaf if name in (STATE, WINDOW) else fn(leaf) for name, leaf in pool.items()}
 
 
-def make_paged_pool(init_cache: Callable, config, num_blocks: int, block_size: int, num_slots: int = 0) -> dict:
+def with_window_leaves(pool: dict, fn: Callable) -> dict:
+    """The pool with ``fn`` applied to every window leaf: the window kind's blocks are numbered on their own."""
+    return {**pool, WINDOW: {name: fn(leaf) for name, leaf in pool[WINDOW].items()}}
+
+
+def window_ring_blocks(window: int, chunk: int, block_size: int) -> int:
+    """The width of a sequence's window table: the blocks that hold ``window +
+    chunk`` rows, and one.  A dispatch writes at most ``chunk`` rows of a
+    sequence (the padded last chunk of a prompt too), each over the row ``width
+    * block_size`` positions before it: with this width that row lies outside
+    the window of every position the dispatch computes, and of every later one."""
+    return -(-(window + chunk) // block_size) + 1
+
+
+def make_paged_pool(
+    init_cache: Callable, config, num_blocks: int, block_size: int, num_slots: int = 0, window_blocks: int = 0
+) -> dict:
     """Zeroed pool derived from a family's own ``init_cache``.  Every token
     leaf ``[L, 1, block_size, *rest]`` of the batch-1 template becomes ``[L,
     num_blocks, block_size, *rest]`` (so the int8 codes+scale layout pages
@@ -151,21 +182,30 @@ def make_paged_pool(init_cache: Callable, config, num_blocks: int, block_size: i
     table padding and inactive-slot writes route there, and no allocated region
     ever reads it.  Every state leaf ``[L, 1, *rest]`` becomes ``[L, num_slots,
     *rest]`` under ``STATE``: entry ``s`` belongs to the sequence in decode slot
-    ``s``.  A family without a state gets the pool it always got."""
+    ``s``.  Every window leaf becomes ``[L, window_blocks, block_size, *rest]``
+    under ``WINDOW``, block 0 its own NULL block.  A family without a state or a
+    window gets the pool it always got."""
     template = init_cache(config, 1, block_size)
-    pool = {}
-    for name, leaf in token_leaves(template).items():
-        if leaf.ndim < 3 or leaf.shape[1] != 1 or leaf.shape[2] != block_size:
-            raise ValueError(
-                f"cache leaf {name!r} has shape {leaf.shape}; paged serving needs "
-                f"the make_kv_cache layout [L, B, max_len, ...] (batch axis 1, "
-                f"token axis 2)"
-            )
-        pool[name] = jnp.zeros(
-            (leaf.shape[0], num_blocks) + leaf.shape[2:], leaf.dtype
-        )
+
+    def paged(leaves: dict, blocks: int) -> dict:
+        out = {}
+        for name, leaf in leaves.items():
+            if leaf.ndim < 3 or leaf.shape[1] != 1 or leaf.shape[2] != block_size:
+                raise ValueError(
+                    f"cache leaf {name!r} has shape {leaf.shape}; paged serving needs "
+                    f"the make_kv_cache layout [L, B, max_len, ...] (batch axis 1, "
+                    f"token axis 2)"
+                )
+            out[name] = jnp.zeros((leaf.shape[0], blocks) + leaf.shape[2:], leaf.dtype)
+        return out
+
+    pool = paged(token_leaves(template), num_blocks)
     if not pool:
         raise ValueError("init_cache produced no pageable KV leaves")
+    if window_leaves(template):
+        if window_blocks < 2:
+            raise ValueError("the cache holds window leaves: the pool needs their number of blocks (a null block and one)")
+        pool[WINDOW] = paged(window_leaves(template), window_blocks)
     state = state_leaves(template)
     if state:
         if num_slots < 1:
@@ -411,6 +451,54 @@ def gather_paged_context(pool_layer: jax.Array, tables: jax.Array) -> jax.Array:
         # as fast as through the (K, 128) tiles of [bs, K, hd] at K = 2.
         rows = pool_layer.reshape(n, bs * rest[0], rest[1])
     return jnp.take(rows, tables, axis=0, mode="clip").reshape(b, m * bs, *rest)
+
+
+def window_group_masks(groups, positions, block_size: int, window: int) -> tuple:
+    """Of every group ``(tokens [B, T], tables, starts [B], window tables [B,
+    W])`` of an ``apply_paged`` call: the mask of its new tokens over the rows
+    its window table names, ``[B, T, W * block_size]``, by position.  Ring row
+    ``r`` of a lane whose dispatch ends at position ``last = starts + T - 1``
+    holds the newest position ``<= last`` that is ``r`` modulo the ring's rows
+    (none yet where that is negative), and a query at ``i`` sees the keys ``j``
+    with ``i - window < j <= i``: the ring's order does not matter, keys are
+    cached after RoPE.  ``W`` is the ring's width, or a narrower table of a
+    dispatch none of whose sequences has wrapped (every position lies under ``W
+    * block_size``): the same formula."""
+    masks = []
+    for pos, (tokens, _, starts, wtables) in zip(positions, groups):
+        rows = wtables.shape[1] * block_size
+        last = (starts + tokens.shape[1] - 1)[:, None]
+        held = last - (last - jnp.arange(rows, dtype=jnp.int32)[None, :]) % rows  # [B, rows]: the position a ring row holds
+        held = held[:, None, :]
+        masks.append((held >= 0) & (held <= pos[:, :, None]) & (held > pos[:, :, None] - window))
+    return tuple(masks)
+
+
+@jax.named_scope("kv_pool.gather")
+def paged_window_write(pool_layer, new_rows: jax.Array, wtables: jax.Array, starts: jax.Array):
+    """:func:`paged_cache_write` for a window leaf: the stored rows of
+    ``new_rows [B, T, K, hd]`` and the context ``[B, W*bs, K, hd]`` gathered
+    through the window tables ``[B, W]``, the new rows overlaid where the ring
+    puts them, ``(starts[b] + t) mod (W*bs)``.  What a dispatch gathers for a
+    window layer is the ring, whatever the sequences' lengths."""
+    stored = new_rows.astype(pool_layer.dtype)
+    ctx = gather_paged_context(pool_layer, wtables)
+    slot = jnp.arange(ctx.shape[0], dtype=jnp.int32)[:, None]
+    at = _token_positions(starts, new_rows.shape[1]) % ctx.shape[1]
+    return stored, ctx.at[slot, at].set(stored, unique_indices=True)
+
+
+@jax.named_scope("kv_pool.write")
+def scatter_window_rows(pool_leaf: jax.Array, rows: jax.Array, wtables: jax.Array, start: jax.Array, count: int) -> jax.Array:
+    """:func:`scatter_token_rows` for a window leaf: rows ``[S, L, count, *r]``
+    at positions ``start[s] + arange(count)``, position ``p`` into block
+    ``wtables[s, (p // bs) mod W]``.  An entry not yet allocated names the
+    window kind's null block (the padding of a prompt's last chunk lands there
+    or on rows that have left every window: :func:`window_ring_blocks`)."""
+    bs = pool_leaf.shape[2]
+    pos = _token_positions(start, count)
+    blk = jnp.take_along_axis(wtables, (pos // bs) % wtables.shape[1], axis=1)
+    return pool_leaf.at[:, blk, pos % bs].set(jnp.moveaxis(rows, 0, 1))
 
 
 def _latent_rows_lie_block_by_block(leaf) -> bool:

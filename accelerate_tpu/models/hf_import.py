@@ -57,6 +57,17 @@ Supported families and their HF architectures:
                 experts stacked ``[L, E, ...]``) into ``dense`` or ``moe``;
                 ``embedding_norm`` is the final norm and the head is the
                 embedding (a convolution bias or an untied head is refused)
+- ``afmoe``   — AfmoeForCausalLM (Arcee Trinity): ``self_attn.{q,k,v,o}_proj``
+                and ``gate_proj`` (``wg``), ``q_norm`` / ``k_norm``, the four
+                sandwich norms (``input_layernorm``, ``post_attention_layernorm``,
+                ``pre_mlp_layernorm``, ``post_mlp_layernorm``), ``mlp.router.gate``
+                as ``router``, ``mlp.expert_bias`` as ``router_bias``,
+                ``mlp.shared_experts.*`` as ``ws_*``, the experts stacked ``[L,
+                held, ...]`` (with ``experts_held=(first, count)`` as an override,
+                that run of each layer's experts alone), an untied ``lm_head``; the
+                leading dense layers and the expert layers land in the ``dense``
+                and ``moe`` stacks; held by a synthetic round trip only (no
+                published checkpoint is in the repository)
 - ``vit``     — ViTForImageClassification / ViTModel (patch-conv kernel
                 [d, C, p, p] -> the patchify matmul's [p*p*C, d])
 - ``resnet``  — ResNetForImageClassification / ResNetModel (HF's v1.5
@@ -111,7 +122,7 @@ def _stack_cat(sd: dict, fmts: list, n: int, transpose: bool = False) -> np.ndar
 
 def _detect_family(hf_config) -> str:
     mt = getattr(hf_config, "model_type", "")
-    known = {"llama", "gpt2", "bert", "t5", "mixtral", "deepseek_v3", "lfm2_moe", "sdar_moe", "vit", "resnet"}
+    known = {"llama", "gpt2", "bert", "t5", "mixtral", "deepseek_v3", "lfm2_moe", "sdar_moe", "afmoe", "vit", "resnet"}
     if mt in ("qwen2", "mistral", "gemma", "phi3"):
         # llama-architecture variants: qwen2 adds Q/K/V biases, mistral is
         # llama-shaped GQA, gemma swaps in GeGLU + (1+w) RMSNorm + sqrt(d)
@@ -395,6 +406,37 @@ def config_from_hf(hf_config, **overrides):
                 kw[ours] = int(getattr(c, theirs))
         kw.update(overrides)
         return SdarMoeConfig(**kw)
+    if family == "afmoe":
+        from .afmoe import AfmoeConfig
+
+        if getattr(c, "tie_word_embeddings", False) or getattr(c, "rope_scaling", None):
+            raise ValueError("afmoe import: a tied head and a scaled RoPE are not implemented (models/afmoe.py)")
+        if getattr(c, "score_func", "sigmoid") != "sigmoid" or getattr(c, "n_group", 1) != 1 or getattr(c, "topk_group", 1) != 1:
+            raise ValueError("afmoe import: only sigmoid scores over one group of experts are implemented (models/afmoe.py)")
+        kw = dict(
+            vocab_size=c.vocab_size,
+            hidden_size=c.hidden_size,
+            intermediate_size=c.intermediate_size,
+            moe_intermediate_size=c.moe_intermediate_size,
+            num_layers=c.num_hidden_layers,
+            layer_types=tuple(c.layer_types),
+            num_dense_layers=c.num_dense_layers,
+            num_heads=c.num_attention_heads,
+            num_kv_heads=c.num_key_value_heads,
+            head_dim=getattr(c, "head_dim", None) or c.hidden_size // c.num_attention_heads,
+            num_experts=c.num_experts,
+            num_experts_per_tok=c.num_experts_per_tok,
+            num_shared_experts=c.num_shared_experts,
+            route_norm=bool(c.route_norm),
+            route_scale=float(c.route_scale),
+            sliding_window=c.sliding_window,
+            mup_enabled=bool(getattr(c, "mup_enabled", False)),
+            max_seq_len=c.max_position_embeddings,
+            rope_theta=float(c.rope_theta),
+            rms_eps=float(c.rms_norm_eps),
+        )
+        kw.update(overrides)
+        return AfmoeConfig(**kw)
     if family == "resnet":
         from .resnet import ResNetConfig
 
@@ -779,6 +821,67 @@ def _import_sdar_moe(sd: dict, cfg) -> dict:
     }
 
 
+def _import_afmoe(sd: dict, cfg) -> dict:
+    c = cfg
+    first, count = c.held
+
+    def stack(layers, fmt, transpose=False):
+        mats = [_np(sd[f"layers.{i}." + fmt]) for i in layers]
+        return np.stack([m.T for m in mats] if transpose else mats)
+
+    def block(layers) -> dict:
+        return {
+            "ln_in": stack(layers, "input_layernorm.weight"),
+            "ln_post_attn": stack(layers, "post_attention_layernorm.weight"),
+            "ln_pre_mlp": stack(layers, "pre_mlp_layernorm.weight"),
+            "ln_post_mlp": stack(layers, "post_mlp_layernorm.weight"),
+            "wq": stack(layers, "self_attn.q_proj.weight", transpose=True),
+            "wk": stack(layers, "self_attn.k_proj.weight", transpose=True),
+            "wv": stack(layers, "self_attn.v_proj.weight", transpose=True),
+            "wg": stack(layers, "self_attn.gate_proj.weight", transpose=True),
+            "wo": stack(layers, "self_attn.o_proj.weight", transpose=True),
+            "ln_q": stack(layers, "self_attn.q_norm.weight"),
+            "ln_k": stack(layers, "self_attn.k_norm.weight"),
+        }
+
+    dense, moe = range(c.num_dense_layers), range(c.num_dense_layers, c.num_layers)
+
+    def experts(which: str) -> np.ndarray:
+        out = []
+        for i in moe:
+            held = []
+            for j in range(c.num_experts):  # every expert's tensor is read; a share keeps its run of them
+                w = sd[f"layers.{i}.mlp.experts.{j}.{which}.weight"]
+                if first <= j < first + count:
+                    held.append(_np(w).T)
+            out.append(np.stack(held))
+        return np.stack(out)  # [L, held, in, out]
+
+    params = {
+        "embed": _np(sd["embed_tokens.weight"]),
+        "final_norm": _np(sd["norm.weight"]),
+        "lm_head": _np(sd["lm_head.weight"]).T,
+    }
+    if len(dense):
+        params["dense"] = {
+            **block(dense),
+            "w_gate": stack(dense, "mlp.gate_proj.weight", transpose=True),
+            "w_up": stack(dense, "mlp.up_proj.weight", transpose=True),
+            "w_down": stack(dense, "mlp.down_proj.weight", transpose=True),
+        }
+    if len(moe):
+        params["moe"] = {
+            **block(moe),
+            "router": stack(moe, "mlp.router.gate.weight", transpose=True),
+            "router_bias": stack(moe, "mlp.expert_bias"),
+            "w_gate": experts("gate_proj"), "w_up": experts("up_proj"), "w_down": experts("down_proj"),
+            "ws_gate": stack(moe, "mlp.shared_experts.gate_proj.weight", transpose=True),
+            "ws_up": stack(moe, "mlp.shared_experts.up_proj.weight", transpose=True),
+            "ws_down": stack(moe, "mlp.shared_experts.down_proj.weight", transpose=True),
+        }
+    return params
+
+
 def _import_lfm2_moe(sd: dict, cfg) -> dict:
     from .lfm2_moe import ATTENTION, CONV
 
@@ -965,6 +1068,7 @@ _IMPORTERS = {
     "deepseek_v3": _import_deepseek_v3,
     "lfm2_moe": _import_lfm2_moe,
     "sdar_moe": _import_sdar_moe,
+    "afmoe": _import_afmoe,
     "vit": _import_vit,
     "resnet": _import_resnet,
 }
@@ -980,6 +1084,7 @@ _PREFIXES = {
     "deepseek_v3": ("model.",),
     "lfm2_moe": ("model.",),
     "sdar_moe": ("model.",),
+    "afmoe": ("model.",),
     "vit": ("vit.",),
     "resnet": ("resnet.",),
 }

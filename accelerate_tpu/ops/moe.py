@@ -266,6 +266,7 @@ def routed_experts(
     normalize_eps: float = 1e-20,
     scale: float = 1.0,
     first_expert: Any = 0,
+    share: Optional[tuple[int, int]] = None,
     compute_dtype: Any = jnp.bfloat16,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Dropless routed SwiGLU experts: every row goes to its ``top_k`` experts,
@@ -278,6 +279,20 @@ def routed_experts(
     f]``, and ``layer * E``, so that the grouped product reads the layer's
     experts where the stack lies: cut out per layer they would be copied whole,
     1.2 GB a layer at 128 experts of 2048 x 768, before a row is multiplied).
+
+    ``share`` = ``(first, count)``, two static integers: this device **holds a
+    share** of the layer, experts ``first .. first + count`` of the router's
+    ``E``, and the weights' rows ``first_expert .. first_expert + count`` are
+    those experts (a merged stack is ``[L * count, d, f]`` and ``first_expert``
+    ``layer * count``).  The router still scores all ``E`` and chooses the
+    published ``top_k``; the pairs that fall on a held expert are computed,
+    the others add nothing: the result is the **partial sum** over the held
+    experts, what a chip of an expert-parallel group contributes before the
+    exchange (no exchange here, and nothing that stands in for the absent
+    chips).  Rows are sorted by expert, so the held experts' rows are one run
+    of the sorted rows; they are brought to the front, the grouped product gets
+    the held experts' ``group_sizes`` alone and computes no other row.  ``None``
+    holds all ``E``: today's computation, operation for operation.
     Scores are ``softmax`` or ``sigmoid`` of the fp32 router logits; the experts
     are the ``top_k`` of ``scores + select_bias`` (the bias only chooses), the
     weights are the chosen experts' scores, divided by (their sum +
@@ -293,10 +308,14 @@ def routed_experts(
 
     Returns (y [..., d] in x.dtype, routing dict: ``scores`` and ``logits``
     [..., E] fp32, ``experts`` [..., top_k], ``weights`` [..., top_k] fp32,
-    ``group_sizes`` [E] int32 = rows each expert computed).
+    ``group_sizes`` int32 = rows each expert computed, ``[E]`` or under a share
+    ``[count]``: the held experts', what was computed here).
     """
     lead, d = x.shape[:-1], x.shape[-1]
     e = w_router.shape[-1]
+    first_held, held = (0, e) if share is None else (int(share[0]), int(share[1]))
+    if not (0 <= first_held and 1 <= held and first_held + held <= e):
+        raise ValueError(f"share {share!r} is not a run of the router's {e} experts")
     tokens = x.reshape(-1, d)
     with jax.named_scope("moe.route"):
         logits = jnp.einsum("nd,de->ne", tokens.astype(jnp.float32), w_router.astype(jnp.float32))
@@ -315,18 +334,22 @@ def routed_experts(
 
         n = tokens.shape[0] * top_k
         expert_of = idx.reshape(n)
+        if held < e:  # the held experts first, in their order: the run of the sorted rows that is computed here
+            expert_of = (expert_of - first_held) % e
         token_of = jnp.repeat(jnp.arange(tokens.shape[0]), top_k)
         order = jnp.argsort(expert_of, stable=True)
-        group_sizes = jnp.bincount(expert_of, length=e).astype(jnp.int32)
+        group_sizes = jnp.bincount(expert_of, length=e).astype(jnp.int32)[:held]
 
     with jax.named_scope("moe.experts"):
         rows = tokens.astype(compute_dtype)[token_of[order]]  # [N*k, d] grouped by expert
-        tm = expert_row_tile(n, e, d, w_gate.shape[-1], compute_dtype)
+        tm = expert_row_tile(-(-n * held // e), held, d, w_gate.shape[-1], compute_dtype)
         product = functools.partial(_fused_swiglu, tm=tm) if tm else _ragged_swiglu
         y_rows = product(
             rows, w_gate.astype(compute_dtype), w_up.astype(compute_dtype), w_down.astype(compute_dtype), group_sizes,
             jnp.asarray(first_expert, jnp.int32))
         weighted = y_rows.astype(jnp.float32) * weights.reshape(n)[order][:, None]
+        if held < e:  # rows behind the held run belong to experts that lie elsewhere: neither product wrote them
+            weighted = jnp.where((jnp.arange(n) < jnp.sum(group_sizes))[:, None], weighted, 0.0)
         y = jnp.zeros(tokens.shape, jnp.float32).at[token_of[order]].add(weighted)
 
     routing = {

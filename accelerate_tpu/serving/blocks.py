@@ -573,7 +573,12 @@ class PagedKVCache:
     pool carries it by decode slot, ``num_slots`` entries a leaf, beside the
     token rows by block.  ``generation.py`` tells the kinds apart; everything
     here that counts, copies or mirrors **blocks** asks it for the token leaves
-    (:meth:`token_leaves`), and a state leaf is no block.
+    (:meth:`token_leaves`), and a state leaf is no block.  Where the family's
+    cache holds **window** leaves (``generation.WINDOW``: token rows of layers
+    that attend over a window), they are a second group of ``window_blocks``
+    blocks with an allocator of its own (:attr:`window_allocator`, block 0 its
+    null block): a block of one kind is no block of the other, and everything
+    that counts bytes or blocks answers per kind.
 
     With ``num_host_blocks > 0`` (or a later :meth:`enable_host_tier`) the
     cache carries a second, host-DRAM tier mirroring the pool's leaf layout;
@@ -589,6 +594,7 @@ class PagedKVCache:
         block_size: int,
         num_host_blocks: int = 0,
         num_slots: int = 0,
+        window_blocks: int = 0,
     ):
         from ..models.generation import make_paged_pool
 
@@ -596,7 +602,9 @@ class PagedKVCache:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.block_size = block_size
         self.allocator = BlockAllocator(num_blocks)
-        self.pool = make_paged_pool(init_cache, config, num_blocks, block_size, num_slots)
+        self.pool = make_paged_pool(init_cache, config, num_blocks, block_size, num_slots, window_blocks)
+        # The window kind's blocks, where the family has window leaves (make_paged_pool has checked their number).
+        self.window_allocator: Optional[BlockAllocator] = BlockAllocator(window_blocks) if self.window_leaves() else None
         self.host: Optional[HostBlockPool] = None
         if num_host_blocks:
             self.enable_host_tier(num_host_blocks)
@@ -684,6 +692,12 @@ class PagedKVCache:
 
         return state_leaves(self.pool)
 
+    def window_leaves(self) -> dict:
+        """The pool's token leaves of the layers that keep a window of rows (most families have none)."""
+        from ..models.generation import window_leaves
+
+        return window_leaves(self.pool)
+
     @property
     def leaf_names(self) -> list:
         return sorted(self.token_leaves())
@@ -694,12 +708,21 @@ class PagedKVCache:
     def state_bytes(self) -> int:
         return sum(leaf.size * leaf.dtype.itemsize for leaf in self.state_leaves().values())
 
+    def window_pool_bytes(self) -> int:
+        return sum(leaf.size * leaf.dtype.itemsize for leaf in self.window_leaves().values())
+
     def block_bytes(self) -> int:
         """Bytes of pool data behind ONE block across every token leaf and
         layer — the unit of the ``serving.decode_gather_bytes`` accounting."""
-        leaves = self.token_leaves().values()
-        num_blocks = next(iter(leaves)).shape[1]
-        return sum(
-            (leaf.size // num_blocks) * leaf.dtype.itemsize
-            for leaf in leaves
-        )
+        return _block_bytes(self.token_leaves())
+
+    def window_block_bytes(self) -> int:
+        """Bytes behind ONE block of the window kind, across its leaves and layers (0 without window leaves)."""
+        return _block_bytes(self.window_leaves())
+
+
+def _block_bytes(leaves: dict) -> int:
+    if not leaves:
+        return 0
+    num_blocks = next(iter(leaves.values())).shape[1]
+    return sum((leaf.size // num_blocks) * leaf.dtype.itemsize for leaf in leaves.values())

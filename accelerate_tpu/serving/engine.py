@@ -238,7 +238,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.generation import MASKED, denoise_schedule, with_token_leaves
+from ..models.generation import MASKED, denoise_schedule, window_ring_blocks, with_token_leaves, with_window_leaves
 from ..telemetry import annotate, get_telemetry, ridge_rows
 from .blocks import (
     NULL_BLOCK,
@@ -248,7 +248,7 @@ from .blocks import (
     blocks_for_tokens,
 )
 from .journal import JournalError, ServingJournal
-from .programs import FEED_CHUNK, FEED_LANE, MOE_COUNTERS, build_programs
+from .programs import DISPATCH_COUNTERS, FEED_CHUNK, FEED_LANE, MOE_COUNTERS, WINDOW_COUNTERS, build_programs
 from .scheduler import Request, RequestState, Scheduler
 from .tracing import ServingTracer, resolve_trace_dir, tracing_enabled
 
@@ -613,7 +613,18 @@ class ServingEngine:
     family), and ``host_blocks > 0`` or ``spec_tokens > 0`` is refused at
     construction.  Whether a family has a state is read from its cache,
     nowhere else; ``stats()`` then carries ``state_bytes`` and
-    ``state_resets``.  A family with an
+    ``state_resets``.  Token rows may be of two kinds too (afmoe: leaves
+    under ``generation.WINDOW`` for the layers that attend over the last
+    ``config.sliding_window`` positions): the pool then holds a second
+    group of blocks with its own allocator, a sequence keeps of it a ring of
+    ``window_ring_blocks`` blocks behind a window table
+    (``_Slot.window_blocks``: allocated at first touch, overwritten in
+    place, freed at the request's end), the scheduler admits, grows and
+    preempts by both kinds, and ``stats()`` answers per kind
+    (``pool_bytes_by_kind``, ``full_blocks_in_use``,
+    ``window_blocks_in_use``, ``window_rows_read``, ``context_rows``).  The
+    same three features are off or refused for it, for the same reason: a
+    sequence's past is not its blocks alone.  A family with an
     ``apply_paged`` serves on the paged path, experts or not (llama, gpt2,
     deepseek_v3, lfm2_moe: dropless routing is row by row, so a token gets the
     same experts whatever the chunk and the batch); one without (mixtral)
@@ -672,10 +683,17 @@ class ServingEngine:
             block_length=self.block_length,
             routed_experts=_routed_experts(config),
         ))
+        # Token rows of two kinds: a family whose cache holds window leaves (generation.WINDOW) keeps of them a ring of
+        # blocks a sequence.  The window is the model config's, the ring's width follows from it, the chunk and the
+        # block; the window kind's pool holds every slot's ring (and its null block) unless num_blocks is smaller.
+        window = int(getattr(config, "sliding_window", 0) or 0)
+        ring = window_ring_blocks(window, sc.prefill_chunk, sc.block_size) if window else 0
         self.cache = PagedKVCache(
             init_cache, config, sc.num_blocks, sc.block_size,
             num_host_blocks=sc.host_blocks, num_slots=sc.max_slots,
+            window_blocks=min(sc.num_blocks, sc.max_slots * ring + 1) if ring else 0,
         )
+        self._ring_blocks = ring if self.cache.window_allocator is not None else 0
         # Whether the family carries a state a sequence is read from its cache (generation.STATE), nowhere else.
         # Three features take a sequence's whole past to be its blocks; with a state it is not.  The prefix cache
         # is not built (a hit would resume at a block boundary, where the state of that boundary is needed:
@@ -690,6 +708,15 @@ class ServingEngine:
                 f"the accepted rows is not kept) are not served for it; got host_blocks={sc.host_blocks}, "
                 f"spec_tokens={sc.spec_tokens}"
             )
+        # With window leaves a sequence's past is not its blocks either: the rows a window layer has let go are gone.
+        # The same three features are off or refused (ROADMAP B4 says what each would take).
+        if self._ring_blocks and (sc.host_blocks or sc.spec_tokens):
+            raise ValueError(
+                f"this family's cache holds window leaves (a ring of {self._ring_blocks} blocks a sequence) beside its "
+                f"full token rows: host_blocks > 0 (the ring does not ride with demoted blocks) and spec_tokens > 0 (a "
+                f"rejected draft's row has already overwritten the ring) are not served for it; got "
+                f"host_blocks={sc.host_blocks}, spec_tokens={sc.spec_tokens}"
+            )
         self.state_resets = 0  # lanes dispatched at position 0, which read a zero state whatever their slot held
         self.sched = Scheduler(
             self.cache.allocator,
@@ -698,6 +725,8 @@ class ServingEngine:
             max_blocks_per_seq=sc.resolved_max_blocks(),
             prefill_chunk=sc.prefill_chunk,
             spec_overshoot=self.block_length if self.block_length > 1 else self.spec_tokens,
+            window_allocator=self.cache.window_allocator,
+            ring_blocks=self._ring_blocks,
         )
         max_len = sc.resolved_max_blocks() * sc.block_size
         model_max = getattr(config, "max_seq_len", None)
@@ -746,6 +775,8 @@ class ServingEngine:
         self.decode_gather_bytes = 0
         # What the expert layers of every dispatch did (MOE_COUNTERS); stays 0 for a family without experts.
         self.moe_counters = dict.fromkeys(MOE_COUNTERS, 0)
+        # What the window layers of every decode dispatch read (WINDOW_COUNTERS); reported where the family has them.
+        self.window_counters = dict.fromkeys(WINDOW_COUNTERS, 0)
         # KV-tiering accounting (engine-side migrations; the prefix cache's
         # own demote/promote churn is folded in at publish time).
         self.tier_demotions = 0
@@ -767,9 +798,10 @@ class ServingEngine:
             ServingJournal(sc.journal_path) if sc.journal_path else None
         )
         self._block_bytes = self.cache.block_bytes()
+        self._window_block_bytes = self.cache.window_block_bytes()
         self._prefix: Optional[PrefixCache] = (
             PrefixCache(self.cache.allocator, sc.block_size)
-            if sc.prefix_cache and not self._state_names else None
+            if sc.prefix_cache and not self._state_names and not self._ring_blocks else None
         )
         if self.cache.host is not None:
             # Wire the tiering policies in: eviction pressure demotes cold
@@ -840,6 +872,18 @@ class ServingEngine:
             )
             weakref.finalize(self, ledger.unregister, "serving.state_pool", state_token)
             self._memledger_tokens += (state_token,)
+        if self._ring_blocks:
+            # The window kind's blocks are a reservation of their own: numbered apart, no part of kv_pool.
+            window_token = ledger.register(
+                "serving.kv_window_pool",
+                tree=self.cache.window_leaves(),
+                detail={
+                    "num_blocks": self.cache.window_allocator.num_blocks, "block_size": sc.block_size,
+                    "block_bytes": self._window_block_bytes, "ring_blocks": self._ring_blocks,
+                },
+            )
+            weakref.finalize(self, ledger.unregister, "serving.kv_window_pool", window_token)
+            self._memledger_tokens += (window_token,)
         if self.cache.host is not None:
             # The host tier's backing arrays live for the engine's life, so
             # the reservation is static — and it charges host DRAM, not HBM
@@ -876,7 +920,8 @@ class ServingEngine:
         # matching the live bucket.  With speculation on, the lanes carry the
         # k+1-window INSTEAD of one token, fed by a host-side drafter.
         self.programs = build_programs(
-            apply_cached, config, self.cache.leaf_names, sc, self.spec_tokens, stateful=bool(self._state_names)
+            apply_cached, config, self.cache.leaf_names, sc, self.spec_tokens, stateful=bool(self._state_names),
+            ring_blocks=self._ring_blocks,
         )
         self.decode_path = self.programs.backend
         self._drafter = None
@@ -1058,7 +1103,7 @@ class ServingEngine:
         self._tick = tick = {
             "tick": self.ticks,
             "prefilling": states.count(RequestState.PREFILLING),
-            "live": 0, "width": None, "width_lanes": 0, "rows_live": 0, "rows_computed": 0,
+            "live": 0, "width": None, "width_lanes": 0, "width_window": 0, "rows_live": 0, "rows_computed": 0,
             "fresh": False, "mixed": False, "pipelined": False, "settle": None, "settles": 0,
             "phase_ms": {},
         }
@@ -1137,7 +1182,7 @@ class ServingEngine:
         self._phase_t0 = None
         account = {
             "rows_live": tick["rows_live"], "rows_computed": tick["rows_computed"],
-            "width": tick["width"] or 0, "width_lanes": tick["width_lanes"],
+            "width": tick["width"] or 0, "width_lanes": tick["width_lanes"], "width_window": tick["width_window"],
             "mixed": int(tick["mixed"]), "pipelined": int(tick["pipelined"]), "settles": tick["settles"],
         }
         self.mixed_dispatches += account["mixed"]
@@ -1534,6 +1579,8 @@ class ServingEngine:
         if self._prefix is not None:
             self._prefix.invalidate_blocks(slot.blocks)
         self.cache.allocator.mark_dirty(slot.blocks)
+        if slot.window_blocks:
+            self.cache.window_allocator.mark_dirty(slot.window_blocks)
         req = self.sched.release(slot, now)
         # Defensive: a slotted request holds no demoted blocks by invariant
         # (promotion clears them at admission), but if any exist they route
@@ -1556,7 +1603,7 @@ class ServingEngine:
             )
         self._complete(req, status="quarantined")
 
-    def _scrub_blocks(self, blocks: List[int]) -> None:
+    def _scrub_blocks(self, blocks: List[int], window: bool = False) -> None:
         # The NULL block is always scrubbed too: a poisoned request's padded
         # prefill rows route PAST its block table into block 0 (the
         # scatter's explicit overflow target), so genuine NaN K/V — unlike
@@ -1565,18 +1612,23 @@ class ServingEngine:
         # are only ever read at masked positions.
         # A state leaf is no block and needs no scrub: its slot's next request starts at position 0 and reads
         # zeros by a select (generation.read_state_rows), a NaN left there included.
+        # The window kind's blocks are numbered apart and have a null block of their own: the same scrub, on its leaves.
         idx = jnp.asarray(sorted(set(blocks) | {NULL_BLOCK}), jnp.int32)
-        self.cache.pool = with_token_leaves(self.cache.pool, lambda leaf: leaf.at[:, idx].set(0))
+        over = with_window_leaves if window else with_token_leaves
+        self.cache.pool = over(self.cache.pool, lambda leaf: leaf.at[:, idx].set(0))
 
     def _drain_scrubs(self, always_null: bool = False) -> None:
         """Scrub-on-last-release: zero the dirty blocks whose final reference
         dropped since the previous drain and hand them back to the free
         list.  They are not allocatable in between, so a dirty block can
         never be granted unscrubbed."""
-        pending = self.cache.allocator.pop_pending_scrub()
-        if pending or always_null:
-            self._scrub_blocks(pending)
-            self.cache.allocator.finish_scrub(pending)
+        for alloc, window in ((self.cache.allocator, False), (self.cache.window_allocator, True)):
+            if alloc is None:  # no window leaves
+                continue
+            pending = alloc.pop_pending_scrub()
+            if pending or always_null:
+                self._scrub_blocks(pending, window)
+                alloc.finish_scrub(pending)
 
     # -- prefix cache --------------------------------------------------------
 
@@ -1696,6 +1748,10 @@ class ServingEngine:
         state, chunk_state = self._state_args([], None), self._state_args([], 0)
         if state:
             chunk[-1] = np.int32(0)
+        if self._ring_blocks:  # every window table names the window kind's null block alone
+            wide = self.programs.window_width(width)
+            state = [np.zeros((self.serving.max_slots, wide), np.int32)]
+            chunk_state = state + [np.zeros((wide,), np.int32)]
         for program, args in ((self.programs.decode, lanes + state), (self.programs.decode_chunk, lanes + chunk + chunk_state)):
             _, _, self.cache.pool = program(self.params, self.cache.pool, *args, *poison)
         return True
@@ -1891,6 +1947,7 @@ class ServingEngine:
         owned = [len(sched.slots[idx].blocks) for idx in live]
         width_lanes = programs.table_width(max(owned)) if live else 0
         width = max(width_lanes, programs.table_width(chunk.blocks) if chunk else 0)
+        width_window = programs.window_width(width)  # what a window layer gathers: the ring at most, whatever the width
         # Rows of the dispatch that belong to a request: the lanes' window (under a verify window a lane's token and
         # its drafts), and the chunk's real rows; the program computes every lane's window and the whole padded chunk.
         if live and programs.window > 1 and self.block_length == 1:
@@ -1899,7 +1956,8 @@ class ServingEngine:
             rows_live = len(live) * programs.window
         prev = self._flight
         self._tick.update(
-            live=len(live), width=width, width_lanes=width_lanes, mixed=bool(chunk and live), pipelined=prev is not None,
+            live=len(live), width=width, width_lanes=width_lanes, width_window=width_window, mixed=bool(chunk and live),
+            pipelined=prev is not None,
             rows_live=rows_live + (chunk.n_real if chunk else 0),
             rows_computed=sc.max_slots * programs.window + (sc.prefill_chunk if chunk else 0),
         )
@@ -1921,6 +1979,16 @@ class ServingEngine:
         if programs.stateful:
             args += self._state_args(live, chunk.idx if chunk else None)
             self.state_resets += bool(chunk and chunk.start == 0)
+        if width_window:
+            wtables = np.zeros((sc.max_slots, width_window), np.int32)
+            for idx in live:
+                held = sched.slots[idx].window_blocks
+                wtables[idx, : len(held)] = held
+            args.append(wtables)
+            if chunk:
+                wtable_row = np.zeros((width_window,), np.int32)
+                wtable_row[: len(chunk.slot.window_blocks)] = chunk.slot.window_blocks
+                args.append(wtable_row)
         if self._poison_ordinal is not None:
             # Armed: the program was traced with the poison lane.  NaN rides
             # into exactly one slot's logits on that request's first decode
@@ -1976,6 +2044,8 @@ class ServingEngine:
                     self._sent(slot)
             if live:
                 gather_bytes = programs.gathered_blocks(owned) * self._block_bytes
+                if width_window:
+                    gather_bytes += programs.gathered_blocks([len(slot.window_blocks) for slot in lanes]) * self._window_block_bytes
                 self.decode_dispatches += 1
                 self.decode_gather_bytes += gather_bytes
                 self._decode_widths.add(width)
@@ -2047,8 +2117,8 @@ class ServingEngine:
         reason = {"settle": settle} if settle else {}
         with _TickPhase(self, "read", of=flight.tick, **reason):
             out = self.programs.unpack(flight.packed, with_chunk=flight.chunk is not None)
-        for name, value in zip(MOE_COUNTERS, out["counters"]):
-            self.moe_counters[name] += int(value)
+        for name, value in zip(DISPATCH_COUNTERS, out["counters"]):
+            (self.moe_counters if name in self.moe_counters else self.window_counters)[name] += int(value)
             if name == "moe_row_tiles" and value and get_telemetry().enabled:
                 get_telemetry().registry.counter("serving.moe_row_tiles").inc(int(value))
         return out
@@ -2483,6 +2553,7 @@ class ServingEngine:
                 str(idx): {
                     "request": slot.request.id,
                     "blocks": list(slot.blocks),
+                    **({"window_blocks": list(slot.window_blocks)} if self._ring_blocks else {}),
                     "cache_len": slot.cache_len,
                 }
                 for idx, slot in sorted(self.sched.slots.items())
@@ -2540,6 +2611,30 @@ class ServingEngine:
             )
         return out
 
+    def _window_stats(self) -> dict:
+        """What ``stats()`` says of a family whose cache holds window leaves
+        beside its full token rows: bytes and blocks in use by kind, what the
+        window layers read, and why no prefix cache was built.  A family
+        without window leaves carries none of these keys."""
+        if not self._ring_blocks:
+            return {}
+        full, window = self.cache.allocator, self.cache.window_allocator
+        out = {
+            "pool_bytes_by_kind": {"full": self.cache.pool_bytes(), "window": self.cache.window_pool_bytes()},
+            "free_pool_bytes_by_kind": {
+                "full": full.free_blocks * self._block_bytes, "window": window.free_blocks * self._window_block_bytes},
+            "full_blocks_in_use": full.used_blocks,
+            "window_blocks_in_use": window.used_blocks,
+            "window_ring_blocks": self._ring_blocks,
+            **self.window_counters,
+        }
+        if self.serving.prefix_cache:
+            out["prefix_cache_off"] = (
+                f"the cache holds window leaves (a ring of {self._ring_blocks} blocks a sequence): a prefix hit would "
+                f"resume behind rows the window layers have let go, so no prefix cache is built"
+            )
+        return out
+
     def _block_stats(self) -> dict:
         """What ``stats()`` says of a family generated by diffusion over
         blocks; any other family carries none of these keys."""
@@ -2580,6 +2675,7 @@ class ServingEngine:
             "pool_bytes": self.cache.pool_bytes(),
             "prefill_chunk": self.serving.prefill_chunk,  # the integer the engine runs: given, or resolved at construction
             **self._state_stats(),
+            **self._window_stats(),
             **self._block_stats(),
             "free_pool_bytes": alloc.free_blocks * self._block_bytes,
             "decode_path": self.decode_path,

@@ -33,6 +33,19 @@ next request never sees its predecessor and admission costs no host work.  A
 family without a state compiles to the programs it always had: no argument,
 no operation more.
 
+Token rows are themselves of two kinds for a family whose cache says so
+(``afmoe``: its sliding layers' leaves under ``generation.WINDOW``): rows a
+**full** layer keeps for the whole sequence, behind the block tables above, and
+rows a **window** layer keeps in a ring of blocks, behind a **window table** of
+fixed width (logical block ``j`` at entry ``j mod width``).  For such a family
+a group is ``(tokens, tables, starts, window tables [B, Mw])``, ``Mw`` =
+:meth:`ServingPrograms.window_width` of the dispatch's table width (the
+ring's, or the block tables' own while they are narrower: then nothing has
+wrapped), the programs take the lanes' window tables ``[S, Mw]`` (and
+``decode_chunk`` the chunk's ``[Mw]``) behind their other arguments, and
+``_write_rows`` puts the rows returned under ``WINDOW`` each at its place in
+the ring.  Never beside a state, never under block generation (refused by name).
+
 - **paged** (a family with an ``apply_paged``: gpt2, llama, deepseek_v3, lfm2_moe):
   the family runs everything that does not look at the cache (embedding,
   norms, projections, the MLP or the experts, the head) once over the rows of
@@ -106,15 +119,20 @@ import jax.numpy as jnp
 from ..models.generation import (
     MASKED,
     STATE,
+    WINDOW,
     block_unmask,
     extract_token_rows,
     gather_block_view,
     scatter_token_rows,
+    scatter_window_rows,
     speculative_verify_greedy,
     write_state_rows,
 )
 
-__all__ = ["FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "ServingPrograms", "build_programs"]
+__all__ = [
+    "DISPATCH_COUNTERS", "FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "WINDOW_COUNTERS", "ServingPrograms",
+    "build_programs",
+]
 
 # Where a decoding lane's input token comes from (a program's per-lane ``source``): the host's ``tokens``, the lane's own
 # entry of the previous dispatch's ``feed``, or that feed's last entry, the chunk's token.
@@ -124,8 +142,16 @@ FEED_HOST, FEED_LANE, FEED_CHUNK = 0, 1, 2
 # summed over its expert layers: token-expert pairs computed, experts with at least one row, the hottest expert's rows,
 # and the row tiles the fused grouped product computed (0 for a dispatch whose expert layers ran ``lax.ragged_dot``: the
 # counter says which product a program runs; ``moe_rows`` / (``moe_row_tiles`` x ``ops/pallas_moe.py:ROW_TILE``) is the
-# tiles' fill).
-MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows", "moe_row_tiles")
+# tiles' fill), and every pair the routers made (``moe_rows`` again where a layer's experts are all held; under a share,
+# ``moe_rows / moe_pairs_routed`` is the share's load).
+MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows", "moe_row_tiles", "moe_pairs_routed")
+# What a family with window leaves counts beside them (``models/afmoe.py``), summed over a dispatch's decoding lanes and
+# window layers: the rows the window layers attended over, and the rows they would have had every layer read the whole
+# context.
+WINDOW_COUNTERS = ("window_rows_read", "context_rows")
+# Every counter a dispatch can carry behind its ``ok`` flags, in the order they are packed; a family that returns
+# counters packs all of them, 0 for what it does not count, so that the host reads one layout.
+DISPATCH_COUNTERS = MOE_COUNTERS + WINDOW_COUNTERS
 
 # No block table is narrower than this many rows.  Every width an engine meets compiles both of its programs, and on the
 # host of a v5e that is one to two seconds of a process's set-up a width even with a warm compile cache (tracing and
@@ -143,6 +169,7 @@ class ServingPrograms:
     decode: Callable  # (params, pool, tables [S, M], lengths [S], tokens [S, W], draft_len [S], feed [S + 1], source [S], *state, *poison)
     decode_chunk: Callable  # (..., source [S], table_row [M], start, chunk [1, C], n_real, *state, *poison) -> (packed, feed, pool), both
     stateful: bool  # the pool holds a state by slot: *state is (live [S],), with a chunk (live [S], slot)
+    ring_blocks: int  # > 0: the pool holds window leaves, a sequence's window table is a ring this wide; *state is led by (window tables [S, Mw],), with a chunk (window tables, the chunk's window table [Mw])
     window: int  # W: 1, or k + 1 under speculation, or the family's block length
     max_slots: int
     min_blocks: int  # the narrowest table: MIN_TABLE_ROWS in blocks, a power of two
@@ -163,10 +190,20 @@ class ServingPrograms:
             width *= 2
         return min(width, self.max_blocks)
 
+    def window_width(self, width: int) -> int:
+        """The width of the window tables in a dispatch whose block tables are
+        ``width`` wide: the ring's, or the block tables' own where they are
+        narrower (then no sequence of the dispatch has wrapped, and its logical
+        block ``j`` lies at entry ``j``).  What a window layer gathers and
+        attends over is bounded by the ring, not by ``width``.  0 without
+        window leaves."""
+        return min(width, self.ring_blocks)
+
     def gathered_blocks(self, owned: Sequence[int]) -> int:
-        """Blocks a decode dispatch gathers, ``owned`` being the blocks of
-        each live lane.  Paged reads the blocks the tables name; dense
-        gathers every lane's worst-case view, live or not."""
+        """Blocks a decode dispatch gathers of one kind (full or window),
+        ``owned`` being the blocks of that kind of each live lane.  Paged reads
+        the blocks the tables name; dense gathers every lane's worst-case
+        view, live or not."""
         if self.backend == "dense":
             return self.max_slots * self.max_blocks
         return sum(owned)
@@ -194,31 +231,36 @@ class ServingPrograms:
 
 
 def build_programs(
-    apply_cached: Callable, config, leaf_names, serving, spec_tokens: int, stateful: bool = False
+    apply_cached: Callable, config, leaf_names, serving, spec_tokens: int, stateful: bool = False, ring_blocks: int = 0
 ) -> ServingPrograms:
     """The programs of an engine that serves ``apply_cached``'s family over a
-    pool with token leaves ``leaf_names`` (and, ``stateful``, a state by slot),
-    at ``serving``'s geometry."""
+    pool with token leaves ``leaf_names`` (and, ``stateful``, a state by slot;
+    ``ring_blocks > 0``, window leaves behind window tables that wide), at
+    ``serving``'s geometry."""
     apply_paged = getattr(inspect.getmodule(apply_cached), "apply_paged", None)
     if apply_paged is not None:
         backend, forward = "paged", _paged_forward(apply_paged, config)
-    elif stateful:
-        raise ValueError("a family whose cache holds a state a sequence is served by its apply_paged: it has none")
+    elif stateful or ring_blocks:
+        raise ValueError(
+            "a family whose cache holds a state a sequence, or window leaves, is served by its apply_paged: it has none")
     else:
         backend, forward = "dense", _dense_forward(apply_cached, config, leaf_names)
     # The window of a decoding lane is the family's to say: a family generated by diffusion over blocks carries its
     # block length (and the id its masked positions read) in its model config; any other compiles to what it had.
     block = int(getattr(config, "block_length", 1))
+    if ring_blocks and (stateful or block > 1):
+        raise ValueError("window leaves beside a state a sequence, or under block generation, are not served")
     if block > 1:
         _check_block_geometry(block, backend, serving, spec_tokens, stateful)
         decode, decode_chunk = _block_heads(forward, int(config.mask_token_id))
     else:
-        decode, decode_chunk = _heads(forward, stateful)
+        decode, decode_chunk = _heads(forward, stateful, bool(ring_blocks))
     return ServingPrograms(
         backend=backend,
         decode=jax.jit(decode, donate_argnums=(1,)),
         decode_chunk=jax.jit(decode_chunk, donate_argnums=(1,)),
         stateful=stateful,
+        ring_blocks=ring_blocks,
         window=block if block > 1 else spec_tokens + 1,
         max_slots=serving.max_slots,
         min_blocks=1 << max(0, -(-MIN_TABLE_ROWS // serving.block_size) - 1).bit_length(),
@@ -274,7 +316,7 @@ def _dense_forward(apply_cached: Callable, config, names) -> Callable:
     return forward
 
 
-def _write_rows(pool: dict, rows: dict, tables, starts, count: int, *lanes, keep=None) -> dict:
+def _write_rows(pool: dict, rows: dict, tables, starts, count: int, *lanes, keep=None, wtables=None) -> dict:
     """The pool with what a forward wrote for one group written in.  Token
     rows are scattered through the group's tables: rows past a lane's accepted
     length (a verify window) or past a chunk's real tokens are stale by
@@ -284,11 +326,17 @@ def _write_rows(pool: dict, rows: dict, tables, starts, count: int, *lanes, keep
     written by slot (``lanes`` = the group's slots and counts) for the lanes
     that advanced alone (``write_state_rows``).  With ``keep [B]`` only those
     lanes' token rows are written (a block family's committing lanes): a
-    denoising pass leaves the pool bit for bit."""
+    denoising pass leaves the pool bit for bit.  Rows under ``WINDOW`` go
+    through the group's window tables ``wtables``, each position to its place
+    in the ring."""
     new_pool = dict(pool)
     for n, r in rows.items():
         if n == STATE:
             new_pool[n] = write_state_rows(pool[n], r, *lanes)
+        elif n == WINDOW:
+            with jax.named_scope("kv_pool.window"):
+                new_pool[n] = {
+                    leaf: scatter_window_rows(pool[n][leaf], leaf_rows, wtables, starts, count) for leaf, leaf_rows in r.items()}
         else:
             new_pool[n] = scatter_token_rows(pool[n], r, tables, starts, count, keep)
     return new_pool
@@ -304,7 +352,7 @@ def _packed(parts, counters):
     behind them.  A family without experts returns two values and compiles to
     a program without them."""
     if counters:
-        parts = [*parts, jnp.stack([counters[0][name] for name in MOE_COUNTERS])]
+        parts = [*parts, jnp.stack([jnp.asarray(counters[0].get(name, 0), jnp.int32) for name in DISPATCH_COUNTERS])]
     return jnp.concatenate([jnp.ravel(p).astype(jnp.int32) for p in parts])
 
 
@@ -347,39 +395,51 @@ def _feed(lanes_next, chunk_token):
     return jnp.concatenate([lanes_next, jnp.reshape(chunk_token, (1,))]).astype(jnp.int32)
 
 
-def _heads(forward: Callable, stateful: bool = False):
+def _heads(forward: Callable, stateful: bool = False, windowed: bool = False):
     """``decode`` and ``decode_chunk`` over ``forward``: the names are the
     profile's (``jit_decode``, ``jit_decode_chunk``).  ``draft_len`` is read
     by a ``k + 1`` window only (jit drops an argument nothing reads).  With a
     state, ``rest`` leads with ``live [S]`` (1 where a lane decodes) and, in
     ``decode_chunk``, the chunk's slot, and the groups carry (slots, counts).
-    Both return ``(packed, feed, pool)``."""
+    With window leaves (never beside a state) ``rest`` leads with the lanes'
+    window tables ``[S, Mw]`` and, in ``decode_chunk``, the chunk's ``[Mw]``,
+    and a group carries its window tables behind its starts.  Both return
+    ``(packed, feed, pool)``."""
 
     def decode(params, pool, tables, lengths, tokens, draft_len, feed, source, *rest):
         tokens = _fed(tokens, feed, source)
-        state = ()
+        state, wtables = (), None
+        if windowed:
+            wtables, *rest = rest
         if stateful:
             live, *rest = rest
             state = (jnp.arange(tokens.shape[0], dtype=jnp.int32), live)
-        (logits,), counters, (rows,) = forward(params, pool, ((tokens, tables, lengths, *state),))
+        group = (tokens, tables, lengths, *((wtables,) if windowed else state))
+        (logits,), counters, (rows,) = forward(params, pool, (group,))
         parts, ok, lanes_next = _lanes_head(logits, tokens, draft_len, rest)
-        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state)
+        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state, wtables=wtables)
         return _packed([*parts, ok], counters), _feed(lanes_next, 0), new_pool
 
     def decode_chunk(params, pool, tables, lengths, tokens, draft_len, feed, source, table_row, start, chunk, n_real, *rest):
         tokens = _fed(tokens, feed, source)
         chunk_tables, chunk_starts = table_row[None], start[None]
         state = chunk_state = ()
+        wtables = chunk_wtables = None
+        if windowed:
+            wtables, wtable_row, *rest = rest
+            chunk_wtables = wtable_row[None]
         if stateful:
             live, slot, *rest = rest
             state, chunk_state = (jnp.arange(tokens.shape[0], dtype=jnp.int32), live), (slot[None], n_real[None])
-        groups = ((tokens, tables, lengths, *state), (chunk, chunk_tables, chunk_starts, *chunk_state))
+        groups = (
+            (tokens, tables, lengths, *((wtables,) if windowed else state)),
+            (chunk, chunk_tables, chunk_starts, *((chunk_wtables,) if windowed else chunk_state)))
         (logits, chunk_logits), counters, (rows, chunk_rows) = forward(params, pool, groups)
         parts, ok, lanes_next = _lanes_head(logits, tokens, draft_len, rest)
         chunk_token = jnp.argmax(chunk_logits[0, n_real - 1], axis=-1)
         chunk_ok = jnp.all(jnp.isfinite(chunk_logits))
-        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state)
-        new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1], *chunk_state)
+        new_pool = _write_rows(pool, rows, tables, lengths, tokens.shape[1], *state, wtables=wtables)
+        new_pool = _write_rows(new_pool, chunk_rows, chunk_tables, chunk_starts, chunk.shape[1], *chunk_state, wtables=chunk_wtables)
         return _packed([*parts, ok, chunk_token, chunk_ok], counters), _feed(lanes_next, chunk_token), new_pool
 
     return decode, decode_chunk

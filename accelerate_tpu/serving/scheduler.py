@@ -34,6 +34,13 @@ The scheduler is pure host-side bookkeeping: admission/preemption decisions
 happen between dispatches and the jitted decode step never sees them (slots
 simply flip their active mask).
 
+For a family whose cache holds window leaves (``generation.WINDOW``) blocks are
+of two kinds, each with its allocator: a slot's ``blocks`` (a block a
+``block_size`` rows, for the whole sequence) and its ``window_blocks`` (a ring
+of ``ring_blocks`` at most).  Every count of blocks a request needs is a pair
+(:meth:`Scheduler.blocks_by_kind`), admission and growth ask both allocators,
+either running dry preempts, and release frees both.
+
 The engine reads a tick back one dispatch late (``serving/engine.py``), so a
 slot's bookkeeping runs ahead of the tokens the host has read: ``cache_len``
 counts the rows *dispatched*, ``unread`` the tokens dispatched and not yet
@@ -197,14 +204,18 @@ class _Slot:
     values the host has not read: ``request.remaining - unread`` is what is
     left to dispatch.  ``block`` is the block a lane of a block-diffusion
     family carries (the engine's; None for every other family): ``cache_len``
-    then counts the rows *committed*, whole blocks."""
+    then counts the rows *committed*, whole blocks.  ``window_blocks`` is the
+    slot's window table for a family with window leaves (else empty): the
+    blocks of the window kind it holds, entry ``j mod ring`` for logical block
+    ``j``, never more than the ring's width."""
 
-    __slots__ = ("request", "idx", "blocks", "cache_len", "admit_seq", "registered_blocks", "unread", "block")
+    __slots__ = ("request", "idx", "blocks", "window_blocks", "cache_len", "admit_seq", "registered_blocks", "unread", "block")
 
     def __init__(self, request: Request, admit_seq: int, idx: int):
         self.request = request
         self.idx = idx
         self.blocks: List[int] = []
+        self.window_blocks: List[int] = []
         self.cache_len = 0
         self.admit_seq = admit_seq
         self.registered_blocks = 0
@@ -223,10 +234,16 @@ class Scheduler:
         max_blocks_per_seq: int,
         prefill_chunk: int,
         spec_overshoot: int = 0,
+        window_allocator: Optional[BlockAllocator] = None,
+        ring_blocks: int = 0,
     ):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.allocator = allocator
+        # Token rows of two kinds (a family with window leaves): the window kind's allocator and the width of a
+        # sequence's ring.  Every count of blocks below is a pair (full, window); window is 0 without the kind.
+        self.window_allocator = window_allocator
+        self.ring_blocks = ring_blocks if window_allocator is not None else 0
         self.num_slots = num_slots
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
@@ -272,10 +289,26 @@ class Scheduler:
         chunks = blocks_for_tokens(rows, self.prefill_chunk)
         return chunks * self.prefill_chunk
 
+    def blocks_by_kind(self, rows: int) -> tuple:
+        """Blocks a sequence of ``rows`` cache rows holds, (full, window): a
+        block a ``block_size`` rows on the full leaves, and on the window
+        leaves the same up to the ring's width, which a sequence never exceeds
+        (0 where the family has no window leaves)."""
+        full = blocks_for_tokens(rows, self.block_size)
+        return full, min(full, self.ring_blocks)
+
+    def max_blocks(self, request: Request) -> tuple:
+        """:meth:`max_rows` in blocks of each kind, (full, window)."""
+        return self.blocks_by_kind(self.max_rows(request))
+
     def validate(self, request: Request) -> None:
         """Reject requests the engine geometry can never serve (otherwise a
         sole OOM-ing request would preempt itself forever)."""
-        need = blocks_for_tokens(self.max_rows(request), self.block_size)
+        need, need_window = self.max_blocks(request)
+        if self.window_allocator is not None and need_window > self.window_allocator.capacity:
+            raise ValueError(
+                f"request needs {need_window} window blocks > window pool capacity {self.window_allocator.capacity}"
+            )
         if need > self.max_blocks_per_seq:
             raise ValueError(
                 f"request needs {need} blocks > max_blocks_per_seq "
@@ -308,7 +341,8 @@ class Scheduler:
                 break
             head = self.queue[0]
             first_chunk = min(len(head.to_feed), self.prefill_chunk)
-            if blocks_for_tokens(first_chunk, self.block_size) > self.allocator.free_blocks:
+            full, window = self.blocks_by_kind(first_chunk)
+            if full > self.allocator.free_blocks or (window and window > self.window_allocator.free_blocks):
                 break
             self.queue.popleft()
             head.state = RequestState.PREFILLING
@@ -355,6 +389,8 @@ class Scheduler:
             migrated = self.on_migrate_out(slot)
         if slot.blocks and not migrated:
             self.allocator.free(slot.blocks)
+        if slot.window_blocks:  # a family with window leaves has no host tier: never migrated
+            self.window_allocator.free(slot.window_blocks)
         req = slot.request
         req.state = RequestState.QUEUED
         req.preemptions += 1
@@ -366,17 +402,22 @@ class Scheduler:
         return idx
 
     def grow_to(self, idx: int, rows: int) -> bool:
-        """Ensure slot ``idx``'s block table covers ``rows`` cache rows,
-        allocating (and preempting LIFO victims) as needed.  Returns False
-        when the slot itself was preempted to satisfy the growth — the caller
-        must drop it from this tick."""
+        """Ensure slot ``idx``'s block table covers ``rows`` cache rows, and
+        its window table the same rows up to the ring's width, allocating (and
+        preempting LIFO victims) as needed, whichever kind runs dry.  Returns
+        False when the slot itself was preempted to satisfy the growth — the
+        caller must drop it from this tick."""
         slot = self.slots.get(idx)
         while slot is not None:
-            need = blocks_for_tokens(rows, self.block_size) - len(slot.blocks)
-            if need <= 0:
+            full, window = self.blocks_by_kind(rows)
+            need, need_window = full - len(slot.blocks), window - len(slot.window_blocks)
+            if need <= 0 and need_window <= 0:
                 return True
             try:
-                slot.blocks.extend(self.allocator.alloc(need))
+                if need > 0:
+                    slot.blocks.extend(self.allocator.alloc(need))
+                if need_window > 0:  # a ring entry is allocated at first touch and overwritten in place after that
+                    slot.window_blocks.extend(self.window_allocator.alloc(need_window))
                 return True
             except BlockOutOfMemory as exc:
                 if self.settle is not None and self.settle("preempt"):
@@ -422,6 +463,8 @@ class Scheduler:
             self.retiring.remove(slot)
         if slot.blocks:
             self.allocator.free(slot.blocks)
+        if slot.window_blocks:
+            self.window_allocator.free(slot.window_blocks)
         req = slot.request
         req.state = RequestState.DONE
         req.finish_t = now

@@ -344,7 +344,7 @@ def engine_against_reference(model, fam, chunk, mix):
 
 def test_expert_counters_against_hand_counted_values():
     sizes = jnp.asarray([[4, 0, 0, 2, 0, 0, 0, 0], [1, 1, 1, 1, 1, 1, 0, 0]], jnp.int32)  # two layers, six pairs each
-    assert {k: int(v) for k, v in ds.expert_counters(sizes).items()} == {"moe_rows": 12, "moe_experts_hit": 8, "moe_max_rows": 5, "moe_row_tiles": 0}
+    assert {k: int(v) for k, v in ds.expert_counters(sizes).items()} == {"moe_rows": 12, "moe_experts_hit": 8, "moe_max_rows": 5, "moe_row_tiles": 0, "moe_pairs_routed": 12}
 
 
 def test_a_family_without_experts_keeps_its_program():

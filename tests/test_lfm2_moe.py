@@ -395,7 +395,7 @@ def test_engine_serves_on_the_paged_path_with_a_state_beside_the_rows(cut, fam, 
 def test_expert_counters_against_hand_counted_values(cut):
     _, c, params = cut
     sizes = jnp.asarray([[4, 0, 0, 2, 0, 0, 0, 0], [1, 1, 1, 1, 1, 1, 0, 0]], jnp.int32)  # two layers, six pairs each
-    assert {k: int(v) for k, v in lf.expert_counters(sizes).items()} == {"moe_rows": 12, "moe_experts_hit": 8, "moe_max_rows": 5, "moe_row_tiles": 0}
+    assert {k: int(v) for k, v in lf.expert_counters(sizes).items()} == {"moe_rows": 12, "moe_experts_hit": 8, "moe_max_rows": 5, "moe_row_tiles": 0, "moe_pairs_routed": 12}
     # one dispatch of 5 rows through the 12 expert layers of the cut: 5 x top-2 pairs a layer
     i32 = lambda x: jnp.asarray(x, jnp.int32)
     _, _, counters = paged_step(c, params)(

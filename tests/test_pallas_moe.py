@@ -191,7 +191,7 @@ def test_gradients_through_the_kernel_are_the_ragged_paths(monkeypatch):
 def test_row_tiles_counted_by_hand():
     sizes = jnp.asarray([[4, 0, 0, 17, 0, 0, 0, 0], [1, 1, 16, 1, 1, 33, 0, 0]], jnp.int32)
     counters = {k: int(v) for k, v in ds.expert_counters(sizes, 16).items()}
-    assert counters == {"moe_rows": 74, "moe_experts_hit": 8, "moe_max_rows": 50, "moe_row_tiles": 1 + 2 + 4 + 1 + 3}
+    assert counters == {"moe_rows": 74, "moe_experts_hit": 8, "moe_max_rows": 50, "moe_row_tiles": 1 + 2 + 4 + 1 + 3, "moe_pairs_routed": 74}
     assert int(ds.expert_counters(sizes)["moe_row_tiles"]) == 0 and int(ds.expert_counters(sizes, 8)["moe_row_tiles"]) == 15
 
 

@@ -343,10 +343,13 @@ def test_the_block_programs_take_the_lanes_phase_as_inputs_of_one_program(model)
     lanes = engine._idle_lanes(2)
     assert [a.shape for a in lanes] == [(s, 2), (s,), (s, W), (s,), (s, W), (s,), (s,), (s,)]
     packed, feed, _ = jax.eval_shape(engine.programs.decode, engine.params, engine.cache.pool, *lanes)
-    assert packed.shape == (s * W + s + 4,) and feed.shape == (s, W)  # states, ok flags, the four expert counters
+    from accelerate_tpu.serving.programs import DISPATCH_COUNTERS
+
+    counters = len(DISPATCH_COUNTERS)  # behind the states and the ok flags: one layout for every family that counts
+    assert packed.shape == (s * W + s + counters,) and feed.shape == (s, W)
     chunk = (np.zeros((2,), np.int32), np.int32(0), np.zeros((1, 8), np.int32), np.int32(8))
     packed, feed, _ = jax.eval_shape(engine.programs.decode_chunk, engine.params, engine.cache.pool, *lanes, *chunk)
-    assert packed.shape == (s * W + s + 2 + 4,) and feed.shape == (s, W)
+    assert packed.shape == (s * W + s + 2 + counters,) and feed.shape == (s, W)
     assert engine.programs.decode.__wrapped__.__name__ == "decode" and engine.programs.decode_chunk.__wrapped__.__name__ == "decode_chunk"
     assert len(jax.make_jaxpr(engine.programs.decode)(engine.params, engine.cache.pool, *lanes).jaxpr.invars) == leaves + 8
     text = jax.jit(engine.programs.decode).lower(engine.params, engine.cache.pool, *lanes).as_text(debug_info=True)

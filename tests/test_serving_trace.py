@@ -321,7 +321,7 @@ TICK_PHASES = ["admit", "prefill.build", "prefill.wait", "prefill.emit",
 # of the tick before, are the wait's two children), then both emits
 MIXED_TICK_PHASES = ["admit", "prefill.build", "decode.build", "decode.wait", "launch", "read", "prefill.emit",
                      "decode.emit", "publish"]
-TICK_ACCOUNT = {"rows_live", "rows_computed", "width", "width_lanes", "mixed", "pipelined", "settles"}
+TICK_ACCOUNT = {"rows_live", "rows_computed", "width", "width_lanes", "width_window", "mixed", "pipelined", "settles"}
 
 
 def _busy_engine(cfg, params, **overrides):

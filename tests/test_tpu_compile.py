@@ -315,6 +315,63 @@ def check_state_step(width, fused=False):
     return "temp_bytes=" + "/".join(map(str, temps))
 
 
+def check_window_step(width, fused=False):
+    # serving/programs.py's decode and decode_chunk for models/afmoe.py at the cut the benchmark serves (trinity-large-preview:
+    # one dense layer and one period s s s f at the published widths, 32 of 256 experts held, the cell's geometry): the full
+    # layer's rows [1, 16384, 16, 8, 128] beside the four sliding layers' [4, 16 * 259 + 1, 16, 8, 128], a ring of 259 blocks a
+    # sequence behind window tables min(width, 259) wide.  At K = 8 x hd = 128 a block is whole (8, 128) tiles
+    # (generation._blocks_lie_row_by_row), so both kinds are read where they lie, under the lax.cond that picks the layer's
+    # kind: no result of either program is as large as the window leaves or a layer's slice of them but the scatters of the
+    # new rows, and what the programs hold besides their arguments stays under the two gathered contexts (the full layer's
+    # 16 x width x 16 rows, K and V, the widest thing a dispatch makes) and a half again.  The experts' grouped product reads
+    # the held experts' stack where it lies.
+    import re
+    from accelerate_tpu.models import afmoe as af
+    from accelerate_tpu.models.generation import WINDOW, make_paged_pool, window_ring_blocks
+    from accelerate_tpu.serving import ServingConfig, programs as P
+
+    blocks, slots, chunk_rows = 16384, 16, 32
+    ring = window_ring_blocks(4096, chunk_rows, 16)
+    window_blocks = slots * ring + 1
+    c = af.AfmoeConfig(
+        vocab_size=25024, num_layers=5, layer_types=(af.SLIDING,) * 4 + (af.FULL,), num_dense_layers=1, experts_held=(0, 32),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False)
+    place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: af.init_params(c, jax.random.key(0))))
+    pool = place(jax.eval_shape(lambda: make_paged_pool(af.init_cache, c, blocks, 16, window_blocks=window_blocks)))
+    assert ring == 259 and pool["k"].shape == (1, blocks, 16, 8, 128) and pool[WINDOW]["v"].shape == (4, window_blocks, 16, 8, 128)
+    serving = ServingConfig(block_size=16, num_blocks=blocks, max_slots=slots, max_blocks_per_seq=1024, prefill_chunk=chunk_rows)
+    built = P.build_programs(af.apply_cached, c, ["k", "v"], serving, 0, ring_blocks=ring)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    wide = built.window_width(width)
+    assert wide == min(width, ring)
+    lanes = (i32(slots, width), i32(slots), i32(slots, 1), i32(slots), i32(slots + 1), i32(slots))
+    chunk = (i32(width), i32(), i32(1, chunk_rows), i32())
+    programs = {"decode": (built.decode, (*lanes, i32(slots, wide))), "decode_chunk": (built.decode_chunk, (*lanes, *chunk, i32(slots, wide), i32(wide)))}
+    sized = re.compile(r"= \w+\[(%d|4,%d|%d)," % (4 * window_blocks, window_blocks, window_blocks))
+    cut = re.compile(r"= \w+\[32,3072,3072\]")
+    context_bytes = 2 * slots * width * 16 * 8 * 128 * 2
+    temps = []
+    for name, (program, args) in programs.items():
+        compiled = program.lower(params, pool, *args).compile()
+        text = compiled.as_text()
+        lines = [line for line in text.splitlines() if not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
+        moved = [line.strip()[:160] for line in lines if sized.search(line) and "scatter(" not in line and "kv_pool.write/scatter" not in line]
+        if moved:
+            raise AssertionError(f"{name} moves arrays as large as the window leaves besides the scatter of the new rows: " + " ;; ".join(moved[:4]))
+        experts = [line.strip()[:160] for line in lines if cut.search(line)]
+        if experts:
+            raise AssertionError(f"{name}: a layer's held experts are cut out of the stack: " + " ;; ".join(experts[:3]))
+        if "kv_pool.window" not in text or "attn.window" not in text or "attn.gate" not in text:
+            raise AssertionError(f"{name}: the sliding layers' scopes are not in the executable's op names")
+        check_grouped_product(text, fused, name + ": ")
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        if temp > 1.5 * context_bytes + 2**27:
+            raise AssertionError(f"{name}: {temp} bytes of temporaries, the full layer's gathered context is {context_bytes}")
+        temps.append(temp)
+    return "temp_bytes=" + "/".join(map(str, temps))
+
+
 def check_context_assembly(text):
     # A decode step assembles its context in one pass (PR 29): under kv_pool.gather the blocks are gathered and the new
     # rows scattered into them, a row-sized write.  Nothing else there is as large as the context: no select over it (the
@@ -375,6 +432,9 @@ for spec in sys.argv[2:]:
             continue
         if case.startswith("state_step"):
             print("COMPILED", spec, check_state_step(int(hd), fused), flush=True)
+            continue
+        if case.startswith("window_step"):
+            print("COMPILED", spec, check_window_step(int(hd), fused), flush=True)
             continue
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
@@ -446,8 +506,14 @@ CASES = [
     ("latent_step_fused", 512, 64),
     ("latent_step_prefill_fused", 512, 64),
     ("state_step_fused", 64, 0),
+    # serving/programs.py's two programs for models/afmoe.py at the trinity-large-preview cell's cut and geometry (PR 38), as a
+    # TPU builds them: token rows of two kinds, the window tables narrower than the ring (256 < 259: no sequence has wrapped)
+    # and at the ring's width under the widest block tables (1024); and once with lax.ragged_dot, as off the TPU
+    ("window_step_fused", 256, 0),
+    ("window_step_fused", 1024, 0),
+    ("window_step", 256, 0),
 ]
-IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
+IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step", "window_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
        else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}" for c, h, b in CASES]
 
 
