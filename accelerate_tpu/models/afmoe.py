@@ -260,6 +260,15 @@ def _attend(q, k_ctx, v_ctx, mask, c: AfmoeConfig, window: bool = False) -> jax.
     return out.reshape(q.shape[:2] + (c.num_heads * c.head_dim,))
 
 
+def _attend_in_place(q, k_new, v_new, pk, pv, tables, starts, c: AfmoeConfig, window: bool, interpret: bool) -> jax.Array:
+    """:func:`_attend` for the decoding lanes read in place (``generation.attend_in_place``), under the same scopes."""
+    from .generation import attend_in_place
+
+    with jax.named_scope("attn.core"), jax.named_scope("attn.window") if window else contextlib.nullcontext():
+        out = attend_in_place(q, k_new, v_new, pk, pv, tables, starts, c.sliding_window if window else 0, interpret)
+    return out.reshape(q.shape[:2] + (c.num_heads * c.head_dim,))
+
+
 @jax.named_scope("attn.out")
 def _out_proj(attn, gate, p, c: AfmoeConfig) -> jax.Array:
     return _mm(attn * gate, p["wo"], c)
@@ -493,7 +502,7 @@ def apply_cached(params: dict, input_ids: jax.Array, config: AfmoeConfig, cache:
     return logits, new_cache
 
 
-def apply_paged(params: dict, groups, config: AfmoeConfig, pool: dict):
+def apply_paged(params: dict, groups, config: AfmoeConfig, pool: dict, interpret: bool = False):
     """Forward over new tokens straight against the paged pool, for a family
     with window leaves: ``groups`` is a short tuple of ``(tokens [B, T], tables
     [B, M], starts [B], window tables [B, Mw])``, the decoding lanes first.
@@ -510,7 +519,11 @@ def apply_paged(params: dict, groups, config: AfmoeConfig, pool: dict):
     counters beside them: over the first group's lanes that hold a sequence
     (``starts > 0``) and the sliding layers, ``window_rows_read`` the keys their
     masks admit and ``context_rows`` the keys a full layer's mask admits at the
-    same rows)."""
+    same rows), and ``attn_rows_read`` where the decoding lanes read a kind in
+    place (``generation.reads_in_place``: the rows the kernel copied over their
+    lanes and that kind's layers).  ``interpret`` runs the decoding lanes'
+    attention through the kernel in the Pallas interpreter whatever the rule
+    says (the CPU tests)."""
     from .generation import (
         WINDOW,
         address_paged_pool_by_layer,
@@ -518,6 +531,8 @@ def apply_paged(params: dict, groups, config: AfmoeConfig, pool: dict):
         join_groups,
         paged_cache_write,
         paged_window_write,
+        reads_in_place,
+        rows_read_in_place,
         split_groups,
         token_leaves,
         window_group_masks,
@@ -533,22 +548,30 @@ def apply_paged(params: dict, groups, config: AfmoeConfig, pool: dict):
     positions = join_groups(positions)
     x = _embed(params, join_groups([tokens for tokens, _, _ in cached]), c)
 
+    # the decoding lanes (the first group, one row a lane) read each kind's pool in place where the rule says so
+    rows = cached[0][0].shape[1]
+    in_place = lambda leaves, width: bool(leaves) and (interpret and rows == 1 or reads_in_place(leaves["k"], rows, width))
+    kernel = {False: in_place(full_pool, groups[0][1].shape[1]), True: in_place(window_pool, groups[0][-1].shape[1])}
+
     def attention(sliding: bool):
         """One kind's attention over every group: (q, k, v, number) -> (attn joined, what each group stores)."""
         def run(q, k, v, number):
             if sliding:
                 q, k = _rotated(q, k, positions, c)
             attn, stored = [], []
-            for q_g, k_g, v_g, group, mask in zip(
-                    *(split_groups(a, shapes) for a in (q, k, v)), groups, masks_w if sliding else masks_f):
-                if sliding:
-                    pk, pv, ltab = address_paged_pool_by_layer(window_pool, group[3], number)
-                    with jax.named_scope("kv_pool"), jax.named_scope("kv_pool.window"):
+            for i, (q_g, k_g, v_g, group, mask) in enumerate(zip(
+                    *(split_groups(a, shapes) for a in (q, k, v)), groups, masks_w if sliding else masks_f)):
+                pk, pv, ltab = address_paged_pool_by_layer(window_pool if sliding else full_pool, group[3 if sliding else 1], number)
+                if i == 0 and kernel[sliding]:
+                    k_store, v_store = k_g.astype(pk.dtype), v_g.astype(pv.dtype)
+                    attn.append(_attend_in_place(q_g, k_store, v_store, pk, pv, ltab, group[2], c, sliding, interpret))
+                    stored.append((k_store, v_store))
+                    continue
+                with jax.named_scope("kv_pool"), jax.named_scope("kv_pool.window") if sliding else contextlib.nullcontext():
+                    if sliding:
                         k_store, k_ctx = paged_window_write(pk, k_g, ltab, group[2])
                         v_store, v_ctx = paged_window_write(pv, v_g, ltab, group[2])
-                else:
-                    pk, pv, ltab = address_paged_pool_by_layer(full_pool, group[1], number)
-                    with jax.named_scope("kv_pool"):
+                    else:
                         k_store, k_ctx = paged_cache_write(pk, k_g, ltab, group[2], c.dtype)
                         v_store, v_ctx = paged_cache_write(pv, v_g, ltab, group[2], c.dtype)
                 attn.append(_attend(q_g, k_ctx, v_ctx, mask, c, window=sliding))
@@ -595,6 +618,9 @@ def apply_paged(params: dict, groups, config: AfmoeConfig, pool: dict):
         holds = (groups[0][2] > 0)[:, None, None]
         counters["window_rows_read"] = c.count(SLIDING) * jnp.sum(masks_w[0] & holds, dtype=jnp.int32)
         counters["context_rows"] = c.count(SLIDING) * jnp.sum(masks_f[0] & holds, dtype=jnp.int32)
+    counters["attn_rows_read"] = sum(
+        (c.count(kind) * rows_read_in_place(groups[0][2], block_size, c.sliding_window if kind == SLIDING else 0)
+         for kind, sliding in ((FULL, False), (SLIDING, True)) if kernel[sliding]), jnp.zeros((), jnp.int32))
     return split_groups(logits, shapes), tuple(rows), counters
 
 

@@ -386,6 +386,85 @@ def _blocks_lie_row_by_row(leaf) -> bool:
         kv_heads % 8 == 0 or (head_dim == 128 and kv_heads in (1, 2, 4)))
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+# The narrowest block table, in bytes of a leaf a lane, whose decoding lanes read the pool in place.  From the probes of
+# PR 39 (one TPU v5e, sixteen lanes at three quarters of the table's rows, ms a dispatch gathered / in place; PERF.md
+# section 6): K 2 x hd 128 (8 KB a block), 36 layers: 64 blocks (512 KB) 1.85-2.01 / 1.92-2.15, 128 blocks (1 MB)
+# 3.55-4.08 / 3.23-3.40, 256 (2 MB) 7.39-8.33 / 6.57-6.59; K 8 x hd 128 (32 KB a block), four window layers: 16 blocks
+# (512 KB) 0.323 / 0.331, 32 (1 MB) 0.347 / 0.311, 64 0.731 / 0.475, 128 1.407 / 0.787, ring 259 4.88 / 1.85; one full
+# layer ties at 16-64 blocks (0.31-0.33 both, a dispatch's floor) and wins from 128 (0.378 / 0.327; 1,024 blocks 4.49 /
+# 0.89).  Under a table of 1 MB the kernel's own cost a lane (its first copies exposed, a step's fixed work) is not paid
+# back by the rows it leaves unread.
+MIN_IN_PLACE_TABLE_BYTES = 1 << 20
+
+
+def reads_in_place(leaf, rows: int, width: int) -> bool:
+    """Whether a group of ``rows`` rows a lane attends over its K/V leaf
+    ``leaf`` (a pool leaf ``[..., bs, K, hd]``, or ``(codes, scale)``) through
+    block tables ``width`` wide **in place**: the Pallas kernel of
+    ``ops/pallas_paged_attention.py`` reads each lane's own blocks where they
+    lie (:func:`attend_in_place`), in place of :func:`paged_cache_write`'s
+    gathered context.  From static facts alone, no option anywhere: a TPU runs
+    the program on one device (``pallas_call`` takes no part in GSPMD's
+    partitioning), the group is the decoding lanes at one row a lane (a chunk,
+    a verify window and a block of several rows keep the gathered path), the
+    leaf is bf16 that the TPU holds row by row (:func:`_blocks_lie_row_by_row`:
+    a block is whole ``[bs*K, hd]`` rows, one copy; int8, hd 64 and latent rows
+    stay gathered) and its tables hold at least ``MIN_IN_PLACE_TABLE_BYTES`` of
+    it a lane (narrower, the gather of a few blocks a lane is the cheaper
+    read).  Off the TPU the gathered path stays (the kernel would run in the
+    Pallas interpreter)."""
+    from ..parallel.sharding import _abstract_mesh
+
+    mesh = _abstract_mesh()
+    if not (_on_tpu() and (mesh.empty or mesh.size == 1) and rows == 1 and not isinstance(leaf, tuple)
+            and leaf.dtype == jnp.bfloat16 and _blocks_lie_row_by_row(leaf)):
+        return False
+    bs, kv_heads, head_dim = leaf.shape[-3:]
+    return width * bs * kv_heads * head_dim * leaf.dtype.itemsize >= MIN_IN_PLACE_TABLE_BYTES
+
+
+def _paged_kernel():
+    from ..ops.moe import pallas_module
+
+    return pallas_module("pallas_paged_attention")
+
+
+def _admitted(starts: jax.Array, window: int):
+    """``(lo, hi)`` ``[B]``: the positions in the pool a decoding lane's query
+    at ``starts`` sees, every earlier one, or under a window of ``window`` the
+    last ``window - 1`` (its own row, the ``window``-th, is not in the pool
+    yet); none where ``hi < lo`` (a lane at 0)."""
+    hi = starts.astype(jnp.int32) - 1
+    return (jnp.maximum(hi - window + 2, 0) if window else jnp.zeros_like(hi)), hi
+
+
+def attend_in_place(q, k_new, v_new, pk, pv, tables, starts, window: int = 0, interpret: bool = False) -> jax.Array:
+    """The decoding lanes' attention read where the pool lies: ``q [B, 1, H,
+    hd]`` over the rows its tables ``[B, W]`` name of the leaves ``pk``, ``pv``
+    (as :func:`address_paged_pool_by_layer` hands them over; position ``p`` in
+    entry ``(p // bs) mod W``, so a window layer's ring too), those at ``lo ..
+    starts - 1`` (:func:`_admitted`), and its own new row ``k_new``, ``v_new``
+    ``[B, 1, K, hd]`` as stored, merged in outside the kernel.  Returns ``[B,
+    1, H, hd]`` in q.dtype: what :func:`paged_cache_write` and ``_attention``
+    give under :func:`group_positions`' or :func:`window_group_masks`' mask,
+    with float32 scores."""
+    kernel = _paged_kernel()
+    lo, hi = _admitted(starts, window)
+    acc, m, l = kernel.paged_decode_attention(q[:, 0], pk, pv, tables, lo, hi, interpret=interpret)
+    return kernel.merge_own_row(acc, m, l, q[:, 0], k_new[:, 0], v_new[:, 0])[:, None]
+
+
+def rows_read_in_place(starts: jax.Array, block_size: int, window: int = 0) -> jax.Array:
+    """The rows :func:`attend_in_place` copies a layer for lanes at ``starts``:
+    every block it touches whole, the edge blocks too; int32."""
+    _, blocks = _paged_kernel().lane_blocks(*_admitted(starts, window), block_size)
+    return jnp.sum(blocks) * block_size
+
+
 @jax.named_scope("kv_pool.gather")
 def paged_cache_write(pool_layer, new_rows: jax.Array, tables: jax.Array, starts: jax.Array, dtype):
     """Per-layer paged analog of :func:`cache_write`: compute the stored

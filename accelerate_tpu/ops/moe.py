@@ -26,6 +26,7 @@ x token fraction per expert) and router z-loss.
 from __future__ import annotations
 
 import functools
+import importlib
 import sys
 from typing import Any, Optional
 
@@ -175,21 +176,21 @@ def _on_tpu() -> bool:
 _GPU_INTERPRETER = "jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call"
 
 
-def _pallas_moe():
-    """``ops/pallas_moe.py``, imported where the kernel is first asked for (as
-    ``models/llama.py`` imports the flash kernel): only a TPU program of few
-    rows an expert needs Pallas.  A process whose backend is a TPU has no use
-    for the Mosaic GPU interpreter: if nobody has imported Pallas yet, that one
-    import is told the module is absent, and the entry is taken back at once."""
+def pallas_module(name: str):
+    """``ops/<name>.py``, a Pallas kernel's module, imported where the kernel is
+    first asked for (as ``models/llama.py`` imports the flash kernel): only a
+    TPU program that takes the kernel needs Pallas.  A process whose backend is
+    a TPU has no use for the Mosaic GPU interpreter: if nobody has imported
+    Pallas yet, that one import is told the module is absent, and the entry is
+    taken back at once."""
     skip = _on_tpu() and "jax._src.pallas.pallas_call" not in sys.modules and _GPU_INTERPRETER not in sys.modules
     if skip:
         sys.modules[_GPU_INTERPRETER] = None
     try:
-        from . import pallas_moe
+        return importlib.import_module(f"{__package__}.{name}")
     finally:
         if skip:
             del sys.modules[_GPU_INTERPRETER]
-    return pallas_moe
 
 
 def expert_row_tile(pairs: int, experts: int, d: int, f: int, dtype: Any) -> int:
@@ -210,7 +211,7 @@ def expert_row_tile(pairs: int, experts: int, d: int, f: int, dtype: Any) -> int
     mesh = _abstract_mesh()
     if not _on_tpu() or (not mesh.empty and mesh.size > 1) or pairs > FUSED_MAX_MEAN_ROWS * experts:
         return 0
-    kernel = _pallas_moe()
+    kernel = pallas_module("pallas_moe")
     if kernel.f_tile(d, f, jnp.dtype(dtype).itemsize) is None:
         return 0
     tm = kernel.ROW_TILE
@@ -235,7 +236,7 @@ def _ragged_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert):
 def _fused_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm):
     """The same product as one Pallas kernel; differentiated as
     :func:`_ragged_swiglu` is, so a gradient through it is that path's."""
-    return _pallas_moe().grouped_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm=tm)
+    return pallas_module("pallas_moe").grouped_swiglu(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm=tm)
 
 
 def _fused_swiglu_fwd(rows, w_gate, w_up, w_down, group_sizes, first_expert, tm):

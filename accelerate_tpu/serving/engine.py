@@ -148,7 +148,10 @@ behind the ``ok`` flags into ``stats()["moe_rows"]``, ``"moe_experts_hit"``,
 ``"moe_max_rows"``, ``"moe_row_tiles"`` -- the last 0 unless the dispatch ran the
 fused kernel of ``ops/pallas_moe.py``, which the telemetry counter
 ``serving.moe_row_tiles`` carries too --; a mixed dispatch streams the experts its chunk and its
-decoders hit once).  A family without an ``apply_paged``
+decoders hit once).  Where the decoding lanes read the pool through the paged
+kernel of ``ops/pallas_paged_attention.py`` (``generation.reads_in_place``),
+``stats()["attn_rows_read"]`` and the telemetry counter
+``serving.attn_rows_read`` count the rows it copied; 0 where they gathered.  A family without an ``apply_paged``
 (``models/mixtral.py``: capacity routing depends on who shares the batch) is
 served **dense**: gather each slot's whole view at the one static table
 width, ``vmap`` the family's ``apply_cached``, extract and scatter the
@@ -248,7 +251,7 @@ from .blocks import (
     blocks_for_tokens,
 )
 from .journal import JournalError, ServingJournal
-from .programs import DISPATCH_COUNTERS, FEED_CHUNK, FEED_LANE, MOE_COUNTERS, WINDOW_COUNTERS, build_programs
+from .programs import ATTN_COUNTERS, DISPATCH_COUNTERS, FEED_CHUNK, FEED_LANE, MOE_COUNTERS, WINDOW_COUNTERS, build_programs
 from .scheduler import Request, RequestState, Scheduler
 from .tracing import ServingTracer, resolve_trace_dir, tracing_enabled
 
@@ -777,6 +780,8 @@ class ServingEngine:
         self.moe_counters = dict.fromkeys(MOE_COUNTERS, 0)
         # What the window layers of every decode dispatch read (WINDOW_COUNTERS); reported where the family has them.
         self.window_counters = dict.fromkeys(WINDOW_COUNTERS, 0)
+        # What the decoding lanes' attention copied where it read the pool in place (ATTN_COUNTERS); 0 where it gathered.
+        self.attn_counters = dict.fromkeys(ATTN_COUNTERS, 0)
         # KV-tiering accounting (engine-side migrations; the prefix cache's
         # own demote/promote churn is folded in at publish time).
         self.tier_demotions = 0
@@ -2118,9 +2123,12 @@ class ServingEngine:
         with _TickPhase(self, "read", of=flight.tick, **reason):
             out = self.programs.unpack(flight.packed, with_chunk=flight.chunk is not None)
         for name, value in zip(DISPATCH_COUNTERS, out["counters"]):
-            (self.moe_counters if name in self.moe_counters else self.window_counters)[name] += int(value)
+            kind = next(c for c in (self.moe_counters, self.window_counters, self.attn_counters) if name in c)
+            kind[name] += int(value)
             if name == "moe_row_tiles" and value and get_telemetry().enabled:
                 get_telemetry().registry.counter("serving.moe_row_tiles").inc(int(value))
+            if name == "attn_rows_read" and value and get_telemetry().enabled:
+                get_telemetry().registry.counter("serving.attn_rows_read").inc(int(value))
         return out
 
     def _apply(self, flight: _Flight, out: dict) -> None:
@@ -2681,6 +2689,7 @@ class ServingEngine:
             "decode_path": self.decode_path,
             "decode_gather_bytes": self.decode_gather_bytes,
             **self.moe_counters,
+            **self.attn_counters,
             "prefix_hits": self.prefix_hits,
             "prefix_blocks_reused": self.prefix_blocks_reused,
             "prefix_cow_copies": self.cow_copies,

@@ -130,8 +130,8 @@ from ..models.generation import (
 )
 
 __all__ = [
-    "DISPATCH_COUNTERS", "FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "WINDOW_COUNTERS", "ServingPrograms",
-    "build_programs",
+    "ATTN_COUNTERS", "DISPATCH_COUNTERS", "FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "WINDOW_COUNTERS",
+    "ServingPrograms", "build_programs",
 ]
 
 # Where a decoding lane's input token comes from (a program's per-lane ``source``): the host's ``tokens``, the lane's own
@@ -149,9 +149,13 @@ MOE_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_max_rows", "moe_row_tiles", 
 # window layers: the rows the window layers attended over, and the rows they would have had every layer read the whole
 # context.
 WINDOW_COUNTERS = ("window_rows_read", "context_rows")
+# What the decoding lanes' attention read where it took the paged kernel (``generation.reads_in_place``): the rows the
+# kernel copied, summed over the lanes and the layers that took it, the edge blocks whole; 0 for a dispatch that
+# gathered its context (as ``moe_row_tiles`` is 0 for ``lax.ragged_dot``).
+ATTN_COUNTERS = ("attn_rows_read",)
 # Every counter a dispatch can carry behind its ``ok`` flags, in the order they are packed; a family that returns
 # counters packs all of them, 0 for what it does not count, so that the host reads one layout.
-DISPATCH_COUNTERS = MOE_COUNTERS + WINDOW_COUNTERS
+DISPATCH_COUNTERS = MOE_COUNTERS + WINDOW_COUNTERS + ATTN_COUNTERS
 
 # No block table is narrower than this many rows.  Every width an engine meets compiles both of its programs, and on the
 # host of a v5e that is one to two seconds of a process's set-up a width even with a warm compile cache (tracing and

@@ -52,6 +52,7 @@ COUNTERS = frozenset({
     "resilience.preempt_signals",
     "resilience.retries",
     "sentinel.anomalies",
+    "serving.attn_rows_read",
     "serving.block_tokens_emitted",
     "serving.blocks_committed",
     "serving.commit_slot_ticks",

@@ -180,10 +180,12 @@ def test_cached_prefill_and_decode_are_the_references_full_forward(fam, model, c
     assert int(cache["index"]) == 40 and (WINDOW in cache) == bool(c.count(af.SLIDING))
 
 
-def paged_run(c, params, tokens, prompt, chunk, lanes=1):
+def paged_run(c, params, tokens, prompt, chunk, lanes=1, interpret=False):
     """The serving path by hand: one sequence in lane 0 of ``lanes``, its prompt in padded chunks of ``chunk`` and
     then a row a dispatch, through ``apply_paged`` over a pool whose window leaves are a ring, written by the
-    engine's own ``_write_rows``.  Returns (logits of every real row, counters of the last dispatch, the ring's width)."""
+    engine's own ``_write_rows`` (``interpret``: a group of one row a lane reads both kinds of pool through the paged
+    kernel in the Pallas interpreter).  Returns (logits of every real row, counters of the last dispatch, the ring's
+    width)."""
     ring = window_ring_blocks(c.sliding_window, chunk, BLOCK)
     blocks = -(-(len(tokens) + chunk) // BLOCK)
     pool = make_paged_pool(af.init_cache, c, blocks + 2, BLOCK, window_blocks=ring + 2)
@@ -196,7 +198,7 @@ def paged_run(c, params, tokens, prompt, chunk, lanes=1):
 
     @jax.jit
     def dispatch(pool, toks, starts):
-        logits, rows, counters = af.apply_paged(params, ((toks, tables, starts, wtables),), c, pool)
+        logits, rows, counters = af.apply_paged(params, ((toks, tables, starts, wtables),), c, pool, interpret=interpret)
         return logits[0], P._write_rows(pool, rows[0], tables, starts, toks.shape[1], wtables=wtables), counters
 
     got, counters = [], None
@@ -223,6 +225,19 @@ def test_paged_ring_is_the_references_full_forward(fam, model, chunk):
     assert np.max(np.abs(got - reference_logits(fam, cfg, params, tokens))) < TOL
 
 
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_the_lanes_read_in_place_are_the_gathered_lanes(fam, model, chunk):
+    """The decoding lanes through ``ops/pallas_paged_attention.py`` (the Pallas interpreter), the chunk's group
+    gathered as ever; at a chunk of 1 the prompt's rows go through the kernel too, from position 0 on.  Both kinds of
+    layer, the ring wrapped: every logit is the gathered path's and the reference's."""
+    cfg, c, params = model
+    tokens = some_tokens(44, 4)
+    want, counters, _ = paged_run(c, params, tokens, 23, chunk, lanes=2)
+    got, counters_in_place, _ = paged_run(c, params, tokens, 23, chunk, lanes=2, interpret=True)
+    assert np.max(np.abs(got - want)) < TOL and np.max(np.abs(got - reference_logits(fam, cfg, params, tokens))) < TOL
+    assert int(counters["attn_rows_read"]) == 0 < int(counters_in_place["attn_rows_read"])
+
+
 def test_counters_of_one_dispatch_against_hand_worked_numbers(share):
     cfg, c, params = share
     tokens = some_tokens(30, 5)
@@ -237,6 +252,11 @@ def test_counters_of_one_dispatch_against_hand_worked_numbers(share):
     # inside the first window the sliding layers read what the full layer reads
     _, early, _ = paged_run(c, params, tokens[:6], 4, 4)
     assert int(early["window_rows_read"]) == int(early["context_rows"]) == 4 * 6
+    # read in place: lane 0 at 29 copies blocks of 4 rows, the full layer positions 0 .. 28 (8 blocks), each sliding
+    # layer 22 .. 28 (3 blocks, the edge blocks whole); the lanes at 0 copy nothing.  Gathered, nothing is counted
+    assert counters["attn_rows_read"] == 0
+    _, in_place, _ = paged_run(c, params, tokens, 20, 4, lanes=3, interpret=True)
+    assert int(in_place["attn_rows_read"]) == 1 * 8 * 4 + 4 * 3 * 4
 
 
 def test_generate_is_greedy_over_the_reference(fam, share):

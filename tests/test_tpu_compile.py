@@ -59,6 +59,8 @@ def program(case, hd, b):
         return latent_step(case.removesuffix("_fused"))
     if case == "moe_kernel":
         return moe_kernel(hd, b)
+    if case == "paged_kernel":
+        return paged_kernel(hd, b)
     raise ValueError(case)
 
 
@@ -315,7 +317,7 @@ def check_state_step(width, fused=False):
     return "temp_bytes=" + "/".join(map(str, temps))
 
 
-def check_window_step(width, fused=False):
+def check_window_step(width, fused=False, in_place=False):
     # serving/programs.py's decode and decode_chunk for models/afmoe.py at the cut the benchmark serves (trinity-large-preview:
     # one dense layer and one period s s s f at the published widths, 32 of 256 experts held, the cell's geometry): the full
     # layer's rows [1, 16384, 16, 8, 128] beside the four sliding layers' [4, 16 * 259 + 1, 16, 8, 128], a ring of 259 blocks a
@@ -368,8 +370,85 @@ def check_window_step(width, fused=False):
         temp = compiled.memory_analysis().temp_size_in_bytes
         if temp > 1.5 * context_bytes + 2**27:
             raise AssertionError(f"{name}: {temp} bytes of temporaries, the full layer's gathered context is {context_bytes}")
+        check_lanes_in_place(text, temp, in_place, f"window_step:{width}:{name}")
         temps.append(temp)
     return "temp_bytes=" + "/".join(map(str, temps))
+
+
+# Bytes of temporaries of serving/programs.py's two programs at the parent of PR 39, where every group gathered its
+# context (this file's child, run in that checkout): what a program whose decoding lanes read the pool in place undercuts.
+# The chat cell's held 354,816 / 548,352 B at widths 64 and 256 alike (XLA fuses each layer's gather into its consumers
+# there), and hold it still: models/llama.py's lanes gather their context
+CHAT_GATHERED_TEMP_BYTES = {"decode": 354_816, "decode_chunk": 548_352}
+GATHERED_TEMP_BYTES = {
+    "window_step:256:decode": 138_034_176, "window_step:256:decode_chunk": 142_011_904,
+    "window_step:1024:decode": 576_196_096, "window_step:1024:decode_chunk": 581_979_136,  # the full layer's context
+}
+IN_PLACE_KERNEL = "paged_decode_attention"  # ops/pallas_paged_attention.py's pallas_call(name=)
+
+
+def check_lanes_in_place(text, temp, in_place, key):
+    # The decoding lanes read the pool through the kernel, one custom call a layer kind, the chunk's group gathered as
+    # ever; what the program holds besides its arguments is less than the gathered program's (PR 39)
+    if not in_place:
+        if IN_PLACE_KERNEL in text:
+            raise AssertionError(f"{key}: the kernel is in a program whose rule keeps the gathered path")
+        return
+    if IN_PLACE_KERNEL not in text:
+        raise AssertionError(f"{key}: the executable does not hold the kernel by its name")
+    if key in GATHERED_TEMP_BYTES and temp >= GATHERED_TEMP_BYTES[key]:
+        raise AssertionError(f"{key}: {temp} bytes of temporaries, {GATHERED_TEMP_BYTES[key]} gathered")
+
+
+def check_chat_step(width, in_place=False):
+    # serving/programs.py's decode and decode_chunk at the chat cell's shapes (qwen2.5-3b through models/llama.py: 36
+    # layers of K 2 x hd 128, [36, 8192, 16, 2, 128] a leaf, sixteen lanes, a chunk of 64), as a TPU builds them and as
+    # the CPU does: the same gathered programs, no kernel (llama.apply_paged does not take it, PR 39); no result of either
+    # program is as large as a pool leaf or a layer's slice of one but the scatters of the new rows
+    import re
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.models.generation import make_paged_pool
+    from accelerate_tpu.serving import ServingConfig, programs as P
+
+    _, widths, max_blocks = MIXED_CELLS["mixed_step_chat"]
+    c = llama.LlamaConfig(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False, **widths)
+    place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: llama.init_params(c, jax.random.key(0))))
+    pool = place(jax.eval_shape(lambda: make_paged_pool(llama.init_cache, c, MIXED_BLOCKS, 16)))
+    serving = ServingConfig(block_size=16, num_blocks=MIXED_BLOCKS, max_slots=MIXED_SLOTS, max_blocks_per_seq=max_blocks, prefill_chunk=64)
+    built = P.build_programs(llama.apply_cached, c, list(pool), serving, 0)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    lanes = (i32(MIXED_SLOTS, width), i32(MIXED_SLOTS), i32(MIXED_SLOTS, 1), i32(MIXED_SLOTS), i32(MIXED_SLOTS + 1), i32(MIXED_SLOTS))
+    chunk = (i32(width), i32(), i32(1, 64), i32())
+    sized = re.compile(r"= \w+\[(%d|36,%d|%d)," % (36 * MIXED_BLOCKS, MIXED_BLOCKS, MIXED_BLOCKS))
+    temps = []
+    for name, program, args in (("decode", built.decode, lanes), ("decode_chunk", built.decode_chunk, (*lanes, *chunk))):
+        compiled = program.lower(params, pool, *args).compile()
+        text = compiled.as_text()
+        lines = [line for line in text.splitlines() if not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
+        moved = [line.strip()[:160] for line in lines if sized.search(line) and "scatter(" not in line and "kv_pool.write/scatter" not in line]
+        if moved:
+            raise AssertionError(f"{name} moves pool-sized arrays besides the scatter of the new rows: " + " ;; ".join(moved[:4]))
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        # as a TPU builds them the chat cell's programs gather, as the parent's
+        check_lanes_in_place(text, temp, False, f"chat_step:{width}:{name}")
+        if in_place and temp != CHAT_GATHERED_TEMP_BYTES[name]:
+            raise AssertionError(f"{name}: {temp} bytes of temporaries, the parent's program held {CHAT_GATHERED_TEMP_BYTES[name]}")
+        temps.append(temp)
+    return "temp_bytes=" + "/".join(map(str, temps))
+
+
+def paged_kernel(width, kv_heads):
+    # ops/pallas_paged_attention.py alone, interpret=False, at the cells' geometries: Trinity's full layer (K 8, a table of
+    # 1,024 blocks over [16384, 16, 8, 128]) and its window layers (a ring of 259 over the four layers' leaf), the chat
+    # cell's K 2 (its 36 layers' leaf): Mosaic takes the double buffer and the scalar-prefetched tables, the pool stays
+    # an operand where it lies
+    from accelerate_tpu.ops.pallas_paged_attention import paged_decode_attention
+    rows = {1024: 16384, 259: 4 * (16 * 259 + 1)}.get(width, 36 * MIXED_BLOCKS)
+    heads = {8: 48, 2: 16}[kv_heads]
+    args = (sds((16, heads, 128)), sds((rows, 16, kv_heads, 128)), sds((rows, 16, kv_heads, 128)), sds((16, width), jnp.int32),
+            sds((16,), jnp.int32), sds((16,), jnp.int32))
+    return (lambda q, k, v, t, lo, hi: paged_decode_attention(q, k, v, t, lo, hi)), args
 
 
 def check_context_assembly(text):
@@ -390,12 +469,12 @@ def check_context_assembly(text):
         raise AssertionError("the context is passed over again after the block gather: " + " ;; ".join(passes[:4]))
 
 
-def pool_sized_results(text, whole_pool_only):
+def pool_sized_results(text, whole_pool_only, sizes=None):
     # Instructions of a compiled paged step that produce an array as large as
     # the pool (whole_pool_only) or as a layer's slice of it: a copy, a slice
     # or a re-tiling.  Views (bitcast), parameters and tuple reads move nothing
     import re
-    sizes = (STEP_LAYERS * STEP_BLOCKS, "%d,%d" % (STEP_LAYERS, STEP_BLOCKS)) + (() if whole_pool_only else (STEP_BLOCKS,))
+    sizes = sizes or (STEP_LAYERS * STEP_BLOCKS, "%d,%d" % (STEP_LAYERS, STEP_BLOCKS)) + (() if whole_pool_only else (STEP_BLOCKS,))
     sized = re.compile(r"= \w+\[(%s)," % "|".join(map(str, sizes)))
     return [line.strip()[:160] for line in text.splitlines()
             if sized.search(line) and not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
@@ -419,14 +498,20 @@ def check_paged_step(spec, compiled):
     return f"temp_bytes={temp}"
 
 
+from accelerate_tpu.models import generation
 from accelerate_tpu.ops import moe
 
 for spec in sys.argv[2:]:
     case, hd, b = spec.split(":")
     print("BEGIN", spec, flush=True)   # an abort after this line belongs to this case
-    fused = case.endswith("_fused") or case == "moe_kernel"
+    in_place = case.endswith("_in_place")
+    fused = case.endswith("_fused") or case == "moe_kernel" or in_place
     moe._on_tpu = (lambda: True) if fused else (lambda: False)  # the one fact of ops/moe.py's rule that this client cannot show
+    generation._on_tpu = (lambda: True) if in_place else (lambda: False)  # and of generation.reads_in_place's
     try:
+        if case.startswith("chat_step"):
+            print("COMPILED", spec, check_chat_step(int(hd), in_place), flush=True)
+            continue
         if case.startswith("mixed_step"):
             print("COMPILED", spec, check_mixed_step(case, int(hd)), flush=True)
             continue
@@ -434,12 +519,15 @@ for spec in sys.argv[2:]:
             print("COMPILED", spec, check_state_step(int(hd), fused), flush=True)
             continue
         if case.startswith("window_step"):
-            print("COMPILED", spec, check_window_step(int(hd), fused), flush=True)
+            print("COMPILED", spec, check_window_step(int(hd), fused, in_place), flush=True)
             continue
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
         if "tpu_custom_call" not in compiled.as_text() and not case.startswith("paged_step"):
             raise AssertionError("compiled, but the executable holds no Mosaic kernel")
+        if case == "paged_kernel" and (pool_sized_results(compiled.as_text(), True, sizes=(args[1].shape[0],))
+                                       or compiled.memory_analysis().temp_size_in_bytes):
+            raise AssertionError("the kernel's pool is copied: temporaries " + str(compiled.memory_analysis().temp_size_in_bytes))
         note = check_paged_step(spec, compiled) if case.startswith("paged_step") else ""
         note = check_latent_step(compiled, fused) if case.startswith("latent_step") else note
         note = check_moe_kernel(int(b), compiled) if case == "moe_kernel" else note
@@ -512,8 +600,23 @@ CASES = [
     ("window_step_fused", 256, 0),
     ("window_step_fused", 1024, 0),
     ("window_step", 256, 0),
+    # ops/pallas_paged_attention.py (PR 39): the kernel alone at Trinity's full table and ring and at the chat cell's K 2
+    # (the fields are the table's width and K) ...
+    ("paged_kernel", 1024, 8),
+    ("paged_kernel", 259, 8),
+    ("paged_kernel", 256, 2),
+    # ... and the two programs that take it, as a TPU builds them: the decoding lanes read in place (one custom call a
+    # kind of layer), nothing pool-sized but the scatters, fewer temporaries than the gathered programs of the parent;
+    # the chat cell's, at tables of 1,024 and 4,096 rows, keep the parent's gathered programs (models/llama.py does not
+    # take the kernel), as a TPU builds them and as the CPU does
+    ("window_step_in_place", 256, 0),
+    ("window_step_in_place", 1024, 0),
+    ("chat_step_in_place", 64, 0),
+    ("chat_step_in_place", 256, 0),
+    ("chat_step", 256, 0),
 ]
-IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step", "window_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
+IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step", "window_step", "chat_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
+       else f"{c}-w{h}-k{b}" if c == "paged_kernel"
        else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}" for c, h, b in CASES]
 
 
