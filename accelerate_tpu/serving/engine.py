@@ -251,7 +251,16 @@ from .blocks import (
     blocks_for_tokens,
 )
 from .journal import JournalError, ServingJournal
-from .programs import ATTN_COUNTERS, DISPATCH_COUNTERS, FEED_CHUNK, FEED_LANE, MOE_COUNTERS, WINDOW_COUNTERS, build_programs
+from .programs import (
+    ATTN_COUNTERS,
+    DISPATCH_COUNTERS,
+    FEED_CHUNK,
+    FEED_LANE,
+    MOE_COUNTERS,
+    SPARSE_COUNTERS,
+    WINDOW_COUNTERS,
+    build_programs,
+)
 from .scheduler import Request, RequestState, Scheduler
 from .tracing import ServingTracer, resolve_trace_dir, tracing_enabled
 
@@ -453,7 +462,8 @@ class CompletedRequest:
     for a request cancelled/shed past its deadline (``tokens`` holds
     whatever was emitted before expiry), or ``"quarantined"`` for a request
     whose decode produced non-finite logits (``tokens`` excludes the
-    poisoned token — it was never meaningful)."""
+    poisoned token — it was never meaningful); ``"in_flight"`` only in a
+    record of :meth:`ServingEngine.unfinished`, a request as it stands."""
 
     id: int
     tokens: List[int]
@@ -720,6 +730,16 @@ class ServingEngine:
                 f"rejected draft's row has already overwritten the ring) are not served for it; got "
                 f"host_blocks={sc.host_blocks}, spec_tokens={sc.spec_tokens}"
             )
+        # A family whose attention reads the rows an indexer selects (keye_vl2) says so by its model config's top-k.
+        # Its index keys are a token leaf, so the prefix cache and the host tier carry them like K/V; a verify window
+        # would be a group of several rows a lane, which its forward attends over the gathered context, a path no
+        # test holds to the oracle for it yet (ROADMAP B).
+        self._index_topk = int(getattr(config, "index_topk", 0) or 0)
+        if self._index_topk and sc.spec_tokens:
+            raise ValueError(
+                f"this family's attention reads the {self._index_topk} rows its indexer selects: spec_tokens > 0 (a "
+                f"verify window over a selected set) is not served for it; got spec_tokens={sc.spec_tokens}"
+            )
         self.state_resets = 0  # lanes dispatched at position 0, which read a zero state whatever their slot held
         self.sched = Scheduler(
             self.cache.allocator,
@@ -782,6 +802,8 @@ class ServingEngine:
         self.window_counters = dict.fromkeys(WINDOW_COUNTERS, 0)
         # What the decoding lanes' attention copied where it read the pool in place (ATTN_COUNTERS); 0 where it gathered.
         self.attn_counters = dict.fromkeys(ATTN_COUNTERS, 0)
+        # What the indexer scored and the attention read where a family selects its rows (SPARSE_COUNTERS).
+        self.sparse_counters = dict.fromkeys(SPARSE_COUNTERS, 0)
         # KV-tiering accounting (engine-side migrations; the prefix cache's
         # own demote/promote churn is folded in at publish time).
         self.tier_demotions = 0
@@ -2122,13 +2144,21 @@ class ServingEngine:
         reason = {"settle": settle} if settle else {}
         with _TickPhase(self, "read", of=flight.tick, **reason):
             out = self.programs.unpack(flight.packed, with_chunk=flight.chunk is not None)
-        for name, value in zip(DISPATCH_COUNTERS, out["counters"]):
-            kind = next(c for c in (self.moe_counters, self.window_counters, self.attn_counters) if name in c)
-            kind[name] += int(value)
-            if name == "moe_row_tiles" and value and get_telemetry().enabled:
-                get_telemetry().registry.counter("serving.moe_row_tiles").inc(int(value))
-            if name == "attn_rows_read" and value and get_telemetry().enabled:
-                get_telemetry().registry.counter("serving.attn_rows_read").inc(int(value))
+        counts = {name: int(value) for name, value in zip(DISPATCH_COUNTERS, out["counters"])}
+        for name, value in counts.items():
+            kind = next(c for c in (self.moe_counters, self.window_counters, self.attn_counters, self.sparse_counters)
+                        if name in c)
+            kind[name] += value
+        if counts and get_telemetry().enabled:
+            registry = get_telemetry().registry
+            if counts["moe_row_tiles"]:
+                registry.counter("serving.moe_row_tiles").inc(counts["moe_row_tiles"])
+            if counts["attn_rows_read"]:
+                registry.counter("serving.attn_rows_read").inc(counts["attn_rows_read"])
+            if self._index_topk:
+                registry.counter("serving.index_rows_scored").inc(counts["index_rows_scored"])
+                registry.counter("serving.sparse_rows_read").inc(counts["sparse_rows_read"])
+                registry.counter("serving.context_rows").inc(counts["context_rows"])
         return out
 
     def _apply(self, flight: _Flight, out: dict) -> None:
@@ -2355,6 +2385,43 @@ class ServingEngine:
             self._complete(req)
 
     def _complete(self, req: Request, status: str = "ok") -> None:
+        rec = self._record(req, status)
+        self._finished.append(rec)
+        if self.journal is not None:
+            self.journal.record_done(req.id, status)
+        tel = get_telemetry()
+        if tel.enabled:
+            reg = tel.registry
+            reg.counter("serving.completed").inc()
+            reg.histogram("serving.queue_wait_ms").observe(rec.queue_wait_ms)
+            if rec.tokens_per_s is not None:
+                reg.histogram("serving.tokens_per_s").observe(rec.tokens_per_s)
+            tel.event(
+                "serving.request_complete",
+                request=req.id,
+                tag=req.tag,
+                status=status,
+                prompt_len=len(req.prompt),
+                new_tokens=len(req.emitted),
+                ttft_ms=round(rec.ttft_ms, 3) if rec.ttft_ms is not None else None,
+                queue_wait_ms=round(rec.queue_wait_ms, 3),
+                preemptions=req.preemptions,
+            )
+        if self.tracer is not None:
+            self.tracer.on_terminal(req, status)
+
+    def unfinished(self) -> List[CompletedRequest]:
+        """A record of every submitted request that has not completed, as it
+        stands (``status`` ``"in_flight"``): the tokens read so far and their
+        moments.  Nothing changes, nothing is read back: a token still on the
+        device is not in it, and the requests go on.  For a caller that stops
+        ticking before every reply is in, as a benchmark does at the close of
+        its window."""
+        live = [slot.request for slot in sorted([*self.sched.slots.values(), *self.sched.retiring], key=lambda s: s.idx)]
+        ids = {req.id for req in live}
+        return [self._record(req, "in_flight") for req in [*live, *(r for r in self.sched.queue if r.id not in ids)]]
+
+    def _record(self, req: Request, status: str) -> CompletedRequest:
         ttft_ms = None
         if req.first_token_t is not None and req.arrival_t is not None:
             ttft_ms = (req.first_token_t - req.arrival_t) * 1e3
@@ -2376,7 +2443,7 @@ class ServingEngine:
             and len(req.emitted) > 1
         ):
             tps = (len(req.emitted) - 1) / (req.finish_t - req.first_token_t)
-        rec = CompletedRequest(
+        return CompletedRequest(
             id=req.id,
             tokens=req.output,
             prompt_len=len(req.prompt),
@@ -2394,29 +2461,6 @@ class ServingEngine:
             prefill_dispatches=req.prefill_dispatches,
             token_passes=list(req.token_passes),
         )
-        self._finished.append(rec)
-        if self.journal is not None:
-            self.journal.record_done(req.id, status)
-        tel = get_telemetry()
-        if tel.enabled:
-            reg = tel.registry
-            reg.counter("serving.completed").inc()
-            reg.histogram("serving.queue_wait_ms").observe(queue_wait_ms)
-            if tps is not None:
-                reg.histogram("serving.tokens_per_s").observe(tps)
-            tel.event(
-                "serving.request_complete",
-                request=req.id,
-                tag=req.tag,
-                status=status,
-                prompt_len=len(req.prompt),
-                new_tokens=len(req.emitted),
-                ttft_ms=round(ttft_ms, 3) if ttft_ms is not None else None,
-                queue_wait_ms=round(queue_wait_ms, 3),
-                preemptions=req.preemptions,
-            )
-        if self.tracer is not None:
-            self.tracer.on_terminal(req, status)
 
     def _publish_gauges(self) -> None:
         tel = get_telemetry()
@@ -2643,6 +2687,16 @@ class ServingEngine:
             )
         return out
 
+    def _sparse_stats(self) -> dict:
+        """What ``stats()`` says of a family whose attention reads the rows an
+        indexer selects: the index keys scored, the rows attended over and the
+        rows a full causal mask would have admitted, each summed over the
+        layers, and the least rows of the lanes' selections; any other family
+        carries none of these keys."""
+        if not self._index_topk:
+            return {}
+        return {"index_topk": self._index_topk, **self.sparse_counters, "context_rows": self.window_counters["context_rows"]}
+
     def _block_stats(self) -> dict:
         """What ``stats()`` says of a family generated by diffusion over
         blocks; any other family carries none of these keys."""
@@ -2684,6 +2738,7 @@ class ServingEngine:
             "prefill_chunk": self.serving.prefill_chunk,  # the integer the engine runs: given, or resolved at construction
             **self._state_stats(),
             **self._window_stats(),
+            **self._sparse_stats(),
             **self._block_stats(),
             "free_pool_bytes": alloc.free_blocks * self._block_bytes,
             "decode_path": self.decode_path,

@@ -46,7 +46,7 @@ wrapped), the programs take the lanes' window tables ``[S, Mw]`` (and
 ``_write_rows`` puts the rows returned under ``WINDOW`` each at its place in
 the ring.  Never beside a state, never under block generation (refused by name).
 
-- **paged** (a family with an ``apply_paged``: gpt2, llama, deepseek_v3, lfm2_moe):
+- **paged** (a family with an ``apply_paged``: gpt2, llama, deepseek_v3, lfm2_moe, sdar_moe, afmoe, keye_vl2):
   the family runs everything that does not look at the cache (embedding,
   norms, projections, the MLP or the experts, the head) once over the rows of
   all groups, so the weights, and the experts the rows hit, stream once a
@@ -130,8 +130,8 @@ from ..models.generation import (
 )
 
 __all__ = [
-    "ATTN_COUNTERS", "DISPATCH_COUNTERS", "FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "WINDOW_COUNTERS",
-    "ServingPrograms", "build_programs",
+    "ATTN_COUNTERS", "DISPATCH_COUNTERS", "FEED_CHUNK", "FEED_HOST", "FEED_LANE", "MOE_COUNTERS", "SPARSE_COUNTERS",
+    "WINDOW_COUNTERS", "ServingPrograms", "build_programs",
 ]
 
 # Where a decoding lane's input token comes from (a program's per-lane ``source``): the host's ``tokens``, the lane's own
@@ -153,9 +153,14 @@ WINDOW_COUNTERS = ("window_rows_read", "context_rows")
 # kernel copied, summed over the lanes and the layers that took it, the edge blocks whole; 0 for a dispatch that
 # gathered its context (as ``moe_row_tiles`` is 0 for ``lax.ragged_dot``).
 ATTN_COUNTERS = ("attn_rows_read",)
+# What a family whose attention reads the rows an indexer selects counts (``models/keye_vl2.py:sparse_counters``), each
+# summed over its layers and the lanes that hold a sequence: the index keys scored (a lane's context), the K/V rows the
+# queries attended over (``min(position + 1, topk)`` a query), and the rows the least read of a lane's selection takes
+# (``min(context, topk)`` a lane); ``context_rows`` above is what a full causal mask would have admitted.
+SPARSE_COUNTERS = ("index_rows_scored", "sparse_rows_read", "sparse_lane_rows")
 # Every counter a dispatch can carry behind its ``ok`` flags, in the order they are packed; a family that returns
 # counters packs all of them, 0 for what it does not count, so that the host reads one layout.
-DISPATCH_COUNTERS = MOE_COUNTERS + WINDOW_COUNTERS + ATTN_COUNTERS
+DISPATCH_COUNTERS = MOE_COUNTERS + WINDOW_COUNTERS + ATTN_COUNTERS + SPARSE_COUNTERS
 
 # No block table is narrower than this many rows.  Every width an engine meets compiles both of its programs, and on the
 # host of a v5e that is one to two seconds of a process's set-up a width even with a warm compile cache (tracing and

@@ -57,11 +57,13 @@ COUNTERS = frozenset({
     "serving.blocks_committed",
     "serving.commit_slot_ticks",
     "serving.completed",
+    "serving.context_rows",
     "serving.deadline_expired",
     "serving.decode_dispatches",
     "serving.decode_gather_bytes",
     "serving.denoise_slot_ticks",
     "serving.drains",
+    "serving.index_rows_scored",
     "serving.journal_recoveries",
     "serving.mixed_dispatches",
     "serving.moe_row_tiles",
@@ -75,6 +77,7 @@ COUNTERS = frozenset({
     "serving.requests",
     "serving.settles",
     "serving.shed",
+    "serving.sparse_rows_read",
     "serving.spec.accepted",
     "serving.spec.proposed",
     "serving.spec.rounds",

@@ -5,6 +5,7 @@ request across randomized arrival/length mixes, including under forced
 preemption and with the int8 KV cache."""
 
 import json
+import time
 
 import numpy as np
 import pytest
@@ -1171,6 +1172,34 @@ def test_drain_without_guard_is_manual_and_idempotent(gpt2_setup):
     # a drained engine cannot be re-armed: its journal is final
     with pytest.raises(RuntimeError, match="already drained"):
         eng.install_preemption_guard(object())
+
+
+def test_unfinished_records_stand_as_the_replies_so_far(gpt2_setup):
+    """``unfinished()`` records every request not yet completed as it stands,
+    changes nothing, and each record is a prefix of the completed reply: its
+    tokens, and the moments of the tokens read so far."""
+    cfg, params = gpt2_setup
+    eng = ServingEngine(
+        gpt2.apply_cached, gpt2.init_cache, params, cfg,
+        serving=ServingConfig(block_size=4, num_blocks=40, max_slots=2, prefill_chunk=8, max_blocks_per_seq=8),
+    )
+    rng = np.random.default_rng(5)
+    ids = [eng.submit(list(rng.integers(0, cfg.vocab_size, size=n)), 8, arrival_t=time.monotonic()) for n in (5, 9, 6)]
+    for _ in range(5):
+        eng.step()
+    assert eng.pop_finished() == []
+    early = {c.id: c for c in eng.unfinished()}
+    assert sorted(early) == ids and {c.status for c in early.values()} == {"in_flight"}
+    assert early[ids[2]].new_tokens == 0 and early[ids[2]].ttft_ms is None  # still queued behind two slots
+    assert 0 < early[ids[0]].new_tokens < 8 and len(early[ids[0]].inter_token_ms) == early[ids[0]].new_tokens - 1
+    assert {c.id: c.tokens for c in eng.unfinished()} == {i: c.tokens for i, c in early.items()}  # nothing moved
+    eng.run(max_ticks=200)
+    done = {c.id: c for c in eng.pop_finished()}
+    assert eng.unfinished() == [] and sorted(done) == ids
+    for i, c in early.items():
+        assert done[i].tokens[: len(c.tokens)] == c.tokens
+        assert done[i].inter_token_ms[: len(c.inter_token_ms)] == c.inter_token_ms
+        assert c.ttft_ms is None or c.ttft_ms == done[i].ttft_ms
 
 
 def test_coordinated_guard_uses_local_flag_not_collective(gpt2_setup):

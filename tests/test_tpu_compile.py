@@ -375,6 +375,51 @@ def check_window_step(width, fused=False, in_place=False):
     return "temp_bytes=" + "/".join(map(str, temps))
 
 
+def check_sparse_step(width, fused=False):
+    # serving/programs.py's decode and decode_chunk for models/keye_vl2.py at the cut the benchmark serves
+    # (keye-vl-2.0-30b-a3b: 4 of 48 layers at the published widths, the cell's geometry): K/V [4, 32768, 16, 4, 128] and
+    # the index keys of two layers a row, ki [2, 32768, 16, 128], both read where they lie.  The three scopes of the sparse
+    # attention are in the executable; no result is as large as a leaf or a layer's slice of one but the scatters of the
+    # new rows; and the decoding lanes read the rows their selection names, not their context: with the lanes alone
+    # (decode) the program holds less than a quarter of the K/V context a dense read of its tables would gather.
+    import re
+    from accelerate_tpu.models import keye_vl2 as kv
+    from accelerate_tpu.models.generation import make_paged_pool
+    from accelerate_tpu.serving import ServingConfig, programs as P
+
+    blocks, slots, chunk_rows = 32768, 16, 32
+    c = kv.KeyeVl2Config(num_layers=4, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False)
+    place = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: kv.init_params(c, jax.random.key(0))))
+    pool = place(jax.eval_shape(lambda: make_paged_pool(kv.init_cache, c, blocks, 16)))
+    assert pool["k"].shape == (4, blocks, 16, 4, 128) and pool["ki"].shape == (2, blocks, 16, 128)
+    serving = ServingConfig(block_size=16, num_blocks=blocks, max_slots=slots, max_blocks_per_seq=2048, prefill_chunk=chunk_rows)
+    built = P.build_programs(kv.apply_cached, c, ["k", "v", "ki"], serving, 0)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    lanes = (i32(slots, width), i32(slots), i32(slots, 1), i32(slots), i32(slots + 1), i32(slots))
+    chunk = (i32(width), i32(), i32(1, chunk_rows), i32())
+    programs = {"decode": (built.decode, lanes), "decode_chunk": (built.decode_chunk, (*lanes, *chunk))}
+    sized = re.compile(r"= \w+\[(%d|4,%d|2,%d|%d),16," % (4 * blocks, blocks, blocks, blocks))
+    dense_context = 2 * slots * width * 16 * 4 * 128 * 2  # K and V of every row the lanes' tables name, one layer
+    temps = []
+    for name, (program, args) in programs.items():
+        compiled = program.lower(params, pool, *args).compile()
+        text = compiled.as_text()
+        lines = [line for line in text.splitlines() if not re.search(r"\} (parameter|bitcast|get-tuple-element)\(", line)]
+        moved = [line.strip()[:160] for line in lines if sized.search(line) and "scatter(" not in line and "kv_pool.write/scatter" not in line]
+        if moved:
+            raise AssertionError(f"{name} moves arrays as large as a pool leaf besides the scatter of the new rows: " + " ;; ".join(moved[:4]))
+        missing = [s for s in ("attn.index", "attn.select", "attn.sparse") if s not in text]
+        if missing:
+            raise AssertionError(f"{name}: the scopes {missing} are not in the executable's op names")
+        check_grouped_product(text, fused, name + ": ")
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        if name == "decode" and temp > dense_context // 4:
+            raise AssertionError(f"decode: {temp} bytes of temporaries, a dense read of the lanes' K/V is {dense_context}")
+        temps.append(temp)
+    return "temp_bytes=" + "/".join(map(str, temps))
+
+
 # Bytes of temporaries of serving/programs.py's two programs at the parent of PR 39, where every group gathered its
 # context (this file's child, run in that checkout): what a program whose decoding lanes read the pool in place undercuts.
 # The chat cell's held 354,816 / 548,352 B at widths 64 and 256 alike (XLA fuses each layer's gather into its consumers
@@ -521,6 +566,9 @@ for spec in sys.argv[2:]:
         if case.startswith("window_step"):
             print("COMPILED", spec, check_window_step(int(hd), fused, in_place), flush=True)
             continue
+        if case.startswith("sparse_step"):
+            print("COMPILED", spec, check_sparse_step(int(hd), fused), flush=True)
+            continue
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
         if "tpu_custom_call" not in compiled.as_text() and not case.startswith("paged_step"):
@@ -614,8 +662,12 @@ CASES = [
     ("chat_step_in_place", 64, 0),
     ("chat_step_in_place", 256, 0),
     ("chat_step", 256, 0),
+    # serving/programs.py's two programs for models/keye_vl2.py at the keye-vl-2.0-30b-a3b cell's cut and geometry, as a
+    # TPU builds them, at tables of 1,024 blocks: the decoding lanes score every index key and read the K/V rows their
+    # top 2,048 name, nothing pool-sized but the scatters
+    ("sparse_step_fused", 1024, 0),
 ]
-IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step", "window_step", "chat_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
+IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step", "window_step", "chat_step", "sparse_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
        else f"{c}-w{h}-k{b}" if c == "paged_kernel"
        else f"{c}-hd{h}-{'k' if c.startswith(('paged_step', 'latent_step')) else 'b'}{b}" for c, h, b in CASES]
 
