@@ -43,19 +43,24 @@ by 0: on a v5e at the benchmark cell's widest table that read and score take
 whole into another layout first) and 4.9 for the packed rows cut to the
 layer's half (PERF.md section 6).
 
-**Serving** (:func:`apply_paged`): a group gathers the index keys its tables
-name under ``kv_pool.gather``, as any family gathers its context; the
-decoding lanes (a group of one row a lane over tables wider than
-``index_topk`` rows) score every row under the scope ``attn.index``, take the
-top ``index_topk`` under ``attn.select``, and read **those rows alone** of K
-and V, by position, under ``attn.sparse``: ``L * 128 B`` of index keys and
-``min(L, 2048) * 2,048 B`` of K/V a lane a layer where a dense read is ``L *
-2,048 B``.  Any other group (a prefill chunk; tables narrower than
-``index_topk`` rows, where the selection is everything) gathers its K/V
-context under ``kv_pool.gather`` and attends over it under the causal mask
-and each query's selection.  The counters of a dispatch say what it read:
-``index_rows_scored``, ``sparse_rows_read``, ``sparse_lane_rows`` and
-``context_rows`` (:func:`apply_paged`).
+**Serving** (:func:`apply_paged`): the decoding lanes (a group of one row a
+lane over tables wider than ``index_topk`` rows) score every row of their own
+under the scope ``attn.index``: where :func:`index_reads_in_place` holds (a
+TPU, one device, tables of at least 1 MB of the leaf a lane) through the
+Pallas kernel of ``ops/pallas_paged_index.py``, which reads each lane's own
+index blocks where they lie in the pool, up to its position and no further,
+else over the index keys their tables name, gathered under
+``kv_pool.gather`` at the table's width as any family gathers its context.
+They take the top ``index_topk`` under ``attn.select``, and read **those rows
+alone** of K and V, by position, under ``attn.sparse``: ``L * 256 B`` of
+packed index keys and ``min(L, 2048) * 2,048 B`` of K/V a lane a layer where a
+dense read is ``L * 2,048 B``.  Any other group (a prefill chunk; tables
+narrower than ``index_topk`` rows, where the selection is everything) gathers
+its index keys and its K/V context under ``kv_pool.gather`` and attends over
+it under the causal mask and each query's selection.  The counters of a
+dispatch say what it read: ``index_rows_scored``, ``sparse_rows_read``,
+``sparse_lane_rows`` and ``context_rows``, and ``attn_rows_read``, the index
+rows the kernel copied (:func:`apply_paged`).
 
 Out of scope, and named so: the vision tower and M-RoPE's three position axes
 (for text tokens the three are equal and M-RoPE is this 1-D RoPE), the
@@ -406,13 +411,57 @@ def _gather_rows(leaf, tables, layer, idx) -> jax.Array:
     return jnp.take(leaf.reshape((layers * blocks * bs,) + leaf.shape[3:]), rows, axis=0, mode="clip")
 
 
-def _attend_selected_rows(q, k_new, v_new, qi, w, ki_ctx, pool, tables, starts, layer, c: KeyeVl2Config):
+def index_reads_in_place(leaf, rows: int, width: int) -> bool:
+    """Whether a group of ``rows`` rows a lane scores the index keys of the
+    leaf ``leaf`` (``ki [G, N, bs, D]``) through block tables ``width`` wide
+    **in place**: the Pallas kernel of ``ops/pallas_paged_index.py`` reads each
+    lane's own blocks where they lie (:func:`_index_scores_in_place`), in place
+    of the gather of every lane's table.  From static facts alone, as
+    ``generation.reads_in_place`` decides for K/V: a TPU runs the program on
+    one device, the group is the decoding lanes at one row a lane, the leaf is
+    bf16 whose block is whole ``(16, 128)`` tiles and a whole part of a row of
+    128 scores (``bs`` 16, 32, 64 or 128; ``D`` whole lanes), and its tables
+    hold at least ``MIN_IN_PLACE_TABLE_BYTES`` of it a lane: 256 blocks of 4
+    KB, the narrowest table on which the benchmark cell's lanes select (2,048
+    of 4,096 rows), where the probe's kernel already won (four layers 0.218
+    against 0.294 ms gathered, PERF.md section 6; narrower was not probed)."""
+    from ..parallel.sharding import _abstract_mesh
+    from . import generation
+
+    mesh = _abstract_mesh()
+    if not (generation._on_tpu() and (mesh.empty or mesh.size == 1) and rows == 1 and leaf.dtype == jnp.bfloat16):
+        return False
+    bs, d = leaf.shape[-2:]
+    if bs % 16 or 128 % bs or d % 128:
+        return False
+    return width * bs * d * leaf.dtype.itemsize >= generation.MIN_IN_PLACE_TABLE_BYTES
+
+
+@jax.named_scope("attn.index")
+def _index_scores_in_place(qi, w, ki_new, leaf, tables, starts, interpret: bool) -> jax.Array:
+    """The decoding lanes' index scores ``[B, W * bs]`` float32, one row a lane
+    at position ``starts``: queries ``qi [B, 1, Hi, D]`` (laid into their
+    layer's lanes) with weights ``w [B, 1, Hi]`` over the keys of every earlier
+    position, read where the leaf lies (``leaf``, ``tables`` as
+    ``generation.address_paged_leaf_by_layer`` hands them over), and over the
+    lane's own new row ``ki_new [B, 1, D]``, not in the pool yet, set at
+    ``starts`` here; ``MASKED`` past it."""
+    from ..ops.moe import pallas_module
+    from .generation import _admitted
+
+    lo, hi = _admitted(starts, 0)
+    scores = pallas_module("pallas_paged_index").paged_index_scores(
+        qi[:, 0], w[:, 0], leaf, tables, lo, hi, interpret=interpret)
+    own = _index_scores(qi, w, ki_new)[:, 0, 0]  # [B]
+    return scores.at[jnp.arange(scores.shape[0]), starts].set(own, mode="drop", unique_indices=True)
+
+
+def _attend_selected_rows(q, k_new, v_new, scores, pool, tables, starts, layer, c: KeyeVl2Config):
     """The decoding lanes' attention, one row a lane at position ``starts``:
-    the indexer over the index keys their tables name (``[B, P, pack * di]``, the new
-    row in place), the top ``index_topk`` positions of those the lane sees, and
+    of the index scores ``[B, P]`` of the rows their tables name (the new row's
+    at ``starts``), the top ``index_topk`` positions of those the lane sees, and
     attention over K/V read **at those positions alone**; the lane's own row,
     not in the pool yet, where it was chosen."""
-    scores = _index_scores(qi, w, ki_ctx)[:, 0]  # [B, P]
     with jax.named_scope("attn.select"):
         seen = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :] <= starts[:, None]
         _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), c.index_topk)
@@ -448,7 +497,7 @@ def sparse_counters(groups, config: KeyeVl2Config) -> dict:
     return {name: (c.num_layers * value).astype(jnp.int32) for name, value in out.items()}
 
 
-def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict):
+def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict, interpret: bool = False):
     """Forward over new tokens straight against the paged pool (the contract of
     ``llama.apply_paged``): ``groups`` is a short tuple of ``(tokens [B, T],
     tables [B, M], starts [B])``, lane ``b`` of a group at positions ``starts[b]
@@ -460,7 +509,12 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict):
     under the causal mask and each query's selection.  Returns (logits a group,
     the rows each group wrote ``{"k", "v": [B, L, T, K, hd], "ki": [B, L / pack,
     T, pack * di]}``, :func:`expert_counters` of the dispatch with
-    :func:`sparse_counters` beside them)."""
+    :func:`sparse_counters` beside them, and ``attn_rows_read``: where the
+    decoding lanes scored their index keys in place
+    (:func:`index_reads_in_place`), the rows the kernel copied, every block a
+    lane touches whole, summed over the lanes and the layers; else 0).
+    ``interpret`` scores the decoding lanes' index keys through the kernel in
+    the Pallas interpreter whatever the rule says (the CPU tests)."""
     from .generation import (
         _insert_rows,
         address_paged_leaf_by_layer,
@@ -468,15 +522,27 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict):
         gather_paged_context,
         group_positions,
         join_groups,
+        rows_read_in_place,
         split_groups,
     )
 
     c = config
     pack = c.index_pack
     shapes = [tokens.shape for tokens, _, _ in groups]
-    positions, masks = group_positions(groups, pool["k"].shape[2])
+    block_size = pool["k"].shape[2]
+    positions, masks = group_positions(groups, block_size)
+    # the decoding lanes (the first group, one row a lane, tables wider than the top-k) read the rows they select; where
+    # the rule says so they score their index keys where they lie
+    lane_tokens, lane_tables, lane_starts = groups[0]
+    sparse_lanes = lane_tokens.shape[1] == 1 and lane_tables.shape[1] * block_size > c.index_topk
+    in_place = sparse_lanes and (interpret or index_reads_in_place(pool["ki"], 1, lane_tables.shape[1]))
     joined = join_groups(positions)
     x = _embed(params, join_groups([tokens for tokens, _, _ in groups]), c)
+
+    def gathered(leaf, tables, new_rows, starts):
+        """A group's context of a leaf, the blocks its tables name, its new rows in place."""
+        with jax.named_scope("kv_pool.gather"):
+            return _insert_rows(gather_paged_context(leaf, tables), new_rows, starts)
 
     def body(x, lp, layer, held):
         with jax.named_scope("attn"):
@@ -491,17 +557,15 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict):
                 # the rows are read whole and the queries laid into the slot, never the row cut (PERF.md section 6)
                 slot = layer % pack
                 ki_leaf, ki_tables = address_paged_leaf_by_layer(pool["ki"], tables, layer // pack)
-                with jax.named_scope("kv_pool.gather"):
-                    ki_ctx = _insert_rows(gather_paged_context(ki_leaf, ki_tables), _into_slot(ki_g, slot, c), starts)
-                qi_g = _into_slot(qi_g, slot, c)
-                if i == 0 and q_g.shape[1] == 1 and mask.shape[-1] > c.index_topk:
-                    attn.append(_attend_selected_rows(
-                        q_g, k_g, v_g, qi_g, w_g, ki_ctx, pool, tables, starts, layer, c))
+                qi_g, ki_row = _into_slot(qi_g, slot, c), _into_slot(ki_g, slot, c)
+                if i == 0 and sparse_lanes:
+                    scores = (_index_scores_in_place(qi_g, w_g, ki_row, ki_leaf, ki_tables, starts, interpret) if in_place
+                              else _index_scores(qi_g, w_g, gathered(ki_leaf, ki_tables, ki_row, starts))[:, 0])
+                    attn.append(_attend_selected_rows(q_g, k_g, v_g, scores, pool, tables, starts, layer, c))
                 else:
                     pk, pv, ltab = address_paged_pool_by_layer({"k": pool["k"], "v": pool["v"]}, tables, layer)
-                    with jax.named_scope("kv_pool.gather"):
-                        k_ctx = _insert_rows(gather_paged_context(pk, ltab), k_g, starts)
-                        v_ctx = _insert_rows(gather_paged_context(pv, ltab), v_g, starts)
+                    k_ctx, v_ctx = gathered(pk, ltab, k_g, starts), gathered(pv, ltab, v_g, starts)
+                    ki_ctx = gathered(ki_leaf, ki_tables, ki_row, starts)
                     attn.append(_attention(q_g, qi_g, w_g, k_ctx, v_ctx, ki_ctx, mask, c))
                 stored.append((k_g, v_g, ki_g))
             x = x + _out_proj(join_groups(attn), lp, c)
@@ -519,7 +583,9 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict):
         for k_rows, v_rows, ki_rows in stored)
     row_tile = expert_row_tile(  # which grouped product this dispatch's expert layers ran
         x.size // c.hidden_size * c.num_experts_per_tok, c.num_experts, c.hidden_size, c.moe_intermediate_size, c.dtype)
-    return split_groups(logits, shapes), rows, {**expert_counters(group_sizes, row_tile), **sparse_counters(groups, c)}
+    read = c.num_layers * rows_read_in_place(lane_starts, block_size) if in_place else jnp.zeros((), jnp.int32)
+    counters = {**expert_counters(group_sizes, row_tile), **sparse_counters(groups, c), "attn_rows_read": read}
+    return split_groups(logits, shapes), rows, counters
 
 
 def generate(

@@ -186,19 +186,20 @@ def test_the_index_leaf_holds_two_layers_a_row():
     assert c.index_pack == 1 and kv.init_cache(c, 1, 8)["ki"].shape == (3, 1, 8, 128)
 
 
-def paged_run(c, params, tokens, prompt, chunk, lanes=1):
+def paged_run(c, params, tokens, prompt, chunk, lanes=1, interpret=False, block=BLOCK):
     """The serving path by hand: one sequence in lane 0 of ``lanes``, its prompt in padded chunks of ``chunk`` and
-    then a row a dispatch, through ``apply_paged`` over a pool of junk, written by the engine's own ``_write_rows``.
-    Returns (logits of every real row, the counters of every dispatch)."""
-    blocks = -(-(len(tokens) + chunk) // BLOCK)
-    pool = make_paged_pool(kv.init_cache, c, blocks + 2, BLOCK)
+    then a row a dispatch, through ``apply_paged`` over a pool of junk in blocks of ``block`` rows, written by the
+    engine's own ``_write_rows`` (``interpret``: the decoding lanes score their index keys through the paged kernel
+    in the Pallas interpreter).  Returns (logits of every real row, the counters of every dispatch)."""
+    blocks = -(-(len(tokens) + chunk) // block)
+    pool = make_paged_pool(kv.init_cache, c, blocks + 2, block)
     pool = jax.tree.map(lambda leaf: jnp.full_like(leaf, 7.0), pool)  # junk in every block: what is read was written
     tables = np.zeros((lanes, blocks), np.int32)
-    tables[0] = 1 + np.arange(blocks)
+    tables[0] = 1 + np.arange(blocks)[::-1]  # the blocks of a sequence out of order in the pool
 
     @jax.jit
     def dispatch(pool, toks, starts):
-        logits, rows, counters = kv.apply_paged(params, ((toks, tables, starts),), c, pool)
+        logits, rows, counters = kv.apply_paged(params, ((toks, tables, starts),), c, pool, interpret=interpret)
         return logits[0], P._write_rows(pool, rows[0], tables, starts, toks.shape[1]), counters
 
     got, counters = [], []
@@ -228,12 +229,32 @@ def test_paged_is_the_references_full_forward(fam, model, chunk):
     assert np.max(np.abs(got - reference_logits(fam, cfg, params, tokens))) < TOL
 
 
+@pytest.mark.parametrize("chunk,block", [(4, BLOCK), (16, 16)])
+def test_the_lanes_index_scores_read_in_place_are_the_gathered_paths(fam, model, chunk, block):
+    """The decoding lanes score their index keys through ``ops/pallas_paged_index.py`` (the Pallas interpreter), the
+    chunk's group gathers them as ever, in blocks of 4 and of 16 rows: the logits are the gathered path's and the
+    reference's, and ``attn_rows_read`` counts what the kernel copied, every block a lane touches whole, a layer."""
+    cfg, c, params = model
+    tokens = some_tokens(48, 4)
+    gathered, counters_gathered = paged_run(c, params, tokens, 23, chunk, lanes=2, block=block)
+    got, counters = paged_run(c, params, tokens, 23, chunk, lanes=2, interpret=True, block=block)
+    assert np.max(np.abs(got - gathered)) < 1e-5
+    assert np.max(np.abs(got - reference_logits(fam, cfg, params, tokens))) < TOL
+    assert all(count["attn_rows_read"] == 0 for count in counters_gathered)
+    prefill = -(-23 // chunk)
+    assert all(count["attn_rows_read"] == 0 for count in counters[:prefill])  # a chunk gathers
+    # lane 0 decodes at position 23 .. 47 and scores rows 0 .. p - 1 in place; the idle lane at 0 copies nothing
+    assert [count["attn_rows_read"] for count in counters[prefill:]] == [
+        c.num_layers * -(-p // block) * block for p in range(23, 48)]
+
+
 def test_counters_of_a_dispatch_against_hand_worked_numbers(model):
     cfg, c, params = model
     tokens = some_tokens(30, 5)
     _, counters = paged_run(c, params, tokens, 20, 8, lanes=3)
     first, last = counters[0], counters[-1]
-    assert set(last) == set(P.DISPATCH_COUNTERS) - {"window_rows_read", "attn_rows_read"}
+    assert set(last) == set(P.DISPATCH_COUNTERS) - {"window_rows_read"}
+    assert all(count["attn_rows_read"] == 0 for count in counters)  # off the TPU the lanes gather their index keys
     # the last dispatch: three lanes of one row, the first at position 29 (the others hold no sequence); three layers
     # score its 30 index keys and attend over 8 of them where a full mask admits 30
     assert (last["index_rows_scored"], last["sparse_lane_rows"]) == (3 * 30, 3 * 8)

@@ -375,13 +375,15 @@ def check_window_step(width, fused=False, in_place=False):
     return "temp_bytes=" + "/".join(map(str, temps))
 
 
-def check_sparse_step(width, fused=False):
+def check_sparse_step(width, fused=False, in_place=False):
     # serving/programs.py's decode and decode_chunk for models/keye_vl2.py at the cut the benchmark serves
     # (keye-vl-2.0-30b-a3b: 4 of 48 layers at the published widths, the cell's geometry): K/V [4, 32768, 16, 4, 128] and
     # the index keys of two layers a row, ki [2, 32768, 16, 128], both read where they lie.  The three scopes of the sparse
     # attention are in the executable; no result is as large as a leaf or a layer's slice of one but the scatters of the
     # new rows; and the decoding lanes read the rows their selection names, not their context: with the lanes alone
-    # (decode) the program holds less than a quarter of the K/V context a dense read of its tables would gather.
+    # (decode) the program holds less than a quarter of the K/V context a dense read of its tables would gather.  Where
+    # the decoding lanes score their index keys in place (check_index_in_place), both programs hold the kernel, and none
+    # of them the lanes' index-key context.
     import re
     from accelerate_tpu.models import keye_vl2 as kv
     from accelerate_tpu.models.generation import make_paged_pool
@@ -416,8 +418,35 @@ def check_sparse_step(width, fused=False):
         temp = compiled.memory_analysis().temp_size_in_bytes
         if name == "decode" and temp > dense_context // 4:
             raise AssertionError(f"decode: {temp} bytes of temporaries, a dense read of the lanes' K/V is {dense_context}")
+        check_index_in_place(text, temp, in_place, width, name)
         temps.append(temp)
     return "temp_bytes=" + "/".join(map(str, temps))
+
+
+# Bytes of temporaries of the keye programs at tables of 2,048 blocks where every group gathers its index keys (the
+# parent of the kernel's PR, sparse_step_fused at this width): 136,259,584 / 138,209,792 B, the lanes' index-key context
+# [16, 32768, 128] bf16 (134 MB) among them
+SPARSE_GATHERED_TEMP_BYTES = {"decode": 136_259_584, "decode_chunk": 138_209_792}
+INDEX_KERNEL = "paged_index_scores"  # ops/pallas_paged_index.py's pallas_call(name=)
+
+
+def check_index_in_place(text, temp, in_place, width, name):
+    # The decoding lanes score their index keys through the kernel, one custom call in the layer loop, the chunk's group
+    # gathered as ever; the programs no longer hold the lanes' index-key context: what they hold besides their arguments
+    # is less than half of it, and at least half of it under the gathered programs' (their 136-138 MB overlap the
+    # context with the selected K/V rows, 34 MB, which both paths hold)
+    import re
+    kernel = re.search(r"%%%s[.\d]* = \S+ custom-call\(" % INDEX_KERNEL, text)
+    if not in_place:
+        if kernel:
+            raise AssertionError(f"{name}: the index kernel is in a program whose rule keeps the gathered path")
+        return
+    if not kernel:
+        raise AssertionError(f"{name}: the executable does not hold the index kernel as a custom call")
+    context = 16 * width * 16 * 128 * 2
+    if temp >= context // 2 or temp > SPARSE_GATHERED_TEMP_BYTES[name] - context // 2:
+        raise AssertionError(f"{name}: {temp} bytes of temporaries, the lanes' index-key context is {context}, "
+                             f"{SPARSE_GATHERED_TEMP_BYTES[name]} gathered")
 
 
 # Bytes of temporaries of serving/programs.py's two programs at the parent of PR 39, where every group gathered its
@@ -567,7 +596,7 @@ for spec in sys.argv[2:]:
             print("COMPILED", spec, check_window_step(int(hd), fused, in_place), flush=True)
             continue
         if case.startswith("sparse_step"):
-            print("COMPILED", spec, check_sparse_step(int(hd), fused), flush=True)
+            print("COMPILED", spec, check_sparse_step(int(hd), fused, in_place), flush=True)
             continue
         f, args = program(case, int(hd), int(b))
         compiled = jax.jit(f, donate_argnums=getattr(f, "donate", ())).lower(*args).compile()
@@ -666,6 +695,9 @@ CASES = [
     # TPU builds them, at tables of 1,024 blocks: the decoding lanes score every index key and read the K/V rows their
     # top 2,048 name, nothing pool-sized but the scatters
     ("sparse_step_fused", 1024, 0),
+    # ... and at the cell's widest table, 2,048 blocks, where the decoding lanes score their index keys where they lie
+    # (ops/pallas_paged_index.py): the kernel in both programs, the lanes' index-key context in neither
+    ("sparse_step_in_place", 2048, 0),
 ]
 IDS = [f"{c}-w{h}" if c.startswith(("mixed_step", "state_step", "window_step", "chat_step", "sparse_step")) else f"{c}-pairs{h}-e{b}" if c == "moe_kernel"
        else f"{c}-w{h}-k{b}" if c == "paged_kernel"
