@@ -15,9 +15,15 @@ Notation: ``n = RMSNorm(x)`` for a position's input ``x``; positions ``t``
   ``RoPE_I`` turns the leading ``index_rope_dim`` features of a head (the
   half-split pairing inside them, at ``rope_theta``) and leaves the rest.
 - Selection: ``S_t`` = the ``index_topk`` positions ``s <= t`` with the largest
-  ``I_{t,s}`` (``lax.top_k``, exact: lower positions first among equal
-  scores); all of ``s <= t`` while ``t < index_topk``.  The current token is a
-  candidate like any other; one ``S_t`` for every query head.
+  ``I_{t,s}`` (exact: lower positions first among equal scores, the set
+  ``lax.top_k`` names); all of ``s <= t`` while ``t < index_topk``.  The
+  current token is a candidate like any other; one ``S_t`` for every query
+  head.  A query's selection as a mask (:func:`_selection`: a chunk, the
+  training and cached forwards) is found by an exact radix select, no sort:
+  the ``index_topk``-th largest score's bits fixed two at a time by counting
+  passes, every score above it, and of those equal to it the ones of lowest
+  position; the decoding lanes, which read their chosen rows by position,
+  take them from ``lax.top_k`` (:func:`_attend_selected_rows`).
 - ``o_t = W_o concat_h sum_{s in S_t} softmax_s(q_{t,h} . k_s / sqrt(128))
   v_s`` (GQA); ``a = x + o``.
 - The expert layer, SDAR's (``models/sdar_moe.py:_ffn``): ``y = a + sum_{e in
@@ -60,7 +66,8 @@ its index keys and its K/V context under ``kv_pool.gather`` and attends over
 it under the causal mask and each query's selection.  The counters of a
 dispatch say what it read: ``index_rows_scored``, ``sparse_rows_read``,
 ``sparse_lane_rows`` and ``context_rows``, and ``attn_rows_read``, the index
-rows the kernel copied (:func:`apply_paged`).
+rows the kernel copied; ``select_tied_rows`` the chunk's queries whose
+selection cut keys tied at its threshold by position (:func:`apply_paged`).
 
 Out of scope, and named so: the vision tower and M-RoPE's three position axes
 (for text tokens the three are equal and M-RoPE is this 1-D RoPE), the
@@ -240,16 +247,65 @@ def _index_scores(qi, w, keys) -> jax.Array:
     return jnp.einsum("bthp,bth->btp", jax.nn.relu(dots), w)
 
 
+# Bits of a threshold that one counting pass of _kth_largest fixes.  On a v5e at the chunk's [1, 32, 32768] (PERF.md
+# section 6) a selection took 0.12 ms at 2 bits (24 passes, each about one read of the 4 MB of keys), 0.33 at 4 and
+# 1.22 at 8 (the compares then bound it), against 1.18 for lax.top_k and its scatter.
+RADIX_BITS = 2
+
+
+def _kth_largest(keys, k, bits: int) -> jax.Array:
+    """Per row of ``keys [..., P]`` (uint32, below ``2 ** bits``), the largest
+    ``t`` that at least ``k [...]`` keys reach (the ``k``-th largest key, for
+    ``1 <= k <= P``), fixed from the top bit down ``RADIX_BITS`` at a time: a
+    pass counts in one reduction the keys at or above each extension of ``t``
+    by a nonzero digit, and ``t`` takes the largest digit whose count still
+    reaches ``k`` (counts fall as the digit grows; ``t`` itself reaches ``k``)."""
+    t = jnp.zeros(keys.shape[:-1], jnp.uint32)
+    digits = jnp.arange(1, 2**RADIX_BITS, dtype=jnp.uint32)
+    for shift in range(-(-bits // RADIX_BITS) * RADIX_BITS - RADIX_BITS, -1, -RADIX_BITS):
+        counts = jnp.sum(keys[..., None, :] >= (t[..., None] | (digits << shift))[..., None], axis=-1, dtype=jnp.int32)
+        t = t | (jnp.sum(counts >= k[..., None], axis=-1, dtype=jnp.uint32) << shift)
+    return t
+
+
+def _score_keys(scores, mask) -> jax.Array:
+    """uint32 keys in the order of float32 ``scores`` (a negative score's bits
+    inverted, any other's sign bit set: ``+0.0`` above ``-0.0``, as
+    ``lax.top_k`` orders them), 0 where ``mask`` admits no key: below every
+    admitted score's."""
+    u = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
+    return jnp.where(mask, jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31)), jnp.uint32(0))
+
+
 @jax.named_scope("attn.select")
 def _selection(scores, mask, topk: int) -> jax.Array:
     """Each query's selection ``[B, T, P]``: the ``topk`` keys its ``mask``
-    admits with the largest scores, or all of them where it admits no more."""
-    if mask.shape[-1] <= topk:
+    admits with the largest scores, lower positions first among equal scores
+    (the set ``lax.top_k`` names), or all of them where it admits no more.  By
+    an exact radix select, no sort: the ``topk``-th largest score's key ``t``
+    (:func:`_kth_largest`), every key above it, and of the keys equal to it the
+    ``topk - #above`` of lowest position (the same select over their positions
+    reversed)."""
+    p = mask.shape[-1]
+    if p <= topk:
         return mask
-    _, idx = jax.lax.top_k(jnp.where(mask, scores, -jnp.inf), topk)
-    b = jnp.arange(idx.shape[0], dtype=jnp.int32)[:, None, None]
-    t = jnp.arange(idx.shape[1], dtype=jnp.int32)[None, :, None]
-    return jnp.zeros(mask.shape, bool).at[b, t, idx].set(True) & mask
+    keys = _score_keys(scores, mask)
+    t = _kth_largest(keys, jnp.full(mask.shape[:-1], topk, jnp.int32), 32)
+    above = keys > t[..., None]
+    need = topk - jnp.sum(above, axis=-1, dtype=jnp.int32)  # >= 1: fewer than topk keys lie above the topk-th
+    first = jnp.where(keys == t[..., None], jnp.uint32(p) - jnp.arange(p, dtype=jnp.uint32), jnp.uint32(0))
+    # & mask: where a query admits fewer than topk keys, t is 0, the key of every one it does not admit
+    return (above | (first >= _kth_largest(first, need, p.bit_length())[..., None])) & mask
+
+
+@jax.named_scope("attn.select")
+def _ties_left_out(scores, mask, chosen) -> jax.Array:
+    """``[B, T]``: whether a query's selection ``chosen`` left out a key its
+    ``mask`` admits whose score equals (to the bit) the least it took: the
+    queries whose selection the cut by position decided."""
+    keys = _score_keys(scores, mask)
+    least = jnp.min(jnp.where(chosen, keys, jnp.uint32(2**32 - 1)), axis=-1, keepdims=True)
+    return jnp.any(mask & ~chosen & (keys == least), axis=-1)
 
 
 def _attend(q, k_ctx, v_ctx, mask, c: KeyeVl2Config) -> jax.Array:
@@ -512,7 +568,9 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict, interpr
     :func:`sparse_counters` beside them, and ``attn_rows_read``: where the
     decoding lanes scored their index keys in place
     (:func:`index_reads_in_place`), the rows the kernel copied, every block a
-    lane touches whole, summed over the lanes and the layers; else 0).
+    lane touches whole, summed over the lanes and the layers; else 0; and
+    ``select_tied_rows``: the queries of the other groups, summed over the
+    layers, whose selection left out a key tied at its threshold).
     ``interpret`` scores the decoding lanes' index keys through the kernel in
     the Pallas interpreter whatever the rule says (the CPU tests)."""
     from .generation import (
@@ -549,7 +607,7 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict, interpr
             h = _llama._rms_norm(x, lp["ln_attn"], c.rms_eps)
             q, k, v = _qkv(h, lp, c, joined)
             qi, w, ki = _index_proj(h, lp, c, joined)
-            attn, stored = [], []
+            attn, stored, cut = [], [], []
             for i, (q_g, k_g, v_g, qi_g, w_g, ki_g, (_, tables, starts), mask) in enumerate(zip(
                     *(split_groups(a, shapes) for a in (q, k, v, qi, w, ki)), groups, masks)):
                 k_g, v_g, ki_g = k_g.astype(pool["k"].dtype), v_g.astype(pool["v"].dtype), ki_g.astype(pool["ki"].dtype)
@@ -566,15 +624,18 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict, interpr
                     pk, pv, ltab = address_paged_pool_by_layer({"k": pool["k"], "v": pool["v"]}, tables, layer)
                     k_ctx, v_ctx = gathered(pk, ltab, k_g, starts), gathered(pv, ltab, v_g, starts)
                     ki_ctx = gathered(ki_leaf, ki_tables, ki_row, starts)
-                    attn.append(_attention(q_g, qi_g, w_g, k_ctx, v_ctx, ki_ctx, mask, c))
+                    scores = _index_scores(qi_g, w_g, ki_ctx)
+                    chosen = _selection(scores, mask, c.index_topk)
+                    attn.append(_attend(q_g, k_ctx, v_ctx, chosen, c))
+                    cut.append(jnp.sum(_ties_left_out(scores, mask, chosen), dtype=jnp.int32))
                 stored.append((k_g, v_g, ki_g))
             x = x + _out_proj(join_groups(attn), lp, c)
         x, sizes = _ffn(x, lp, c, held)
-        return x, (tuple(stored), sizes)
+        return x, (tuple(stored), sizes, tuple(cut))
 
     # the pool is a constant of the loop, addressed by layer in its body: never a scanned input
     with jax.named_scope("layers"):
-        x, (stored, group_sizes) = _scan_layers(
+        x, (stored, group_sizes, cuts) = _scan_layers(
             params, body, x, jnp.arange(c.num_layers, dtype=jnp.int32), hold_experts=True)
     with jax.named_scope("head"):
         logits = _head(params, x, c)
@@ -584,7 +645,8 @@ def apply_paged(params: dict, groups, config: KeyeVl2Config, pool: dict, interpr
     row_tile = expert_row_tile(  # which grouped product this dispatch's expert layers ran
         x.size // c.hidden_size * c.num_experts_per_tok, c.num_experts, c.hidden_size, c.moe_intermediate_size, c.dtype)
     read = c.num_layers * rows_read_in_place(lane_starts, block_size) if in_place else jnp.zeros((), jnp.int32)
-    counters = {**expert_counters(group_sizes, row_tile), **sparse_counters(groups, c), "attn_rows_read": read}
+    counters = {**expert_counters(group_sizes, row_tile), **sparse_counters(groups, c), "attn_rows_read": read,
+                "select_tied_rows": sum((jnp.sum(tied) for tied in cuts), jnp.zeros((), jnp.int32))}
     return split_groups(logits, shapes), rows, counters
 
 

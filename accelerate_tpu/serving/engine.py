@@ -2691,8 +2691,9 @@ class ServingEngine:
         """What ``stats()`` says of a family whose attention reads the rows an
         indexer selects: the index keys scored, the rows attended over and the
         rows a full causal mask would have admitted, each summed over the
-        layers, and the least rows of the lanes' selections; any other family
-        carries none of these keys."""
+        layers, the least rows of the lanes' selections, and the chunk's
+        queries whose selection cut a key tied at its threshold; any other
+        family carries none of these keys."""
         if not self._index_topk:
             return {}
         return {"index_topk": self._index_topk, **self.sparse_counters, "context_rows": self.window_counters["context_rows"]}

@@ -156,8 +156,10 @@ ATTN_COUNTERS = ("attn_rows_read",)
 # What a family whose attention reads the rows an indexer selects counts (``models/keye_vl2.py:sparse_counters``), each
 # summed over its layers and the lanes that hold a sequence: the index keys scored (a lane's context), the K/V rows the
 # queries attended over (``min(position + 1, topk)`` a query), and the rows the least read of a lane's selection takes
-# (``min(context, topk)`` a lane); ``context_rows`` above is what a full causal mask would have admitted.
-SPARSE_COUNTERS = ("index_rows_scored", "sparse_rows_read", "sparse_lane_rows")
+# (``min(context, topk)`` a lane); ``context_rows`` above is what a full causal mask would have admitted; and the
+# queries of a chunk whose selection left out a key tied at its threshold (``keye_vl2.apply_paged``: the exact select's
+# one data-dependent path).
+SPARSE_COUNTERS = ("index_rows_scored", "sparse_rows_read", "sparse_lane_rows", "select_tied_rows")
 # Every counter a dispatch can carry behind its ``ok`` flags, in the order they are packed; a family that returns
 # counters packs all of them, 0 for what it does not count, so that the host reads one layout.
 DISPATCH_COUNTERS = MOE_COUNTERS + WINDOW_COUNTERS + ATTN_COUNTERS + SPARSE_COUNTERS

@@ -140,6 +140,68 @@ def test_the_programs_selection_is_the_references(fam, model):
     assert np.max(np.abs(np.asarray(kv._index_scores(qi, w, ki))[0] - np.asarray(scores))) < 1e-5
 
 
+def sorted_selection(scores, mask, topk):
+    """``lax.top_k``'s set as a mask, lower positions first among equal scores (what the radix select is held
+    to), and whether it left out an admitted key of the same bits as one it took: a key tied at its threshold."""
+    _, idx = jax.lax.top_k(jnp.where(mask, scores, -jnp.inf), topk)
+    chosen = np.zeros(mask.shape, bool)
+    np.put_along_axis(chosen, np.asarray(idx), True, axis=-1)
+    chosen &= mask
+    bits = scores.view(np.uint32)
+    tied = np.array([np.isin(b[m & ~c], b[c]).any() for b, m, c in zip(
+        bits.reshape(-1, bits.shape[-1]), mask.reshape(-1, mask.shape[-1]), chosen.reshape(-1, chosen.shape[-1]))])
+    return chosen, tied.reshape(mask.shape[:-1])
+
+
+def selection_case(case, rng):
+    """(scores, mask, topk) of one case: rows of [2, 3, P] unless named."""
+    shape, topk = (2, 3, 64), 16
+    ints = rng.integers(0, 4, shape).astype(np.float32)
+    mask = rng.random(shape) < 0.7
+    if case == "ties_straddle_the_cut":
+        return ints, mask, topk
+    if case == "signed_zeros":
+        return rng.choice(np.float32([0.0, -0.0]), shape), mask, topk
+    if case == "all_equal":
+        return np.full(shape, 1.5, np.float32), mask, topk
+    if case == "negative":
+        return -np.abs(rng.normal(size=(2, 3, 257))).round(1).astype(np.float32), rng.random((2, 3, 257)) < 0.8, 37
+    if case.startswith("admits_"):
+        admitted = topk + {"admits_topk_minus_1": -1, "admits_topk": 0, "admits_topk_plus_1": 1}[case]
+        order = np.argsort(rng.random(shape), axis=-1)
+        return ints, order < admitted, topk
+    if case == "random_admit":
+        return rng.normal(size=(2, 3, 257)).astype(np.float32), rng.random((2, 3, 257)) < rng.random((2, 3, 1)), 29
+    if case == "topk_1":
+        return ints, mask, 1
+    if case == "topk_p_minus_1":
+        return ints, rng.random(shape) < 0.995, shape[-1] - 1
+    # the benchmark cell's chunk: 32 rows at positions 16,000 .. 16,031 over a table of 32,768, the top 2,048, the
+    # scores rounded to an eighth so that ties fall at the cut
+    p = 32768
+    scores = (np.round(rng.normal(size=(1, 32, p)) * 8) / 8).astype(np.float32)
+    return scores, (16000 + np.arange(32))[None, :, None] >= np.arange(p)[None, None, :], 2048
+
+
+SELECTION_CASES = ["ties_straddle_the_cut", "signed_zeros", "all_equal", "negative", "admits_topk_minus_1",
+                   "admits_topk", "admits_topk_plus_1", "random_admit", "topk_1", "topk_p_minus_1", "chunk_shape"]
+
+
+@pytest.mark.parametrize("case", SELECTION_CASES)
+def test_the_selection_is_lax_top_k_bit_for_bit(case):
+    """``_selection`` (a radix select over the scores' bits) names ``lax.top_k``'s set, ties and signed zeros and all,
+    and takes ``min(admitted, topk)`` keys a query; ``_ties_left_out`` flags the queries whose selection left out a key
+    tied at the threshold."""
+    scores, mask, topk = selection_case(case, np.random.default_rng(SELECTION_CASES.index(case)))
+    got = jax.jit(kv._selection, static_argnums=2)(scores, mask, topk)
+    want, want_tied = sorted_selection(scores, mask, topk)
+    assert (np.asarray(got) == want).all()
+    assert (np.asarray(got).sum(-1) == np.minimum(mask.sum(-1), topk)).all()
+    assert (np.asarray(jax.jit(kv._ties_left_out)(scores, mask, got)) == want_tied).all()
+    if case in ("ties_straddle_the_cut", "signed_zeros", "all_equal", "chunk_shape"):
+        assert want_tied.any()  # the case does cut ties
+
+
 @pytest.mark.parametrize("control", ["dense", "no_relu", "topk_1024"])
 def test_the_controls_are_told_from_the_program(fam, model, control):
     """The reference without the selection, without the score's ReLU, and at half the top-k (8 -> 4): every one moves
@@ -266,6 +328,23 @@ def test_counters_of_a_dispatch_against_hand_worked_numbers(model):
     second = counters[1]
     assert (second["index_rows_scored"], second["sparse_lane_rows"]) == (3 * 16, 3 * 8)
     assert (second["sparse_rows_read"], second["context_rows"]) == (3 * 8 * 8, 3 * sum(range(9, 17)))
+
+
+def test_select_tied_rows_against_hand_worked_numbers(model):
+    """With the indexer's head weights 0 every score is +0.0: all keys a query admits tie, and a query at position
+    ``p >= 8`` keeps positions 0 .. 7 and leaves the rest out.  ``select_tied_rows`` counts those queries of a chunk
+    (padded rows too) a layer; the decoding lanes (tables of 40 rows, wider than the top-k) take ``lax.top_k`` and
+    count none.  Through ``apply_paged`` and through the engine's ``stats()``."""
+    _, c, params = model
+    flat = {**params, "layers": {**params["layers"], "wwi": jnp.zeros_like(params["layers"]["wwi"])}}
+    _, counters = paged_run(c, flat, some_tokens(30, 5), 20, 8, lanes=3)
+    # chunks at 0 .. 7 (no query past the top-k), 8 .. 15 and 16 .. 23 (4 of them padding): 8 queries a layer each
+    assert [count["select_tied_rows"] for count in counters] == [0, 3 * 8, 3 * 8] + [0] * 10
+    eng = engine((None, c, flat))
+    eng.submit(some_tokens(13, 11), 6)
+    eng.run()
+    # chunks of 4 at 0 .. 3, 4 .. 7, 8 .. 11 and 12 .. 15 (3 of them padding): the last two cut 4 queries a layer each
+    assert eng.stats()["select_tied_rows"] == 3 * (4 + 4)
 
 
 def test_at_contexts_within_the_top_k_it_is_sdar_at_block_length_one(fam, model):

@@ -419,8 +419,33 @@ def check_sparse_step(width, fused=False, in_place=False):
         if name == "decode" and temp > dense_context // 4:
             raise AssertionError(f"decode: {temp} bytes of temporaries, a dense read of the lanes' K/V is {dense_context}")
         check_index_in_place(text, temp, in_place, width, name)
+        check_chunk_select(text, temp, in_place, width, name)
         temps.append(temp)
     return "temp_bytes=" + "/".join(map(str, temps))
+
+
+# Bytes of temporaries of the keye programs at tables of 2,048 blocks, the decoding lanes scoring in place, where the
+# chunk's selection was lax.top_k and a scatter (the parent of the radix select, sparse_step_in_place).  The select
+# holds a uint32 key a score where the sort held a float32 and an int32; XLA places its per-row values and the tie
+# count's reductions 0.3 MB above the sort's: SELECT_ROOM_BYTES is an eighth of the chunk's 4 MB of scores
+SORTED_SELECT_TEMP_BYTES = {"decode": 35_525_632, "decode_chunk": 37_025_280}
+SELECT_ROOM_BYTES = 2**19
+
+
+def check_chunk_select(text, temp, in_place, width, name):
+    # The chunk's selection is an exact radix select (keye_vl2._selection): no sort of its 32 rows of scores over the
+    # table, [1, 32, W * 16], in either program (the largest sort of the parent's decode_chunk); the decoding lanes keep
+    # lax.top_k, a sort of their [16, W * 16] scores, in both; where the lanes read in place, no more temporaries than
+    # the sorted selection's programs beyond SELECT_ROOM_BYTES (a count materialized as [1, 32, 3, W * 16], 12 MB, or
+    # a second key row, 4 MB, would show)
+    import re
+    sorts = [m.group(1) for m in re.finditer(r"= \(\w+\[([\d,]+)\][^\n]* sort\(", text)]
+    if f"1,32,{width * 16}" in sorts:
+        raise AssertionError(f"{name}: the chunk's scores are sorted ({sorts})")
+    if f"16,{width * 16}" not in sorts:
+        raise AssertionError(f"{name}: no sort of the decoding lanes' [16, {width * 16}] scores ({sorts})")
+    if in_place and temp > SORTED_SELECT_TEMP_BYTES[name] + SELECT_ROOM_BYTES:
+        raise AssertionError(f"{name}: {temp} bytes of temporaries, {SORTED_SELECT_TEMP_BYTES[name]} with the sorted selection")
 
 
 # Bytes of temporaries of the keye programs at tables of 2,048 blocks where every group gathers its index keys (the
